@@ -563,6 +563,18 @@ def _free_port(host: str = "127.0.0.1") -> int:
         return s.getsockname()[1]
 
 
+def _backend() -> str:
+    """The jax backend this process has initialised, ``""`` if none yet —
+    the spawn guard's probe.  It never initialises one itself: a launcher
+    that stays off jax holds no chip and must not take one by asking."""
+    import jax
+    from jax._src import xla_bridge
+
+    if not xla_bridge.backends_are_initialized():
+        return ""
+    return jax.default_backend()
+
+
 def _repo_root() -> str:
     return os.path.dirname(
         os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
@@ -666,6 +678,19 @@ class BridgeFleet:
             return
         env = dict(os.environ)
         env.update(self.base_env)
+        platforms = env.get("JAX_PLATFORMS", "").lower().split(",")
+        if (not platforms[0] or "tpu" in platforms) and _backend() == "tpu":
+            # a chip belongs to one process: a replica whose environment
+            # names no platform, or names the TPU, would reach for the chip
+            # this process holds and die or hang out ready_timeout_s.
+            # Assigning chips to replicas is not built; keep them off it.
+            raise RuntimeError(
+                f"refusing to spawn fleet replica {rep.name}: this process "
+                f"holds the TPU and the replica's JAX_PLATFORMS="
+                f"{env.get('JAX_PLATFORMS', '')!r} would have it contend "
+                f"for the same chip — pass base_env={{'JAX_PLATFORMS': "
+                f"'cpu', ...}} (or run thread-mode replicas in this process)"
+            )
         fault = self.fault_env.get(rep.name)
         if fault is not None:
             env["TFS_FAULT_INJECT"] = fault

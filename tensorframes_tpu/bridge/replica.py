@@ -10,10 +10,13 @@ SIGKILL (the ``replica_kill`` fault, or an impatient operator) skips
 all of that, which is the point: the fleet's journal-backed migration
 is what makes that death survivable.
 
-Everything else — shared compile cache, journal dir, fleet registry,
-fault specs — arrives via the environment the spawner
+Everything else — journal dir, fleet registry, fault specs — arrives
+via the environment the spawner
 (:class:`~tensorframes_tpu.bridge.fleet.BridgeFleet`) builds, so this
-module stays a thin arg-parse around :func:`serve`.
+module stays a thin arg-parse around :func:`serve`.  The compile cache
+is the entry points' one (``compile_cache.configure_entry_point``):
+``JAX_COMPILATION_CACHE_DIR``, else ``TFS_COMPILE_CACHE``, else
+``<checkout>/.cache/jax`` — shared by every replica either way.
 """
 
 from __future__ import annotations
@@ -51,8 +54,10 @@ def main(argv=None) -> int:
 
         env_set_default(ENV_FLEET_REPLICA, args.name)
 
+    from .. import compile_cache
     from .server import serve
 
+    compile_cache.configure_entry_point()
     server = serve(host=args.host, port=args.port, background=True)
     log.info(
         "replica %s pid=%d serving on %s:%d",

@@ -85,9 +85,19 @@ def _pool(x, attrs, reducer, init, avg=False):
         x, init, reducer, tuple(ksize), tuple(strides), padding
     )
     if avg:
-        ones = jnp.ones_like(x)
+        # window population per output position.  The ones tensor keeps
+        # extent 1 on every axis the window does not span (batch,
+        # channels) and broadcasts in the divide: XLA folds this
+        # reduce_window at compile time with its slow evaluator, and at
+        # x's full shape that cost 611 s for Inception-v3's nine SAME
+        # avg-pools at 128 rows on the v5e (PR 21 chip run).
+        span = [
+            n if k > 1 or s > 1 else 1
+            for n, k, s in zip(x.shape, ksize, strides)
+        ]
         counts = lax.reduce_window(
-            ones, 0.0, lax.add, tuple(ksize), tuple(strides), padding
+            jnp.ones(span, x.dtype), 0.0, lax.add, tuple(ksize),
+            tuple(strides), padding,
         )
         out = out / counts
     return out

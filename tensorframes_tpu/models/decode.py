@@ -203,7 +203,7 @@ def _generate_jit(
 ):
     """The whole generation — weight cast, prefill, scanned decode — as
     ONE compiled dispatch (the eager per-op prefill used to dominate
-    single-stream latency over a remote link, docs/PERF.md).
+    single-stream latency, docs/PERF.md).
 
     Static args are the ones that change shapes or branches (``cfg``,
     token count, ``top_k``, greedy/nucleus flags); ``temperature`` and

@@ -38,8 +38,7 @@ def _conv_init(key, kh, kw, cin, cout, dtype):
     fan_in = kh * kw * cin
     # host-side numpy init (He-normal): params stay numpy until the jitted
     # scoring program captures them, so construction costs ZERO device
-    # dispatches — a jax.random draw per conv (~190 of them) costs seconds
-    # of pure dispatch latency on a remote/tunneled TPU
+    # dispatches (a jax.random draw per conv would be ~190 of them)
     w = (key.randn(kh, kw, cin, cout) * np.sqrt(2.0 / fan_in)).astype(dtype)
     # folded inference BatchNorm: y = conv(x) * scale + shift
     return {
@@ -304,15 +303,11 @@ def init(rng, dtype=jnp.bfloat16) -> Params:
     """Build frozen-inference parameters as HOST numpy arrays.
 
     ``rng`` is an int seed or a jax PRNGKey (only its entropy is used).
-    Host-side construction matters on remote TPUs: params are captured by
-    the jitted scoring program and shipped in one transfer, instead of one
-    device dispatch per weight tensor."""
-    if hasattr(rng, "dtype"):
-        try:  # new-style typed keys (jax.random.key) are ndim-0
-            if jnp.issubdtype(rng.dtype, jax.dtypes.prng_key):
-                rng = jax.random.key_data(rng)
-        except Exception:
-            pass
+    Host-side construction: params are captured by the jitted scoring
+    program and shipped in one transfer, instead of one device dispatch
+    per weight tensor."""
+    if hasattr(rng, "dtype") and jnp.issubdtype(rng.dtype, jax.dtypes.prng_key):
+        rng = jax.random.key_data(rng)  # typed keys (jax.random.key) are ndim-0
     if hasattr(rng, "dtype") and getattr(rng, "ndim", 0) >= 1:
         seed = int(np.asarray(rng).reshape(-1)[-1])
     else:

@@ -148,14 +148,6 @@ class TransformerConfig:
         return self.d_model // self.n_heads
 
 
-def _abstract_mesh():
-    """The ambient abstract mesh, or None on jax versions without the
-    ``get_abstract_mesh`` API (constraints then no-op: those versions
-    have no ambient-mesh context for them to bind against either)."""
-    get = getattr(jax.sharding, "get_abstract_mesh", None)
-    return get() if get is not None else None
-
-
 def shard(x: jnp.ndarray, *spec) -> jnp.ndarray:
     """Constrain ``x``'s sharding against the ambient mesh.
 
@@ -168,8 +160,8 @@ def shard(x: jnp.ndarray, *spec) -> jnp.ndarray:
         raise ValueError(
             f"shard: {len(spec)} spec entries for a rank-{x.ndim} array"
         )
-    mesh = _abstract_mesh()
-    if mesh is None or not mesh.axis_names:
+    mesh = jax.sharding.get_abstract_mesh()
+    if not mesh.axis_names:
         return x
     # axes already bound as Manual (we are inside a shard_map over them,
     # e.g. the pipeline stage body) cannot be constrained again — drop them
@@ -433,9 +425,6 @@ def _attn_residual(bp, x, positions, cfg, kv=None, segments=None):
     h, kvh, dh = cfg.n_heads, cfg.n_kv_heads, cfg.head_dim
     dt = cfg.dtype
     q, k, v = _attn_qkv(bp, x, positions, cfg)
-    # the parallel package imports lazily and only on the paths that use
-    # it: the decode (kv) branch must stay importable on jax builds whose
-    # mesh API the distributed stack needs is absent
     if kv is not None:
         ck, cv, idx = kv
         ck = jax.lax.dynamic_update_slice_in_dim(ck, k.astype(ck.dtype), idx, 1)
@@ -600,12 +589,8 @@ def apply(
         # below the crossover the fused XLA path wins; at long L flash's
         # O(L) HBM traffic does.  Custom positions force the XLA paths
         # (the Pallas kernels mask with row-major arange).
-        mesh = _abstract_mesh()
-        sp = (
-            mesh.shape["sp"]
-            if mesh is not None and "sp" in mesh.axis_names
-            else 1
-        )
+        mesh = jax.sharding.get_abstract_mesh()
+        sp = mesh.shape["sp"] if "sp" in mesh.axis_names else 1
         if sp > 1:
             from ..parallel.flash import chunk_supported
 

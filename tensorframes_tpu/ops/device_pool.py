@@ -84,7 +84,7 @@ ENV_VAR = "TFS_DEVICE_POOL"
 _COPY_FALLBACK_TYPES = (
     RuntimeError,
     NotImplementedError,
-) + resilience._runtime_error_types()
+) + resilience._RUNTIME_TYPES
 
 _warned: set = set()
 

@@ -30,6 +30,8 @@ import random
 import time
 from typing import Any, Callable, Optional, Tuple
 
+from jax.errors import JaxRuntimeError
+
 from . import cancellation
 
 _log = logging.getLogger("tensorframes_tpu.resilience")
@@ -59,26 +61,11 @@ _FATAL_TYPES = (TypeError, ValueError, KeyError, AttributeError)
 _TRANSIENT_TYPES: tuple = (ConnectionError, TimeoutError)
 
 
-def _runtime_error_types() -> tuple:
-    """jax/XLA runtime-failure exception types for type-first classification.
-
-    ``JaxRuntimeError`` wraps every XLA status (UNAVAILABLE preemptions and
-    INTERNAL compiler bugs alike), so membership alone proves nothing — it
-    unlocks the status-code check below, nothing more."""
-    try:
-        from jax.errors import JaxRuntimeError
-
-        return (JaxRuntimeError,)
-    except ImportError:  # pragma: no cover - older jaxlib layout
-        try:
-            from jaxlib.xla_extension import XlaRuntimeError
-
-            return (XlaRuntimeError,)
-        except ImportError:
-            return ()
-
-
-_RUNTIME_TYPES = _runtime_error_types()
+# jax/XLA runtime-failure exception types for type-first classification.
+# ``JaxRuntimeError`` wraps every XLA status (UNAVAILABLE preemptions and
+# INTERNAL compiler bugs alike), so membership alone proves nothing — it
+# unlocks the status-code check below, nothing more.
+_RUNTIME_TYPES = (JaxRuntimeError,)
 
 # XLA runtime errors open with their absl status code; these codes mean the
 # *infrastructure* went away mid-call (vs INTERNAL / INVALID_ARGUMENT which
@@ -134,7 +121,7 @@ class FailureDetector:
             return False
         if isinstance(exc, _TRANSIENT_TYPES):
             return True
-        if _RUNTIME_TYPES and isinstance(exc, _RUNTIME_TYPES):
+        if isinstance(exc, _RUNTIME_TYPES):
             if str(exc).lower().lstrip().startswith(_TRANSIENT_XLA_STATUS):
                 return True
         text = f"{type(exc).__name__}: {exc}".lower()

@@ -312,16 +312,9 @@ def _resolve_compiled(target, args, kwargs):
 
 
 def _aggregate_cost(compiled) -> Tuple[Optional[float], Optional[float]]:
-    """(flops, bytes accessed) from ``cost_analysis`` — list- or
-    dict-shaped across jax versions."""
-    try:
-        ca = compiled.cost_analysis()
-    except Exception:
-        return None, None
-    if isinstance(ca, (list, tuple)):
-        ca = ca[0] if ca else None
-    if not isinstance(ca, dict):
-        return None, None
+    """(flops, bytes accessed) from ``cost_analysis`` (None where the
+    backend reports no analysis)."""
+    ca = compiled.cost_analysis() or {}
     return ca.get("flops"), ca.get("bytes accessed")
 
 

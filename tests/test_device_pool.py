@@ -66,11 +66,8 @@ def test_assign_least_loaded_deterministic():
 
 def test_executor_opt_in_flags():
     assert engine.Executor.supports_device_pool is True
-    dist = pytest.importorskip(
-        "tensorframes_tpu.parallel.dist",
-        reason="mesh paths need a newer jax (env, not code)",
-        exc_type=ImportError,
-    )
+    from tensorframes_tpu.parallel import dist
+
     assert dist.MeshExecutor.supports_device_pool is False
 
 

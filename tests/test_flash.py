@@ -56,6 +56,23 @@ def test_matches_reference_bf16():
     )
 
 
+def test_interpret_mode_is_never_silent(caplog):
+    """Off the TPU the kernels run interpreted; on a host whose TPU failed
+    to initialise that would be reference code standing in for the kernel,
+    so the default says so — once — and naming the mode says nothing."""
+    from tensorframes_tpu import envutil
+
+    q, k, v = _qkv(1, 16, 2, 8, jnp.float32)
+    envutil._warned_once.discard("flash.interpret")
+    with caplog.at_level("WARNING", logger="tensorframes_tpu.flash"):
+        flash_attention(q, k, v, True, 128, 128, True)  # asked for: quiet
+        assert caplog.records == []
+        flash_attention(q, k, v, True)
+        jax.grad(lambda q: flash_attention(q, k, v, True).sum())(q)
+    assert len(caplog.records) == 1
+    assert "INTERPRET" in caplog.text and "'cpu'" in caplog.text
+
+
 def test_cross_attention_lengths():
     q, k, v = _qkv(1, 24, 2, 8, jnp.float32, Lk=40)
     got = flash_attention(q, k, v, False)
@@ -324,6 +341,58 @@ def test_flash_rejects_custom_positions():
         tfm.apply(params, toks, cfg, positions=pos)
     # default positions stay fine
     assert tfm.apply(params, toks, cfg).shape == (1, 8, 17)
+
+
+@pytest.mark.parametrize("impl", ["flash", "ring_flash"])
+def test_kernels_lower_for_tpu_under_a_mesh(devices, impl, monkeypatch):
+    """Mosaic kernels cannot be partitioned automatically: where a
+    pallas_call lowers for the TPU, the region must be manual over EVERY
+    mesh axis, or jax raises — which interpret mode on XLA:CPU never does,
+    so the four-chip run was the first to see it.  Lowering for the
+    ``tpu`` platform applies the same rule without a chip: under a
+    dp x sp x tp mesh every kernel (forward, backward, ring step) must
+    lower, and the interpreted run must still match the unsharded result
+    on GSPMD-sharded GQA inputs."""
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    from tensorframes_tpu.parallel import flash
+    from tensorframes_tpu.parallel.mesh import training_mesh
+    from tensorframes_tpu.parallel.ring import ring_attention
+
+    rng = np.random.RandomState(0)
+    q = jnp.asarray(rng.randn(4, 256, 4, 64), jnp.float32)
+    k = jnp.asarray(rng.randn(4, 256, 2, 64), jnp.float32)
+    v = jnp.asarray(rng.randn(4, 256, 2, 64), jnp.float32)
+
+    def attend(q, k, v):
+        if impl == "flash":
+            return flash_attention(q, k, v, True)
+        return ring_attention(q, k, v, True, impl="flash")
+
+    def grad():  # a fresh function each time: jit caches traces by function
+        return jax.grad(
+            lambda q, k, v: (attend(q, k, v) ** 2).sum(), argnums=(0, 1, 2)
+        )
+
+    want = jax.grad(
+        lambda q, k, v: (flash_attention(q, k, v, True) ** 2).sum(),
+        argnums=(0, 1, 2),
+    )(q, k, v)
+    mesh = training_mesh(dp=2, sp=2, tp=2)
+    with jax.set_mesh(mesh):
+        sh = NamedSharding(mesh, P("dp", "sp", "tp", None))
+        qs, ks, vs = (jax.device_put(a, sh) for a in (q, k, v))
+        got = jax.jit(grad())(qs, ks, vs)
+        monkeypatch.setattr(flash, "_resolve_interpret", lambda _: False)
+        with jax.enable_x64(False):  # as on the chip
+            lowered = jax.jit(grad()).trace(qs, ks, vs).lower(
+                lowering_platforms=("tpu",)
+            )
+    assert "tpu_custom_call" in lowered.as_text()
+    for g, w in zip(got, want):
+        np.testing.assert_allclose(
+            np.asarray(g), np.asarray(w), rtol=2e-4, atol=2e-5
+        )
 
 
 def test_ring_step_rejects_unaligned_chunk():

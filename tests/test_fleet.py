@@ -528,6 +528,35 @@ def test_fleet_validation(monkeypatch):
         BridgeFleet(1, mode="thread", base_env={"X": "1"}).start()
 
 
+def test_spawn_refuses_replicas_that_would_contend_for_the_chip(monkeypatch):
+    """One process owns a chip.  When this process holds the TPU, process
+    replicas whose environment names no platform — or names the TPU —
+    are refused with the reason instead of racing for the device and
+    timing out; replicas pinned to another platform still spawn."""
+    spawned = []
+
+    class _Proc:
+        def __init__(self, argv, env=None, **kw):
+            spawned.append(env["JAX_PLATFORMS"])
+
+    monkeypatch.setattr(fleet_mod.subprocess, "Popen", _Proc)
+    monkeypatch.setattr(fleet_mod, "_backend", lambda: "tpu")
+    monkeypatch.delenv("JAX_PLATFORMS")
+    with pytest.raises(RuntimeError, match="refusing to spawn.*same chip"):
+        BridgeFleet(1)._spawn(fleet_mod._Replica("r0", "127.0.0.1", 0))
+    monkeypatch.setenv("JAX_PLATFORMS", "tpu,cpu")  # what a chip host exports
+    with pytest.raises(RuntimeError, match="'tpu,cpu'"):
+        BridgeFleet(1)._spawn(fleet_mod._Replica("r0", "127.0.0.1", 0))
+    assert spawned == []
+    BridgeFleet(1, base_env={"JAX_PLATFORMS": "cpu"})._spawn(
+        fleet_mod._Replica("r0", "127.0.0.1", 0)
+    )
+    # a launcher that holds no chip keeps today's behaviour
+    monkeypatch.setattr(fleet_mod, "_backend", lambda: "")
+    BridgeFleet(1)._spawn(fleet_mod._Replica("r1", "127.0.0.1", 0))
+    assert spawned == ["cpu", "tpu,cpu"]
+
+
 # ---------------------------------------------------------------------------
 # registry + janitor interplay (satellite: fleet-liveness veto)
 # ---------------------------------------------------------------------------

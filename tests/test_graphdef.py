@@ -177,6 +177,45 @@ def test_import_conv_pool_graph():
     np.testing.assert_allclose(out.column("pool").data, pool, rtol=1e-5)
 
 
+def test_avg_pool_same_counts_do_not_span_the_batch():
+    """SAME AvgPool divides by the window population.  The population is
+    a compile-time constant that XLA folds with its slow evaluator, so it
+    must be built at extent 1 on the batch and channel axes (at the
+    block's full shape the fold took minutes on the chip), and must still
+    give the edge-aware average."""
+    import jax
+    from jax import lax
+
+    rng = np.random.RandomState(0)
+    img = rng.randn(4, 5, 5, 3).astype(np.float32)
+    g = GraphBuilder()
+    g.placeholder("img", "float32", [-1, 5, 5, 3])
+    g.op(
+        "AvgPool", "pool", ["img"],
+        ksize=[1, 3, 3, 1], strides=[1, 1, 1, 1], padding=b"SAME",
+    )
+    p = import_graphdef(g.build(), fetches=["pool"])
+    out = tfs.map_blocks(p, frame({"img": img}))
+    summed = lax.reduce_window(
+        img, 0.0, lax.add, (1, 3, 3, 1), (1, 1, 1, 1), "SAME"
+    )
+    counts = lax.reduce_window(
+        np.ones((1, 5, 5, 1), np.float32), 0.0, lax.add, (1, 3, 3, 1),
+        (1, 1, 1, 1), "SAME",
+    )
+    np.testing.assert_allclose(
+        out.column("pool").data, np.asarray(summed / counts), rtol=1e-6
+    )
+    windows = [
+        e.invars[0].aval.shape
+        for e in jax.make_jaxpr(lambda x: p.call({"img": x}, p.params))(
+            img
+        ).jaxpr.eqns
+        if e.primitive.name == "reduce_window_sum"
+    ]
+    assert sorted(windows) == [(1, 5, 5, 1), (4, 5, 5, 3)], windows
+
+
 def test_import_segment_sum_preagg():
     # the kmeans_demo.py:101-168 pre-aggregation kernel pattern
     b = GraphBuilder()

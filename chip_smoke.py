@@ -703,27 +703,32 @@ def run_phases(
 
 
 def run(phases, sz: Sizes, ctx: Dict[str, Any], complete: bool = True) -> int:
-    """Run ``phases``, print the verdict as the last line, and return the
-    process's exit status: 0 only when every phase of the whole list ran
-    and passed."""
+    """Run ``phases``, print a summary line and then the verdict as the
+    last line, and return the process's exit status: 0 only when every
+    phase of the whole list ran and passed.  The verdict holds exactly
+    ``ok`` and ``device`` (the chip check reads it strictly); everything
+    else about the run is on the summary line before it."""
     import jax
 
     dev = jax.devices()[0]
     t0 = time.perf_counter()
     failed = run_phases(phases, ctx, sz, emit=lambda s: print(s, flush=True))
-    verdict: Dict[str, Any] = {
+    summary: Dict[str, Any] = {
+        "phase": "summary",
+        "wall_s": round(time.perf_counter() - t0, 1),
+        "failed": failed,
+    }
+    if not complete:
+        summary["partial"] = [name for name, _ in phases]
+    print(json.dumps(summary), flush=True)
+    verdict = {
         "ok": not failed and complete,
         "device": {
             "platform": dev.platform,
             "kind": dev.device_kind,
             "count": len(jax.devices()),
         },
-        "wall_s": round(time.perf_counter() - t0, 1),
     }
-    if failed:
-        verdict["failed"] = failed
-    if not complete:
-        verdict["partial"] = [name for name, _ in phases]
     print(json.dumps(verdict), flush=True)
     return 0 if verdict["ok"] else 1
 

@@ -51,10 +51,13 @@ def test_runner_exit_status_follows_the_phases(capsys):
     ctx = {"platform": "cpu", "full_width": False}
     assert smoke.run([("a", good), ("b", good)], smoke.Sizes(), ctx) == 0
     lines = [json.loads(l) for l in capsys.readouterr().out.splitlines()]
-    assert [l.get("phase") for l in lines] == ["a", "b", None]
+    assert [l.get("phase") for l in lines] == ["a", "b", "summary", None]
     assert lines[0]["ok"] and lines[0]["answer"] == 42
-    assert lines[-1]["ok"] is True
+    assert lines[-2]["failed"] == []
+    # the verdict is read strictly: exactly these keys, nothing beside them
+    assert set(lines[-1]) == {"ok", "device"} and lines[-1]["ok"] is True
     assert set(lines[-1]["device"]) == {"platform", "kind", "count"}
+    assert isinstance(lines[-1]["device"]["count"], int)
 
     ran.clear()
     rc = smoke.run([("a", good), ("b", bad), ("c", good)], smoke.Sizes(), ctx)
@@ -62,10 +65,13 @@ def test_runner_exit_status_follows_the_phases(capsys):
     lines = [json.loads(l) for l in out.out.splitlines()]
     assert rc == 1
     assert ran == ["good", "bad", "good"]  # later phases still report
-    assert [l["ok"] for l in lines] == [True, False, True, False]
-    assert "boom" in lines[1]["error"] and lines[-1]["failed"] == ["b"]
+    assert [l.get("ok") for l in lines] == [True, False, True, None, False]
+    assert "boom" in lines[1]["error"] and lines[-2]["failed"] == ["b"]
+    assert set(lines[-1]) == {"ok", "device"}
     assert "RuntimeError: boom" in out.err  # the traceback is kept
 
     # a subset of the phases never counts as a pass
     assert smoke.run([("a", good)], smoke.Sizes(), ctx, complete=False) == 1
-    assert json.loads(capsys.readouterr().out.splitlines()[-1])["ok"] is False
+    lines = [json.loads(l) for l in capsys.readouterr().out.splitlines()]
+    assert lines[-2]["partial"] == ["a"]
+    assert lines[-1]["ok"] is False and set(lines[-1]) == {"ok", "device"}

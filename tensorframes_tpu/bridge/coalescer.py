@@ -741,7 +741,13 @@ class Coalescer:
             deadline_s=deadline_s, label="bridge:coalesce"
         )
         self._register_scope(scope)
-        t_tr = observability.trace_now()
+        # one span for the shared dispatch, carrying every
+        # participating correlation id
+        sp = observability.span(
+            "bridge.coalesced", "bridge/coalescer",
+            verb=verb, requests=len(alive), rows=total, blocks=nb,
+            cids=",".join(m.cid for m in alive if m.cid),
+        )
         try:
             with cancellation.activate(scope):
                 counters, blocks, rows, out = self._metered(
@@ -749,18 +755,7 @@ class Coalescer:
                 )
         finally:
             self._unregister_scope(scope)
-        # one trace record for the shared dispatch, carrying every
-        # participating correlation id
-        cids = [m.cid for m in alive if m.cid]
-        observability.trace_complete(
-            f"coalesced {verb}",
-            "bridge/coalescer",
-            t_tr,
-            cids=",".join(cids),
-            requests=len(alive),
-            rows=total,
-            blocks=nb,
-        )
+        sp.end()
         observability.note_coalesced_batch(len(alive), total)
         with self._lock:
             k = len(alive)
@@ -1261,9 +1256,21 @@ class _PagedSeq:
     __slots__ = (
         "prompt", "max_new", "until", "tenant", "scope", "charge",
         "table_row", "out", "emitted", "done", "error", "abandoned",
+        "cid", "t_submit", "t_admit", "t_first", "t_done",
     )
 
     def __init__(self, prompt, max_new, until, tenant, scope, charge):
+        # the request's life, in time.perf_counter_ns: submit (handler
+        # thread), then admit / first token / retire (driver thread);
+        # 0 until stamped.  cid strings its spans together.
+        led = observability.current_request()
+        self.cid = (
+            led.correlation_id
+            if led is not None
+            else observability.new_correlation_id()
+        )
+        self.t_submit = time.perf_counter_ns()
+        self.t_admit = self.t_first = self.t_done = 0
         self.prompt = prompt  # np.int32 [Lp]
         self.max_new = max(1, int(max_new))
         self.until = until
@@ -1276,6 +1283,27 @@ class _PagedSeq:
         self.done = threading.Event()
         self.error: Optional[BaseException] = None
         self.abandoned = False
+
+    def timing(self) -> Dict[str, float]:
+        """The stamps as a caller can use them: milliseconds from submit
+        to admission, to the first token, and to retirement."""
+        return {
+            "queued_ms": (self.t_admit - self.t_submit) / 1e6,
+            "ttft_ms": (self.t_first - self.t_submit) / 1e6,
+            "total_ms": (self.t_done - self.t_submit) / 1e6,
+        }
+
+
+# ring track of the decode driver's spans (the profiler's trace places
+# them by thread: the driver's is ``tfs-paged-decode``)
+_DECODE_TRACK = "decode/driver"
+
+_DECODE_TIME_KEYS = (
+    "decode_steps", "decode_host_ns", "decode_step_wait_ns",
+    "decode_prefill_ns", "decode_busy_ns", "decode_admitted",
+    "decode_queue_wait_ns", "decode_first_tokens", "decode_ttft_ns",
+    "decode_stream_ns", "decode_stream_tokens",
+)
 
 
 class DecodeScheduler:
@@ -1388,6 +1416,12 @@ class DecodeScheduler:
         # (pool size / backlog cap), not compute, was the limit — the
         # decode_slot_starvation doctor rule's evidence
         self.refused_while_idle = 0
+        # the driver's counter deltas since its last bump (driver thread
+        # only; one observability bump a step or prefill) and their
+        # running totals for snapshot()
+        self._tally: Dict[str, int] = collections.defaultdict(int)
+        self._time_totals = dict.fromkeys(_DECODE_TIME_KEYS, 0)
+        self._busy_mark = 0
         _LIVE_DECODE.add(self)
 
     # -- public --------------------------------------------------------------
@@ -1405,6 +1439,23 @@ class DecodeScheduler:
         boundary; blocks until the stream retires and returns the
         emitted tokens.  Raises :class:`DecodeRefused` when the page
         pool or the slot backlog cannot take the sequence."""
+        return self.submit_request(
+            prompt, max_new, until=until, tenant=tenant,
+            timeout_s=timeout_s,
+        ).out
+
+    def submit_request(
+        self,
+        prompt,
+        max_new: int,
+        until: Optional[Callable[[int], bool]] = None,
+        tenant: Optional[str] = None,
+        timeout_s: Optional[float] = None,
+    ) -> _PagedSeq:
+        """:meth:`submit`, returning the retired request itself: its
+        tokens (``out``) and the stamps of its life (``timing()``) —
+        the only place a unary caller can learn its time to first
+        token."""
         prompt = np.asarray(prompt, np.int32).reshape(-1)
         if prompt.size < 1:
             raise ValueError("decode needs a non-empty prompt")
@@ -1456,23 +1507,28 @@ class DecodeScheduler:
         row = np.zeros((self.max_pages,), np.int32)
         row[: len(pages)] = pages
         req.table_row = row
-        with self._cv:
-            if self._closed:
-                self.pool.free(charge)
-                raise RuntimeError("DecodeScheduler is closed")
-            self._pending.append(req)
-            self._ensure_driver()
-            self._cv.notify_all()
-        if not req.done.wait(timeout=timeout_s):
+        with observability.span(
+            "decode.request",
+            f"decode/{threading.current_thread().name}",
+            cid=req.cid, prompt_tokens=int(prompt.size), max_new=max_new,
+        ):
             with self._cv:
-                req.abandoned = True
+                if self._closed:
+                    self.pool.free(charge)
+                    raise RuntimeError("DecodeScheduler is closed")
+                self._pending.append(req)
+                self._ensure_driver()
                 self._cv.notify_all()
-            raise TimeoutError(
-                f"decode request did not finish within {timeout_s}s"
-            )
+            if not req.done.wait(timeout=timeout_s):
+                with self._cv:
+                    req.abandoned = True
+                    self._cv.notify_all()
+                raise TimeoutError(
+                    f"decode request did not finish within {timeout_s}s"
+                )
         if req.error is not None:
             raise req.error
-        return req.out
+        return req
 
     def speculative(
         self,
@@ -1550,6 +1606,7 @@ class DecodeScheduler:
                 "pages_capacity": stats["pages_total"],
                 "pages_allocated_total": stats["allocated_total"],
                 "pages_freed_total": stats["freed_total"],
+                **self._time_totals,
             }
 
     # -- driver --------------------------------------------------------------
@@ -1573,7 +1630,38 @@ class DecodeScheduler:
         self._toks[slot] = 0
         self.retired += 1
         self.pool.free(req.charge)
+        req.t_done = time.perf_counter_ns()
+        if req.t_first:
+            self._tally["decode_stream_ns"] += req.t_done - req.t_first
+            self._tally["decode_stream_tokens"] += max(0, req.emitted - 1)
+        observability.instant(
+            "decode.retire", _DECODE_TRACK, cid=req.cid, tokens=req.emitted
+        )
         req.done.set()
+
+    def _flush_tally(self) -> None:
+        """The driver's one counter bump a step or prefill.  Closes the
+        busy interval — the loop's wall time since ``_busy_mark``, which
+        the driver resets when it wakes from a wait with nothing to do
+        — and splits it: what was not the wait for a step's tokens nor
+        a prefill is the loop's own host time (boundary, dispatch,
+        bookkeeping and whatever lies between them), so
+        ``decode_busy_ns`` is the sum of its three parts at every
+        bump."""
+        tally = self._tally
+        t = time.perf_counter_ns()
+        whole = t - self._busy_mark
+        self._busy_mark = t
+        tally["decode_busy_ns"] += whole
+        tally["decode_host_ns"] += (
+            whole
+            - tally.get("decode_step_wait_ns", 0)
+            - tally.get("decode_prefill_ns", 0)
+        )
+        for k in _DECODE_TIME_KEYS:
+            self._time_totals[k] += tally.get(k, 0)
+        observability.note_decode_driver(tally)
+        tally.clear()
 
     def _dispatch(self, fn, *args):
         """One compiled dispatch with chaos injection + bounded retry:
@@ -1597,15 +1685,20 @@ class DecodeScheduler:
         import jax.numpy as jnp
 
         kv = self._kv
+        tally = self._tally
+        now = time.perf_counter_ns
+        span = observability.span
+        self._busy_mark = now()
         try:
             while True:
                 with self._cv:
-                    while (
-                        not self._closed
-                        and not self._pending
-                        and not self._active
-                    ):
-                        self._cv.wait()
+                    if not (self._closed or self._pending or self._active):
+                        self._flush_tally()
+                        while not (
+                            self._closed or self._pending or self._active
+                        ):
+                            self._cv.wait()
+                        self._busy_mark = now()
                     if self._closed and not self._active:
                         err = RuntimeError(
                             "DecodeScheduler closed before this "
@@ -1616,7 +1709,13 @@ class DecodeScheduler:
                             req.error = err
                             req.done.set()
                         self._pending.clear()
+                        self._flush_tally()
                         return
+                    sp = span(
+                        "decode.boundary", _DECODE_TRACK,
+                        active=len(self._active),
+                        pending=len(self._pending),
+                    )
                     # step boundary: deadline/cancel checks retire
                     # expired rows and free their pages BEFORE admission
                     # (their slots are immediately reusable)
@@ -1646,55 +1745,63 @@ class DecodeScheduler:
                         admitted.append((slot, req))
                         if was_running:
                             self.joined_mid_run += 1
-                    active = bool(self._active)
-                if not active:
-                    continue
-                if admitted:
-                    self._prefill(admitted, jnp)
-                    # prefill may retire 1-token streams at once; the
-                    # boundary loop re-checks before the next step
-                    with self._cv:
-                        for slot, req in admitted:
-                            if slot in self._active and (
-                                req.emitted >= req.max_new
-                                or (
-                                    req.until is not None
-                                    and req.out
-                                    and bool(req.until(req.out[-1]))
-                                )
-                            ):
-                                self._retire_locked(slot, req)
-                        if not self._active:
-                            continue
-                # decode lane: one fixed-shape step for the population
-                toks, self._kp, self._vp = self._dispatch(
-                    kv.paged_decode_step,
-                    self._params,
-                    jnp.asarray(self._toks),
-                    jnp.asarray(self._tables),
-                    jnp.asarray(self._indices),
-                    self._kp,
-                    self._vp,
-                    self.cfg,
-                )
-                emitted = np.asarray(toks)
-                self.steps += 1
-                with self._cv:
-                    for slot, req in list(self._active.items()):
-                        self._indices[slot] += 1
-                        tok = int(emitted[slot])
-                        self._toks[slot] = tok
-                        req.out.append(tok)
-                        req.emitted += 1
-                        self.total_tokens += 1
-                        observability.note_decode_tokens(1)
-                        stop = req.emitted >= req.max_new or (
-                            req.until is not None and bool(req.until(tok))
+                        req.t_admit = now()
+                        wait = req.t_admit - req.t_submit
+                        tally["decode_queue_wait_ns"] += wait
+                        observability.instant(
+                            "decode.admit", _DECODE_TRACK,
+                            cid=req.cid, wait_us=wait // 1000,
                         )
-                        if stop or req.abandoned:
-                            self._retire_locked(slot, req)
-                    # idle slots keep index 0 / token 0: their writes
-                    # land on the trash page via their all-zero tables
+                    n_active = len(self._active)
+                    sp.end(admitted=len(admitted))
+                if not n_active:
+                    continue
+                if admitted and not self._prefill(admitted, jnp):
+                    # every admitted stream was one token long and no
+                    # other is active: nothing to step
+                    continue
+                # decode lane: one fixed-shape step for the population
+                with span(
+                    "decode.step", _DECODE_TRACK,
+                    step=self.steps, active=len(self._active),
+                ):
+                    with span("decode.step.dispatch", _DECODE_TRACK):
+                        toks, self._kp, self._vp = self._dispatch(
+                            kv.paged_decode_step,
+                            self._params,
+                            jnp.asarray(self._toks),
+                            jnp.asarray(self._tables),
+                            jnp.asarray(self._indices),
+                            self._kp,
+                            self._vp,
+                            self.cfg,
+                        )
+                    with span("decode.step.wait", _DECODE_TRACK) as sp_w:
+                        emitted = np.asarray(toks)
+                    self.steps += 1
+                    with span("decode.step.emit", _DECODE_TRACK):
+                        with self._cv:
+                            n_tok = len(self._active)
+                            for slot, req in list(self._active.items()):
+                                self._indices[slot] += 1
+                                tok = int(emitted[slot])
+                                self._toks[slot] = tok
+                                req.out.append(tok)
+                                req.emitted += 1
+                                stop = req.emitted >= req.max_new or (
+                                    req.until is not None
+                                    and bool(req.until(tok))
+                                )
+                                if stop or req.abandoned:
+                                    self._retire_locked(slot, req)
+                            self.total_tokens += n_tok
+                            # idle slots keep index 0 / token 0: their
+                            # writes land on the trash page via their
+                            # all-zero tables
+                tally["decode_steps"] += 1
+                tally["decode_tokens"] += n_tok
+                tally["decode_step_wait_ns"] += sp_w.ns
+                self._flush_tally()
         except BaseException as e:  # noqa: BLE001 — fail every waiter
             with self._cv:
                 for req in list(self._active.values()):
@@ -1712,44 +1819,72 @@ class DecodeScheduler:
                 self._indices[:] = 0
                 self._toks[:] = 0
 
-    def _prefill(self, admitted, jnp) -> None:
+    def _prefill(self, admitted, jnp) -> bool:
         """The disaggregated prefill lane: the boundary's newly admitted
         sequences prefill as ONE bucket-padded batch through the
         existing ladder.  Rows not being prefilled ride along with
         all-trash tables (their live tables stay untouched — prefill
-        writes only through the batch's own table argument)."""
+        writes only through the batch's own table argument).  Returns
+        whether any stream is left to step: a one-token stream retires
+        here."""
         kv = self._kv
+        tally = self._tally
         max_lp = max(int(r.prompt.size) for _, r in admitted)
         lb = min(max(bucketing.bucket_for(max_lp), 1), self.cap)
         lb = max(lb, max_lp)
-        toks = np.zeros((self.max_slots, lb), np.int32)
-        tables = np.zeros((self.max_slots, self.max_pages), np.int32)
-        last_pos = np.zeros((self.max_slots,), np.int32)
-        for slot, req in admitted:
-            lp = int(req.prompt.size)
-            toks[slot, :lp] = req.prompt
-            tables[slot] = req.table_row
-            last_pos[slot] = lp - 1
-        tok0, self._kp, self._vp = self._dispatch(
-            kv.paged_prefill,
-            self._params,
-            jnp.asarray(toks),
-            jnp.asarray(tables),
-            jnp.asarray(last_pos),
-            self._kp,
-            self._vp,
-            self.cfg,
-        )
-        tok0 = np.asarray(tok0)
-        self.prefill_batches += 1
-        observability.note_decode_prefill_batch()
-        with self._cv:
+        with observability.span(
+            "decode.prefill", _DECODE_TRACK,
+            bucket=lb, admitted=len(admitted),
+            slots=",".join(str(slot) for slot, _ in admitted),
+        ) as sp:
+            toks = np.zeros((self.max_slots, lb), np.int32)
+            tables = np.zeros((self.max_slots, self.max_pages), np.int32)
+            last_pos = np.zeros((self.max_slots,), np.int32)
             for slot, req in admitted:
                 lp = int(req.prompt.size)
-                self._indices[slot] = lp
-                tok = int(tok0[slot])
-                self._toks[slot] = tok
-                req.out.append(tok)
-                req.emitted += 1
-                self.total_tokens += 1
-                observability.note_decode_tokens(1)
+                toks[slot, :lp] = req.prompt
+                tables[slot] = req.table_row
+                last_pos[slot] = lp - 1
+            tok0, self._kp, self._vp = self._dispatch(
+                kv.paged_prefill,
+                self._params,
+                jnp.asarray(toks),
+                jnp.asarray(tables),
+                jnp.asarray(last_pos),
+                self._kp,
+                self._vp,
+                self.cfg,
+            )
+            with observability.span("decode.prefill.wait", _DECODE_TRACK):
+                tok0 = np.asarray(tok0)
+            self.prefill_batches += 1
+            with self._cv:
+                t_first = time.perf_counter_ns()
+                for slot, req in admitted:
+                    lp = int(req.prompt.size)
+                    self._indices[slot] = lp
+                    tok = int(tok0[slot])
+                    self._toks[slot] = tok
+                    req.out.append(tok)
+                    req.emitted += 1
+                    req.t_first = t_first
+                    ttft = t_first - req.t_submit
+                    tally["decode_ttft_ns"] += ttft
+                    observability.instant(
+                        "decode.first_token", _DECODE_TRACK,
+                        cid=req.cid, ttft_us=ttft // 1000,
+                    )
+                    if req.emitted >= req.max_new or (
+                        req.until is not None and bool(req.until(tok))
+                    ):
+                        self._retire_locked(slot, req)
+                self.total_tokens += len(admitted)
+                any_active = bool(self._active)
+        n = len(admitted)
+        tally["decode_prefill_ns"] += sp.ns
+        tally["decode_prefill_batches"] += 1
+        tally["decode_admitted"] += n
+        tally["decode_first_tokens"] += n
+        tally["decode_tokens"] += n
+        self._flush_tally()
+        return any_active

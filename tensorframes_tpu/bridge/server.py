@@ -926,24 +926,20 @@ class _Handler(socketserver.StreamRequestHandler):
             f"bridge/{threading.current_thread().name.split(' ')[0]}"
         )
         t0 = time.perf_counter()
-        t_tr = t0 if observability.trace_enabled() else None
         self._req_cid = None  # set by _dispatch for gated requests
+        sp = observability.span("bridge.request", track, method=label)
         try:
             return self._dispatch(msg, rbins, method, track)
         finally:
             observability.record_latency(
                 "bridge", label, time.perf_counter() - t0
             )
-            # the request event closes AFTER the ledger context is
-            # reset, so the cid is passed explicitly (round 15)
+            # the request span closes AFTER the ledger context is reset,
+            # so the ring's cid is passed explicitly (round 15)
             if self._req_cid is not None:
-                observability.trace_complete(
-                    f"request {label}", track, t_tr, cid=self._req_cid
-                )
+                sp.end(cid=self._req_cid)
             else:
-                observability.trace_complete(
-                    f"request {label}", track, t_tr
-                )
+                sp.end()
 
     def _dispatch(self, msg: dict, rbins: list, method, track: str):
         """-> ``(reply_without_id, bins)``; raises ``_DropReply`` for an
@@ -1173,11 +1169,12 @@ class _Handler(socketserver.StreamRequestHandler):
             # flight recorder: admission wait and execution are separate
             # events on this handler's track, so queueing-vs-compute time
             # is visible per request in the Perfetto view
-            t_admit = observability.trace_now()
-            server.gate.admit(scope)
-            observability.trace_complete(f"admit {method}", track, t_admit)
+            with observability.span("bridge.admit", track, method=method):
+                server.gate.admit(scope)
             server._register_scope(scope)
-            t_exec = observability.trace_now()
+            sp_exec = observability.span(
+                "bridge.execute", track, method=method
+            )
             try:
                 with observability.verb_span(
                     f"bridge:{method}", 0, 0
@@ -1221,9 +1218,7 @@ class _Handler(socketserver.StreamRequestHandler):
                         reply, bins = {"error": payload}, []
                         entry = ("error", payload, [])
             finally:
-                observability.trace_complete(
-                    f"execute {method}", track, t_exec
-                )
+                sp_exec.end()
                 server._unregister_scope(scope)
                 server.gate.release()
         finally:
@@ -1757,6 +1752,7 @@ class BridgeServer(socketserver.ThreadingTCPServer):
             if stop_token is not None
             else None
         )
+        timing = None
         try:
             if speculative:
                 toks = sched.speculative(
@@ -1768,9 +1764,10 @@ class BridgeServer(socketserver.ThreadingTCPServer):
                             toks = toks[: i + 1]
                             break
             else:
-                toks = sched.submit(
+                req = sched.submit_request(
                     prompt, int(max_new), until=until, tenant=tenant
                 )
+                toks, timing = req.out, req.timing()
         except _coalescer.DecodeRefused as e:
             raise ServerBusy(
                 str(e),
@@ -1781,11 +1778,17 @@ class BridgeServer(socketserver.ThreadingTCPServer):
         # unit for its fair-share window (frame verbs bill rows)
         if self.scheduler.enabled():
             self.scheduler.note(tenant, len(toks))
-        return {
+        reply = {
             "tokens": [int(t) for t in toks],
             "generated": len(toks),
             "speculative": bool(speculative),
         }
+        if timing is not None:
+            # the scheduler's stamps (queued_ms / ttft_ms / total_ms from
+            # submit): decode is a unary RPC, so only the scheduler can
+            # tell a caller its time to first token
+            reply["timing"] = timing
+        return reply
 
     # -- health --------------------------------------------------------------
 

@@ -342,17 +342,25 @@ def init(rng, dtype=jnp.bfloat16) -> Params:
 
 def apply(params: Params, images: jnp.ndarray) -> jnp.ndarray:
     """images [N, 299, 299, 3] (float, ~[-1, 1]) -> logits [N, 1000]."""
+    # scope names are metadata: a profiler session groups the device
+    # operations of a block under stem / mixed<i>_<variant> / head
     x = images
-    for p, (_, _, _, stride, padding, then_pool) in zip(params["stem"], _STEM):
-        x = _conv(p, x, stride, padding)
-        if then_pool:
-            x = _pool(x, "max", 3, 2, "VALID")
-    for bp, (variant, kw_) in zip(params["blocks"], _BLOCKS):
-        x = _block_apply(bp, x, variant, **kw_)
-    x = jnp.mean(x, axis=(1, 2))  # global average pool
-    return (
-        x @ params["fc_w"].astype(x.dtype) + params["fc_b"].astype(x.dtype)
-    ).astype(jnp.float32)
+    with jax.named_scope("stem"):
+        for p, (_, _, _, stride, padding, then_pool) in zip(
+            params["stem"], _STEM
+        ):
+            x = _conv(p, x, stride, padding)
+            if then_pool:
+                x = _pool(x, "max", 3, 2, "VALID")
+    for i, (bp, (variant, kw_)) in enumerate(zip(params["blocks"], _BLOCKS)):
+        with jax.named_scope(f"mixed{i}_{variant}"):
+            x = _block_apply(bp, x, variant, **kw_)
+    with jax.named_scope("head"):
+        x = jnp.mean(x, axis=(1, 2))  # global average pool
+        return (
+            x @ params["fc_w"].astype(x.dtype)
+            + params["fc_b"].astype(x.dtype)
+        ).astype(jnp.float32)
 
 
 def scoring_program(params: Params, dtype=jnp.bfloat16, fold: bool = True):

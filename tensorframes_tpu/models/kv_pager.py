@@ -251,37 +251,45 @@ def _paged_block(bp, x, positions, cfg, kp, vp, tables):
     h, dh = cfg.n_heads, cfg.head_dim
     dt = cfg.dtype
     P = kp.shape[1]
-    q, k, v = tfm._attn_qkv(bp, x, positions, cfg)
-    # scatter this chunk's k/v into the pages
-    page_slot = positions // P  # [B, L]
-    offset = positions % P
-    max_pages = tables.shape[1]
-    # positions past a row's table (bucket padding that overruns the
-    # sequence capacity) write the trash page, never a clamped real slot
-    dest = jnp.where(
-        page_slot < max_pages,
-        jnp.take_along_axis(
-            tables, jnp.minimum(page_slot, max_pages - 1), axis=1
-        ),
-        0,
-    )  # [B, L]
-    flat_dest = dest.reshape(B * L)
-    flat_off = offset.reshape(B * L)
-    kvh = k.shape[2]
-    kp = kp.at[flat_dest, flat_off].set(
-        k.astype(kp.dtype).reshape(B * L, kvh, dh), mode="drop"
-    )
-    vp = vp.at[flat_dest, flat_off].set(
-        v.astype(vp.dtype).reshape(B * L, kvh, dh), mode="drop"
-    )
-    # gather each row's pages into its contiguous cache view
-    ck = kp[tables].reshape(B, tables.shape[1] * P, kvh, dh)
-    cv = vp[tables].reshape(B, tables.shape[1] * P, kvh, dh)
-    att = tfm._cache_attention(q, ck.astype(dt), cv.astype(dt), positions)
-    att = att.reshape(B, L, h * dh)
-    x = x + tfm.shard(
-        att @ tfm.weight(bp["wo"], dt), ("dp", "ep"), "sp", None
-    )
+    # scope names are metadata: a profiler session groups the device
+    # operations of a step under attention / page_write / page_gather
+    with jax.named_scope("attention"):
+        q, k, v = tfm._attn_qkv(bp, x, positions, cfg)
+        with jax.named_scope("page_write"):
+            # scatter this chunk's k/v into the pages
+            page_slot = positions // P  # [B, L]
+            offset = positions % P
+            max_pages = tables.shape[1]
+            # positions past a row's table (bucket padding that overruns
+            # the sequence capacity) write the trash page, never a
+            # clamped real slot
+            dest = jnp.where(
+                page_slot < max_pages,
+                jnp.take_along_axis(
+                    tables, jnp.minimum(page_slot, max_pages - 1), axis=1
+                ),
+                0,
+            )  # [B, L]
+            flat_dest = dest.reshape(B * L)
+            flat_off = offset.reshape(B * L)
+            kvh = k.shape[2]
+            kp = kp.at[flat_dest, flat_off].set(
+                k.astype(kp.dtype).reshape(B * L, kvh, dh), mode="drop"
+            )
+            vp = vp.at[flat_dest, flat_off].set(
+                v.astype(vp.dtype).reshape(B * L, kvh, dh), mode="drop"
+            )
+        with jax.named_scope("page_gather"):
+            # gather each row's pages into its contiguous cache view
+            ck = kp[tables].reshape(B, tables.shape[1] * P, kvh, dh)
+            cv = vp[tables].reshape(B, tables.shape[1] * P, kvh, dh)
+        att = tfm._cache_attention(
+            q, ck.astype(dt), cv.astype(dt), positions
+        )
+        att = att.reshape(B, L, h * dh)
+        x = x + tfm.shard(
+            att @ tfm.weight(bp["wo"], dt), ("dp", "ep"), "sp", None
+        )
     x, _aux = tfm._mlp_residual(bp, x, cfg)
     return x, kp, vp
 
@@ -320,13 +328,14 @@ def apply_paged(
     x, (kps, vps) = jax.lax.scan(
         step, x, (params["blocks"], k_pages, v_pages)
     )
-    x = tfm._rms_norm(x, params["ln_f"])
-    logits = jnp.einsum(
-        "bld,dv->blv",
-        x,
-        tfm.weight(params["lm_head"], cfg.dtype),
-        preferred_element_type=jnp.float32,
-    )
+    with jax.named_scope("head"):
+        x = tfm._rms_norm(x, params["ln_f"])
+        logits = jnp.einsum(
+            "bld,dv->blv",
+            x,
+            tfm.weight(params["lm_head"], cfg.dtype),
+            preferred_element_type=jnp.float32,
+        )
     return logits, kps, vps
 
 

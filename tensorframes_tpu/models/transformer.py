@@ -394,6 +394,7 @@ def _attn_qkv(bp, x, positions, cfg):
     return q, k, v
 
 
+@jax.named_scope("mlp")
 def _mlp_residual(bp, x, cfg, segments=None):
     """The MLP half of a block: x -> x + FF(rms_norm(x)).  Returns
     ``(x', aux)`` — aux is the MoE load-balance loss (0 for dense).
@@ -416,6 +417,7 @@ def _mlp_residual(bp, x, cfg, segments=None):
     return x, aux
 
 
+@jax.named_scope("attention")
 def _attn_residual(bp, x, positions, cfg, kv=None, segments=None):
     """The attention half of a block: x -> x + Wo(attn(...)).  Returns
     ``(x', cache)`` (cache None outside decode).  Split out of ``_block``
@@ -637,13 +639,14 @@ def apply(
     x = embed_lookup(params["embed"], tokens, cfg.dtype)
     x = shard(x, ("dp", "ep"), "sp", None)
     x, aux = blocks_runner(params["blocks"], x, positions, cfg, segment_ids)
-    x = _rms_norm(x, params["ln_f"])
-    logits = jnp.einsum(
-        "bld,dv->blv",
-        x,
-        weight(params["lm_head"], cfg.dtype),
-        preferred_element_type=jnp.float32,
-    )
+    with jax.named_scope("head"):
+        x = _rms_norm(x, params["ln_f"])
+        logits = jnp.einsum(
+            "bld,dv->blv",
+            x,
+            weight(params["lm_head"], cfg.dtype),
+            preferred_element_type=jnp.float32,
+        )
     logits = shard(logits, ("dp", "ep"), "sp", "tp")
     out = (logits,)
     if return_hidden:

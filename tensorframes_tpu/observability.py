@@ -8,12 +8,20 @@ The reference's observability is a Logging trait + log4j config + pervasive
 * ``initialize_logging(level)`` — one-call logger setup (the
   ``initialize_logging`` analog; PySpark misconfigured log4j, ad-hoc scripts
   misconfigure ``logging`` the same way);
-* ``enable(profile_dir=None)`` — opt-in per-verb phase spans.  Every verb
-  then logs ``validate / dispatch / sync`` wall times (the phases that matter
-  on an async data plane: dispatch = host work to enqueue all blocks, sync =
-  time to materialise results).  With ``profile_dir`` set, each verb call is
-  additionally wrapped in a ``jax.profiler`` trace whose dump can be opened
-  in TensorBoard/XProf — the real tool for on-device timeline analysis;
+* ``enable()`` — opt-in per-verb phase spans.  Every verb then logs
+  ``validate / dispatch / sync`` wall times (the phases that matter on an
+  async data plane: dispatch = host work to enqueue all blocks, sync =
+  time to materialise results);
+* **spans** — :class:`span` / :func:`instant`, the ONE primitive every
+  emission site calls.  Each span is a ``jax.profiler.TraceAnnotation``
+  named ``tfs:<name>``, so a profiler session around the workload
+  (``jax.profiler.start_trace(dir)`` ... ``stop_trace()``) shows the
+  program's host spans on the device trace's clock, arguments as event
+  stats — the real tool for on-device timeline analysis — and, with the
+  flight recorder on, an event in the ring below.  The decode
+  scheduler's step and request life, the engine's block loop and the
+  pool's readback also feed always-on time counters (``*_ns``) taken at
+  the spans' own boundaries;
 * ``last_spans()`` — the most recent spans as dicts (programmatic access;
   what ``bench.py`` surfaces as its phase breakdown).
 * **retrace counters** (round 7) — always-on cumulative counts of
@@ -25,8 +33,9 @@ The reference's observability is a Logging trait + log4j config + pervasive
   as ``retrace``; ``bench.py`` attaches the per-config delta to every
   record — compile counts are *proven*, not asserted.
 * **flight recorder** (round 13) — an opt-in bounded ring buffer
-  (``TFS_TRACE=1``, capacity ``TFS_TRACE_EVENTS``) of structured events
-  at *block* granularity: engine serial/pooled/sharded dispatches,
+  (``TFS_TRACE=1``, capacity ``TFS_TRACE_EVENTS``) of the same spans,
+  for when no profiler is at hand, at *block* granularity: engine
+  serial/pooled/sharded dispatches,
   per-lane staging, overlapped D2H readback, retry/quarantine/OOM-split
   instants, cache evictions/spills, streaming windows, and the bridge
   request lifecycle.  ``dump_trace(path)`` exports Chrome-trace JSON —
@@ -60,10 +69,11 @@ The reference's observability is a Logging trait + log4j config + pervasive
   (JSON) log line.  With no active request the whole layer is one
   contextvar read per block.
 
-Deliberately cheap: a disabled span is one ``if``; a counter bump is one
-dict increment under an uncontended lock (bridge handler threads bump
+Deliberately cheap: a disabled verb span is one ``if``; a counter bump is
+one dict increment under an uncontended lock (bridge handler threads bump
 concurrently since round 11; the paths are at most per-block, never
-per-element); a disabled trace emission is one boolean check.
+per-element); a span with no profiler session and the recorder off is an
+idle ``TraceAnnotation``, two clock reads and one boolean check.
 """
 
 from __future__ import annotations
@@ -76,7 +86,6 @@ import copy
 import itertools
 import json
 import logging
-import os
 from . import envutil
 import threading
 import time
@@ -84,6 +93,8 @@ import uuid
 from typing import (
     Any, Callable, Dict, List, Mapping, Optional, Sequence, Tuple,
 )
+
+from jax.profiler import TraceAnnotation as _TraceAnnotation
 
 from .envutil import env_float, env_int, warn_once
 
@@ -94,7 +105,6 @@ _MAX_SPANS = 256
 
 _state: Dict[str, Any] = {
     "enabled": False,
-    "profile_dir": None,
     "spans": [],
 }
 
@@ -210,6 +220,38 @@ _counters: Dict[str, int] = {
     "kv_pages_allocated": 0,
     "kv_pages_freed": 0,
     "decode_prefill_batches": 0,
+    # time counters (nanoseconds of time.perf_counter_ns, monotonic),
+    # taken at the boundaries of the spans of the same name and bumped
+    # once per step / prefill / block / verb.  Decode scheduler: steps;
+    # the driver loop's wall time while any stream is active (busy) and
+    # its three parts — the wait for a step's tokens, the whole of its
+    # prefills, and host time, which is the rest (boundary, dispatch,
+    # bookkeeping), so the parts sum to busy at every bump; and the
+    # request-life stamps: requests admitted with their submit -> admit
+    # wait, first tokens with their submit -> first-token time, and
+    # first -> last token time over the tokens after the first
+    "decode_steps": 0,
+    "decode_host_ns": 0,
+    "decode_step_wait_ns": 0,
+    "decode_prefill_ns": 0,
+    "decode_busy_ns": 0,
+    "decode_admitted": 0,
+    "decode_queue_wait_ns": 0,
+    "decode_first_tokens": 0,
+    "decode_ttft_ns": 0,
+    "decode_stream_ns": 0,
+    "decode_stream_tokens": 0,
+    # map verbs: verbs with their entry -> return time, entry -> first
+    # block (head) and last block enqueued -> return (tail); block-loop
+    # iterations with their start -> outputs-enqueued time; and the
+    # pool's blocking readbacks (PoolRun._materialize)
+    "map_verbs": 0,
+    "map_verb_ns": 0,
+    "map_head_ns": 0,
+    "map_tail_ns": 0,
+    "dispatch_blocks": 0,
+    "dispatch_host_ns": 0,
+    "readback_wait_ns": 0,
 }
 _by_verb: Dict[str, Dict[str, int]] = {}
 
@@ -588,6 +630,18 @@ def _bump(key: str, n: int = 1) -> None:
     if led is not None:
         led.add(key, n)
 
+
+def _bump_many(deltas: Mapping[str, int]) -> None:
+    """Several counters under ONE lock take — how a step, a block or a
+    verb bumps its count and its time counters together."""
+    with _counters_lock:
+        for key, n in deltas.items():
+            _counters[key] += n
+    led = _request_ctx.get()
+    if led is not None:
+        led.absorb(deltas)
+
+
 # the verb currently executing on this thread (set by verb_span even when
 # spans are disabled, so counter attribution never depends on enable())
 _current_verb: "contextvars.ContextVar[Optional[str]]" = (
@@ -797,11 +851,12 @@ def note_plan_stream_window() -> None:
     _bump("plan_stream_windows")
 
 
-def note_d2h_bytes(n: int) -> None:
-    """``n`` device bytes assembled back to host by the pooled readback
-    window (``PoolRun._materialize``) — the D2H half of the round trip a
-    fused terminal reduce eliminates."""
-    _bump("d2h_bytes_assembled", int(n))
+def note_readback(nbytes: int, ns: int) -> None:
+    """One blocking readback of a pooled block (``pool.readback``,
+    ``PoolRun._materialize``): ``nbytes`` device bytes assembled back to
+    host — the D2H half of the round trip a fused terminal reduce
+    eliminates — for which the dispatching thread stood blocked ``ns``."""
+    _bump_many({"d2h_bytes_assembled": int(nbytes), "readback_wait_ns": ns})
 
 
 def note_analysis_static_hit() -> None:
@@ -925,6 +980,31 @@ def note_decode_prefill_batch() -> None:
     """One bucket-coalesced prefill batch run by the disaggregated
     prefill lane of the decode scheduler."""
     _bump("decode_prefill_batches")
+
+
+def note_decode_driver(deltas: Mapping[str, int]) -> None:
+    """What one step or one prefill of the decode scheduler's driver
+    added to the ``decode_*`` counters (tokens, steps, prefill batches,
+    the time counters and the request-life stamps), in one bump."""
+    _bump_many(deltas)
+
+
+def note_dispatch_block(ns: int) -> None:
+    """One iteration of a map verb's block loop (``engine.block``):
+    start -> outputs enqueued took ``ns``."""
+    _bump_many({"dispatch_blocks": 1, "dispatch_host_ns": ns})
+
+
+def note_map_verb(verb_ns: int, head_ns: int, tail_ns: int) -> None:
+    """One map verb returned: entry -> return ``verb_ns``, of which
+    entry -> first block ``head_ns`` (``engine.head``) and last block
+    enqueued -> return ``tail_ns`` (``engine.tail``)."""
+    _bump_many({
+        "map_verbs": 1,
+        "map_verb_ns": verb_ns,
+        "map_head_ns": head_ns,
+        "map_tail_ns": tail_ns,
+    })
 
 
 def note_stream_window() -> None:
@@ -1099,6 +1179,24 @@ def counters_delta(
             "kv_pages_allocated",
             "kv_pages_freed",
             "decode_prefill_batches",
+            "decode_steps",
+            "decode_host_ns",
+            "decode_step_wait_ns",
+            "decode_prefill_ns",
+            "decode_busy_ns",
+            "decode_admitted",
+            "decode_queue_wait_ns",
+            "decode_first_tokens",
+            "decode_ttft_ns",
+            "decode_stream_ns",
+            "decode_stream_tokens",
+            "map_verbs",
+            "map_verb_ns",
+            "map_head_ns",
+            "map_tail_ns",
+            "dispatch_blocks",
+            "dispatch_host_ns",
+            "readback_wait_ns",
         )
     }
 
@@ -1109,9 +1207,8 @@ def counters_delta(
 # per-element) granularity by the execution stack: engine dispatch loops,
 # prefetch staging lanes, PoolRun readback, fault-tolerance instants,
 # cache evictions/spills, streaming windows, and the bridge request
-# lifecycle.  Off by default: every emission site is a single boolean
-# check (``trace_enabled``), so the suite's timing-sensitive fences and
-# the serving hot path pay nothing.  Events carry perf_counter-derived
+# lifecycle — every :class:`span` and :func:`instant`.  Off by default:
+# a span then appends nothing.  Events carry perf_counter-derived
 # microsecond timestamps relative to one process epoch; ``dump_trace``
 # renders them as Chrome-trace JSON with one track ("thread") per device
 # / staging lane, which Perfetto and chrome://tracing open directly.
@@ -1131,18 +1228,26 @@ _trace_state: Dict[str, Any] = {
     "override": None,
     "capacity": None,  # None follows TFS_TRACE_EVENTS
     "drops": 0,
-    "epoch": time.perf_counter(),
+    "epoch_ns": time.perf_counter_ns(),
+    "on": False,  # trace_enabled()'s kept answer; resolved below
 }
 
 
 def trace_enabled() -> bool:
     """Whether the flight recorder is on (API override, else
-    ``TFS_TRACE``).  The one check every emission site pays when
-    disabled."""
+    ``TFS_TRACE``).  Resolves the answer afresh and keeps it: a span
+    consults the kept answer — one dict read, where reading the
+    environment costs more than the whole of an idle span — so
+    ``TFS_TRACE`` counts as it stood at import, or at the last call of
+    this function, :func:`enable_trace` or :func:`disable_trace`."""
     ov = _trace_state["override"]
-    if ov is not None:
-        return bool(ov)
-    return envutil.env_raw(ENV_TRACE).lower() in _TRACE_TRUTHY
+    on = (
+        bool(ov)
+        if ov is not None
+        else envutil.env_raw(ENV_TRACE).lower() in _TRACE_TRUTHY
+    )
+    _trace_state["on"] = on
+    return on
 
 
 def enable_trace(capacity: Optional[int] = None) -> None:
@@ -1150,12 +1255,15 @@ def enable_trace(capacity: Optional[int] = None) -> None:
     ``capacity`` overrides ``TFS_TRACE_EVENTS`` for the ring buffer."""
     if capacity is not None:
         _trace_state["capacity"] = max(1, int(capacity))
-    _trace_state["override"] = True
+    _trace_state["override"] = _trace_state["on"] = True
 
 
 def disable_trace() -> None:
     """Pin the flight recorder off (wins over ``TFS_TRACE``)."""
-    _trace_state["override"] = False
+    _trace_state["override"] = _trace_state["on"] = False
+
+
+trace_enabled()  # TFS_TRACE as the process started
 
 
 def clear_trace() -> None:
@@ -1184,70 +1292,100 @@ def _trace_append(ev: Dict[str, Any]) -> None:
             _trace_state["drops"] += 1
 
 
-def trace_now() -> Optional[float]:
-    """``time.perf_counter()`` when tracing, else None — the start-stamp
-    helper for call sites that must not pay a clock read when disabled
-    (pair with :func:`trace_complete`, which no-ops on ``t0=None``)."""
-    return time.perf_counter() if trace_enabled() else None
+_now_ns = time.perf_counter_ns
 
 
-def trace_complete(
-    name: str, track: str, t0: Optional[float],
-    t1: Optional[float] = None, **args: Any,
+def _ring_event(
+    name: str, ph: str, track: str, t0_ns: int, dur_ns: Optional[int],
+    args: Dict[str, Any],
 ) -> None:
-    """Record one complete ("X") event spanning ``[t0, t1]`` on
-    ``track``.  No-op when disabled or ``t0`` is None.  ``args`` must be
-    JSON-safe primitives (they land in the Chrome-trace ``args`` pane)."""
-    if t0 is None or not trace_enabled():
-        return
-    if t1 is None:
-        t1 = time.perf_counter()
-    e = _trace_state["epoch"]
+    """Append one event to the ring (the recorder is known to be on)."""
     ev: Dict[str, Any] = {
         "name": name,
-        "ph": "X",
+        "ph": ph,
         "track": track,
-        "ts": round((t0 - e) * 1e6, 3),
-        "dur": round(max(0.0, t1 - t0) * 1e6, 3),
+        "ts": round((t0_ns - _trace_state["epoch_ns"]) / 1e3, 3),
     }
-    led = _request_ctx.get()
-    if led is not None and "cid" not in args:
-        # correlation (round 15): every event emitted under a request
-        # context carries its cid, so one Perfetto search strings a
-        # request's bridge/engine/staging/fault events together
-        args = dict(args, cid=led.correlation_id)
+    if dur_ns is not None:
+        ev["dur"] = round(max(0, dur_ns) / 1e3, 3)
     if args:
         ev["args"] = args
     _trace_append(ev)
 
 
-def trace_instant(name: str, track: str = "events", **args: Any) -> None:
-    """Record one instant ("i") event — retries, quarantines, evictions,
-    sheds: things that happen AT a moment rather than over one."""
-    if not trace_enabled():
-        return
-    ev: Dict[str, Any] = {
-        "name": name,
-        "ph": "i",
-        "track": track,
-        "ts": round((time.perf_counter() - _trace_state["epoch"]) * 1e6, 3),
-    }
+class span(_TraceAnnotation):
+    """THE span primitive: one program span, written in one call to both
+    places a span can go.
+
+    * Always: it IS a ``jax.profiler.TraceAnnotation`` named
+      ``"tfs:" + name`` on the calling thread.  With no profiler session
+      it costs about a microsecond and records nothing; inside one —
+      ``jax.profiler.start_trace(dir)`` ... ``stop_trace()`` around the
+      workload, the benchmark's or an operator's — the span lands in the
+      profiler's own trace, on the device trace's clock, with ``args``
+      as event stats.
+    * Only when the flight recorder is on (:func:`trace_enabled`): one
+      complete ("X") event on ``track`` in the ring, for
+      :func:`dump_trace`.
+
+    Names are STABLE (``engine.block``, never ``map_blocks b3``): what
+    varies rides in ``args`` (JSON-safe primitives).  The active
+    request's ``cid`` is added to both.  The span starts when it is
+    made and ends at :meth:`end`: ``with span(...) as sp:`` calls it, or
+    ``sp = span(...)`` ... ``sp.end(**late)`` where the code between is
+    a loop body with its own exits (a span never ended leaves no ring
+    event, and its annotation closes when the object is dropped).
+    ``late`` — arguments known only at the end (``shard_hit``) — reach
+    the ring only, as does a ``track`` set meanwhile.  ``ns`` holds the
+    duration once ended (``end`` returns it), so a time counter is
+    taken at exactly the span's boundaries."""
+
+    __slots__ = ("name", "track", "args", "ns", "_t0")
+
+    def __init__(self, name: str, track: str, **args: Any):
+        led = _request_ctx.get()
+        if led is not None and "cid" not in args:
+            # correlation (round 15): every span opened under a request
+            # context carries its cid, so one search strings a request's
+            # bridge/engine/staging events together
+            args["cid"] = led.correlation_id
+        _TraceAnnotation.__init__(self, "tfs:" + name, **args)
+        self.name = name
+        self.track = track
+        self.args = args
+        self._t0 = _now_ns()
+
+    def end(self, **late: Any) -> int:
+        ns = self.ns = _now_ns() - self._t0
+        _TraceAnnotation.__exit__(self, None, None, None)
+        if _trace_state["on"]:
+            if late:
+                self.args.update(late)
+            _ring_event(self.name, "X", self.track, self._t0, ns, self.args)
+        return ns
+
+    def __exit__(self, exc_type, exc, tb) -> bool:
+        # end(), spelled out: a call less on the path every span takes
+        ns = self.ns = _now_ns() - self._t0
+        _TraceAnnotation.__exit__(self, None, None, None)
+        if _trace_state["on"]:
+            _ring_event(self.name, "X", self.track, self._t0, ns, self.args)
+        return False
+
+
+def instant(name: str, track: str = "events", **args: Any) -> None:
+    """The instant form of :class:`span` — retries, quarantines,
+    evictions, a request's admit / first-token / retire stamps: things
+    that happen AT a moment.  A zero-length ``tfs:`` annotation in the
+    profiler's trace, an "i" event in the ring when the recorder is
+    on."""
     led = _request_ctx.get()
     if led is not None and "cid" not in args:
-        args = dict(args, cid=led.correlation_id)
-    if args:
-        ev["args"] = args
-    _trace_append(ev)
-
-
-@contextlib.contextmanager
-def trace_span(name: str, track: str, **args: Any):
-    """Context-manager form of :func:`trace_complete`."""
-    t0 = trace_now()
-    try:
-        yield
-    finally:
-        trace_complete(name, track, t0, **args)
+        args["cid"] = led.correlation_id
+    with _TraceAnnotation("tfs:" + name, **args):
+        pass
+    if _trace_state["on"]:
+        _ring_event(name, "i", track, _now_ns(), None, args)
 
 
 def trace_depth() -> int:
@@ -1730,42 +1868,17 @@ def initialize_logging(level=logging.INFO, stream=None) -> None:
     logger.propagate = False
 
 
-def enable(profile_dir: Optional[str] = None) -> None:
-    """Turn on per-verb phase spans (and jax.profiler traces when
-    ``profile_dir`` is given).
-
-    ``profile_dir`` semantics, explicit since round 13: EVERY verb call
-    is wrapped in its own ``jax.profiler.trace`` dump under the
-    directory, and jax supports **one active profiler trace per
-    process** — so per-verb profiling is a single-threaded diagnosis
-    tool.  When verbs overlap (threaded bridge handlers, user threads),
-    the verb that arrives second runs *unprofiled* (its span still
-    records; a warning logs once) rather than crashing the data plane
-    inside jax's second-trace error.  The directory is created here, up
-    front, and a jax build without profiler support fails here with a
-    clear error instead of at the first verb call."""
-    if profile_dir is not None:
-        try:
-            import jax.profiler
-
-            if not callable(getattr(jax.profiler, "trace", None)):
-                raise AttributeError(
-                    "jax.profiler.trace is missing or not callable"
-                )
-        except Exception as e:  # noqa: BLE001 — surfaced with context
-            raise RuntimeError(
-                f"observability.enable(profile_dir=...) requires a jax "
-                f"build with profiler support ({type(e).__name__}: {e}); "
-                f"call enable() without profile_dir for plain spans"
-            ) from e
-        os.makedirs(profile_dir, exist_ok=True)
+def enable() -> None:
+    """Turn on per-verb phase spans (``last_spans``).  For a device
+    timeline, wrap the workload in ONE profiler session —
+    ``jax.profiler.start_trace(dir)`` ... ``jax.profiler.stop_trace()``
+    — and every :class:`span` of the program lands in it beside the
+    device operations (``docs/OBSERVABILITY.md``)."""
     _state["enabled"] = True
-    _state["profile_dir"] = profile_dir
 
 
 def disable() -> None:
     _state["enabled"] = False
-    _state["profile_dir"] = None
 
 
 def is_enabled() -> bool:
@@ -1849,11 +1962,7 @@ class _NullSpan:
 
 _NULL = _NullSpan()
 
-
-# jax.profiler allows ONE active trace per process (see ``enable``); the
-# gate hands it to whichever verb arrives first and lets overlapping
-# verbs run unprofiled with a once-per-process warning
-_profiler_gate = threading.Lock()
+_MAP_VERBS = ("map_blocks", "map_rows")
 
 
 @contextlib.contextmanager
@@ -1864,52 +1973,31 @@ def verb_span(verb: str, rows: int, blocks: int):
     Always tags the thread with the verb name so the retrace counters
     attribute traces/compiles per verb even with spans disabled; always
     records the verb's wall time into the latency histograms (round 13)
-    — and, with the flight recorder on, a whole-verb event on the
-    ``verbs`` track."""
+    and opens the whole-verb :class:`span` (``engine.map`` for the two
+    map verbs, ``engine.verb`` for the rest, on the ``verbs`` track)."""
     token = _current_verb.set(verb)
-    t_verb = time.perf_counter()
-    t_trace = t_verb if trace_enabled() else None
+    sp = span(
+        "engine.map" if verb in _MAP_VERBS else "engine.verb",
+        "verbs", verb=verb, rows=rows, blocks=blocks,
+    )
     try:
         if not _state["enabled"]:
             yield _NULL
             return
-        span = _Span(verb, {"rows": rows, "blocks": blocks})
-        profile_dir = _state["profile_dir"]
+        vspan = _Span(verb, {"rows": rows, "blocks": blocks})
         try:
-            if profile_dir:
-                import jax
-
-                if _profiler_gate.acquire(blocking=False):
-                    try:
-                        with jax.profiler.trace(profile_dir):
-                            yield span
-                    finally:
-                        _profiler_gate.release()
-                else:
-                    # a concurrent verb holds the one process-wide
-                    # profiler trace: run unprofiled, never crash
-                    warn_once(
-                        logger,
-                        "observability:profiler-busy",
-                        "jax.profiler supports one trace at a time; a "
-                        "concurrent verb is being profiled, so %s runs "
-                        "unprofiled (spans still record)",
-                        verb,
-                    )
-                    yield span
-            else:
-                yield span
+            yield vspan
         except BaseException:
             # failed verbs must still record: the span is the diagnostic
-            span.meta["failed"] = True
+            vspan.meta["failed"] = True
             raise
         finally:
-            span._finish()
+            vspan._finish()
     finally:
         _current_verb.reset(token)
+        ns = sp.end()
         if not verb.startswith("bridge:"):
             # bridge methods are recorded end-to-end (admission wait
             # included) by the server itself — recording the execution
             # span here too would double-count the family
-            record_latency("verb", verb, time.perf_counter() - t_verb)
-        trace_complete(verb, "verbs", t_trace, rows=rows, blocks=blocks)
+            record_latency("verb", verb, ns / 1e9)

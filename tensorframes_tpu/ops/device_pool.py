@@ -270,8 +270,9 @@ class PoolRun:
         with _quarantine_lock:
             _quarantine_history.add(di)
         observability.note_device_quarantined()
-        observability.trace_instant(
-            "quarantine", "faults", device=di, failures=self.failures[di]
+        observability.instant(
+            "pool.quarantine", "faults", device=di,
+            failures=self.failures[di],
         )
         healthy = len(self.devices) - len(self.quarantined)
         logger.warning(
@@ -356,20 +357,18 @@ class PoolRun:
 
     def _materialize(self, di: int, out_blocks) -> None:
         bi, outs = self._window[di].pop(0)
-        t0 = time.perf_counter()
-        out_blocks[bi] = {k: np.asarray(v) for k, v in outs.items()}
-        observability.note_d2h_bytes(
-            sum(int(v.nbytes) for v in out_blocks[bi].values())
+        # the D2H materialisation is where a pooled block actually
+        # syncs: the span shows per-device readback overlap, the counter
+        # how long the dispatching thread stood blocked in it
+        with observability.span(
+            "pool.readback", f"device/{di}", block=bi, device=di
+        ) as sp:
+            out_blocks[bi] = {k: np.asarray(v) for k, v in outs.items()}
+        observability.note_readback(
+            sum(int(v.nbytes) for v in out_blocks[bi].values()), sp.ns
         )
-        now = time.perf_counter()
-        # flight recorder: the D2H materialisation is where a pooled
-        # block actually syncs — its track placement shows per-device
-        # readback overlap in the Perfetto timeline
-        observability.trace_complete(
-            f"readback b{bi}", f"device/{di}", t0, now, block=bi, device=di
-        )
-        self.drain_s += now - t0
-        self._last_done[di] = now
+        self.drain_s += sp.ns / 1e9
+        self._last_done[di] = time.perf_counter()
 
     def finish(self, out_blocks) -> None:
         for di in range(len(self.devices)):

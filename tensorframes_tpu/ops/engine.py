@@ -37,6 +37,7 @@ from __future__ import annotations
 
 import functools
 import logging
+import time
 from typing import Any, Dict, List, Mapping, Optional, Sequence, Tuple
 
 import jax
@@ -107,6 +108,38 @@ def _with_prelude(program: Program, host_stage):
 
 def _np(x) -> np.ndarray:
     return np.asarray(jax.device_get(x))
+
+
+class _MapTimes:
+    """One map verb's head and tail, as spans and as time counters:
+    ``engine.head`` runs from the verb's entry to the start of its block
+    loop (validation, bucket plan, pool and session set-up),
+    ``engine.tail`` from the last block enqueued to the return (the
+    pool's drain, output assembly).  Bumped once, with the verb's own
+    time, at the return; a verb that raises counts nothing."""
+
+    __slots__ = ("_t0", "_head", "_head_ns", "_tail")
+
+    def __init__(self):
+        self._t0 = time.perf_counter_ns()
+        self._head = observability.span("engine.head", "verbs")
+        self._head_ns = 0
+        self._tail = None
+
+    def first_block(self) -> None:
+        if self._head is not None:
+            self._head_ns = self._head.end()
+            self._head = None
+
+    def last_block(self) -> None:
+        self._tail = observability.span("engine.tail", "verbs")
+
+    def done(self) -> None:
+        self.first_block()  # an empty frame has no block loop
+        tail_ns = self._tail.end() if self._tail is not None else 0
+        observability.note_map_verb(
+            time.perf_counter_ns() - self._t0, self._head_ns, tail_ns
+        )
 
 
 class GroupedFrame:
@@ -606,16 +639,19 @@ class Executor:
         with observability.verb_span(
             "map_blocks", frame.num_rows, frame.num_blocks
         ) as span:
+            times = _MapTimes()
             infos = validation.check_map_inputs(
                 program, frame, "map_blocks", host_staged=host_stage or ()
             )
             span.mark("validate")
             out_blocks = self._map_dispatch(
                 program, frame, infos, host_stage, span,
-                rows_level=False, trim=trim,
+                rows_level=False, trim=trim, times=times,
             )
             span.mark("dispatch")
-            return self._build_map_output(frame, out_blocks, trim)
+            out = self._build_map_output(frame, out_blocks, trim)
+            times.done()
+            return out
 
     def _map_dispatch(
         self,
@@ -626,6 +662,7 @@ class Executor:
         span,
         rows_level: bool,
         trim: bool,
+        times: "_MapTimes",
     ) -> List[Dict[str, Any]]:
         """Shared block loop of the two map verbs, prefetched: up to
         ``TFS_PREFETCH_BLOCKS`` blocks are staged (host cast + host_stage +
@@ -661,7 +698,7 @@ class Executor:
         if cache is not None:
             return self._map_dispatch_sharded(
                 program, frame, infos, host_stage, span, rows_level, trim,
-                cache,
+                cache, times,
             )
         # plan on the caller thread: _stream_plan and _bucket_plan may
         # trace (row-independence proofs); all jit entry points stay off
@@ -700,7 +737,7 @@ class Executor:
         if len(pool_devs) >= 2:
             return self._map_dispatch_pool(
                 program, frame, infos, host_stage, span, rows_level, trim,
-                plans, pads, donate, pool_devs, session,
+                plans, pads, donate, pool_devs, session, times,
             )
         # only spin up a staging thread when some block will actually
         # stage on it; otherwise (device-resident frame, or every block
@@ -724,13 +761,17 @@ class Executor:
         items = pf if pf is not None else (
             None for _ in range(frame.num_blocks)
         )
+        times.first_block()
         for bi, staged in enumerate(items):
             # cooperative cancellation (bridge deadlines / drain): the
             # block boundary is the check granularity — one contextvar
             # read when no scope is active
             cancellation.checkpoint()
-            t_blk = observability.trace_now()  # flight recorder (r13)
             n_rows = block_sizes[bi]
+            sp = observability.span(
+                "engine.block", "serial",
+                verb=verb, block=bi, rows=n_rows, device=0,
+            )
             if plans[bi] is not None:
                 outs = self._run_block_streamed(
                     program, frame.block(bi), infos, plans[bi],
@@ -769,10 +810,9 @@ class Executor:
             # block when no ledger is active — the documented hot-path
             # cost of the attribution layer on the serial loop
             observability.note_request_block(0, n_rows)
-            observability.trace_complete(
-                f"{verb} b{bi}", "serial", t_blk, block=bi, rows=n_rows
-            )
+            observability.note_dispatch_block(sp.end())
             out_blocks.append(outs)
+        times.last_block()
         # the loop consumed every item, so the staging thread has finished
         # (its last stats write happened-before the last queue get): pf.stats
         # is safe to read and merge with the chunk prefetchers' totals.
@@ -1093,7 +1133,8 @@ class Executor:
         pads: Sequence[Optional[int]],
         donate: bool,
         devices: Sequence[Any],
-        session=None,
+        session,
+        times: "_MapTimes",
     ) -> List[Dict[str, Any]]:
         """Device-pool edition of the map-verb block loop: blocks dispatch
         round-robin/least-loaded across ``devices`` with per-device
@@ -1156,10 +1197,14 @@ class Executor:
         chunk_stats = {"items": 0, "stage_s": 0.0, "wait_s": 0.0}
         out_blocks: List[Optional[Dict[str, Any]]] = [None] * nb
         lane_dead = [False] * (1 if single_iter is not None else len(devices))
+        times.first_block()
         for bi in range(nb):
             cancellation.checkpoint()  # block boundary (pooled loop)
-            t_blk = observability.trace_now()  # flight recorder (r13)
             di = assignment[bi]
+            sp = observability.span(
+                "engine.block", f"device/{di}",
+                verb=verb, block=bi, rows=sizes[bi], device=di,
+            )
             li = 0 if single_iter is not None else di
             it = single_iter if single_iter is not None else lane_iters[di]
             # the shared host_stage lane stages blocks for EVERY device,
@@ -1202,11 +1247,10 @@ class Executor:
                 if pads[bi] is not None:
                     outs = {k: v[:n_rows] for k, v in outs.items()}
             self._check_block_outputs(program, outs, n_rows, rows_level, trim)
-            observability.trace_complete(
-                f"{verb} b{bi}", f"device/{di_eff}", t_blk,
-                block=bi, rows=n_rows, device=di_eff,
-            )
+            sp.track = f"device/{di_eff}"  # a quarantine may have moved it
+            observability.note_dispatch_block(sp.end(device=di_eff))
             pool.submit(bi, di_eff, n_rows, outs, out_blocks)
+        times.last_block()
         pool.finish(out_blocks)
         staged_blocks = sum(1 for p in plans if p is None)
         stage_s = (
@@ -1243,6 +1287,7 @@ class Executor:
         rows_level: bool,
         trim: bool,
         cache,
+        times: "_MapTimes",
     ) -> List[Dict[str, Any]]:
         """Affinity-aware dispatch for sharded-cached frames
         (``ops/frame_cache.py``): block ``bi``'s program runs on the
@@ -1296,10 +1341,14 @@ class Executor:
         out_blocks: List[Optional[Dict[str, Any]]] = [None] * nb
         hits = 0
         restaged = 0
+        times.first_block()
         for bi in range(nb):
             cancellation.checkpoint()  # block boundary (sharded loop)
-            t_blk = observability.trace_now()  # flight recorder (r13)
             di = cache.assignment[bi]
+            sp = observability.span(
+                "engine.block", f"device/{di}",
+                verb=verb, block=bi, rows=sizes[bi], device=di,
+            )
             di_eff = pool.effective_device(di) if session is not None else di
             shard = cache.shard(bi)
             block = dict(frame.block(bi))
@@ -1346,11 +1395,12 @@ class Executor:
                 if pads[bi] is not None:
                     outs = {k: v[:n_rows] for k, v in outs.items()}
             self._check_block_outputs(program, outs, n_rows, rows_level, trim)
-            observability.trace_complete(
-                f"{verb} b{bi}", f"device/{di_eff}", t_blk,
-                block=bi, rows=n_rows, device=di_eff, shard_hit=used,
+            sp.track = f"device/{di_eff}"  # a quarantine may have moved it
+            observability.note_dispatch_block(
+                sp.end(device=di_eff, shard_hit=used)
             )
             pool.submit(bi, di_eff, n_rows, outs, out_blocks)
+        times.last_block()
         pool.finish(out_blocks)
         span.annotate("device_pool", pool.record())
         fc = cache.record()
@@ -1425,6 +1475,7 @@ class Executor:
         with observability.verb_span(
             "map_rows", frame.num_rows, frame.num_blocks
         ) as span:
+            times = _MapTimes()
             infos = validation.check_map_inputs(
                 program,
                 frame,
@@ -1440,20 +1491,24 @@ class Executor:
                 and frame.column(program.column_for_input(n)).is_ragged
             ]
             if ragged:
+                times.first_block()  # shape buckets, not the block loop
                 out = self._map_rows_ragged(
                     program, frame, infos, host_stage, ragged
                 )
                 span.mark("dispatch")
+                times.done()
                 return out
             # row programs are row-independent BY CONSTRUCTION (the cell
             # program is vmapped), so big uncached blocks always stream
             # their h2d in chunks (check_independence=False in the plan)
             out_blocks = self._map_dispatch(
                 program, frame, infos, host_stage, span,
-                rows_level=True, trim=False,
+                rows_level=True, trim=False, times=times,
             )
             span.mark("dispatch")
-            return self._build_map_output(frame, out_blocks, trim=False)
+            out = self._build_map_output(frame, out_blocks, trim=False)
+            times.done()
+            return out
 
     def _run_rows_bucket(
         self, program: Program, arrays: Dict[str, jnp.ndarray]
@@ -2060,7 +2115,10 @@ class Executor:
             partials: List[Dict[str, jnp.ndarray]] = []
             for bi in nonempty:
                 cancellation.checkpoint()  # block boundary (partials)
-                t_blk = observability.trace_now()  # flight recorder
+                sp = observability.span(
+                    "engine.reduce_block", "serial",
+                    block=bi, rows=sizes[bi], device=0,
+                )
 
                 def attempt(a, dev_i, _bi=bi):
                     block = frame.block(_bi)
@@ -2079,10 +2137,7 @@ class Executor:
                         session.run(bi, sizes[bi], attempt, device=0)
                     )
                 observability.note_request_block(0, sizes[bi])
-                observability.trace_complete(
-                    f"reduce b{bi}", "serial", t_blk,
-                    block=bi, rows=sizes[bi],
-                )
+                sp.end()
             if session is not None and session.events():
                 span.annotate("fault_tolerance", session.record())
             span.mark("dispatch_partials")
@@ -2110,8 +2165,11 @@ class Executor:
         partials = []
         for k, bi in enumerate(nonempty):
             cancellation.checkpoint()  # block boundary (pooled partials)
-            t_blk = observability.trace_now()  # flight recorder (r13)
             di = assignment[k]
+            sp = observability.span(
+                "engine.reduce_block", f"device/{di}",
+                block=bi, rows=sizes[bi], device=di,
+            )
             if session is None:
                 arrays = next(lane_iters[di])
                 p = run(arrays)
@@ -2142,10 +2200,8 @@ class Executor:
                 )
                 di_eff = pool.effective_device(di)
             pool.note_dispatch(di_eff, sizes[bi])
-            observability.trace_complete(
-                f"reduce b{bi}", f"device/{di_eff}", t_blk,
-                block=bi, rows=sizes[bi], device=di_eff,
-            )
+            sp.track = f"device/{di_eff}"
+            sp.end(device=di_eff)
             # async hop to the combine device: one reduced cell per base
             partials.append(
                 {b: jax.device_put(p[b], combine) for b in bases}
@@ -2186,8 +2242,11 @@ class Executor:
         hits = 0
         for bi in nonempty:
             cancellation.checkpoint()  # block boundary (sharded partials)
-            t_blk = observability.trace_now()  # flight recorder (r13)
             di = cache.assignment[bi]
+            sp = observability.span(
+                "engine.reduce_block", f"device/{di}",
+                block=bi, rows=sizes[bi], device=di,
+            )
             shard0 = cache.shard(bi)
             has_shard = shard0 is not None and any(
                 cols[b] in shard0 for b in bases
@@ -2240,11 +2299,8 @@ class Executor:
                 hits += 1
                 observability.note_cache_shard_hit()
             pool.note_dispatch(di_eff, sizes[bi])
-            observability.trace_complete(
-                f"reduce b{bi}", f"device/{di_eff}", t_blk,
-                block=bi, rows=sizes[bi], device=di_eff,
-                shard_hit=used["v"],
-            )
+            sp.track = f"device/{di_eff}"
+            sp.end(device=di_eff, shard_hit=used["v"])
             # async hop to the combine device: one reduced cell per base
             partials.append(
                 {b: jax.device_put(p[b], combine) for b in bases}

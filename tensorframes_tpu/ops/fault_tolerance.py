@@ -236,8 +236,8 @@ class FrameRetrySession:
                 )
                 self.retries += 1
                 observability.note_block_retry()
-                observability.trace_instant(
-                    "retry",
+                observability.instant(
+                    "engine.retry",
                     "faults",
                     verb=self.verb,
                     block=bi,
@@ -268,8 +268,8 @@ class FrameRetrySession:
         """One binary OOM split performed for block ``bi``."""
         self.oom_splits += 1
         observability.note_oom_split()
-        observability.trace_instant(
-            "oom_split", "faults", verb=self.verb, block=bi
+        observability.instant(
+            "engine.oom_split", "faults", verb=self.verb, block=bi
         )
 
     def note_cache_restage(self) -> None:

@@ -296,8 +296,8 @@ class FrameCache:
                 observability.note_h2d_bytes(arr.nbytes)
                 staged[name] = jax.device_put(arr, dev)
             if self.insert(bi, staged):
-                observability.trace_instant(
-                    "spill_restore", "cache", block=bi
+                observability.instant(
+                    "cache.spill_restore", "cache", block=bi
                 )
                 return self.blocks[bi]
             # the budget cannot hold it even now — the disk copy stays
@@ -324,8 +324,8 @@ class FrameCache:
             self._spilled.add(bi)
             spilled_now = True
         if shard is not None:
-            observability.trace_instant(
-                "evict",
+            observability.instant(
+                "cache.evict",
                 "cache",
                 block=bi,
                 bytes=self.nbytes[bi],
@@ -738,8 +738,8 @@ def release_host_columns(frame) -> int:
                 cache, name, frame.offsets, d.dtype, d.shape[1:]
             )
     if released:
-        observability.trace_instant(
-            "release_host", "cache", bytes=released,
+        observability.instant(
+            "cache.release_host", "cache", bytes=released,
             blocks=frame.num_blocks,
         )
     return released

@@ -1240,8 +1240,12 @@ def _run_pooled_chain(
     shard_hits = 0
     for bi in range(nb):
         cancellation.checkpoint()  # block boundary (pooled chain)
-        t_blk = observability.trace_now()  # flight recorder
         di = assignment[bi]
+        sp = observability.span(
+            "plan.block", f"device/{di}",
+            verb=terminal.verb if terminal is not None else "map",
+            block=bi, rows=sizes[bi], device=di,
+        )
         if terminal is not None and sizes[bi] == 0:
             # the eager reduce never dispatches empty blocks; consume
             # the staged lane entry so later blocks stay aligned
@@ -1336,10 +1340,8 @@ def _run_pooled_chain(
                 )
             eff_assign.append(di_eff)
             pool.note_dispatch(di_eff, sizes[bi])
-            observability.trace_complete(
-                f"plan+{terminal.verb} b{bi}", f"device/{di_eff}", t_blk,
-                block=bi, rows=sizes[bi],
-            )
+            sp.track = f"device/{di_eff}"
+            sp.end(device=di_eff)
             continue
         if pads[bi] is not None:
             # bucket-padded chain: slice the pad rows back off (the
@@ -1350,10 +1352,8 @@ def _run_pooled_chain(
             adopt_outs[bi] = outs
         eff_assign.append(di_eff)
         pool.submit(bi, di_eff, sizes[bi], outs, out_blocks)
-        observability.trace_complete(
-            f"plan b{bi}", f"device/{di_eff}", t_blk,
-            block=bi, rows=sizes[bi],
-        )
+        sp.track = f"device/{di_eff}"
+        sp.end(device=di_eff)
     pool.finish(out_blocks)
     if terminal is not None:
         rec = {

@@ -186,7 +186,12 @@ class Prefetcher:
             # synchronous fallback: stage inline on the consumer thread
             i = 0
             while self._n is None or i < self._n:
-                t0 = time.perf_counter()
+                # one span per staged item on this lane's track
+                # (synchronous path: staging == waiting)
+                sp = observability.span(
+                    "stage.block", f"lane/{self._name}",
+                    block=i, lane=self._name,
+                )
                 try:
                     v = self._stage(i)
                 except StopIteration as e:
@@ -201,17 +206,11 @@ class Prefetcher:
                             f"{self._n} items"
                         ) from e
                     return  # unbounded source exhausted
-                t1 = time.perf_counter()
-                dt = t1 - t0
+                dt = sp.end() / 1e9
                 self.stats["stage_s"] += dt
                 self.stats["wait_s"] += dt
                 if self._n is None:
                     self.stats["items"] += 1
-                # flight recorder: one event per staged item on this
-                # lane's track (synchronous path: staging == waiting)
-                observability.trace_complete(
-                    f"stage {i}", f"lane/{self._name}", t0, t1, item=i
-                )
                 yield v
                 i += 1
             return
@@ -237,7 +236,12 @@ class Prefetcher:
                 while self._n is None or i < self._n:
                     if stop.is_set():
                         return
-                    t0 = time.perf_counter()
+                    # the staging timeline per lane: the H2D/compute
+                    # overlap half of the trace
+                    sp = observability.span(
+                        "stage.block", f"lane/{self._name}",
+                        block=i, lane=self._name,
+                    )
                     try:
                         v = self._stage(i)
                     except StopIteration:
@@ -248,15 +252,9 @@ class Prefetcher:
                             # otherwise block on the queue forever)
                             raise
                         break  # unbounded source exhausted
-                    t1 = time.perf_counter()
-                    self.stats["stage_s"] += t1 - t0
+                    self.stats["stage_s"] += sp.end() / 1e9
                     if self._n is None:
                         self.stats["items"] += 1
-                    # flight recorder: staging timeline per lane — the
-                    # H2D/compute-overlap half of the Perfetto view
-                    observability.trace_complete(
-                        f"stage {i}", f"lane/{self._name}", t0, t1, item=i
-                    )
                     if not put((v, None)):
                         return
                     i += 1

@@ -543,7 +543,9 @@ class JournalWriter:
                 f"{'closed' if self._closed else 'completed'} journal"
             )
         idx = len(self._boundaries)
-        t0 = observability.trace_now()
+        sp = observability.span(
+            "journal.append", "recovery", job=self.job_id, boundary=idx
+        )
         faults.maybe_kill_boundary(idx, "pre")
         entry: Dict[str, Any] = {"extra": dict(extra or {})}
         stale: List[str] = []
@@ -588,10 +590,7 @@ class JournalWriter:
         for name in stale:
             _rm(os.path.join(self.dir, name))
         observability.note_journal_append()
-        observability.trace_complete(
-            f"journal b{idx}", "recovery", t0,
-            job=self.job_id, boundary=idx,
-        )
+        sp.end()
         faults.maybe_kill_boundary(idx, "post")
         return idx
 
@@ -635,8 +634,8 @@ class JournalWriter:
         self._write_manifest()
         for name in stale:
             _rm(os.path.join(self.dir, name))
-        observability.trace_instant(
-            "journal complete", "recovery", job=self.job_id,
+        observability.instant(
+            "journal.complete", "recovery", job=self.job_id,
             boundaries=len(self._boundaries),
         )
         self.close()

@@ -329,16 +329,15 @@ class BroadcastJoinStream(StreamFrame):
         self._ensure_index()
         for wi, wf in enumerate(self._left.windows()):
             cancellation.checkpoint()
-            t_win = observability.trace_now()
+            sp = observability.span(
+                "join.window", "relational",
+                window=wi, probe_rows=wf.num_rows, strategy="broadcast",
+            )
             out = _join_window(
                 wf, self._index, self._on, self._how, self._num_blocks
             )
             if out is not None:
-                observability.trace_complete(
-                    f"join window {wi}", "relational", t_win,
-                    window=wi, probe_rows=wf.num_rows,
-                    out_rows=out.num_rows, strategy="broadcast",
-                )
+                sp.end(out_rows=out.num_rows)
                 yield out
 
 
@@ -467,7 +466,10 @@ class SortMergeJoinStream(StreamFrame):
         self._ensure_shuffled()
         for p in range(self._P):
             cancellation.checkpoint()
-            t_win = observability.trace_now()
+            sp = observability.span(
+                "join.partition", "relational",
+                partition=p, strategy="sort_merge",
+            )
             lp = self._materialize(self._ls.partition(p))
             if lp is None:
                 continue
@@ -481,11 +483,9 @@ class SortMergeJoinStream(StreamFrame):
                 lp, index, self._on, self._how, self._num_blocks
             )
             if out is not None:
-                observability.trace_complete(
-                    f"join partition {p}", "relational", t_win,
-                    partition=p, probe_rows=lp.num_rows,
-                    build_rows=rp.num_rows, out_rows=out.num_rows,
-                    strategy="sort_merge",
+                sp.end(
+                    probe_rows=lp.num_rows, build_rows=rp.num_rows,
+                    out_rows=out.num_rows,
                 )
                 yield out
 

@@ -435,7 +435,7 @@ def run_stream_pipeline(
     rows = prior_rows
     it = iter(cur.windows())
     i = start_window
-    t_pipe = observability.trace_now()
+    sp_pipe = observability.span("pipeline", "relational")
     try:
         while True:
             cancellation.checkpoint()
@@ -522,9 +522,7 @@ def run_stream_pipeline(
         if writer is not None:
             writer.close()  # stays resumable from the journal
         raise
-    observability.trace_complete(
-        "pipeline", "relational", t_pipe, windows=i, rows=rows,
-    )
+    sp_pipe.end(windows=i, rows=rows)
 
     result: Dict[str, Any] = {
         "rows": rows,

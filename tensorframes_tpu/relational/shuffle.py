@@ -579,7 +579,9 @@ def shuffle(
     written: List[str] = []
     window_written: List[str] = []
     completed = False
-    t_shuffle = observability.trace_now()
+    sp_shuffle = observability.span(
+        "shuffle", "relational", key=key, partitions=P
+    )
     try:
         for wi, wf in enumerate(windows, start=start_window):
             # window boundary = cancellation checkpoint (PR 6): a
@@ -587,7 +589,10 @@ def shuffle(
             # window partitions, and the runs written so far are
             # discarded atomically below
             cancellation.checkpoint()
-            t_win = observability.trace_now()
+            sp = observability.span(
+                "shuffle.window", "relational",
+                window=wi, rows=wf.num_rows, key=key,
+            )
             kcol = _check_key_column(wf, key)
             if infos is None:
                 kinds = _column_kinds(wf)
@@ -621,10 +626,7 @@ def shuffle(
                     extra["kinds"] = kinds
                 writer.append(extra=extra)
                 window_written = []
-            observability.trace_complete(
-                f"shuffle window {wi}", "relational", t_win,
-                window=wi, rows=wf.num_rows, key=key,
-            )
+            sp.end()
         completed = True
     finally:
         if not completed:
@@ -640,10 +642,7 @@ def shuffle(
                 # re-key
                 for k in written:
                     spill.delete(k)
-    observability.trace_complete(
-        "shuffle", "relational", t_shuffle,
-        key=key, partitions=P, rows=sum(partition_rows),
-    )
+    sp_shuffle.end(rows=sum(partition_rows))
     _note_shuffle_stats(key, partition_rows)
     with _closing_on_error(writer):
         if infos is None:

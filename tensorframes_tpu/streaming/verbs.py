@@ -222,6 +222,14 @@ def _frame_bytes(frame: TensorFrame) -> int:
     return total
 
 
+def _window_args(frame: TensorFrame) -> Dict[str, int]:
+    """What a ``stream.window`` span learns at its end, for the ring
+    alone — so sized only when the recorder is on."""
+    if not observability.trace_enabled():
+        return {}
+    return {"rows": frame.num_rows, "bytes": _frame_bytes(frame)}
+
+
 def _annotate(span, stream: StreamFrame, windows: int, rows: int) -> None:
     span.annotate(
         "streaming",
@@ -298,9 +306,11 @@ def _drain_to_sink(
             it = iter(outputs)
             while True:
                 # the window's verb dispatch happens inside next(): the
-                # flight-recorder event spans compute + sink write, one
-                # event per window on the "stream" track
-                t_win = observability.trace_now()
+                # span covers compute + sink write, one per window on
+                # the "stream" track
+                sp = observability.span(
+                    "stream.window", "stream", window=windows
+                )
                 try:
                     out = it.__next__()
                 except StopIteration:
@@ -312,11 +322,7 @@ def _drain_to_sink(
                     # the two re-runs the window; the part rewrite is
                     # idempotent — same window, same bytes)
                     writer.append(extra={"rows": out.num_rows})
-                observability.trace_complete(
-                    f"window {windows}", "stream", t_win,
-                    window=windows, rows=out.num_rows,
-                    bytes=_frame_bytes(out) if t_win is not None else 0,
-                )
+                sp.end(**_window_args(out))
                 windows += 1
                 rows += out.num_rows
                 del out
@@ -511,7 +517,9 @@ def _reduce_stream(
             windows, rows = start_window, prior_rows
             for wf in stream.windows():
                 cancellation.checkpoint()
-                t_win = observability.trace_now()
+                sp = observability.span(
+                    "stream.window", "stream", window=windows
+                )
                 if setup is None:
                     setup = (
                         ex._reduce_rows_setup(program, wf, mode)
@@ -535,11 +543,7 @@ def _reduce_stream(
                         ),
                         extra={"rows": wf.num_rows},
                     )
-                observability.trace_complete(
-                    f"window {windows}", "stream", t_win,
-                    window=windows, rows=wf.num_rows,
-                    bytes=_frame_bytes(wf) if t_win is not None else 0,
-                )
+                sp.end(**_window_args(wf))
                 windows += 1
                 rows += wf.num_rows
             if setup is None:
@@ -715,7 +719,9 @@ def aggregate(
             windows, rows = start_window, prior_rows
             for wf in stream.windows():
                 cancellation.checkpoint()
-                t_win = observability.trace_now()
+                sp = observability.span(
+                    "stream.window", "stream", window=windows
+                )
                 part = ex.aggregate(program, GroupedFrame(wf, keys))
                 acc = (
                     part
@@ -736,11 +742,7 @@ def aggregate(
                         extra={**extra, "rows": wf.num_rows},
                         replace_state=True,
                     )
-                observability.trace_complete(
-                    f"window {windows}", "stream", t_win,
-                    window=windows, rows=wf.num_rows,
-                    bytes=_frame_bytes(wf) if t_win is not None else 0,
-                )
+                sp.end(**_window_args(wf))
                 windows += 1
                 rows += wf.num_rows
             if acc is None:

@@ -208,6 +208,7 @@ def test_coalesced_ledger_row_shares_sum_to_global_delta():
     setup = threading.Barrier(4)
     go = threading.Barrier(4)
     fired = threading.Barrier(4)
+    snapped = threading.Barrier(4)
     try:
 
         def worker(k):
@@ -220,7 +221,8 @@ def test_coalesced_ledger_row_shares_sum_to_global_delta():
                 go.wait()  # main thread snapshots between these
                 out = f.map_blocks(_add3_graph(), fetches=["z"])
                 cids[k] = c.last_correlation_id
-                fired.wait()  # maps (only) inside the delta window
+                fired.wait()  # maps (only) inside the delta window:
+                snapped.wait()  # no collect before the window is shut
                 outs[k] = out.collect()["z"]
                 atts[k] = c.attribution(cids[k])["ledger"]
 
@@ -232,6 +234,7 @@ def test_coalesced_ledger_row_shares_sum_to_global_delta():
             go.wait()
             fired.wait()
             state["after"] = observability.counters()
+            snapped.wait()
 
         t = threading.Thread(target=main_side)
         t.start()

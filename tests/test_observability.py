@@ -99,18 +99,6 @@ def test_span_buffer_bounded():
     assert observability._state["spans"][-1]["verb"] == "map_blocks"
 
 
-def test_profile_dir_writes_trace(tmp_path):
-    import os
-
-    observability.enable(profile_dir=str(tmp_path / "prof"))
-    tfs.map_blocks(lambda x: {"z": x + 1.0}, _frame())
-    observability.disable()
-    dumped = []
-    for root, _, files in os.walk(tmp_path / "prof"):
-        dumped.extend(files)
-    assert dumped, "jax.profiler trace produced no files"
-
-
 # ---------------------------------------------------------------------------
 # retrace counters (round 7)
 # ---------------------------------------------------------------------------

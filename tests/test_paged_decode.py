@@ -304,8 +304,14 @@ def test_scheduler_deadline_expiry_frees_pages_neighbors_bit_identical(
 
 def test_scheduler_drain_mid_stream_completes_in_flight(params):
     """close() mid-stream drains: already-submitted streams run to
-    retirement bit-identically; later submits are refused outright."""
-    jobs = _prompts(((5, 10), (8, 10), (11, 10)), seed=6)
+    retirement bit-identically; later submits are refused outright.
+
+    Deterministic since the scheduler counts admissions: close() comes
+    only once every stream has been ADMITTED (``decode_admitted`` in the
+    snapshot), so no submit can still be on its way in and lose the
+    race with close() — the way this test used to fail under load —
+    and the streams are long enough that the batch is still live."""
+    jobs = _prompts(((5, 40), (8, 40), (11, 40)), seed=6)
     sched = DecodeScheduler(
         params, CFG, max_slots=4, tokens_per_page=PAGE, max_seq=CAP
     )
@@ -325,21 +331,23 @@ def test_scheduler_drain_mid_stream_completes_in_flight(params):
     ]
     for t in ts:
         t.start()
-    deadline = time.monotonic() + 30
+    deadline = time.monotonic() + 60
     while time.monotonic() < deadline:
-        if sched.snapshot()["active"] >= 1:
+        if sched.snapshot()["decode_admitted"] == len(jobs):
             break
-        time.sleep(0.005)
+        time.sleep(0.001)
     else:
-        pytest.fail("no stream ever became active")
-    sched.close()  # mid-stream: the batch is live right now
+        pytest.fail("not every stream was admitted")
+    sched.close()  # every stream is in flight (or already retired)
     for t in ts:
         t.join()
     if errs:
         raise errs[0]
     for i in range(len(jobs)):
         assert results[i] == refs[i], f"stream {i} diverged across drain"
-    assert sched.snapshot()["pages_used"] == 0
+    snap = sched.snapshot()
+    assert snap["pages_used"] == 0
+    assert snap["decode_first_tokens"] == len(jobs)
     with pytest.raises(RuntimeError):
         sched.submit(np.arange(4, dtype=np.int32), 2, timeout_s=5)
 

@@ -32,14 +32,17 @@ from tensorframes_tpu.graphdef.builder import GraphBuilder
 
 @pytest.fixture(autouse=True)
 def _telemetry_reset():
-    observability.clear_trace()
-    observability._trace_state["override"] = None
-    observability.reset_request_metrics()
+    _follow_env()
     yield
+    _follow_env()
+    observability.disable()
+
+
+def _follow_env():
     observability.clear_trace()
     observability._trace_state["override"] = None
+    observability.trace_enabled()  # re-resolve: spans read the kept answer
     observability.reset_request_metrics()
-    observability.disable()
 
 
 def _frame(n=64, blocks=4, extra_cols=()):
@@ -293,12 +296,12 @@ def test_bridge_request_attribution_with_deadline_and_faults(monkeypatch):
                 if e.get("args", {}).get("cid") == cid
             ]
             tracks = {e["track"] for e in evs}
-            names = {e["name"].split(" ")[0] for e in evs}
+            names = {e["name"] for e in evs}
             assert any(t.startswith("bridge/") for t in tracks)  # bridge
             assert "serial" in tracks or any(
                 t.startswith("device/") for t in tracks
             )  # engine
-            assert "faults" in tracks and "retry" in names  # fault layer
+            assert "faults" in tracks and "engine.retry" in names  # faults
             # the verb still computed correctly through the retry
             np.testing.assert_allclose(
                 out.collect()["z"], np.arange(24.0) + 3.0

@@ -20,8 +20,9 @@ Rules (each violation prints ``file:line: [rule] message``):
   module constants) must appear in ``docs/COMPONENTS.md`` (the operator
   knob reference) AND in ``tests/conftest.py`` (the absence-default pin
   block that keeps the main suite's trace/compile fences deterministic).
-* **counter-decl** — every counter key ``observability._bump`` is called
-  with must be declared in the ``_counters`` init dict; every declared
+* **counter-decl** — every counter key ``observability._bump`` (or a
+  literal dict given to ``_bump_many``) is called with must be declared
+  in the ``_counters`` init dict; every declared
   counter (gauges excepted) must be listed in ``counters_delta``; no
   delta duplicates; no registered gauge name may collide with a counter
   family (``tfs_<name>_total``) — the ``metrics_text`` no-dup-family
@@ -275,6 +276,14 @@ def check_counters(root: str) -> List[Violation]:
                 node.args[0], ast.Constant
             ) and isinstance(node.args[0].value, str):
                 bumps.append((node.args[0].value, node.lineno))
+            if name == "_bump_many" and node.args and isinstance(
+                node.args[0], ast.Dict
+            ):
+                for k in node.args[0].keys:
+                    if isinstance(k, ast.Constant) and isinstance(
+                        k.value, str
+                    ):
+                        bumps.append((k.value, k.lineno))
         if isinstance(node, ast.FunctionDef) and node.name == (
             "counters_delta"
         ):

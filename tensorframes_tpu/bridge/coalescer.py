@@ -1316,11 +1316,14 @@ class DecodeScheduler:
     shared :class:`~..models.kv_pager.PagePool`, and the driver thread
     alternates two fixed-shape compiled dispatches:
 
-    * **prefill lane** (disaggregated): sequences admitted at a step
-      boundary prefill together as one bucket-padded batch
-      (``ops/bucketing`` ladder — the same geometric ladder every verb
-      uses, so the executable grid stays bounded), writing their
-      prompts' KV straight into their reserved pages;
+    * **prefill lane** (disaggregated): each sequence admitted at a
+      step boundary prefills in a dispatch of its own, in admission
+      order, padded to the bucket of its OWN prompt (``ops/bucketing``
+      ladder — the same geometric ladder every verb uses, so the
+      executable grid stays bounded and one ``submit`` per bucket warms
+      it).  The dispatch computes that prompt and nothing else — one
+      row, attention over the prompt, the head at its last position —
+      writing the prompt's KV straight into its reserved pages;
     * **decode lane**: one ``[max_slots]``-shaped greedy step for the
       whole population; slots join at step boundaries and retire the
       moment their stream finishes (``max_new`` reached, ``until`` hit,
@@ -1338,7 +1341,10 @@ class DecodeScheduler:
     perturbing neighbors: per-row results are bit-identical to solo
     ``decode.generate`` at the scheduler's capacity (rows under the
     batched einsums are independent; masked slots carry exact-zero
-    weight; the attention reduction extent matches by construction).
+    weight; a decode step's attention reduction extent matches by
+    construction, a prefill's is its bucket — the keys it leaves out
+    had zero weight, so the two agree to f32 rounding and, in the
+    suite on XLA:CPU, token for token).
 
     ``speculative`` runs the draft/verify path (B=1 by its contract)
     solo in the caller's thread — an opt-in per-request latency knob,
@@ -1820,71 +1826,65 @@ class DecodeScheduler:
                 self._toks[:] = 0
 
     def _prefill(self, admitted, jnp) -> bool:
-        """The disaggregated prefill lane: the boundary's newly admitted
-        sequences prefill as ONE bucket-padded batch through the
-        existing ladder.  Rows not being prefilled ride along with
-        all-trash tables (their live tables stay untouched — prefill
-        writes only through the batch's own table argument).  Returns
-        whether any stream is left to step: a one-token stream retires
-        here."""
-        kv = self._kv
+        """The disaggregated prefill lane: ONE dispatch per admitted
+        request, in admission order.  Returns whether any stream is left
+        to step: a one-token stream retires here."""
+        for slot, req in admitted:
+            self._prefill_one(slot, req, jnp)
+        with self._cv:
+            return bool(self._active)
+
+    def _prefill_one(self, slot: int, req: _PagedSeq, jnp) -> None:
+        """One request's prefill (``kv_pager.paged_prefill``: one row,
+        its table row, the head at its last position) at the bucket of
+        its OWN prompt, so executables stay keyed by the bucket alone.
+        No live row is in the dispatch, and the request's first token
+        and its stamps follow it, not the boundary."""
         tally = self._tally
-        max_lp = max(int(r.prompt.size) for _, r in admitted)
-        lb = min(max(bucketing.bucket_for(max_lp), 1), self.cap)
-        lb = max(lb, max_lp)
+        lp = int(req.prompt.size)
+        lb = min(max(bucketing.bucket_for(lp), 1), self.cap)
+        lb = max(lb, lp)
         with observability.span(
             "decode.prefill", _DECODE_TRACK,
-            bucket=lb, admitted=len(admitted),
-            slots=",".join(str(slot) for slot, _ in admitted),
+            bucket=lb, admitted=1, slots=str(slot),
         ) as sp:
-            toks = np.zeros((self.max_slots, lb), np.int32)
-            tables = np.zeros((self.max_slots, self.max_pages), np.int32)
-            last_pos = np.zeros((self.max_slots,), np.int32)
-            for slot, req in admitted:
-                lp = int(req.prompt.size)
-                toks[slot, :lp] = req.prompt
-                tables[slot] = req.table_row
-                last_pos[slot] = lp - 1
+            toks = np.zeros((1, lb), np.int32)
+            toks[0, :lp] = req.prompt
             tok0, self._kp, self._vp = self._dispatch(
-                kv.paged_prefill,
+                self._kv.paged_prefill,
                 self._params,
                 jnp.asarray(toks),
-                jnp.asarray(tables),
-                jnp.asarray(last_pos),
+                jnp.asarray(req.table_row[None]),
+                jnp.asarray(np.array([lp - 1], np.int32)),
                 self._kp,
                 self._vp,
                 self.cfg,
             )
             with observability.span("decode.prefill.wait", _DECODE_TRACK):
-                tok0 = np.asarray(tok0)
-            self.prefill_batches += 1
+                tok = int(np.asarray(tok0)[0])
             with self._cv:
-                t_first = time.perf_counter_ns()
-                for slot, req in admitted:
-                    lp = int(req.prompt.size)
-                    self._indices[slot] = lp
-                    tok = int(tok0[slot])
-                    self._toks[slot] = tok
-                    req.out.append(tok)
-                    req.emitted += 1
-                    req.t_first = t_first
-                    ttft = t_first - req.t_submit
-                    tally["decode_ttft_ns"] += ttft
-                    observability.instant(
-                        "decode.first_token", _DECODE_TRACK,
-                        cid=req.cid, ttft_us=ttft // 1000,
-                    )
-                    if req.emitted >= req.max_new or (
-                        req.until is not None and bool(req.until(tok))
-                    ):
-                        self._retire_locked(slot, req)
-                self.total_tokens += len(admitted)
-                any_active = bool(self._active)
-        n = len(admitted)
+                self.prefill_batches += 1
+                self._indices[slot] = lp
+                self._toks[slot] = tok
+                req.out.append(tok)
+                req.emitted += 1
+                req.t_first = time.perf_counter_ns()
+                ttft = req.t_first - req.t_submit
+                observability.instant(
+                    "decode.first_token", _DECODE_TRACK,
+                    cid=req.cid, ttft_us=ttft // 1000,
+                )
+                if req.emitted >= req.max_new or (
+                    req.until is not None and bool(req.until(tok))
+                ):
+                    self._retire_locked(slot, req)
+                self.total_tokens += 1
         tally["decode_prefill_ns"] += sp.ns
         tally["decode_prefill_batches"] += 1
-        tally["decode_admitted"] += n
-        tally["decode_first_tokens"] += n
-        tally["decode_tokens"] += n
+        tally["decode_prefill_prompt_tokens"] += lp
+        tally["decode_prefill_run_tokens"] += lb
+        tally["decode_admitted"] += 1
+        tally["decode_first_tokens"] += 1
+        tally["decode_ttft_ns"] += ttft
+        tally["decode_tokens"] += 1
         self._flush_tally()
-        return any_active

@@ -27,14 +27,24 @@ serving removed (PAPERS.md).  This module is the paged layout:
   the unmodified ``transformer._cache_attention`` a cache of the same
   sequence capacity, and masked slots contribute exact zeros (softmax
   of ``-inf`` is exactly 0, and ``0 * v`` terms are accumulation-
-  neutral), so stale page contents never perturb a single bit.
+  neutral), so stale page contents never perturb a single bit;
+* :func:`paged_prefill` is the serving prefill — ONE admitted prompt
+  and nothing else.  A prefill starts at position 0, so the only keys
+  its queries may see are the chunk's own: it writes them to the pages
+  as :func:`apply_paged` would (the shared :func:`_page_write`) but
+  attends over them directly, with no gather, and takes the head at
+  the prompt's last position alone.  Nothing in it scales with the
+  slot count or the capacity but the page pools and the table row.
 
 Bit-identity contract: a paged sequence whose table spans ``n_pages_seq
 = cap // P`` pages attends over ``S' = cap`` gathered slots.  Compare
 against the contiguous path at the SAME capacity (``decode.generate``'s
 ``cache_len=cap``) — matching reduction extents keep CPU/TPU
 accumulation order identical; the suite pins this per step and for
-whole generations.
+whole generations.  The prefill's reduction extent is its bucket: the
+slots it leaves out had exact-zero weight, so it agrees with
+:func:`apply_paged` to f32 rounding (layer 0's k/v bit for bit), which
+the suite pins, with the first token, on the same row.
 """
 
 from __future__ import annotations
@@ -235,6 +245,47 @@ def init_tables(batch: int, max_pages: int) -> jnp.ndarray:
 # ---------------------------------------------------------------------------
 
 
+@jax.named_scope("page_write")
+def _page_write(kp, vp, k, v, positions, tables):
+    """Scatter a chunk's k/v ``[B, L, kvh, Dh]`` into one layer's pages
+    at ``tables[b, pos // P]``, offset ``pos % P``.  Returns the updated
+    ``(kp, vp)``."""
+    B, L, kvh, dh = k.shape
+    P = kp.shape[1]
+    page_slot = positions // P  # [B, L]
+    offset = positions % P
+    max_pages = tables.shape[1]
+    # positions past a row's table (bucket padding that overruns
+    # the sequence capacity) write the trash page, never a
+    # clamped real slot
+    dest = jnp.where(
+        page_slot < max_pages,
+        jnp.take_along_axis(
+            tables, jnp.minimum(page_slot, max_pages - 1), axis=1
+        ),
+        0,
+    )  # [B, L]
+    flat_dest = dest.reshape(B * L)
+    flat_off = offset.reshape(B * L)
+    kp = kp.at[flat_dest, flat_off].set(
+        k.astype(kp.dtype).reshape(B * L, kvh, dh), mode="drop"
+    )
+    vp = vp.at[flat_dest, flat_off].set(
+        v.astype(vp.dtype).reshape(B * L, kvh, dh), mode="drop"
+    )
+    return kp, vp
+
+
+def _attn_out(bp, x, att, cfg):
+    """x + Wo(att): the residual half both paged blocks end their
+    attention with.  att: [B, L, h, Dh]."""
+    B, L = att.shape[:2]
+    att = att.reshape(B, L, cfg.n_heads * cfg.head_dim)
+    return x + tfm.shard(
+        att @ tfm.weight(bp["wo"], cfg.dtype), ("dp", "ep"), "sp", None
+    )
+
+
 def _paged_block(bp, x, positions, cfg, kp, vp, tables):
     """One decoder block against one layer's page arrays.
 
@@ -247,38 +298,15 @@ def _paged_block(bp, x, positions, cfg, kp, vp, tables):
     UNMODIFIED ``transformer._cache_attention`` on it: positions past a
     row's frontier are masked to exact zero weight, so stale page
     contents (previous tenants included) never contribute a bit."""
-    B, L, D = x.shape
-    h, dh = cfg.n_heads, cfg.head_dim
+    B = x.shape[0]
     dt = cfg.dtype
     P = kp.shape[1]
     # scope names are metadata: a profiler session groups the device
     # operations of a step under attention / page_write / page_gather
     with jax.named_scope("attention"):
         q, k, v = tfm._attn_qkv(bp, x, positions, cfg)
-        with jax.named_scope("page_write"):
-            # scatter this chunk's k/v into the pages
-            page_slot = positions // P  # [B, L]
-            offset = positions % P
-            max_pages = tables.shape[1]
-            # positions past a row's table (bucket padding that overruns
-            # the sequence capacity) write the trash page, never a
-            # clamped real slot
-            dest = jnp.where(
-                page_slot < max_pages,
-                jnp.take_along_axis(
-                    tables, jnp.minimum(page_slot, max_pages - 1), axis=1
-                ),
-                0,
-            )  # [B, L]
-            flat_dest = dest.reshape(B * L)
-            flat_off = offset.reshape(B * L)
-            kvh = k.shape[2]
-            kp = kp.at[flat_dest, flat_off].set(
-                k.astype(kp.dtype).reshape(B * L, kvh, dh), mode="drop"
-            )
-            vp = vp.at[flat_dest, flat_off].set(
-                v.astype(vp.dtype).reshape(B * L, kvh, dh), mode="drop"
-            )
+        kvh, dh = k.shape[2:]
+        kp, vp = _page_write(kp, vp, k, v, positions, tables)
         with jax.named_scope("page_gather"):
             # gather each row's pages into its contiguous cache view
             ck = kp[tables].reshape(B, tables.shape[1] * P, kvh, dh)
@@ -286,10 +314,27 @@ def _paged_block(bp, x, positions, cfg, kp, vp, tables):
         att = tfm._cache_attention(
             q, ck.astype(dt), cv.astype(dt), positions
         )
-        att = att.reshape(B, L, h * dh)
-        x = x + tfm.shard(
-            att @ tfm.weight(bp["wo"], dt), ("dp", "ep"), "sp", None
+        x = _attn_out(bp, x, att, cfg)
+    x, _aux = tfm._mlp_residual(bp, x, cfg)
+    return x, kp, vp
+
+
+def _prefill_block(bp, x, positions, cfg, kp, vp, tables):
+    """:func:`_paged_block` for a chunk that STARTS its sequence
+    (``positions`` count from 0): the same projections and page write,
+    but the only keys such a chunk's queries may see are its own, which
+    the layer has just computed — so attention runs causally over the
+    chunk's k/v (rounded to the page dtype, as the pages hold them) and
+    nothing is gathered back from the pages."""
+    dt = cfg.dtype
+    with jax.named_scope("attention"):
+        q, k, v = tfm._attn_qkv(bp, x, positions, cfg)
+        kp, vp = _page_write(kp, vp, k, v, positions, tables)
+        att = tfm._cache_attention(
+            q, k.astype(kp.dtype).astype(dt), v.astype(vp.dtype).astype(dt),
+            positions,
         )
+        x = _attn_out(bp, x, att, cfg)
     x, _aux = tfm._mlp_residual(bp, x, cfg)
     return x, kp, vp
 
@@ -358,20 +403,40 @@ def paged_decode_step(params, toks, tables, indices, k_pages, v_pages, cfg):
 
 
 @functools.partial(jax.jit, static_argnames=("cfg",))
-def paged_prefill(params, toks, tables, last_pos, k_pages, v_pages, cfg):
-    """Bucket-coalesced prefill for newly admitted sequences: toks
-    [B, Lb] (rows padded to the shared bucket), ``last_pos`` [B] each
-    row's final REAL position.  Returns each row's first greedy token —
-    argmax over the logits at its own prompt frontier, exactly what the
+def paged_prefill(params, toks, table, last_pos, k_pages, v_pages, cfg):
+    """Prefill of ONE newly admitted sequence, and of nothing else: toks
+    [1, Lb] (the prompt padded to its own bucket), ``table`` [1,
+    max_pages] its page-table row, ``last_pos`` [1] its final REAL
+    position.  Every prompt token's k/v land in the sequence's pages
+    (pad positions past the reservation in the trash page) exactly as
+    :func:`apply_paged` would write them, but a prefill starts at
+    position 0, so it attends over the chunk's own k/v with no gather
+    (:func:`_prefill_block`) and needs the head at ONE position: apart
+    from the page pools and the table row nothing here scales with the
+    slot count or the capacity.  Returns the first greedy token [1] —
+    argmax over the logits at the prompt's frontier, exactly what the
     contiguous ``generate`` samples from ``logits[:, -1]``.  One
-    executable per prompt bucket (the ladder bounds the grid); rows not
-    being prefilled ride along with all-trash tables."""
-    zeros = jnp.zeros((toks.shape[0],), jnp.int32)
-    logits, k_pages, v_pages = apply_paged(
-        params, toks, tables, zeros, k_pages, v_pages, cfg
+    executable per prompt bucket (the ladder bounds the grid)."""
+    B, L = toks.shape
+    positions = jnp.broadcast_to(jnp.arange(L, dtype=jnp.int32), (B, L))
+    x = tfm.embed_lookup(params["embed"], toks, cfg.dtype)
+
+    def step(x, layer):
+        bp, kp, vp = layer
+        x, kp, vp = _prefill_block(bp, x, positions, cfg, kp, vp, table)
+        return x, (kp, vp)
+
+    x, (k_pages, v_pages) = jax.lax.scan(
+        step, x, (params["blocks"], k_pages, v_pages)
     )
-    last = jnp.take_along_axis(
-        logits, last_pos[:, None, None], axis=1
-    )[:, 0]  # [B, V]
-    tok0 = jnp.argmax(last, axis=-1).astype(jnp.int32)
+    with jax.named_scope("head"):
+        x = jnp.take_along_axis(x, last_pos[:, None, None], axis=1)[:, 0]
+        x = tfm._rms_norm(x, params["ln_f"])
+        logits = jnp.einsum(
+            "bd,dv->bv",
+            x,
+            tfm.weight(params["lm_head"], cfg.dtype),
+            preferred_element_type=jnp.float32,
+        )
+    tok0 = jnp.argmax(logits, axis=-1).astype(jnp.int32)
     return tok0, k_pages, v_pages

@@ -220,6 +220,10 @@ _counters: Dict[str, int] = {
     "kv_pages_allocated": 0,
     "kv_pages_freed": 0,
     "decode_prefill_batches": 0,
+    # what the prefill lane ran: real prompt tokens, and the tokens its
+    # executables computed (rows x bucket) — the rest is padding
+    "decode_prefill_prompt_tokens": 0,
+    "decode_prefill_run_tokens": 0,
     # time counters (nanoseconds of time.perf_counter_ns, monotonic),
     # taken at the boundaries of the spans of the same name and bumped
     # once per step / prefill / block / verb.  Decode scheduler: steps;
@@ -297,7 +301,11 @@ _request_ctx: "contextvars.ContextVar[Optional[RequestLedger]]" = (
 # syscall (~35 µs in containers with slow entropy paths — measured; the
 # id is minted per request AND per client call, so it sits on the
 # serving hot path).  itertools.count.__next__ is atomic under the GIL.
-_cid_prefix = uuid.uuid4().hex[:8]
+# led by a letter no number starts with: a profiler session stores an
+# annotation's argument that parses as a number AS a number (an
+# all-digit id an int, "123e4567..." a float), and a search for the
+# request's cid among the session's event stats then finds nothing
+_cid_prefix = "c" + uuid.uuid4().hex[:7]
 _cid_counter = itertools.count(1)
 
 
@@ -976,12 +984,6 @@ def note_kv_pages_freed(n: int) -> None:
     _bump("kv_pages_freed", n)
 
 
-def note_decode_prefill_batch() -> None:
-    """One bucket-coalesced prefill batch run by the disaggregated
-    prefill lane of the decode scheduler."""
-    _bump("decode_prefill_batches")
-
-
 def note_decode_driver(deltas: Mapping[str, int]) -> None:
     """What one step or one prefill of the decode scheduler's driver
     added to the ``decode_*`` counters (tokens, steps, prefill batches,
@@ -1179,6 +1181,8 @@ def counters_delta(
             "kv_pages_allocated",
             "kv_pages_freed",
             "decode_prefill_batches",
+            "decode_prefill_prompt_tokens",
+            "decode_prefill_run_tokens",
             "decode_steps",
             "decode_host_ns",
             "decode_step_wait_ns",

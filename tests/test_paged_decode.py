@@ -19,6 +19,7 @@ The contract under test, at every layer:
   run), including under injected transient dispatch faults.
 """
 
+import collections
 import threading
 import time
 
@@ -34,6 +35,7 @@ from tensorframes_tpu.bridge.coalescer import DecodeRefused, DecodeScheduler
 from tensorframes_tpu.bridge.server import serve
 from tensorframes_tpu.models import decode, kv_pager
 from tensorframes_tpu.models import transformer as tfm
+from tensorframes_tpu.ops import bucketing
 
 CFG = tfm.TransformerConfig(
     vocab_size=97,
@@ -126,6 +128,130 @@ def test_paged_attention_bit_identical_to_contiguous(params):
         assert outs[b] == refs[b], f"row {b} diverged from contiguous"
 
 
+# (prompt length, max_new): under, at and over the 16 bucket's edge with
+# the whole bucket reserved, and one whose bucket (32) overruns its
+# reservation (3 pages = 24 positions), so pads 24..31 take the trash page
+PREFILL_CASES = {
+    "under_edge": (15, 9),
+    "at_edge": (16, 8),
+    "over_edge": (17, 15),
+    "bucket_overruns_reservation": (17, 3),
+}
+
+
+@pytest.mark.parametrize("case", list(PREFILL_CASES))
+def test_paged_prefill_matches_apply_paged_and_spares_neighbors(params, case):
+    """The one-request prefill against the general chunk path on the
+    same row: the same first token, the same k/v at the prompt's
+    positions of the request's pages, and a live neighbour's pages left
+    bit for bit as they were."""
+    lp, max_new = PREFILL_CASES[case]
+    cp = decode.cast_params(params, CFG.dtype)
+    max_pages = CAP // PAGE
+    pool = kv_pager.PagePool(CFG, n_pages=2 * max_pages + 1, tokens_per_page=PAGE)
+    (neighbor, _), (prompt, _) = _prompts(((21, 4), (lp, max_new)), seed=11)
+
+    def table_row(n_tokens):
+        _, pages = pool.allocate(kv_pager.pages_for(n_tokens, PAGE))
+        row = np.zeros((1, max_pages), np.int32)
+        row[0, : len(pages)] = pages
+        return pages, jnp.asarray(row)
+
+    # a live neighbour: its prompt's k/v sit in its pages
+    neighbor_pages, n_row = table_row(neighbor.size + 4)
+    _, kp0, vp0 = kv_pager.apply_paged(
+        cp, jnp.asarray(neighbor[None]), n_row, jnp.zeros((1,), jnp.int32),
+        pool.k_pages, pool.v_pages, CFG,
+    )
+    assert np.abs(np.asarray(kp0)[:, neighbor_pages]).max() > 0
+
+    pages, row = table_row(lp + max_new)
+    lb = bucketing.bucket_for(lp)
+    assert (lb > len(pages) * PAGE) == (case == "bucket_overruns_reservation")
+    toks = np.zeros((1, lb), np.int32)
+    toks[0, :lp] = prompt
+    toks = jnp.asarray(toks)
+
+    logits, kp_ref, vp_ref = kv_pager.apply_paged(
+        cp, toks, row, jnp.zeros((1,), jnp.int32), kp0, vp0, CFG
+    )
+    tok0, kp, vp = kv_pager.paged_prefill(
+        cp, toks, row, jnp.asarray([lp - 1], jnp.int32), kp0, vp0, CFG
+    )
+    assert tok0.shape == (1,)
+    assert int(tok0[0]) == int(jnp.argmax(logits[0, lp - 1]))
+
+    def prompt_kv(pages_arr):
+        # [n_layers, lp, kvh, Dh]: the request's pages in sequence order
+        a = np.asarray(pages_arr)[:, pages]
+        return a.reshape(a.shape[0], -1, *a.shape[3:])[:, :lp]
+
+    for got, want in ((kp, kp_ref), (vp, vp_ref)):
+        got, want = prompt_kv(got), prompt_kv(want)
+        # layer 0's k/v are computed before any attention
+        assert np.array_equal(got[0], want[0])
+        # above it they differ by the softmax's reduction extent only
+        np.testing.assert_allclose(got[1:], want[1:], rtol=2e-5, atol=2e-6)
+    for got, before in ((kp, kp0), (vp, vp0)):
+        got, before = np.asarray(got), np.asarray(before)
+        assert np.array_equal(got[:, neighbor_pages], before[:, neighbor_pages])
+        # nothing but the request's pages and the trash page was written
+        others = [
+            i for i in range(pool.n_pages) if i != 0 and i not in pages
+        ]
+        assert np.array_equal(got[:, others], before[:, others])
+
+
+def _eqn_avals(jaxpr):
+    """Every value a jaxpr computes, nested jaxprs (pjit, scan, custom
+    rules) included."""
+    for eqn in jaxpr.eqns:
+        for v in eqn.outvars:
+            yield v.aval
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            yield from _eqn_avals(sub)
+
+
+def test_paged_prefill_scales_with_the_bucket_alone(params):
+    """Shape guard: traced at two capacities and two slot counts, same
+    bucket, every intermediate of ``paged_prefill`` other than the page
+    pools (whole or a layer's) and the table row has the same shape in
+    all four, and the only vocabulary-wide ones are one position's
+    ``[1, vocabulary]`` — neither the slot batch, nor the gathered
+    capacity, nor every position's logits can come back unseen."""
+    bucket = 32
+    kvh, dh, n = CFG.n_kv_heads, CFG.head_dim, CFG.n_layers
+    seen = []
+    for cap in (64, 128):
+        for slots in (2, 5):
+            max_pages = cap // PAGE
+            n_pages = slots * max_pages + 1
+            pools = jax.ShapeDtypeStruct(
+                (n, n_pages, PAGE, kvh, dh), CFG.dtype
+            )
+            i32 = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.int32)
+            closed = jax.make_jaxpr(
+                lambda *a: kv_pager.paged_prefill(*a, CFG)
+            )(params, i32(1, bucket), i32(1, max_pages), i32(1), pools, pools)
+            exempt = {
+                (n, n_pages, PAGE, kvh, dh), (n_pages, PAGE, kvh, dh),
+                (1, max_pages),
+            }
+            avals = [
+                a for a in _eqn_avals(closed.jaxpr)
+                if hasattr(a, "shape") and a.shape not in exempt
+            ]
+            assert len(avals) > 50  # the walk went inside the jit
+            # the logits are of one position: [1, vocabulary], never
+            # [1, bucket, vocabulary]
+            wide = [a.shape for a in avals if CFG.vocab_size in a.shape]
+            assert wide and set(wide) == {(1, CFG.vocab_size)}
+            seen.append(collections.Counter(
+                (a.shape, str(a.dtype)) for a in avals
+            ))
+    assert all(s == seen[0] for s in seen[1:])
+
+
 def test_page_pool_exhaustion_is_typed_and_free_restores():
     pool = kv_pager.PagePool(CFG, n_pages=4, tokens_per_page=PAGE)
     assert pool.stats()["pages_free"] == 3  # page 0 is the trash page
@@ -194,6 +320,69 @@ def test_scheduler_concurrent_mixed_streams_bit_identical(params):
         assert d["decode_prefill_batches"] == snap["prefill_batches"]
     finally:
         sched.close()
+
+
+def test_scheduler_prefills_each_admitted_request_alone(params):
+    """Three requests admitted at ONE boundary make three dispatches,
+    each at its own prompt's bucket, in admission order."""
+    jobs = _prompts(((5, 3), (20, 4), (9, 2)), seed=12)
+    sched = DecodeScheduler(
+        params, CFG, max_slots=4, tokens_per_page=PAGE, max_seq=CAP
+    )
+    reqs = [None] * len(jobs)
+    errs = []
+
+    def worker(i):
+        try:
+            reqs[i] = sched.submit_request(*jobs[i], timeout_s=120)
+        except BaseException as e:  # noqa: BLE001 — surfaced below
+            errs.append(e)
+
+    try:
+        refs = [_reference(params, p, mn, cap=sched.cap) for p, mn in jobs]
+        c0 = obs.counters()
+        # no driver until all three are queued, in this order: they are
+        # then admitted together, at the driver's first boundary
+        start_driver = sched._ensure_driver
+        sched._ensure_driver = lambda: None
+        ts = [
+            threading.Thread(target=worker, args=(i,))
+            for i in range(len(jobs))
+        ]
+        for i, t in enumerate(ts):
+            t.start()
+            deadline = time.monotonic() + 30
+            while sched.snapshot()["pending"] <= i and not errs:
+                assert time.monotonic() < deadline, "submit never queued"
+                time.sleep(0.001)
+        sched._ensure_driver = start_driver
+        with sched._cv:
+            sched._ensure_driver()
+        for t in ts:
+            t.join(timeout=180)
+            assert not t.is_alive()
+        if errs:
+            raise errs[0]
+        sched.close()  # the driver's last bump happens before it exits
+        d = obs.counters_delta(c0)
+    finally:
+        sched.close()
+    lengths = [int(p.size) for p, _ in jobs]
+    buckets = [bucketing.bucket_for(n) for n in lengths]
+    assert len(set(buckets)) == 3  # 8, 32, 16: nobody pays the longest's
+    assert sched.snapshot()["prefill_batches"] == 3
+    assert d["decode_prefill_batches"] == 3
+    assert d["decode_prefill_prompt_tokens"] == sum(lengths)
+    assert d["decode_prefill_run_tokens"] == sum(buckets)
+    assert d["decode_admitted"] == d["decode_first_tokens"] == 3
+    # one boundary admitted all three before the first prefill ended ...
+    assert max(r.t_admit for r in reqs) <= min(r.t_first for r in reqs)
+    assert sched.snapshot()["joined_mid_run"] == 0
+    # ... and each first token followed its own dispatch, in that order
+    assert [r.t_admit for r in reqs] == sorted(r.t_admit for r in reqs)
+    assert reqs[0].t_first < reqs[1].t_first < reqs[2].t_first
+    for i, r in enumerate(reqs):
+        assert r.out == refs[i], f"stream {i} diverged"
 
 
 def test_scheduler_admission_refusals_are_typed(params):

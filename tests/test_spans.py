@@ -39,6 +39,9 @@ CFG = tfm.TransformerConfig(
 PAGE = 8
 CAP = 64
 
+PREFILL_TOKEN_COUNTERS = (
+    "decode_prefill_prompt_tokens", "decode_prefill_run_tokens",
+)
 DECODE_COUNTERS = (
     "decode_steps", "decode_host_ns", "decode_step_wait_ns",
     "decode_prefill_ns", "decode_busy_ns", "decode_admitted",
@@ -54,7 +57,7 @@ NEW_METRICS = (
     "prefill_stall_share.decode", "queue_wait_ms.decode",
     "ttft_ms.decode", "itl_ms.decode", "dispatch_host_ms.score",
     "verb_head_ms.score", "verb_tail_ms.score",
-    "readback_wait_share.score",
+    "readback_wait_share.score", "prefill_pad_share.decode",
 )
 
 
@@ -232,7 +235,7 @@ def test_new_counters_reach_delta_and_metrics_text():
     before = obs.counters()
     d = obs.counters_delta(before)
     text = obs.metrics_text()
-    for key in DECODE_COUNTERS + ENGINE_COUNTERS:
+    for key in DECODE_COUNTERS + ENGINE_COUNTERS + PREFILL_TOKEN_COUNTERS:
         assert key in before, key
         assert d[key] == 0, key
         assert f"tfs_{key}_total " in text, key
@@ -367,8 +370,10 @@ def test_profiler_session_holds_the_tfs_spans(params, tmp_path):
         kids = [e for e in drv if e[0] == f"tfs:decode.step.{child}"]
         assert len(kids) == len(steps)
         assert all(any(_inside(k, s) for s in steps) for k in kids)
+    # one dispatch for the one request, at its own prompt's bucket
     prefill = [e for e in drv if e[0] == "tfs:decode.prefill"]
     assert len(prefill) == 1 and prefill[0][3]["admitted"] == 1
+    assert prefill[0][3]["bucket"] == 8 and prefill[0][3]["slots"] in (0, 1)
     wait = next(e for e in drv if e[0] == "tfs:decode.prefill.wait")
     assert _inside(wait, prefill[0])
     assert all("step" in s[3] and s[3]["active"] == 1 for s in steps)
@@ -391,6 +396,34 @@ def test_profiler_session_holds_the_tfs_spans(params, tmp_path):
     ]
     assert all(_inside(s, req) for s in stamps)
     assert [s[1] for s in stamps] == sorted(s[1] for s in stamps)
+    # the first token follows the request's own dispatch: its stamp lies
+    # in that prefill's span, after the wait for the token
+    assert _inside(stamps[1], prefill[0]) and wait[2] <= stamps[1][1]
+
+
+# a profiler session stores an annotation's argument that parses as a
+# number as a number: an all-digit cid would come back an int, and
+# "123e4567..." a float (inf).  Which of the suite's runs got such an id
+# was the draw of the process's random prefix, about 1 in 25 — the way
+# the session test above used to fail.  The prefix is now led by "c".
+@pytest.mark.parametrize("prefix", ["c1234567", "c123e456"])
+def test_cid_reads_back_from_a_session_as_written(prefix, tmp_path, monkeypatch):
+    obs.disable_trace()
+    with pytest.raises(ValueError):
+        float(obs.new_correlation_id())  # a fresh id is never a number
+    assert obs._cid_prefix[0] == prefix[0]
+    monkeypatch.setattr(obs, "_cid_prefix", prefix)
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        with obs.request_ledger() as led:
+            with obs.span("engine.block", "serial", block=3):
+                pass
+    finally:
+        jax.profiler.stop_trace()
+    assert led.correlation_id.startswith(prefix)
+    (ev,) = [e for evs in _tfs_events(str(tmp_path)).values() for e in evs]
+    assert ev[0] == "tfs:engine.block" and ev[3]["block"] == 3
+    assert ev[3]["cid"] == led.correlation_id
 
 
 # ---------------------------------------------------------------------------
@@ -485,6 +518,9 @@ def test_benchmark_metric_file_reads_the_counters(name):
         "counters.decode_ttft_ns": 14_000_000_000,
         "counters.decode_stream_ns": 150_000_000_000,
         "counters.decode_stream_tokens": 5000,
+        # ... 40 prompts of 300 tokens on the 512 bucket ...
+        "counters.decode_prefill_prompt_tokens": 12_000,
+        "counters.decode_prefill_run_tokens": 20_480,
         # ... and 25 epochs of 8 blocks
         "counters.map_verbs": 25,
         "counters.map_verb_ns": 50_000_000_000,
@@ -505,6 +541,7 @@ def test_benchmark_metric_file_reads_the_counters(name):
         "verb_head_ms.score": 3.0,
         "verb_tail_ms.score": 5.0,
         "readback_wait_share.score": 10.0,
+        "prefill_pad_share.decode": 41.40625,
     }[name]
     assert read_metric(name, obs_) == pytest.approx(want)
     # the parent commit has no such counter: nothing to read, no raise

@@ -1256,7 +1256,7 @@ class _PagedSeq:
     __slots__ = (
         "prompt", "max_new", "until", "tenant", "scope", "charge",
         "table_row", "out", "emitted", "done", "error", "abandoned",
-        "cid", "t_submit", "t_admit", "t_first", "t_done",
+        "cid", "t_submit", "t_admit", "t_first", "t_done", "routing",
     )
 
     def __init__(self, prompt, max_new, until, tenant, scope, charge):
@@ -1283,6 +1283,10 @@ class _PagedSeq:
         self.done = threading.Event()
         self.error: Optional[BaseException] = None
         self.abandoned = False
+        # with the scheduler's ``routing_trace`` on: the experts the timed
+        # path chose for this sequence, columns int32 [n_layers, positions
+        # fed by one dispatch], which retirement joins and hands over
+        self.routing: List[np.ndarray] = []
 
     def timing(self) -> Dict[str, float]:
         """The stamps as a caller can use them: milliseconds from submit
@@ -1303,6 +1307,13 @@ _DECODE_TIME_KEYS = (
     "decode_prefill_ns", "decode_busy_ns", "decode_admitted",
     "decode_queue_wait_ns", "decode_first_tokens", "decode_ttft_ns",
     "decode_stream_ns", "decode_stream_tokens",
+)
+
+# what an expert-routing model's executables count on the device, in the
+# order of ``kv_pager._routing``'s ``stats``
+_MOE_KEYS = (
+    "moe_route_calls", "moe_routed_tokens", "moe_busiest_expert_tokens",
+    "moe_experts_touched",
 )
 
 
@@ -1363,6 +1374,7 @@ class DecodeScheduler:
         pool_pages: Optional[int] = None,
         draft_params=None,
         draft_cfg=None,
+        routing_trace: int = 0,
     ):
         from ..models import decode as decode_mod
         from ..models import kv_pager
@@ -1396,9 +1408,24 @@ class DecodeScheduler:
             if pool_pages is not None
             else self.max_slots * self.max_pages + 1
         )
-        self.pool = kv_pager.PagePool(cfg, n_pages, tokens_per_page=P)
+        self.pool = kv_pager.PagePool(
+            cfg, n_pages, tokens_per_page=P, slots=self.max_slots
+        )
         self._kp = self.pool.k_pages
         self._vp = self.pool.v_pages
+        # a 'cca' block's convolution state, one row a slot (None for
+        # other blocks): an argument and a result of both executables,
+        # like the pages; a prefill overwrites the admitted slot's row
+        self._state = self.pool.conv_state
+        # ``routing_trace`` > 0 keeps, for that many retired requests, the
+        # expert every fed position chose in every layer (``routing_of``):
+        # what a reference needs to follow the served path, since top-1
+        # routing flips on rounding.  Off, the choices are read back with
+        # the tokens all the same (a few KB) and dropped
+        self._routing_keep = int(routing_trace)
+        self._routing_done: "collections.OrderedDict[bytes, np.ndarray]" = (
+            collections.OrderedDict()
+        )
         self._tables = np.zeros(
             (self.max_slots, self.max_pages), np.int32
         )
@@ -1635,6 +1662,13 @@ class DecodeScheduler:
         self._indices[slot] = 0
         self._toks[slot] = 0
         self.retired += 1
+        if req.routing:
+            self._routing_done[req.prompt.tobytes()] = np.concatenate(
+                req.routing, axis=1
+            )
+            req.routing = []
+            while len(self._routing_done) > self._routing_keep:
+                self._routing_done.popitem(last=False)
         self.pool.free(req.charge)
         req.t_done = time.perf_counter_ns()
         if req.t_first:
@@ -1668,6 +1702,47 @@ class DecodeScheduler:
             self._time_totals[k] += tally.get(k, 0)
         observability.note_decode_driver(tally)
         tally.clear()
+
+    def _run(self, fn, *args, slot=None):
+        """Dispatch a serving executable on the current pools (and, for a
+        block that is not the dense one, the convolution state, with the
+        admitted ``slot`` for a prefill), keep what it returns of them,
+        and hand back ``(tokens, stats)``: device arrays, ``stats`` the
+        dispatch's routing counts or None."""
+        if self.cfg.block.stateless:
+            toks, self._kp, self._vp = self._dispatch(
+                fn, self._params, *args, self._kp, self._vp, self.cfg
+            )
+            return toks, None
+        extra = () if slot is None else (np.array([slot], np.int32),)
+        toks, self._kp, self._vp, self._state, stats = self._dispatch(
+            fn, self._params, *args, self._kp, self._vp, self.cfg,
+            self._state, *extra,
+        )
+        return toks, stats
+
+    def _fetch(self, toks, routing):
+        """The one wait for a dispatch's tokens: ``(tokens, chosen)``.  A
+        model's routing, where it has any (``kv_pager._routing``), rides
+        the same transfer: its counts go into the tally, its choices
+        ``[n_layers, rows]`` back to the caller (None otherwise)."""
+        if routing is None:
+            return np.asarray(toks), None
+        import jax
+
+        toks, (stats, chosen) = jax.device_get((toks, routing))
+        for key, n in zip(_MOE_KEYS, stats):
+            self._tally[key] += int(n)
+        return toks, chosen
+
+    def routing_of(self, prompt) -> Optional[np.ndarray]:
+        """The experts the served path chose for a retired request with
+        this prompt: int32 [n_layers, prompt + emitted - 1] (the last
+        token was never fed), or None (``routing_trace`` off, a model
+        without experts, or the request fell out of the window kept)."""
+        key = np.asarray(prompt, np.int32).tobytes()
+        with self._lock:
+            return self._routing_done.get(key)
 
     def _dispatch(self, fn, *args):
         """One compiled dispatch with chaos injection + bounded retry:
@@ -1772,24 +1847,23 @@ class DecodeScheduler:
                     step=self.steps, active=len(self._active),
                 ):
                     with span("decode.step.dispatch", _DECODE_TRACK):
-                        toks, self._kp, self._vp = self._dispatch(
+                        toks, stats = self._run(
                             kv.paged_decode_step,
-                            self._params,
                             jnp.asarray(self._toks),
                             jnp.asarray(self._tables),
                             jnp.asarray(self._indices),
-                            self._kp,
-                            self._vp,
-                            self.cfg,
                         )
                     with span("decode.step.wait", _DECODE_TRACK) as sp_w:
-                        emitted = np.asarray(toks)
+                        emitted, chosen = self._fetch(toks, stats)
+                    keep = self._routing_keep and chosen is not None
                     self.steps += 1
                     with span("decode.step.emit", _DECODE_TRACK):
                         with self._cv:
                             n_tok = len(self._active)
                             for slot, req in list(self._active.items()):
                                 self._indices[slot] += 1
+                                if keep:
+                                    req.routing.append(chosen[:, slot, None])
                                 tok = int(emitted[slot])
                                 self._toks[slot] = tok
                                 req.out.append(tok)
@@ -1850,18 +1924,18 @@ class DecodeScheduler:
         ) as sp:
             toks = np.zeros((1, lb), np.int32)
             toks[0, :lp] = req.prompt
-            tok0, self._kp, self._vp = self._dispatch(
+            tok0, stats = self._run(
                 self._kv.paged_prefill,
-                self._params,
                 jnp.asarray(toks),
                 jnp.asarray(req.table_row[None]),
                 jnp.asarray(np.array([lp - 1], np.int32)),
-                self._kp,
-                self._vp,
-                self.cfg,
+                slot=slot,
             )
             with observability.span("decode.prefill.wait", _DECODE_TRACK):
-                tok = int(np.asarray(tok0)[0])
+                tok0, chosen = self._fetch(tok0, stats)
+                tok = int(tok0[0])
+            if self._routing_keep and chosen is not None:
+                req.routing.append(chosen[:, :lp])
             with self._cv:
                 self.prefill_batches += 1
                 self._indices[slot] = lp

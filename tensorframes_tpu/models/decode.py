@@ -108,13 +108,8 @@ def apply_cached(
     x, (cks, cvs) = jax.lax.scan(
         step, x, (params["blocks"], cache["k"], cache["v"])
     )
-    x = tfm._rms_norm(x, params["ln_f"])
-    logits = jnp.einsum(
-        "bld,dv->blv",
-        x,
-        tfm.weight(params["lm_head"], cfg.dtype),
-        preferred_element_type=jnp.float32,
-    )
+    x = tfm._rms_norm(x, params["ln_f"], cfg.block.norm_eps)
+    logits = tfm.head(params, x, cfg)
     return logits, {"k": cks, "v": cvs, "index": idx + L}
 
 

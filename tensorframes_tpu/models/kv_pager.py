@@ -57,6 +57,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from . import cca, moe
 from . import transformer as tfm
 from .. import observability
 from ..envutil import env_int as _env_int
@@ -118,7 +119,10 @@ class PagePool:
 
     Page 0 is the trash page: idle slots and pad tokens write there, so
     every scatter is unconditional.  It is excluded from the free list
-    and from capacity accounting."""
+    and from capacity accounting.
+
+    For a ``cca`` block the pool also holds ``conv_state``, the per-slot
+    convolution state (``slots`` rows a layer; None for other blocks)."""
 
     def __init__(
         self,
@@ -126,6 +130,7 @@ class PagePool:
         n_pages: int,
         tokens_per_page: Optional[int] = None,
         dtype=None,
+        slots: Optional[int] = None,
     ):
         P = page_tokens() if tokens_per_page is None else int(tokens_per_page)
         if P < 1:
@@ -143,6 +148,19 @@ class PagePool:
         shape = (n, self.n_pages, P, kvh, dh)
         self.k_pages = jnp.zeros(shape, dtype)
         self.v_pages = jnp.zeros(shape, dtype)
+        # the second kind of per-sequence state: a ``cca`` block's decode
+        # step needs the previous position's convolution inputs, a fixed
+        # ``cca.state_width`` values a layer whatever the length.  One
+        # array [n_layers, slots, width] beside the pages, indexed by the
+        # decode slot, threaded through the executables like the pages
+        self.conv_state = None
+        if cfg.block.attention == "cca":
+            if slots is None:
+                raise ValueError(
+                    "a 'cca' block keeps a convolution state per decode "
+                    "slot: PagePool(..., slots=max_slots)"
+                )
+            self.conv_state = cca.init_state(cfg, slots, dtype)
         # one page's HBM across all layers, k and v together — the unit
         # the budget LRU accounts
         self.page_bytes = int(
@@ -286,7 +304,25 @@ def _attn_out(bp, x, att, cfg):
     )
 
 
-def _paged_block(bp, x, positions, cfg, kp, vp, tables):
+def _feed_forward(bp, x, cfg, route):
+    """The feed-forward half by the spec's kind.  ``route`` is None for a
+    block that routes nothing, else ``(r_prev, live, experts, layer)``: the
+    router's carry, the tokens that count, and the expert weights of all
+    layers with this layer's index (``moe.stack_experts``).  Returns
+    ``(x', routed)``: ``routed`` is ``(r, counts, chosen)``, the carry for
+    the layer above, the live tokens each expert got and each token's
+    expert; None without a router."""
+    if route is None:
+        x, _aux = tfm._mlp_residual(bp, x, cfg)
+        return x, None
+    r_prev, live, experts, layer = route
+    y = tfm._rms_norm(x, bp["ln2"], cfg.block.norm_eps)
+    out, *routed = moe.experts_top1(bp, y, r_prev, live, cfg, experts, layer)
+    return x + out, tuple(routed)
+
+
+def _paged_block(bp, x, positions, cfg, kp, vp, tables, st=None,
+                 route=None):
     """One decoder block against one layer's page arrays.
 
     ``kp``/``vp``: [n_pages, P, kvh, Dh]; ``tables``: [B, max_pages];
@@ -297,14 +333,23 @@ def _paged_block(bp, x, positions, cfg, kp, vp, tables):
     pages into a [B, max_pages * P] contiguous view and runs the
     UNMODIFIED ``transformer._cache_attention`` on it: positions past a
     row's frontier are masked to exact zero weight, so stale page
-    contents (previous tenants included) never contribute a bit."""
+    contents (previous tenants included) never contribute a bit.
+
+    A ``cca`` block (``L`` = 1) also takes the layer's convolution state
+    ``st`` [B, width] and returns the state this position leaves; an
+    ``experts_top1`` block takes ``route`` (:func:`_feed_forward`).
+    Returns ``(x', kp', vp', st', routed)``, the last two None where the
+    spec has no such thing."""
     B = x.shape[0]
     dt = cfg.dtype
     P = kp.shape[1]
     # scope names are metadata: a profiler session groups the device
     # operations of a step under attention / page_write / page_gather
     with jax.named_scope("attention"):
-        q, k, v = tfm._attn_qkv(bp, x, positions, cfg)
+        if cfg.block.attention == "cca":
+            q, k, v, st = cca.qkv_step(bp, x, positions, st, cfg)
+        else:
+            q, k, v = tfm._attn_qkv(bp, x, positions, cfg)
         kvh, dh = k.shape[2:]
         kp, vp = _page_write(kp, vp, k, v, positions, tables)
         with jax.named_scope("page_gather"):
@@ -315,28 +360,113 @@ def _paged_block(bp, x, positions, cfg, kp, vp, tables):
             q, ck.astype(dt), cv.astype(dt), positions
         )
         x = _attn_out(bp, x, att, cfg)
-    x, _aux = tfm._mlp_residual(bp, x, cfg)
-    return x, kp, vp
+    x, routed = _feed_forward(bp, x, cfg, route)
+    return x, kp, vp, st, routed
 
 
-def _prefill_block(bp, x, positions, cfg, kp, vp, tables):
+def _prefill_block(bp, x, positions, cfg, kp, vp, tables, route=None):
     """:func:`_paged_block` for a chunk that STARTS its sequence
     (``positions`` count from 0): the same projections and page write,
     but the only keys such a chunk's queries may see are its own, which
     the layer has just computed — so attention runs causally over the
     chunk's k/v (rounded to the page dtype, as the pages hold them) and
-    nothing is gathered back from the pages."""
+    nothing is gathered back from the pages.  In the state's place it
+    returns ``tail`` [B, L, width], what every position of a ``cca``
+    block would leave behind (None otherwise)."""
     dt = cfg.dtype
+    tail = None
     with jax.named_scope("attention"):
-        q, k, v = tfm._attn_qkv(bp, x, positions, cfg)
+        if cfg.block.attention == "cca":
+            q, k, v, tail = cca.qkv_sequence(bp, x, positions, cfg)
+        else:
+            q, k, v = tfm._attn_qkv(bp, x, positions, cfg)
         kp, vp = _page_write(kp, vp, k, v, positions, tables)
         att = tfm._cache_attention(
             q, k.astype(kp.dtype).astype(dt), v.astype(vp.dtype).astype(dt),
             positions,
         )
         x = _attn_out(bp, x, att, cfg)
-    x, _aux = tfm._mlp_residual(bp, x, cfg)
-    return x, kp, vp
+    x, routed = _feed_forward(bp, x, cfg, route)
+    return x, kp, vp, tail, routed
+
+
+def _scan_layers(block, x, params, k_pages, v_pages, state, live, cfg):
+    """The layer scan both forwards share.  ``block(bp, x, kp, vp, st,
+    route) -> (x, kp, vp, st, routed)`` is one layer; the scan slices
+    the stacked block params, the pools and the state a layer at a time,
+    carries ``x`` (and a router's ``r``), and stacks what each layer
+    returns.  For the dense block every extra is None, an empty pytree:
+    the lowered program is the one without them."""
+    blocks, experts, r0, layers = params["blocks"], None, None, None
+    if cfg.block.ffn == "experts_top1":
+        blocks, experts = moe.stack_experts(blocks, cfg)
+        r0 = jnp.zeros(x.shape[:2] + (cfg.block.router_hidden,), jnp.float32)
+        layers = jnp.arange(cfg.n_layers, dtype=jnp.int32)
+
+    def step(carry, layer):
+        x, r = carry
+        bp, kp, vp, st, i = layer
+        route = None if experts is None else (r, live, experts, i)
+        x, kp, vp, st, routed = block(bp, x, kp, vp, st, route)
+        r, report = (None, None) if routed is None else (routed[0], routed[1:])
+        return (x, r), (kp, vp, st, report)
+
+    (x, _), out = jax.lax.scan(
+        step, (x, r0), (blocks, k_pages, v_pages, state, layers)
+    )
+    return (x,) + out
+
+
+def _routing(routed):
+    """What a dispatch reports of its routing, read back with the tokens:
+    ``(stats, chosen)``, or None for a model that routes nothing.
+    ``stats`` int32 [4] is what the dispatch adds to the ``moe_*``
+    counters, from the per-layer expert counts [n_layers, E]: layer-steps
+    routed, tokens routed, the fullest expert's tokens and the experts
+    that got any, each summed over the layers.  ``chosen`` int32
+    [n_layers, B * L] is every token's expert in every layer (``E`` for a
+    token that is not live): the decisions themselves, which a reference
+    needs beside the tokens because top-1 routing is discontinuous."""
+    if routed is None:
+        return None
+    counts, chosen = routed
+    stats = jnp.stack([
+        jnp.int32(counts.shape[0]),
+        jnp.sum(counts),
+        jnp.sum(jnp.max(counts, axis=1)),
+        jnp.sum(counts > 0),
+    ]).astype(jnp.int32)
+    return stats, chosen.reshape(chosen.shape[0], -1)
+
+
+def _head_logits(params, x, cfg, lead):
+    with jax.named_scope("head"):
+        x = tfm._rms_norm(x, params["ln_f"], cfg.block.norm_eps)
+        return tfm.head(params, x, cfg, lead)
+
+
+def _step_forward(params, tokens, tables, indices, k_pages, v_pages, cfg,
+                  state=None):
+    """A token chunk against the paged cache, whatever the block:
+    ``(logits, k_pages', v_pages', state', stats)``; see
+    :func:`apply_paged`.  ``state`` [n_layers, B, width] is the ``cca``
+    convolution state of the rows, ``stats`` is :func:`_routing` over the
+    rows that hold a sequence (a reserved page: ``tables[:, 0]``)."""
+    B, L = tokens.shape
+    positions = indices[:, None] + jnp.arange(L, dtype=jnp.int32)[None, :]
+    x = tfm.embed_lookup(params["embed"], tokens, cfg.dtype)
+    live = None
+    if cfg.block.ffn == "experts_top1":
+        live = jnp.broadcast_to(tables[:, :1] > 0, (B, L))
+
+    def block(bp, x, kp, vp, st, route):
+        return _paged_block(bp, x, positions, cfg, kp, vp, tables, st, route)
+
+    x, kps, vps, state, routed = _scan_layers(
+        block, x, params, k_pages, v_pages, state, live, cfg
+    )
+    logits = _head_logits(params, x, cfg, "bl")
+    return logits, kps, vps, state, _routing(routed)
 
 
 def apply_paged(
@@ -361,27 +491,44 @@ def apply_paged(
     the caller discards, and their k/v land in the trash page (or in
     positions later overwritten before any query can attend to them),
     so no masking beyond the causal one exists anywhere."""
-    B, L = tokens.shape
-    positions = indices[:, None] + jnp.arange(L, dtype=jnp.int32)[None, :]
-    x = tfm.embed_lookup(params["embed"], tokens, cfg.dtype)
+    return _step_forward(
+        params, tokens, tables, indices, k_pages, v_pages, cfg
+    )[:3]
 
-    def step(x, layer):
-        bp, kp, vp = layer
-        x, kp, vp = _paged_block(bp, x, positions, cfg, kp, vp, tables)
-        return x, (kp, vp)
 
-    x, (kps, vps) = jax.lax.scan(
-        step, x, (params["blocks"], k_pages, v_pages)
-    )
-    with jax.named_scope("head"):
-        x = tfm._rms_norm(x, params["ln_f"])
-        logits = jnp.einsum(
-            "bld,dv->blv",
-            x,
-            tfm.weight(params["lm_head"], cfg.dtype),
-            preferred_element_type=jnp.float32,
+def _prefill_forward(params, toks, table, last_pos, k_pages, v_pages, cfg,
+                     state=None, slot=None):
+    """ONE sequence from position 0, whatever the block: ``(logits [1, V]
+    at last_pos, k_pages', v_pages', state', stats)``; see
+    :func:`paged_prefill`.  A ``cca`` block's state row of ``slot`` [1] is
+    OVERWRITTEN with what the prompt's last real position leaves, so
+    nothing of the slot's previous tenant survives admission; tokens past
+    ``last_pos`` are padding, routed to no expert and counted nowhere."""
+    B, L = toks.shape
+    positions = jnp.broadcast_to(jnp.arange(L, dtype=jnp.int32), (B, L))
+    x = tfm.embed_lookup(params["embed"], toks, cfg.dtype)
+    live = None
+    if cfg.block.ffn == "experts_top1":
+        live = positions <= last_pos[:, None]
+
+    def block(bp, x, kp, vp, st, route):
+        x, kp, vp, tail, routed = _prefill_block(
+            bp, x, positions, cfg, kp, vp, table, route
         )
-    return logits, kps, vps
+        if tail is not None:
+            with jax.named_scope("attention/conv_state"):
+                last = jnp.take_along_axis(
+                    tail, last_pos[:, None, None], axis=1
+                )[:, 0]
+                st = st.at[slot].set(last.astype(st.dtype))
+        return x, kp, vp, st, routed
+
+    x, k_pages, v_pages, state, routed = _scan_layers(
+        block, x, params, k_pages, v_pages, state, live, cfg
+    )
+    x = jnp.take_along_axis(x, last_pos[:, None, None], axis=1)[:, 0]
+    logits = _head_logits(params, x, cfg, "b")
+    return logits, k_pages, v_pages, state, _routing(routed)
 
 
 # ---------------------------------------------------------------------------
@@ -389,21 +536,35 @@ def apply_paged(
 # ---------------------------------------------------------------------------
 
 
+def _results(tokens, k_pages, v_pages, state, stats, cfg):
+    """What a serving executable returns: the dense block's three, and for
+    any other block also its state and its routing (:func:`_routing`;
+    either may be None), which a dense model has none of."""
+    if cfg.block.stateless:
+        return tokens, k_pages, v_pages
+    return tokens, k_pages, v_pages, state, stats
+
+
 @functools.partial(jax.jit, static_argnames=("cfg",))
-def paged_decode_step(params, toks, tables, indices, k_pages, v_pages, cfg):
+def paged_decode_step(params, toks, tables, indices, k_pages, v_pages, cfg,
+                      state=None):
     """One greedy decode step for the whole slot batch: toks [B] ->
     next tokens [B].  Fixed [max_slots] shapes — the ONE executable the
     scheduler reuses for every step of every request population (idle
-    slots decode garbage into the trash page that nobody reads)."""
-    logits, k_pages, v_pages = apply_paged(
-        params, toks[:, None], tables, indices, k_pages, v_pages, cfg
+    slots decode garbage into the trash page that nobody reads).
+    Returns ``(next, k_pages', v_pages')``; a block that is not the dense
+    one (``cfg.block``) also takes the pool's convolution ``state`` and
+    returns ``(..., state', stats)`` (:func:`_results`)."""
+    logits, k_pages, v_pages, state, stats = _step_forward(
+        params, toks[:, None], tables, indices, k_pages, v_pages, cfg, state
     )
     nxt = jnp.argmax(logits[:, -1], axis=-1).astype(jnp.int32)
-    return nxt, k_pages, v_pages
+    return _results(nxt, k_pages, v_pages, state, stats, cfg)
 
 
 @functools.partial(jax.jit, static_argnames=("cfg",))
-def paged_prefill(params, toks, table, last_pos, k_pages, v_pages, cfg):
+def paged_prefill(params, toks, table, last_pos, k_pages, v_pages, cfg,
+                  state=None, slot=None):
     """Prefill of ONE newly admitted sequence, and of nothing else: toks
     [1, Lb] (the prompt padded to its own bucket), ``table`` [1,
     max_pages] its page-table row, ``last_pos`` [1] its final REAL
@@ -415,28 +576,13 @@ def paged_prefill(params, toks, table, last_pos, k_pages, v_pages, cfg):
     from the page pools and the table row nothing here scales with the
     slot count or the capacity.  Returns the first greedy token [1] —
     argmax over the logits at the prompt's frontier, exactly what the
-    contiguous ``generate`` samples from ``logits[:, -1]``.  One
+    contiguous ``generate`` samples from ``logits[:, -1]`` — and the
+    pools; a block that is not the dense one also takes the pool's
+    convolution ``state`` and the sequence's ``slot`` [1] and returns
+    ``(..., state', stats)`` as :func:`paged_decode_step` does.  One
     executable per prompt bucket (the ladder bounds the grid)."""
-    B, L = toks.shape
-    positions = jnp.broadcast_to(jnp.arange(L, dtype=jnp.int32), (B, L))
-    x = tfm.embed_lookup(params["embed"], toks, cfg.dtype)
-
-    def step(x, layer):
-        bp, kp, vp = layer
-        x, kp, vp = _prefill_block(bp, x, positions, cfg, kp, vp, table)
-        return x, (kp, vp)
-
-    x, (k_pages, v_pages) = jax.lax.scan(
-        step, x, (params["blocks"], k_pages, v_pages)
+    logits, k_pages, v_pages, state, stats = _prefill_forward(
+        params, toks, table, last_pos, k_pages, v_pages, cfg, state, slot
     )
-    with jax.named_scope("head"):
-        x = jnp.take_along_axis(x, last_pos[:, None, None], axis=1)[:, 0]
-        x = tfm._rms_norm(x, params["ln_f"])
-        logits = jnp.einsum(
-            "bd,dv->bv",
-            x,
-            tfm.weight(params["lm_head"], cfg.dtype),
-            preferred_element_type=jnp.float32,
-        )
     tok0 = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-    return tok0, k_pages, v_pages
+    return _results(tok0, k_pages, v_pages, state, stats, cfg)

@@ -268,3 +268,110 @@ def moe_mlp(
     ).astype(dt)
     out = out.reshape(B, L, D)
     return shard(out, ("dp", "ep"), "sp", None), aux
+
+
+# ---------------------------------------------------------------------------
+# dropless top-1 experts behind an MLP router (the serving path's layer)
+# ---------------------------------------------------------------------------
+#
+# The capacity path above gives every expert ``ceil(1.25 S / E)`` places a
+# group and drops the rest: right for training under ``ep`` (static shapes,
+# one all-to-all), wrong for serving, where the published model drops
+# nothing, a group may be one decode slot, and the pad tokens of a prefill
+# bucket would claim places.  Here tokens are sorted by their expert and the
+# three expert products are grouped matrix products (``jax.lax.ragged_dot``:
+# on a TPU a Mosaic kernel that visits only the groups that got rows, so an
+# expert nobody chose is neither multiplied nor read).  Tokens that are not
+# live (bucket padding, idle decode slots) go to no expert at all.
+
+
+def router_top1(bp, y: jnp.ndarray, r_prev: jnp.ndarray, live: jnp.ndarray,
+                eps: float):
+    """The ZAYA1 router (arXiv:2511.17127) on tokens ``y`` [T, D].
+
+    ``r = y Wr + gamma * r_prev`` (depth averaging: ``r_prev`` [T, R] is
+    the same tokens' ``r`` of the layer below, zeros at the first layer);
+    scores ``W3 gelu(W2 gelu(W1 RMSNorm(r)))``; ``p = softmax(scores)``;
+    the expert is ``argmax(p + bias)`` and the gate is its own ``p``,
+    unnormalised (top-1).  All in float32 at full matmul precision (the
+    MXU's default would round ``r`` to bfloat16 and move near-ties).
+    Returns ``(expert [T] int32, gate [T] f32, r [T, R] f32)``; a token
+    that is not ``live`` gets expert ``E``, which sorts last and belongs
+    to no group."""
+    f32 = lambda name: bp[name].astype(jnp.float32)
+    mm = lambda a, w: jnp.matmul(a, w, precision=jax.lax.Precision.HIGHEST)
+    r = mm(y.astype(jnp.float32), f32("router_in")) + f32("router_gamma") * r_prev
+    z = r * jax.lax.rsqrt(jnp.mean(r * r, -1, keepdims=True) + eps)
+    z = jax.nn.gelu(mm(z * f32("router_ln"), f32("router_w1")))
+    z = jax.nn.gelu(mm(z, f32("router_w2")))
+    p = jax.nn.softmax(mm(z, f32("router_w3")), axis=-1)
+    E = p.shape[-1]
+    expert = jnp.argmax(p + f32("router_bias"), axis=-1).astype(jnp.int32)
+    gate_p = jnp.take_along_axis(p, expert[:, None], axis=-1)[:, 0]
+    return jnp.where(live, expert, E), gate_p, r
+
+
+def stack_experts(blocks, cfg):
+    """Split a stacked block tree for a layer scan: ``(rest, experts)``.
+    ``experts`` holds ``we_gate`` / ``we_up`` / ``we_down`` of ALL layers as
+    one ``[n_layers * E, ...]`` array each, in the compute dtype, to be
+    closed over by the scan's body; ``rest`` is what the scan slices a
+    layer at a time.  A grouped product wants its weights as one buffer:
+    sliced by the scan, a layer's 16 experts are COPIED out of the stack
+    every step (3 x 134 MB a layer at ZAYA1's widths); addressed as groups
+    ``layer * E ..`` of the whole stack they are read in place."""
+    from .transformer import weight
+
+    names = ("we_gate", "we_up", "we_down")
+    rest = {k: v for k, v in blocks.items() if k not in names}
+    experts = {
+        k: weight(blocks[k], cfg.dtype).reshape((-1,) + blocks[k].shape[2:])
+        for k in names
+    }
+    return rest, experts
+
+
+def experts_top1(bp, y: jnp.ndarray, r_prev: jnp.ndarray, live: jnp.ndarray,
+                 cfg, experts, layer):
+    """The dropless expert layer: ``y`` [B, L, D] (post-RMSNorm) ->
+    ``(out [B, L, D], r [B, L, R] f32, counts [E] int32, chosen [B, L]
+    int32)``.  ``bp`` holds the layer's ``router_*``; the expert weights
+    are the groups of layer ``layer`` in ``experts``, the stack of all
+    layers (:func:`stack_experts`).
+
+    ``out`` is ``p[e] * Wdown(e)(silu(y Wgate(e)) * y Wup(e))`` for every
+    live token at any load, exact zeros elsewhere; ``r`` is the router's
+    carry for the layer above; ``counts`` the live tokens each expert got;
+    ``chosen`` each token's expert (``E`` for a token that is not live)."""
+    from .transformer import weight
+
+    B, L, D = y.shape
+    dt = cfg.dtype
+    T = B * L
+    yt, live = y.reshape(T, D), live.reshape(T)
+    with jax.named_scope("moe/router"):
+        expert, gate_p, r = router_top1(
+            bp, yt, r_prev.reshape(T, -1), live, cfg.block.norm_eps
+        )
+    E = cfg.moe_experts
+    with jax.named_scope("moe/sort"):
+        order = jnp.argsort(expert)  # stable: ties keep token order
+        sizes = jnp.bincount(expert, length=E + 1)[:E].astype(jnp.int32)
+        xs = yt[order]
+        # every other layer's groups are empty
+        groups = jax.lax.dynamic_update_slice(
+            jnp.zeros((experts["we_gate"].shape[0],), jnp.int32),
+            sizes, (layer * E,),
+        )
+    with jax.named_scope("moe/experts"):
+        grouped = lambda a, w: jax.lax.ragged_dot(
+            a, weight(w, dt), groups, preferred_element_type=jnp.float32
+        )
+        hidden = jax.nn.silu(grouped(xs, experts["we_gate"])) * grouped(xs, experts["we_up"])
+        ys = grouped(hidden.astype(dt), experts["we_down"])
+    with jax.named_scope("moe/combine"):
+        # rows past the groups (tokens routed nowhere) hold nothing defined
+        keep = (expert < E)[order]
+        ys = jnp.where(keep[:, None], ys * gate_p[order][:, None], 0.0)
+        out = jnp.zeros((T, D), dt).at[order].set(ys.astype(dt))
+    return out.reshape(B, L, D), r.reshape(B, L, -1), sizes, expert.reshape(B, L)

@@ -71,6 +71,42 @@ def embed_lookup(emb: "QTensor | jnp.ndarray", tokens, dt) -> jnp.ndarray:
 
 
 @dataclasses.dataclass(frozen=True)
+class BlockSpec:
+    """What one decoder block is made of (ROADMAP D8): the kinds of its
+    two sublayers and the numbers they share.  A model with another
+    block states it here, not in a further flag on
+    :class:`TransformerConfig`; the default is the dense GQA + SwiGLU
+    block every earlier configuration runs, op for op."""
+
+    # "gqa": grouped-query attention over pages of K and V;
+    # "cca": compressed convolutional attention (``models/cca.py``), whose
+    #   decode step also needs a fixed-size per-sequence convolution state
+    attention: str = "gqa"
+    # "swiglu": the dense SwiGLU (or the capacity-routed ``moe.moe_mlp``
+    #   when ``moe_experts`` > 0, the training path);
+    # "experts_top1": the dropless top-1 expert layer behind an MLP
+    #   router (``moe.experts_top1``): ``moe_experts`` experts of width
+    #   ``moe_d_ff``, router width ``router_hidden``
+    ffn: str = "swiglu"
+    norm_eps: float = 1e-6
+    rotary_share: float = 1.0  # share of a head's dimensions RoPE rotates
+    head_dim: Optional[int] = None  # None: d_model // n_heads
+    router_hidden: int = 0
+
+    def __post_init__(self):
+        if self.attention not in ("gqa", "cca"):
+            raise ValueError(f"attention {self.attention!r}: 'gqa' or 'cca'")
+        if self.ffn not in ("swiglu", "experts_top1"):
+            raise ValueError(f"ffn {self.ffn!r}: 'swiglu' or 'experts_top1'")
+
+    @property
+    def stateless(self) -> bool:
+        """The dense block: pages are its only per-sequence state and it
+        routes nothing, so its executables take and return no more."""
+        return self.attention == "gqa" and self.ffn == "swiglu"
+
+
+@dataclasses.dataclass(frozen=True)
 class TransformerConfig:
     vocab_size: int = 32_000
     d_model: int = 512
@@ -125,6 +161,7 @@ class TransformerConfig:
     # slice exist at a time, recomputed in the backward (jax.checkpoint).
     # 0 = classic full-logits loss.  Must divide the training L.
     ce_chunk: int = 0
+    block: BlockSpec = BlockSpec()
 
     def __post_init__(self):
         if self.remat_policy not in (
@@ -134,8 +171,14 @@ class TransformerConfig:
                 f"remat_policy {self.remat_policy!r}: use 'none', 'full', "
                 f"'dots', 'attn' or 'selective'"
             )
-        if self.d_model % self.n_heads:
+        if self.block.head_dim is None and self.d_model % self.n_heads:
             raise ValueError("d_model must be divisible by n_heads")
+        if self.block.ffn == "experts_top1" and not (
+            self.moe_experts and self.block.router_hidden
+        ):
+            raise ValueError(
+                "ffn 'experts_top1' needs moe_experts and block.router_hidden"
+            )
         if self.n_heads % self.n_kv_heads:
             raise ValueError("n_heads must be divisible by n_kv_heads")
         if self.moe_experts and self.moe_top_k > self.moe_experts:
@@ -145,7 +188,7 @@ class TransformerConfig:
 
     @property
     def head_dim(self) -> int:
-        return self.d_model // self.n_heads
+        return self.block.head_dim or self.d_model // self.n_heads
 
 
 def shard(x: jnp.ndarray, *spec) -> jnp.ndarray:
@@ -324,8 +367,17 @@ def _rms_norm(x: jnp.ndarray, w: jnp.ndarray, eps: float = 1e-6):
     return (x32 * scale).astype(x.dtype) * w.astype(x.dtype)
 
 
-def _rope(x: jnp.ndarray, positions: jnp.ndarray, theta: float):
-    """Rotary embedding.  x: [B, L, H, Dh]; positions: [B, L] (absolute)."""
+def _rope(
+    x: jnp.ndarray, positions: jnp.ndarray, theta: float, share: float = 1.0
+):
+    """Rotary embedding.  x: [B, L, H, Dh]; positions: [B, L] (absolute).
+    ``share`` < 1 rotates the first ``share * Dh`` dimensions of each
+    head and passes the rest through (partial rotary)."""
+    if share < 1.0:
+        rot = int(x.shape[-1] * share)
+        return jnp.concatenate(
+            [_rope(x[..., :rot], positions, theta), x[..., rot:]], -1
+        )
     dh = x.shape[-1]
     freqs = theta ** (
         -jnp.arange(0, dh // 2, dtype=jnp.float32) / (dh // 2)
@@ -335,6 +387,20 @@ def _rope(x: jnp.ndarray, positions: jnp.ndarray, theta: float):
     sin = jnp.sin(ang)[:, :, None, :].astype(x.dtype)
     x1, x2 = jnp.split(x, 2, axis=-1)
     return jnp.concatenate([x1 * cos - x2 * sin, x1 * sin + x2 * cos], -1)
+
+
+def head(params: Params, x: jnp.ndarray, cfg, lead: str = "bl"):
+    """The output head on final-norm hidden states, f32 logits.  A param
+    tree without ``lm_head`` is a tied model: the head reads ``embed``
+    ``[V, D]`` as it lies, contracting its second axis, and no transposed
+    copy of it is ever held."""
+    if "lm_head" in params:
+        w, eq = params["lm_head"], f"{lead}d,dv->{lead}v"
+    else:
+        w, eq = params["embed"], f"{lead}d,vd->{lead}v"
+    return jnp.einsum(
+        eq, x, weight(w, cfg.dtype), preferred_element_type=jnp.float32
+    )
 
 
 # attention numerics live in parallel.ring (full_attention is the shared
@@ -380,15 +446,17 @@ def _attn_qkv(bp, x, positions, cfg):
     B, L, D = x.shape
     h, kvh, dh = cfg.n_heads, cfg.n_kv_heads, cfg.head_dim
     dt = cfg.dtype
-    y = _saved(_rms_norm(x, bp["ln1"]))
+    y = _saved(_rms_norm(x, bp["ln1"], cfg.block.norm_eps))
     q = (y @ weight(bp["wq"], dt)).reshape(B, L, h, dh)
     k = (y @ weight(bp["wk"], dt)).reshape(B, L, kvh, dh)
     v = (y @ weight(bp["wv"], dt)).reshape(B, L, kvh, dh)
     q = _saved(
-        shard(_rope(q, positions, cfg.rope_theta), ("dp", "ep"), "sp", "tp", None)
+        shard(_rope(q, positions, cfg.rope_theta, cfg.block.rotary_share),
+              ("dp", "ep"), "sp", "tp", None)
     )
     k = _saved(
-        shard(_rope(k, positions, cfg.rope_theta), ("dp", "ep"), "sp", "tp", None)
+        shard(_rope(k, positions, cfg.rope_theta, cfg.block.rotary_share),
+              ("dp", "ep"), "sp", "tp", None)
     )
     v = _saved(shard(v, ("dp", "ep"), "sp", "tp", None))
     return q, k, v
@@ -402,7 +470,7 @@ def _mlp_residual(bp, x, cfg, segments=None):
     (``models/kv_pager.py``) composes the same halves in the same
     order."""
     dt = cfg.dtype
-    y = _saved(_rms_norm(x, bp["ln2"]))
+    y = _saved(_rms_norm(x, bp["ln2"], cfg.block.norm_eps))
     if cfg.moe_experts:
         from .moe import moe_mlp
 
@@ -632,6 +700,13 @@ def apply(
             f"never materialise the [L, L] probabilities in the first "
             f"place) — use remat_policy='none'/'full'/'selective' there."
         )
+    if not cfg.block.stateless:
+        raise NotImplementedError(
+            f"block {cfg.block.attention}/{cfg.block.ffn} runs on the paged "
+            f"serving path (models/kv_pager.py) only: the whole-batch forward "
+            f"and training carry neither its convolution state nor its "
+            f"router's layer-to-layer carry"
+        )
     if positions is None:
         positions = jnp.broadcast_to(jnp.arange(L, dtype=jnp.int32), (B, L))
     if blocks_runner is None:
@@ -640,13 +715,8 @@ def apply(
     x = shard(x, ("dp", "ep"), "sp", None)
     x, aux = blocks_runner(params["blocks"], x, positions, cfg, segment_ids)
     with jax.named_scope("head"):
-        x = _rms_norm(x, params["ln_f"])
-        logits = jnp.einsum(
-            "bld,dv->blv",
-            x,
-            weight(params["lm_head"], cfg.dtype),
-            preferred_element_type=jnp.float32,
-        )
+        x = _rms_norm(x, params["ln_f"], cfg.block.norm_eps)
+        logits = head(params, x, cfg)
     logits = shard(logits, ("dp", "ep"), "sp", "tp")
     out = (logits,)
     if return_hidden:

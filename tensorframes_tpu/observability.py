@@ -224,6 +224,16 @@ _counters: Dict[str, int] = {
     # executables computed (rows x bucket) — the rest is padding
     "decode_prefill_prompt_tokens": 0,
     "decode_prefill_run_tokens": 0,
+    # expert routing of a served model (``moe.experts_top1``), counted on
+    # the device over live tokens only and read back with a dispatch's
+    # tokens: layer-steps routed (layers x dispatches), tokens routed
+    # (summed over layers), and over the same layer-steps the sum of the
+    # fullest expert's tokens and of the experts that got any.  A dense
+    # model bumps none of them
+    "moe_route_calls": 0,
+    "moe_routed_tokens": 0,
+    "moe_busiest_expert_tokens": 0,
+    "moe_experts_touched": 0,
     # time counters (nanoseconds of time.perf_counter_ns, monotonic),
     # taken at the boundaries of the spans of the same name and bumped
     # once per step / prefill / block / verb.  Decode scheduler: steps;
@@ -1183,6 +1193,10 @@ def counters_delta(
             "decode_prefill_batches",
             "decode_prefill_prompt_tokens",
             "decode_prefill_run_tokens",
+            "moe_route_calls",
+            "moe_routed_tokens",
+            "moe_busiest_expert_tokens",
+            "moe_experts_touched",
             "decode_steps",
             "decode_host_ns",
             "decode_step_wait_ns",

@@ -1303,7 +1303,8 @@ class _PagedSeq:
 _DECODE_TRACK = "decode/driver"
 
 _DECODE_TIME_KEYS = (
-    "decode_steps", "decode_host_ns", "decode_step_wait_ns",
+    "decode_steps", "decode_kernel_steps", "decode_host_ns",
+    "decode_step_wait_ns",
     "decode_prefill_ns", "decode_busy_ns", "decode_admitted",
     "decode_queue_wait_ns", "decode_first_tokens", "decode_ttft_ns",
     "decode_stream_ns", "decode_stream_tokens",
@@ -1355,7 +1356,10 @@ class DecodeScheduler:
     weight; a decode step's attention reduction extent matches by
     construction, a prefill's is its bucket — the keys it leaves out
     had zero weight, so the two agree to f32 rounding and, in the
-    suite on XLA:CPU, token for token).
+    suite on XLA:CPU, token for token).  Where the step's shapes fit the
+    paged-attention kernel (``kv_pager.paged_kernel_fits``, PR 30) its
+    attention sums the same terms in another order: equal to rounding,
+    and in the suite the same greedy tokens, not the same bits.
 
     ``speculative`` runs the draft/verify path (B=1 by its contract)
     solo in the caller's thread — an opt-in per-request latency knob,
@@ -1413,6 +1417,12 @@ class DecodeScheduler:
         )
         self._kp = self.pool.k_pages
         self._vp = self.pool.v_pages
+        # whether the step executable attends through the paged-attention
+        # kernel: what ``kv_pager._paged_block`` will decide when it traces
+        # this pool's one-token step, asked once (``decode_kernel_steps``)
+        self._kernel_step = int(kv_pager.paged_kernel_fits(
+            cfg, P, self.max_slots, 1, self._kp.dtype
+        ))
         # a 'cca' block's convolution state, one row a slot (None for
         # other blocks): an argument and a result of both executables,
         # like the pages; a prefill overwrites the admitted slot's row
@@ -1879,6 +1889,7 @@ class DecodeScheduler:
                             # writes land on the trash page via their
                             # all-zero tables
                 tally["decode_steps"] += 1
+                tally["decode_kernel_steps"] += self._kernel_step
                 tally["decode_tokens"] += n_tok
                 tally["decode_step_wait_ns"] += sp_w.ns
                 self._flush_tally()

@@ -6,11 +6,15 @@ prompt/continuation lengths therefore reserves worst-case HBM for every
 sequence, which is exactly the fragmentation PagedAttention/Orca-style
 serving removed (PAPERS.md).  This module is the paged layout:
 
-* a process-level :class:`PagePool` owns ``[n_layers, n_pages, P, kvh,
+* a process-level :class:`PagePool` owns ``[n_layers, kvh, n_pages, P,
   Dh]`` k/v page arrays (``P = TFS_DECODE_PAGE_TOKENS``) and a free
-  list; **physical page 0 is the trash page** — never allocated, it
-  absorbs the writes of pad tokens and idle decode slots so no write
-  path needs a validity mask;
+  list.  The layout is head-major because the decode kernel dictates it
+  (PR 30): one page of one head is ``[P, Dh]``, whole native tiles (one
+  4 KB tile at P=16, Dh=128 in bf16) that a DMA moves as they lie,
+  whatever ``kvh`` is; with the heads inside the page (``[P, kvh, Dh]``)
+  a model of 2 kv heads filled a quarter of each tile.  **Physical page
+  0 is the trash page** — never allocated, it absorbs the writes of pad
+  tokens and idle decode slots so no write path needs a validity mask;
 * each live sequence holds a **page table** (one int32 row mapping its
   ``pos // P`` slots to physical pages) and charges its reserved pages
   against the PR 5 frame-cache LRU (``ops/frame_cache._HbmBudget``)
@@ -20,14 +24,23 @@ serving removed (PAPERS.md).  This module is the paged layout:
   remains, allocation fails as a typed :class:`PagesExhausted` refusal
   the serving layer surfaces with ``retry_after_ms`` instead of OOMing
   mid-step;
-* :func:`apply_paged` runs a token chunk against the paged cache with
-  **gather-based attention that is bit-identical to the contiguous
-  path**: the projection half is ``transformer._attn_qkv`` (the SAME
-  ops, shared by construction), the gathered ``kp[tables]`` view hands
-  the unmodified ``transformer._cache_attention`` a cache of the same
-  sequence capacity, and masked slots contribute exact zeros (softmax
-  of ``-inf`` is exactly 0, and ``0 * v`` terms are accumulation-
-  neutral), so stale page contents never perturb a single bit;
+* :func:`apply_paged` runs a token chunk against the paged cache.  The
+  general path is **gather-based attention that is bit-identical to the
+  contiguous path**: the projection half is ``transformer._attn_qkv``
+  (the SAME ops, shared by construction), the gathered ``kp[:, tables]``
+  view hands the unmodified ``transformer._cache_attention`` a cache of
+  the same sequence capacity, and masked slots contribute exact zeros
+  (softmax of ``-inf`` is exactly 0, and ``0 * v`` terms are
+  accumulation-neutral), so stale page contents never perturb a single
+  bit;
+* a ONE-token chunk whose shapes fit (:func:`paged_kernel_fits`: decided
+  at trace time from what the call is given, no knob) attends instead
+  through ``parallel/paged_attention.py``: the Pallas kernel reads each
+  row's pages in place through its table row, up to its frontier and no
+  further, so a decode step moves the K and V a sequence HOLDS and not
+  its capacity.  Same mathematics (f32 scores, softmax and accumulation;
+  exact zero weight past the frontier), another order of summation: it
+  agrees with the gather path to rounding, not bit for bit;
 * :func:`paged_prefill` is the serving prefill — ONE admitted prompt
   and nothing else.  A prefill starts at position 0, so the only keys
   its queries may see are the chunk's own: it writes them to the pages
@@ -36,8 +49,9 @@ serving removed (PAPERS.md).  This module is the paged layout:
   the prompt's last position alone.  Nothing in it scales with the
   slot count or the capacity but the page pools and the table row.
 
-Bit-identity contract: a paged sequence whose table spans ``n_pages_seq
-= cap // P`` pages attends over ``S' = cap`` gathered slots.  Compare
+Bit-identity contract (the gather path): a paged sequence whose table
+spans ``n_pages_seq = cap // P`` pages attends over ``S' = cap`` gathered
+slots.  Compare
 against the contiguous path at the SAME capacity (``decode.generate``'s
 ``cache_len=cap``) — matching reduction extents keep CPU/TPU
 accumulation order identical; the suite pins this per step and for
@@ -62,6 +76,7 @@ from . import transformer as tfm
 from .. import observability
 from ..envutil import env_int as _env_int
 from ..ops import frame_cache
+from ..parallel import paged_attention
 
 ENV_PAGE_TOKENS = "TFS_DECODE_PAGE_TOKENS"
 DEFAULT_PAGE_TOKENS = 16
@@ -111,9 +126,11 @@ class _SeqPages:
 class PagePool:
     """Fixed-size physical KV page pool shared by every decode slot.
 
-    ``k_pages``/``v_pages`` are ``[n_layers, n_pages, P, kvh, Dh]``
-    functional jax arrays; the serving driver threads them through the
-    prefill/step executables and stores the returned (updated) arrays.
+    ``k_pages``/``v_pages`` are ``[n_layers, kvh, n_pages, P, Dh]``
+    functional jax arrays — head-major, so a page of one head is whole
+    native tiles whatever ``kvh`` is (the module docstring says why);
+    the serving driver threads them through the prefill/step executables
+    and stores the returned (updated) arrays.
     The pool object itself only manages the free list and the budget
     accounting — page CONTENTS are owned by whoever holds the arrays.
 
@@ -145,7 +162,7 @@ class PagePool:
         self.n_pages = int(n_pages)
         dtype = dtype or cfg.dtype
         kvh, dh, n = cfg.n_kv_heads, cfg.head_dim, cfg.n_layers
-        shape = (n, self.n_pages, P, kvh, dh)
+        shape = (n, kvh, self.n_pages, P, dh)
         self.k_pages = jnp.zeros(shape, dtype)
         self.v_pages = jnp.zeros(shape, dtype)
         # the second kind of per-sequence state: a ``cca`` block's decode
@@ -264,15 +281,34 @@ def init_tables(batch: int, max_pages: int) -> jnp.ndarray:
 
 
 @jax.named_scope("page_write")
-def _page_write(kp, vp, k, v, positions, tables):
+def _page_write(kp, vp, k, v, positions, tables, from_zero=False):
     """Scatter a chunk's k/v ``[B, L, kvh, Dh]`` into one layer's pages
-    at ``tables[b, pos // P]``, offset ``pos % P``.  Returns the updated
-    ``(kp, vp)``."""
+    ``[kvh, n_pages, P, Dh]`` at ``tables[b, pos // P]``, offset ``pos %
+    P``, of every head.  Returns the updated ``(kp, vp)``.
+
+    The pool is written as windows of ``w`` rows of Dh, in the layout the
+    kernel reads (a scatter windowed over (kvh, Dh) makes XLA turn the
+    whole pool head-minor and back around every write), and XLA's scatter
+    takes a window at a time, ~45 ns each whatever its size.  A chunk
+    anywhere is written a token of a head a window (``w`` = 1: a decode
+    step's 2 x 48 to 8 x 12 of them).  ``from_zero`` (static) says the
+    chunk starts its sequence — ``positions`` is ``arange(L)`` on every
+    row, which a prefill knows of itself — so it fills whole pages from
+    each table's first and is written a page of a head a window (``w`` =
+    P, a 4 KB tile: 8 x 64 windows for a 1,024 bucket, not 8 x 1,024),
+    its last page padded with zeros where no query can look before a
+    decode step has written there."""
     B, L, kvh, dh = k.shape
-    P = kp.shape[1]
-    page_slot = positions // P  # [B, L]
-    offset = positions % P
+    _, n_pages, P, _ = kp.shape
     max_pages = tables.shape[1]
+    if from_zero:
+        w, n = P, -(-L // P)
+        page_slot = jnp.broadcast_to(jnp.arange(n, dtype=jnp.int32), (B, n))
+        window = 0
+    else:
+        w, n = 1, L
+        page_slot = positions // P  # [B, L]
+        window = (positions % P).reshape(B * n)
     # positions past a row's table (bucket padding that overruns
     # the sequence capacity) write the trash page, never a
     # clamped real slot
@@ -282,16 +318,19 @@ def _page_write(kp, vp, k, v, positions, tables):
             tables, jnp.minimum(page_slot, max_pages - 1), axis=1
         ),
         0,
-    )  # [B, L]
-    flat_dest = dest.reshape(B * L)
-    flat_off = offset.reshape(B * L)
-    kp = kp.at[flat_dest, flat_off].set(
-        k.astype(kp.dtype).reshape(B * L, kvh, dh), mode="drop"
-    )
-    vp = vp.at[flat_dest, flat_off].set(
-        v.astype(vp.dtype).reshape(B * L, kvh, dh), mode="drop"
-    )
-    return kp, vp
+    ).reshape(B * n)
+    heads = jnp.arange(kvh, dtype=jnp.int32)[:, None]
+    at = ((heads * n_pages + dest) * (P // w) + window).reshape(kvh * B * n)
+
+    def put(pages, x):
+        x = jnp.pad(
+            x.astype(pages.dtype), ((0, 0), (0, n * w - L), (0, 0), (0, 0))
+        ).reshape(B * n, w, kvh, dh).transpose(2, 0, 1, 3)
+        return pages.reshape(kvh * n_pages * (P // w), w, dh).at[at].set(
+            x.reshape(kvh * B * n, w, dh), mode="drop"
+        ).reshape(pages.shape)
+
+    return put(kp, k), put(vp, v)
 
 
 def _attn_out(bp, x, att, cfg):
@@ -321,18 +360,49 @@ def _feed_forward(bp, x, cfg, route):
     return x + out, tuple(routed)
 
 
+def paged_kernel_fits(cfg, P: int, B: int, L: int, dtype) -> bool:
+    """Whether a chunk attends through the Pallas kernel
+    (``parallel/paged_attention.py``), decided at trace time from what
+    the call is given: ``L`` tokens a row for ``B`` rows against pools of
+    ``P``-token pages in ``dtype``.  It takes ONE token a row, heads of
+    whole lane tiles, pages of whole sublane tiles of the pool's dtype
+    (16 rows of bf16, 8 of f32: a page is then a run of native tiles),
+    pools in the compute dtype, its buffers within VMEM (query heads
+    come in whole groups by the configuration's own check) — and no mesh
+    axis left to partition over, which a Mosaic kernel cannot be
+    (``flash._per_shard``; the scheduler runs on one device).  Whatever
+    it refuses takes the gather path."""
+    dtype = jnp.dtype(dtype)
+    mesh = jax.sharding.get_abstract_mesh()
+    partitioned = any(
+        t != jax.sharding.AxisType.Manual for t in mesh.axis_types
+    )
+    return (
+        L == 1
+        and cfg.head_dim % 128 == 0
+        and P % (32 // dtype.itemsize) == 0
+        and dtype == jnp.dtype(cfg.dtype)
+        and not partitioned
+        and paged_attention.vmem_bytes(
+            B, cfg.n_heads, cfg.n_kv_heads, cfg.head_dim, P, dtype
+        ) <= paged_attention.VMEM_BUDGET_BYTES
+    )
+
+
 def _paged_block(bp, x, positions, cfg, kp, vp, tables, st=None,
                  route=None):
     """One decoder block against one layer's page arrays.
 
-    ``kp``/``vp``: [n_pages, P, kvh, Dh]; ``tables``: [B, max_pages];
+    ``kp``/``vp``: [kvh, n_pages, P, Dh]; ``tables``: [B, max_pages];
     ``positions``: [B, L] absolute positions (per-row frontiers).  The
     chunk's k/v scatter to ``tables[b, pos // P]`` at offset ``pos %
     P`` — table slots a sequence never reserved hold 0, so pad tokens
-    and idle slots write the trash page.  Attention gathers the table's
+    and idle slots write the trash page.  A one-token chunk that fits
+    (:func:`paged_kernel_fits`) then attends over its pages in place,
+    through the table, up to its frontier; any other gathers the table's
     pages into a [B, max_pages * P] contiguous view and runs the
-    UNMODIFIED ``transformer._cache_attention`` on it: positions past a
-    row's frontier are masked to exact zero weight, so stale page
+    UNMODIFIED ``transformer._cache_attention`` on it.  Either way
+    positions past a row's frontier have exact zero weight, so stale page
     contents (previous tenants included) never contribute a bit.
 
     A ``cca`` block (``L`` = 1) also takes the layer's convolution state
@@ -340,11 +410,13 @@ def _paged_block(bp, x, positions, cfg, kp, vp, tables, st=None,
     ``experts_top1`` block takes ``route`` (:func:`_feed_forward`).
     Returns ``(x', kp', vp', st', routed)``, the last two None where the
     spec has no such thing."""
-    B = x.shape[0]
+    B, L = x.shape[:2]
     dt = cfg.dtype
-    P = kp.shape[1]
+    P = kp.shape[2]
+    cap = tables.shape[1] * P
     # scope names are metadata: a profiler session groups the device
-    # operations of a step under attention / page_write / page_gather
+    # operations of a step under attention / page_write / paged_kernel
+    # (or page_gather, on the general path)
     with jax.named_scope("attention"):
         if cfg.block.attention == "cca":
             q, k, v, st = cca.qkv_step(bp, x, positions, st, cfg)
@@ -352,13 +424,25 @@ def _paged_block(bp, x, positions, cfg, kp, vp, tables, st=None,
             q, k, v = tfm._attn_qkv(bp, x, positions, cfg)
         kvh, dh = k.shape[2:]
         kp, vp = _page_write(kp, vp, k, v, positions, tables)
-        with jax.named_scope("page_gather"):
-            # gather each row's pages into its contiguous cache view
-            ck = kp[tables].reshape(B, tables.shape[1] * P, kvh, dh)
-            cv = vp[tables].reshape(B, tables.shape[1] * P, kvh, dh)
-        att = tfm._cache_attention(
-            q, ck.astype(dt), cv.astype(dt), positions
-        )
+        if paged_kernel_fits(cfg, P, B, L, kp.dtype):
+            with jax.named_scope("paged_kernel"):
+                # an idle row (any index, its table all trash) attends
+                # the trash page like the gather path: at least one key,
+                # at most the capacity
+                lengths = jnp.clip(positions[:, 0] + 1, 1, cap)
+                att = paged_attention.paged_attention(
+                    q[:, 0], kp, vp, tables, lengths
+                )[:, None]
+        else:
+            with jax.named_scope("page_gather"):
+                # gather each row's pages into its contiguous cache view
+                ck, cv = (
+                    jnp.moveaxis(pages[:, tables], 0, 3).reshape(
+                        B, cap, kvh, dh
+                    ).astype(dt)
+                    for pages in (kp, vp)
+                )
+            att = tfm._cache_attention(q, ck, cv, positions)
         x = _attn_out(bp, x, att, cfg)
     x, routed = _feed_forward(bp, x, cfg, route)
     return x, kp, vp, st, routed
@@ -380,7 +464,7 @@ def _prefill_block(bp, x, positions, cfg, kp, vp, tables, route=None):
             q, k, v, tail = cca.qkv_sequence(bp, x, positions, cfg)
         else:
             q, k, v = tfm._attn_qkv(bp, x, positions, cfg)
-        kp, vp = _page_write(kp, vp, k, v, positions, tables)
+        kp, vp = _page_write(kp, vp, k, v, positions, tables, from_zero=True)
         att = tfm._cache_attention(
             q, k.astype(kp.dtype).astype(dt), v.astype(vp.dtype).astype(dt),
             positions,

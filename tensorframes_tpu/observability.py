@@ -236,7 +236,10 @@ _counters: Dict[str, int] = {
     "moe_experts_touched": 0,
     # time counters (nanoseconds of time.perf_counter_ns, monotonic),
     # taken at the boundaries of the spans of the same name and bumped
-    # once per step / prefill / block / verb.  Decode scheduler: steps;
+    # once per step / prefill / block / verb.  Decode scheduler: steps,
+    # and those of them whose executable attends through the paged-
+    # attention kernel (``kv_pager.paged_kernel_fits``, evaluated once
+    # when the scheduler builds its pool: all of a scheduler's or none);
     # the driver loop's wall time while any stream is active (busy) and
     # its three parts — the wait for a step's tokens, the whole of its
     # prefills, and host time, which is the rest (boundary, dispatch,
@@ -245,6 +248,7 @@ _counters: Dict[str, int] = {
     # wait, first tokens with their submit -> first-token time, and
     # first -> last token time over the tokens after the first
     "decode_steps": 0,
+    "decode_kernel_steps": 0,
     "decode_host_ns": 0,
     "decode_step_wait_ns": 0,
     "decode_prefill_ns": 0,
@@ -1198,6 +1202,7 @@ def counters_delta(
             "moe_busiest_expert_tokens",
             "moe_experts_touched",
             "decode_steps",
+            "decode_kernel_steps",
             "decode_host_ns",
             "decode_step_wait_ns",
             "decode_prefill_ns",

@@ -18,10 +18,22 @@ at single-host scale, with no mesh and no collectives.  ``TFS_DEVICE_POOL``
 sizes it; ``pool_devices()``/``pool_enabled()`` report the resolved pool.
 """
 
-from ..ops.device_pool import enabled as pool_enabled, pool_devices
-from .dist import MeshExecutor
-from .mesh import data_mesh, device_count, training_mesh
-from .multihost import (
+import sys
+
+# This package's Pallas kernels (``flash``, ``paged_attention``) are TPU
+# kernels.  ``jax.experimental.pallas`` imports its GPU interpreter beside
+# the TPU backend — an LLVM dialect and Mosaic GPU, 0.7 s of the 1.2 s the
+# import takes on a v5e host, in the set-up of every process that serves
+# decode (PERF.md §6, PR 30) — and is written to do without it (``except
+# ImportError`` in ``pallas_call``).  A None entry makes that import raise;
+# where Pallas is already loaded this does nothing, and a jax that moves the
+# module only loses the saving.
+sys.modules.setdefault("jax._src.pallas.mosaic_gpu.interpret", None)
+
+from ..ops.device_pool import enabled as pool_enabled, pool_devices  # noqa: E402
+from .dist import MeshExecutor  # noqa: E402
+from .mesh import data_mesh, device_count, training_mesh  # noqa: E402
+from .multihost import (  # noqa: E402
     frame_from_process_local,
     initialize,
     process_count,

@@ -1,0 +1,236 @@
+"""Paged decode attention as a Pallas TPU kernel.
+
+A one-token decode step attends over what each sequence HOLDS: its K and
+V live in fixed-size pages of a shared pool (``models/kv_pager.py``),
+scattered wherever the free list put them, and the row's page table says
+where.  The XLA path gathers every page of every row's CAPACITY into a
+contiguous copy and masks most of it out; this kernel reads the pages in
+place — page by page through the table row, up to the row's frontier and
+no further — with the online-softmax recurrence of ``parallel/flash.py``:
+nothing of shape ``[B, max_pages * P, kvh, Dh]`` is written and no scores
+over the capacity exist.
+
+Layout it dictates: a layer's pool is ``[kvh, n_pages, P, Dh]``, so one
+page of one head is ``[P, Dh]`` — with ``P`` a whole number of sublane
+tiles (16 rows of bf16) a run of native tiles, 4 KB at P=16/Dh=128 — and
+``pool.at[:, page]`` is one strided DMA that brings a page of EVERY kv
+head.
+
+Shape of the kernel: ONE program, no grid.  ``q`` [B, kvh, g, Dh] and the
+output sit whole in VMEM (a decode step's are a few hundred KB), tables
+and lengths in SMEM, the pools stay in HBM.  Rows are walked in order;
+a row's keys come in compute blocks of ``pages_per_block`` pages (128
+keys) through a ring of ``_RING`` buffers: while block ``n`` is
+multiplied the copies of the next ``_RING - 1`` blocks — the row's own,
+then the NEXT rows' first ones — are in flight, so only the first DMAs of
+a call are exposed.  Scores, softmax statistics and the accumulator are
+f32; the probabilities are rounded to the pool's dtype for the second
+product, as ``transformer._cache_attention`` rounds them: same
+mathematics, another order of summation.
+
+Off-TPU the kernel runs in Pallas interpret mode (``flash``'s rule and
+its one WARNING).
+"""
+
+from __future__ import annotations
+
+import functools
+from typing import Optional
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+from .flash import _NEG_INF, _resolve_interpret
+
+# the trace reduction and the docs find the kernel by this name
+KERNEL_NAME = "tfs_paged_attention"
+
+# keys per compute block: one lane-width of scores
+_BLOCK_KEYS = 128
+# buffers in the ring: _RING - 1 blocks' copies are in flight while one is
+# multiplied
+_RING = 8
+# what the kernel may ask of VMEM: the smallest default scoped limit of the
+# chips served (16 MiB), with room left for the compiler's own
+VMEM_BUDGET_BYTES = 12 * 2**20
+
+
+def pages_per_block(P: int) -> int:
+    """Pages a compute block holds: ``_BLOCK_KEYS`` keys' worth."""
+    return max(1, _BLOCK_KEYS // int(P))
+
+
+def vmem_bytes(B: int, h: int, kvh: int, dh: int, P: int, dtype) -> int:
+    """VMEM the kernel asks for: K's and V's rings of buffers, each a
+    compute block of every kv head, and ``q`` with the output (a group's
+    rows padded to a sublane tile)."""
+    item = jnp.dtype(dtype).itemsize
+    buffers = 2 * _RING * kvh * pages_per_block(P) * P * dh * item
+    rows = -(-(h // kvh) // 8) * 8
+    return buffers + 2 * B * kvh * rows * dh * 4
+
+
+def _paged_kernel(
+    lengths_ref,
+    tables_ref,
+    q_ref,
+    k_hbm,
+    v_hbm,
+    o_ref,
+    k_buf,
+    v_buf,
+    sems,
+    *,
+    scale: float,
+    max_pages: int,
+):
+    B, kvh, g, dh = q_ref.shape
+    ring, _, ppb, P, _ = k_buf.shape
+    T = ppb * P
+    kv = ((k_hbm, k_buf), (v_hbm, v_buf))
+    # loop bounds and counters are int32 by hand: with x64 on, a Python
+    # bound makes an int64 index, which Mosaic has no use for
+    zero, ring_ = jnp.int32(0), jnp.int32(ring)
+
+    def n_blocks(b):
+        return jax.lax.div(lengths_ref[b] + (T - 1), jnp.int32(T))
+
+    def fetch(b, i, slot):
+        """Start the page copies of row ``b``'s block ``i`` into buffer
+        ``slot`` — a page of every kv head a copy, K's before V's — and
+        return the block after it: the row's next, or the next row's
+        first.  Table slots past the row's width repeat its last page
+        (masked like everything past the frontier); past the last row
+        the walk stays on it, copies the kernel's end drains — always
+        issued, so they schedule beside the products, not behind a
+        branch."""
+        pages = [
+            tables_ref[b * max_pages + jnp.minimum(i * ppb + j, max_pages - 1)]
+            for j in range(ppb)
+        ]
+        for x, (hbm, buf) in enumerate(kv):
+            for j, page in enumerate(pages):
+                pltpu.make_async_copy(
+                    hbm.at[:, page], buf.at[slot, :, j], sems.at[x, slot]
+                ).start()
+        last = i + 1 >= n_blocks(b)
+        return (
+            jnp.where(last, jnp.minimum(b + 1, B - 1), b),
+            jnp.where(last, 0, i + 1),
+        )
+
+    def arrived(x, slot):
+        """Wait for the ``ppb`` copies into K's (0) or V's (1) ``slot``;
+        a wait knows its semaphore and its size, not its source."""
+        hbm, buf = kv[x]
+        for j in range(ppb):
+            pltpu.make_async_copy(
+                hbm.at[:, 0], buf.at[slot, :, j], sems.at[x, slot]
+            ).wait()
+
+    def row(b, carry):
+        length = lengths_ref[b]
+        q = q_ref[b]  # [kvh, g, dh]
+
+        def block(i, carry):
+            m, l, acc, n, fb, fi = carry
+            # keep ring - 1 blocks in flight: the one that takes the
+            # buffer the previous iteration has done with
+            fb, fi = fetch(fb, fi, jax.lax.rem(n + ring - 1, ring_))
+            slot = jax.lax.rem(n, ring_)
+            arrived(0, slot)
+            k = k_buf[slot].reshape(kvh, T, dh)
+            s = jnp.einsum(
+                "kgd,ktd->kgt", q, k, preferred_element_type=jnp.float32
+            ) * np.float32(scale)
+            pos = i * T + jax.lax.broadcasted_iota(jnp.int32, s.shape, 2)
+            s = jnp.where(pos < length, s, _NEG_INF)
+            # every block walked holds a key under the frontier (length
+            # >= 1), so m_new is finite and exp(-inf - m_new) is 0
+            m_new = jnp.maximum(m, s.max(axis=-1, keepdims=True))
+            p = jnp.exp(s - m_new)
+            alpha = jnp.exp(m - m_new)
+            l = alpha * l + p.sum(axis=-1, keepdims=True)
+            arrived(1, slot)
+            v = v_buf[slot].reshape(kvh, T, dh)
+            acc = acc * alpha + jnp.einsum(
+                "kgt,ktd->kgd", p.astype(v.dtype), v,
+                preferred_element_type=jnp.float32,
+            )
+            return m_new, l, acc, n + 1, fb, fi
+
+        _, l, acc, *carry = jax.lax.fori_loop(
+            zero, n_blocks(b), block,
+            (
+                jnp.full((kvh, g, 1), _NEG_INF, jnp.float32),
+                jnp.zeros((kvh, g, 1), jnp.float32),
+                jnp.zeros((kvh, g, dh), jnp.float32),
+                *carry,
+            ),
+        )
+        o_ref[b] = (acc / l).astype(o_ref.dtype)
+        return tuple(carry)
+
+    fb, fi = jax.lax.fori_loop(
+        zero, ring_ - 1, lambda d, at: fetch(*at, d), (zero, zero)
+    )
+    n, _, _ = jax.lax.fori_loop(zero, jnp.int32(B), row, (zero, fb, fi))
+
+    def drain(d, _):
+        slot = jax.lax.rem(n + d, ring_)
+        arrived(0, slot)
+        arrived(1, slot)
+
+    jax.lax.fori_loop(zero, ring_ - 1, drain, None)
+
+
+def paged_attention(
+    q,
+    k_pages,
+    v_pages,
+    tables,
+    lengths,
+    interpret: Optional[bool] = None,
+):
+    """softmax(q K^T / sqrt(d)) V of one query position a row over the
+    keys the row holds in its pages.
+
+    q: [B, h, Dh]; k_pages/v_pages: one layer's pools [kvh, n_pages, P,
+    Dh] with ``h % kvh == 0``; tables: int32 [B, max_pages], physical
+    page of each of the row's page slots; lengths: int32 [B], keys the
+    row attends (positions ``0 .. length - 1``), AT LEAST 1 — an idle
+    row reads its table's first page like any other.  Returns [B, h, Dh]
+    in ``q.dtype``.  Keys past a row's frontier — the tail of its last
+    page, pages it never reserved — get exact zero weight; they must be
+    finite, as zero times them is added."""
+    B, h, dh = q.shape
+    kvh, _, P, _ = k_pages.shape
+    g = h // kvh
+    max_pages = tables.shape[1]
+    buf = pltpu.VMEM((_RING, kvh, pages_per_block(P), P, dh), k_pages.dtype)
+    smem = pl.BlockSpec(memory_space=pltpu.SMEM)
+    vmem = pl.BlockSpec(memory_space=pltpu.VMEM)
+    hbm = pl.BlockSpec(memory_space=pl.ANY)
+    out = pl.pallas_call(
+        functools.partial(
+            _paged_kernel,
+            scale=1.0 / np.sqrt(dh),
+            max_pages=max_pages,
+        ),
+        in_specs=[smem, smem, vmem, hbm, hbm],
+        out_specs=vmem,
+        out_shape=jax.ShapeDtypeStruct((B, kvh, g, dh), q.dtype),
+        scratch_shapes=[buf, buf, pltpu.SemaphoreType.DMA((2, _RING))],
+        interpret=_resolve_interpret(interpret),
+        name=KERNEL_NAME,
+    )(
+        lengths.astype(jnp.int32),
+        tables.astype(jnp.int32).reshape(-1),
+        q.reshape(B, kvh, g, dh),
+        k_pages,
+        v_pages,
+    )
+    return out.reshape(B, h, dh)

@@ -1,0 +1,195 @@
+"""The decode step's Pallas kernel, compiled at the benchmark's real widths
+for a TPU v5e that is described and not attached (PR 30).
+
+Interpret mode (``tests/test_paged_attention.py``) checks the kernel's
+arithmetic; it cannot see a slice that is not aligned to the tiling, a
+DMA the hardware cannot describe, or more VMEM than a kernel may use.
+The TPU's compiler is installed here and refuses those for a chip it is
+only told about.  Nothing runs: no time or result comes from this file.
+
+The topology is described inside a fixture, after a test of this file has
+started, and never at import (one process at a time may load the TPU's
+library; every xdist worker imports every test file).  Keep such tests in
+this one file.
+"""
+
+import json
+import os
+import re
+import sys
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+if ROOT not in sys.path:
+    sys.path.insert(0, ROOT)
+
+from perfbench.drivers.bridge_decode_zaya import transformer_config  # noqa: E402
+from perfbench.refs import transformer_decoder, zaya_decoder  # noqa: E402
+from tensorframes_tpu.models import cca, kv_pager  # noqa: E402
+from tensorframes_tpu.models import transformer as tfm  # noqa: E402
+from tensorframes_tpu.parallel import paged_attention as pa  # noqa: E402
+
+# (configuration, the traffic file whose slots and capacity serve it)
+CELLS = {
+    "zaya1_8b_l20": "decode_reason",
+    "mistral_7b_l8": "decode_chat",
+}
+
+
+def _load(kind, name):
+    with open(os.path.join(ROOT, "perfbench", kind, name + ".json")) as f:
+        return json.load(f)
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+
+    try:
+        topo = topologies.get_topology_desc(
+            platform="tpu", topology_name="v5e:2x2"
+        )
+    except Exception as e:  # noqa: BLE001 — any failure means "not here"
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture()
+def compiled_not_interpreted(monkeypatch):
+    """The persistent cache off (a compile for a described chip can be
+    written to it and never read back), and the kernel as the chip gets
+    it: ``jax.default_backend()`` is the CPU here, so the test steers
+    what the program would decide from it, and the suite's x64 (on for
+    dtype fidelity on the CPU) is off as it is on the chip."""
+    from jax.experimental.compilation_cache import compilation_cache
+
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    monkeypatch.setattr(pa, "_resolve_interpret", lambda interpret: False)
+    with jax.enable_x64(False):
+        yield
+    jax.config.update("jax_enable_compilation_cache", was)
+    compilation_cache.reset_cache()
+
+
+def _cell(config, sharding):
+    """``(cfg, args, kwargs)`` of the scheduler's ``paged_decode_step`` for
+    a benchmark configuration, as shapes on the described chip."""
+    m, serve = _load("configs", config), _load("traffic", CELLS[config])["serve"]
+    dtype = jnp.dtype(m["dtype"])
+    if m["reference"] == "zaya_decoder":
+        cfg = transformer_config(m, serve["max_seq"], dtype)
+        make = zaya_decoder.make_weights
+    else:
+        cfg = tfm.TransformerConfig(
+            vocab_size=m["vocab_size"], d_model=m["hidden_size"],
+            n_layers=m["num_hidden_layers"], n_heads=m["num_attention_heads"],
+            n_kv_heads=m["num_key_value_heads"], d_ff=m["intermediate_size"],
+            max_seq=serve["max_seq"], rope_theta=float(m["rope_theta"]),
+            dtype=dtype, param_dtype=dtype,
+        )
+        make = transformer_decoder.make_weights
+    slots, P = serve["max_slots"], serve["tokens_per_page"]
+    max_pages = kv_pager.pages_for(serve["max_seq"], P)
+
+    def on_chip(tree):
+        return jax.tree.map(
+            lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=sharding),
+            tree,
+        )
+
+    pool = jax.eval_shape(
+        lambda: kv_pager.PagePool(
+            cfg, slots * max_pages + 1, tokens_per_page=P, slots=slots
+        ).k_pages
+    )
+    i32 = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.int32)  # noqa: E731
+    args = on_chip((
+        jax.eval_shape(lambda: make(0, m, dtype)),
+        i32(slots), i32(slots, max_pages), i32(slots), pool, pool,
+    ))
+    kwargs = {}
+    if cfg.block.attention == "cca":
+        kwargs["state"] = on_chip(
+            jax.eval_shape(lambda: cca.init_state(cfg, slots, dtype))
+        )
+    return cfg, args, kwargs
+
+
+@pytest.mark.parametrize("config", list(CELLS))
+def test_kernel_compiles_at_real_widths(config, one_chip, compiled_not_interpreted):
+    cfg, (_, toks, tables, indices, kp, _), _ = _cell(config, one_chip)
+    assert kv_pager.paged_kernel_fits(
+        cfg, kp.shape[3], toks.shape[0], 1, kp.dtype
+    )
+    shape = lambda *s, dt=cfg.dtype: jax.ShapeDtypeStruct(  # noqa: E731
+        s, dt, sharding=one_chip
+    )
+    compiled = jax.jit(pa.paged_attention).lower(
+        shape(toks.shape[0], cfg.n_heads, cfg.head_dim),
+        shape(*kp.shape[1:]), shape(*kp.shape[1:]), tables, indices,
+    ).compile()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text and pa.KERNEL_NAME in text
+    # q and the output whole in VMEM: nothing else is asked of the device
+    assert compiled.memory_analysis().temp_size_in_bytes == 0
+
+
+@pytest.mark.parametrize("config", list(CELLS))
+def test_decode_step_compiles_with_the_kernel_and_no_gather(
+    config, one_chip, compiled_not_interpreted
+):
+    """The scheduler's whole step at the cell's slots and capacity: the
+    kernel is in it, and nothing of the capacity's extent (slots x
+    max_pages pages, gathered) is."""
+    cfg, args, kwargs = _cell(config, one_chip)
+    compiled = kv_pager.paged_decode_step.lower(*args, cfg, **kwargs).compile()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text and pa.KERNEL_NAME in text
+    slots, max_pages = args[2].shape
+    P, dh = args[4].shape[3:]
+    assert f"bf16[{slots * max_pages},{P}," not in text
+    assert f"[{slots},{max_pages * P},{cfg.n_kv_heads},{dh}]" not in text
+    _pool_keeps_its_layout(text, args[4].shape)
+    mem = compiled.memory_analysis()
+    assert (
+        mem.argument_size_in_bytes + mem.output_size_in_bytes
+        + mem.temp_size_in_bytes < 16 * 2**30
+    )
+
+
+def _pool_keeps_its_layout(text, pool_shape):
+    """Every value of a layer's pool shape in the compiled program lies
+    row-major, heads outermost: a page write windowed over (kvh, Dh) made
+    XLA turn the whole pool head-minor (``{3,0,2,1:T(2,128)}``) and back
+    around every write, 18% of a traced window (PERF.md §6, PR 30)."""
+    dims = ",".join(str(d) for d in pool_shape[1:])
+    layouts = set(re.findall(r"\[(?:1,)?" + dims + r"\]\{([0-9,]+)", text))
+    assert layouts and layouts <= {"3,2,1,0", "4,3,2,1,0"}, layouts
+
+
+@pytest.mark.parametrize("config", list(CELLS))
+def test_prefill_writes_whole_pages_and_keeps_the_layout(
+    config, one_chip, compiled_not_interpreted
+):
+    """The 256 bucket's prefill: the page write scatters pages of a head,
+    ``[kvh * n_pages, P, Dh]`` windows, into a pool left as it lies."""
+    cfg, (weights, _, tables, _, kp, vp), kwargs = _cell(config, one_chip)
+    i32 = lambda *shape: jax.ShapeDtypeStruct(  # noqa: E731
+        shape, jnp.int32, sharding=one_chip
+    )
+    if kwargs:
+        kwargs["slot"] = i32(1)
+    text = kv_pager.paged_prefill.lower(
+        weights, i32(1, 256), i32(1, tables.shape[1]), i32(1), kp, vp, cfg,
+        **kwargs,
+    ).compile().as_text()
+    _pool_keeps_its_layout(text, kp.shape)
+    _, kvh, n_pages, P, dh = kp.shape
+    assert f"[{kvh * n_pages},{P},{dh}]" in text

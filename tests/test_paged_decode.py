@@ -163,7 +163,7 @@ def test_paged_prefill_matches_apply_paged_and_spares_neighbors(params, case):
         cp, jnp.asarray(neighbor[None]), n_row, jnp.zeros((1,), jnp.int32),
         pool.k_pages, pool.v_pages, CFG,
     )
-    assert np.abs(np.asarray(kp0)[:, neighbor_pages]).max() > 0
+    assert np.abs(np.asarray(kp0)[:, :, neighbor_pages]).max() > 0
 
     pages, row = table_row(lp + max_new)
     lb = bucketing.bucket_for(lp)
@@ -182,9 +182,9 @@ def test_paged_prefill_matches_apply_paged_and_spares_neighbors(params, case):
     assert int(tok0[0]) == int(jnp.argmax(logits[0, lp - 1]))
 
     def prompt_kv(pages_arr):
-        # [n_layers, lp, kvh, Dh]: the request's pages in sequence order
-        a = np.asarray(pages_arr)[:, pages]
-        return a.reshape(a.shape[0], -1, *a.shape[3:])[:, :lp]
+        # [n_layers, kvh, lp, Dh]: the request's pages in sequence order
+        a = np.asarray(pages_arr)[:, :, pages]
+        return a.reshape(*a.shape[:2], -1, a.shape[-1])[:, :, :lp]
 
     for got, want in ((kp, kp_ref), (vp, vp_ref)):
         got, want = prompt_kv(got), prompt_kv(want)
@@ -194,12 +194,14 @@ def test_paged_prefill_matches_apply_paged_and_spares_neighbors(params, case):
         np.testing.assert_allclose(got[1:], want[1:], rtol=2e-5, atol=2e-6)
     for got, before in ((kp, kp0), (vp, vp0)):
         got, before = np.asarray(got), np.asarray(before)
-        assert np.array_equal(got[:, neighbor_pages], before[:, neighbor_pages])
+        assert np.array_equal(
+            got[:, :, neighbor_pages], before[:, :, neighbor_pages]
+        )
         # nothing but the request's pages and the trash page was written
         others = [
             i for i in range(pool.n_pages) if i != 0 and i not in pages
         ]
-        assert np.array_equal(got[:, others], before[:, others])
+        assert np.array_equal(got[:, :, others], before[:, :, others])
 
 
 def _eqn_avals(jaxpr):
@@ -227,15 +229,17 @@ def test_paged_prefill_scales_with_the_bucket_alone(params):
             max_pages = cap // PAGE
             n_pages = slots * max_pages + 1
             pools = jax.ShapeDtypeStruct(
-                (n, n_pages, PAGE, kvh, dh), CFG.dtype
+                (n, kvh, n_pages, PAGE, dh), CFG.dtype
             )
             i32 = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.int32)
             closed = jax.make_jaxpr(
                 lambda *a: kv_pager.paged_prefill(*a, CFG)
             )(params, i32(1, bucket), i32(1, max_pages), i32(1), pools, pools)
             exempt = {
-                (n, n_pages, PAGE, kvh, dh), (n_pages, PAGE, kvh, dh),
-                (1, max_pages),
+                (n, kvh, n_pages, PAGE, dh), (kvh, n_pages, PAGE, dh),
+                # a layer's pool as pages of a head, which a prefill's page
+                # write scatters whole
+                (kvh * n_pages, PAGE, dh), (1, max_pages),
             }
             avals = [
                 a for a in _eqn_avals(closed.jaxpr)

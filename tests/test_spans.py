@@ -43,7 +43,8 @@ PREFILL_TOKEN_COUNTERS = (
     "decode_prefill_prompt_tokens", "decode_prefill_run_tokens",
 )
 DECODE_COUNTERS = (
-    "decode_steps", "decode_host_ns", "decode_step_wait_ns",
+    "decode_steps", "decode_kernel_steps", "decode_host_ns",
+    "decode_step_wait_ns",
     "decode_prefill_ns", "decode_busy_ns", "decode_admitted",
     "decode_queue_wait_ns", "decode_first_tokens", "decode_ttft_ns",
     "decode_stream_ns", "decode_stream_tokens",
@@ -58,6 +59,7 @@ NEW_METRICS = (
     "ttft_ms.decode", "itl_ms.decode", "dispatch_host_ms.score",
     "verb_head_ms.score", "verb_tail_ms.score",
     "readback_wait_share.score", "prefill_pad_share.decode",
+    "paged_kernel_step_share.decode",
 )
 
 
@@ -508,6 +510,7 @@ def test_benchmark_metric_file_reads_the_counters(name):
     # a synthetic window: 30 ms a step, a third of it in prefill ...
     obs_ = {
         "counters.decode_steps": 500,
+        "counters.decode_kernel_steps": 500,
         "counters.decode_host_ns": 1_250_000_000,
         "counters.decode_step_wait_ns": 5_500_000_000,
         "counters.decode_prefill_ns": 8_250_000_000,
@@ -542,6 +545,7 @@ def test_benchmark_metric_file_reads_the_counters(name):
         "verb_tail_ms.score": 5.0,
         "readback_wait_share.score": 10.0,
         "prefill_pad_share.decode": 41.40625,
+        "paged_kernel_step_share.decode": 100.0,
     }[name]
     assert read_metric(name, obs_) == pytest.approx(want)
     # the parent commit has no such counter: nothing to read, no raise

@@ -372,4 +372,5 @@ def test_cca_pool_needs_its_slot_count():
         kv_pager.PagePool(CFG, 9, tokens_per_page=PAGE)
     pool = kv_pager.PagePool(CFG, 9, tokens_per_page=PAGE, slots=5)
     assert pool.conv_state.shape == (M["num_hidden_layers"], 5, cca.state_width(CFG))
-    assert pool.k_pages.shape[-2:] == (M["num_key_value_heads"], M["head_dim"])
+    assert pool.k_pages.shape == (
+        M["num_hidden_layers"], M["num_key_value_heads"], 9, PAGE, M["head_dim"])

@@ -28,9 +28,9 @@ replies, graceful drain, and an ungated ``health`` RPC (see
 
 Round 16 adds the multi-tenant THROUGHPUT layer (``docs/SERVING.md``):
 request coalescing into bucket-canonical micro-batches over a warm
-program pool (``Coalescer`` / ``WarmPool``), SLO-aware fair-share
-admission (``SloScheduler``), and continuous decode batching
-(``ContinuousBatcher``).
+program pool (``Coalescer`` / ``WarmPool``) and SLO-aware fair-share
+admission (``SloScheduler``); continuous decode batching is the paged
+``DecodeScheduler`` (``coalescer.py``, round 22).
 
 Round 21 scales the seam OUT: ``fleet`` runs N replicas behind a
 rendezvous-hashing ``FleetRouter`` (health-polled, flap-quarantining),
@@ -56,7 +56,6 @@ from .client import (
 from .fleet import BridgeFleet, FleetClient, FleetRouter
 from .coalescer import (
     Coalescer,
-    ContinuousBatcher,
     SloScheduler,
     WarmPool,
     WarmSpec,
@@ -70,7 +69,6 @@ __all__ = [
     "BridgeServer",
     "Cancelled",
     "Coalescer",
-    "ContinuousBatcher",
     "DeadlineExceeded",
     "Draining",
     "FleetClient",

@@ -46,15 +46,6 @@ staging, and dispatch.  This module is the throughput layer on top:
   additionally sheds the dominant row consumer once the measured bridge
   p99 climbs past 80% of the target.
 
-* :class:`ContinuousBatcher` — **continuous decode batching** (builds
-  on bench config 8): decode-style requests join a RUNNING batch at
-  step boundaries and retire the moment their own stream finishes, so
-  a short request never waits for a long one and the step executable
-  (one jit(vmap) signature) stays hot across the whole request
-  population.  Per-row results are bit-identical to solo execution for
-  the same reason ``map_rows`` bucketing is: rows under vmap are
-  independent by construction.
-
 Knobs (absence = feature off; the conftest pins them off for the main
 suite, ``run_tests.sh``'s serving tier runs them live):
 
@@ -1001,209 +992,6 @@ class SloScheduler:
 
 
 # ---------------------------------------------------------------------------
-# continuous decode batching
-# ---------------------------------------------------------------------------
-
-
-class ContinuousBatcher:
-    """Continuous batching for autoregressive decode (bench config 8's
-    serving form): requests JOIN the running batch at step boundaries
-    and RETIRE the moment their own stream finishes — a short request
-    never waits out a long neighbor, and the step executable (one
-    ``jit(vmap(row_step))`` signature at ``max_batch``) stays hot for
-    the whole request population.
-
-    ``row_step(state, token) -> (state, token)`` is the per-row decode
-    step over a pytree ``state`` (e.g. a KV cache slice + position) and
-    a scalar token; the batcher vmaps it over the slot axis, so per-row
-    results are independent by construction — the same guarantee that
-    makes ``map_rows`` bucket padding bit-identical.  Free slots step
-    garbage that no one reads.
-
-    ``submit`` blocks until the request's stream completes and returns
-    the emitted tokens; it is thread-safe (one server handler thread
-    per request parks here while the driver thread steps the batch).
-    """
-
-    def __init__(self, row_step, max_batch: int = 8):
-        import jax
-
-        self.max_batch = max(1, int(max_batch))
-        self._step = jax.jit(jax.vmap(row_step))
-        self._lock = threading.Lock()
-        self._cv = threading.Condition(self._lock)
-        self._pending: "collections.deque" = collections.deque()
-        self._active: Dict[int, "_DecodeSlot"] = {}
-        self._free = list(range(self.max_batch))
-        self._states = None  # stacked pytree, built from the first row
-        self._tokens = None  # np [max_batch]
-        self._driver: Optional[threading.Thread] = None
-        self._closed = False
-        self.steps = 0  # batch steps executed (telemetry/tests)
-        self.joined_mid_run = 0  # requests admitted while others ran
-
-    # -- public --------------------------------------------------------------
-
-    def submit(
-        self,
-        state,
-        first_token,
-        max_new: int,
-        until: Optional[Callable[[Any], bool]] = None,
-        timeout_s: Optional[float] = None,
-    ) -> List[Any]:
-        """Decode up to ``max_new`` tokens from ``(state, first_token)``,
-        stopping early when ``until(token)`` is true.  Returns the
-        emitted tokens (the stop token included)."""
-        slot_req = _DecodeSlot(state, first_token, max_new, until)
-        with self._cv:
-            if self._closed:
-                raise RuntimeError("ContinuousBatcher is closed")
-            self._pending.append(slot_req)
-            self._ensure_driver()
-            self._cv.notify_all()
-        if not slot_req.done.wait(timeout=timeout_s):
-            with self._cv:
-                slot_req.abandoned = True
-            raise TimeoutError(
-                f"decode request did not finish within {timeout_s}s"
-            )
-        if slot_req.error is not None:
-            raise slot_req.error
-        return slot_req.out
-
-    def close(self) -> None:
-        with self._cv:
-            self._closed = True
-            self._cv.notify_all()
-        if self._driver is not None:
-            self._driver.join(timeout=5.0)
-
-    # -- driver --------------------------------------------------------------
-
-    def _ensure_driver(self) -> None:
-        if self._driver is None or not self._driver.is_alive():
-            self._driver = threading.Thread(
-                target=self._drive, name="tfs-decode-batcher", daemon=True
-            )
-            self._driver.start()
-
-    def _drive(self) -> None:
-        import jax
-        import jax.numpy as jnp
-
-        try:
-            while True:
-                with self._cv:
-                    while (
-                        not self._closed
-                        and not self._pending
-                        and not self._active
-                    ):
-                        self._cv.wait()
-                    if self._closed and not self._active:
-                        # clean shutdown: requests still queued (never
-                        # admitted to a slot) must not block their
-                        # submit() callers forever
-                        err = RuntimeError(
-                            "ContinuousBatcher closed before this "
-                            "request was admitted"
-                        )
-                        for req in self._pending:
-                            req.error = err
-                            req.done.set()
-                        self._pending.clear()
-                        return
-                    was_running = bool(self._active)
-                    # step boundary: admit pending requests into free slots
-                    while self._pending and self._free:
-                        req = self._pending.popleft()
-                        if req.abandoned:
-                            continue
-                        slot = self._free.pop()
-                        self._admit(slot, req, jnp)
-                        if was_running:
-                            self.joined_mid_run += 1
-                    active = dict(self._active)
-                if not active:
-                    continue
-                states, toks = self._step(self._states, self._tokens)
-                self._states, self._tokens = states, toks
-                self.steps += 1
-                emitted = np.asarray(toks)
-                with self._cv:
-                    for slot, req in list(self._active.items()):
-                        tok = emitted[slot]
-                        req.out.append(tok)
-                        req.emitted += 1
-                        stop = req.emitted >= req.max_new or (
-                            req.until is not None and bool(req.until(tok))
-                        )
-                        if stop or req.abandoned:
-                            del self._active[slot]
-                            self._free.append(slot)
-                            req.done.set()
-        except BaseException as e:  # noqa: BLE001 — fail every waiter
-            with self._cv:
-                for req in list(self._active.values()):
-                    req.error = e
-                    req.done.set()
-                for req in self._pending:
-                    req.error = e
-                    req.done.set()
-                self._active.clear()
-                self._pending.clear()
-                self._free = list(range(self.max_batch))
-
-    def _admit(self, slot: int, req: "_DecodeSlot", jnp) -> None:
-        import jax
-
-        if self._states is None:
-            # stack template from the first row: zeros at [max_batch,...]
-            self._states = jax.tree_util.tree_map(
-                lambda a: jnp.zeros(
-                    (self.max_batch,) + tuple(np.shape(a)),
-                    jnp.asarray(a).dtype,
-                ),
-                req.state,
-            )
-            t0 = jnp.asarray(req.first_token)
-            self._tokens = jnp.zeros((self.max_batch,), t0.dtype)
-        self._states = jax.tree_util.tree_map(
-            lambda stack, row: stack.at[slot].set(row),
-            self._states,
-            req.state,
-        )
-        self._tokens = self._tokens.at[slot].set(req.first_token)
-        self._active[slot] = req
-
-
-class _DecodeSlot:
-    __slots__ = (
-        "state",
-        "first_token",
-        "max_new",
-        "until",
-        "out",
-        "emitted",
-        "done",
-        "error",
-        "abandoned",
-    )
-
-    def __init__(self, state, first_token, max_new, until):
-        self.state = state
-        self.first_token = first_token
-        self.max_new = max(1, int(max_new))
-        self.until = until
-        self.out: List[Any] = []
-        self.emitted = 0
-        self.done = threading.Event()
-        self.error: Optional[BaseException] = None
-        self.abandoned = False
-
-
-# ---------------------------------------------------------------------------
 # paged continuous decode (round 22)
 # ---------------------------------------------------------------------------
 
@@ -1322,8 +1110,7 @@ class DecodeScheduler:
     """Continuous decode over the PAGED KV cache (round 22): the
     serving form of ``models/kv_pager.py``.
 
-    The ContinuousBatcher above batches opaque per-row step functions;
-    this scheduler owns the transformer serving path end to end — each
+    This scheduler owns the transformer serving path end to end — each
     of its ``TFS_DECODE_MAX_SLOTS`` slots holds a page table into the
     shared :class:`~..models.kv_pager.PagePool`, and the driver thread
     alternates two fixed-shape compiled dispatches:

@@ -153,8 +153,9 @@ def checkpoint() -> None:
     """The block-boundary hook: raises ``Cancelled``/``DeadlineExceeded``
     when the active scope says stop; one contextvar read otherwise.
 
-    Called by every engine dispatch loop (serial, pooled, sharded,
-    streamed chunks, reduce partials), the pooled pipeline chain, and
+    Called by the engine's one block loop (``ops/block_loop.py``: every
+    placement, maps and reduce partials), its streamed chunk loops, the
+    pooled pipeline and planner chains, and
     ``FrameRetrySession.run`` before each attempt and each backoff
     sleep."""
     scope = _current.get()

@@ -36,7 +36,6 @@ with collective cross-shard reduction for the GSPMD form.
 from __future__ import annotations
 
 import functools
-import logging
 import time
 from typing import Any, Dict, List, Mapping, Optional, Sequence, Tuple
 
@@ -50,18 +49,15 @@ from ..program import Program
 from ..schema import ColumnInfo, Schema
 from ..shape import Shape, ShapeError, UNKNOWN
 from . import (
+    block_loop,
     bucketing,
-    device_pool,
     fault_tolerance,
-    frame_cache,
     prefetch,
     segment_compile,
     validation,
 )
 from ..analysis import rowdep as analysis
 from .validation import ValidationError
-
-_log = logging.getLogger("tensorframes_tpu.engine")
 
 
 def _check_shape_hints(
@@ -140,6 +136,229 @@ class _MapTimes:
         observability.note_map_verb(
             time.perf_counter_ns() - self._t0, self._head_ns, tail_ns
         )
+
+
+class _MapWork(block_loop.Work):
+    """The map verbs' blocks, as the one block loop sees them
+    (``ops/block_loop.py``): ``_device_inputs`` with the bucket target
+    stages a block, the memoised entries (``_rows_run`` / ``_block_run``
+    / ``_run_block_program``) run it, and the bucket padding is sliced
+    back off.  Blocks whose every input buffer was freshly staged run
+    through a donating executable, so steady-state HBM holds at most the
+    prefetch window of input blocks; blocks with device-resident inputs
+    (cached frames, chained verbs) keep the plain non-donating entries —
+    donating a shared column buffer would corrupt the frame
+    (prefetch.py's safety contract).  Streamed blocks (``_stream_plan``)
+    prefetch + donate at chunk granularity instead."""
+
+    def __init__(
+        self, ex, program, frame, infos, host_stage, rows_level, trim,
+        plans, pads, donate, fresh,
+    ):
+        self.ex = ex
+        self.program = program
+        self.frame = frame
+        self.infos = infos
+        self.host_stage = host_stage
+        self.rows_level = rows_level
+        self.trim = trim
+        self.plans = plans
+        self.pads = pads
+        self.donate = donate
+        self.sizes = frame.block_sizes
+        self.name = "map_rows" if rows_level else "map_blocks"
+        self.reads = frozenset(
+            program.column_for_input(n)
+            for n in program.input_names
+            if not (host_stage and n in host_stage)
+        )
+        self.streams = frozenset(
+            bi for bi, p in enumerate(plans) if p is not None
+        )
+        self.in_order = bool(host_stage)
+        # only spin up a staging thread when some block will actually
+        # stage on it; otherwise (device-resident frame, or every block
+        # streamed at chunk level) keep the plain consumer loop
+        self.ahead = fresh and len(self.streams) < frame.num_blocks
+
+    def stage(self, bi, block, device):
+        return self.ex._device_inputs(
+            self.program, block, self.infos, self.host_stage,
+            pad_to=self.pads[bi], device=device,
+        )
+
+    def run(self, bi, inputs):
+        if self.rows_level:
+            outs = self.ex._rows_run(self.program, self.donate)(inputs)
+        elif self.donate:
+            outs = self.ex._block_run(self.program, True)(inputs)
+        else:
+            outs = self.ex._run_block_program(self.program, inputs)
+        del inputs
+        if self.pads[bi] is not None:
+            # bucket-padded execution: slice the pad rows back off
+            # (row-independence guarantees real rows' values are
+            # bit-identical to the exact-shape path)
+            n_rows = self.sizes[bi]
+            outs = {k: v[:n_rows] for k, v in outs.items()}
+        return outs
+
+    def run_streamed(self, bi, device, session, resolver, stats):
+        return self.ex._run_block_streamed(
+            self.program, self.frame.block(bi), self.infos, self.plans[bi],
+            rows_level=self.rows_level, pf_stats=stats, device=device,
+            bi=bi, session=session, device_resolver=resolver,
+        )
+
+    def check(self, bi, outs):
+        self.ex._check_block_outputs(
+            self.program, outs, self.sizes[bi], self.rows_level, self.trim
+        )
+
+    # -- OOM degradation (round 9, ops/fault_tolerance.py) ------------------
+    # Re-staging under the retry session re-runs any ``host_stage`` fn
+    # for the retried block — the same semantics as Spark's lineage
+    # replay, which re-executes the whole partition pipeline on task
+    # retry and therefore requires deterministic tasks.  The retry
+    # contract requires the same of stage fns: deterministic per (block,
+    # cells), like the decode fns that motivate ``host_stage``.  A stage
+    # fn whose output depends on invocation order cannot participate in
+    # block retry (run it with ``TFS_BLOCK_RETRIES=0``, where every
+    # error surfaces unretried).
+
+    def oom_split(self, bi, session, devices, pool, di):
+        """The OOM-degradation policy for one map-verb block: split the
+        block in half and re-dispatch (recursively, floor
+        ``TFS_MIN_SPLIT_ROWS``) when that is provably semantics-safe —
+        ``map_rows`` is row-independent by construction, ``map_blocks``
+        must pass the jaxpr proof at EVERY size the split can reach.
+        Trimmed maps (program-defined output row count), host-staged
+        blocks (one-unit staging contract), and cross-row programs
+        surface a :class:`fault_tolerance.BlockExecutionError` naming
+        the block and row range instead."""
+        n_rows = self.sizes[bi]
+
+        def refuse(exc: BaseException, why: str):
+            raise fault_tolerance.BlockExecutionError(
+                f"{self.name}: block {bi} rows [0, {n_rows}) exhausted "
+                f"device memory and cannot degrade by splitting: {why}"
+            ) from exc
+
+        def split(exc: BaseException) -> Dict[str, Any]:
+            floor = fault_tolerance.min_split_rows()
+            if self.trim:
+                refuse(exc, "trimmed maps define their own output row "
+                            "count, so half-block outputs cannot be "
+                            "reassembled")
+            if self.host_stage:
+                refuse(exc, "host-staged blocks stage as one unit")
+            if n_rows < 2 * floor:
+                refuse(
+                    exc,
+                    f"the block is already at the split floor "
+                    f"(TFS_MIN_SPLIT_ROWS={floor})",
+                )
+            if not self.rows_level:
+                # every size the recursive split can reach, proven
+                # row-independent in one shot (memoized on the program)
+                sizes = set()
+                stack = [(0, n_rows)]
+                while stack:
+                    lo, hi = stack.pop()
+                    sizes.add(hi - lo)
+                    if hi - lo >= 2 * floor:
+                        mid = (lo + hi) // 2
+                        stack += [(lo, mid), (mid, hi)]
+                specs = analysis.input_specs_for(self.program, self.infos)
+                if specs is None or not analysis.rows_independent(
+                    self.program, specs, sorted(sizes)
+                ):
+                    refuse(
+                        exc,
+                        "the program is not provably row-independent "
+                        "(cross-row outputs cannot be recomputed from "
+                        "half blocks)",
+                    )
+            dev_i = (
+                pool.effective_device(di)
+                if pool is not None
+                else 0  # serial dispatch = device 0
+            )
+            dev = devices[dev_i] if devices is not None else None
+            return self._split_halves(session, bi, 0, n_rows, dev, dev_i)
+
+        return split
+
+    def _split_halves(
+        self, session, bi: int, lo: int, hi: int, dev, dev_i: Optional[int]
+    ) -> Dict[str, Any]:
+        mid = (lo + hi) // 2
+        left = self._split_range(session, bi, lo, mid, dev, dev_i)
+        right = self._split_range(session, bi, mid, hi, dev, dev_i)
+        session.note_split(bi)
+        return {k: jnp.concatenate([left[k], right[k]]) for k in left}
+
+    def _split_range(
+        self, session, bi: int, lo: int, hi: int, dev, dev_i: Optional[int]
+    ) -> Dict[str, Any]:
+        """Dispatch rows ``[lo, hi)`` of block ``bi``, splitting again on
+        a further OOM until ``TFS_MIN_SPLIT_ROWS``.  Sub-dispatches use
+        the plain non-donating entries (fresh small buffers; donation
+        would fork another executable per split size for no HBM win) and
+        their injected-fault site is ``"split"`` so attempt-selected
+        specs never re-fire on recovery work."""
+        floor = fault_tolerance.min_split_rows()
+        try:
+            faults.maybe_inject(bi, 0, dev_i, hi - lo, site="split")
+            block = self.frame.block(bi)
+            sub = {k: v[lo:hi] for k, v in block.items()}
+            inputs = self.ex._device_inputs(
+                self.program, sub, self.infos, None, device=dev
+            )
+            if self.rows_level:
+                return self.program.vmapped()(inputs)
+            return self.ex._run_block_program(self.program, inputs)
+        except BaseException as exc:  # noqa: BLE001 - OOM-only recovery
+            if not faults.is_oom(exc):
+                raise
+            if hi - lo < 2 * floor:
+                raise fault_tolerance.BlockExecutionError(
+                    f"block {bi} rows [{lo}, {hi}) exhausted device "
+                    f"memory at the split floor (TFS_MIN_SPLIT_ROWS="
+                    f"{floor}); this row range does not fit on the device"
+                ) from exc
+            return self._split_halves(session, bi, lo, hi, dev, dev_i)
+
+
+class _ReduceWork(block_loop.Work):
+    """The reduce verbs' per-block partials: each base column of the
+    block cast and moved to its device, folded there by ``run`` — the
+    device-granularity analog of the reference's per-partition reduce
+    (SURVEY P1/P4).  Reduce partials are cross-row by definition: no OOM
+    split — an OOM surfaces with the block's row range."""
+
+    name = "reduce"
+    span = "engine.reduce_block"
+    to_host = False
+
+    def __init__(self, ex, run, bases, sts, cols):
+        self.ex = ex
+        self._run = run
+        self.bases = bases
+        self.sts = sts
+        self.cols = cols
+        self.reads = frozenset(cols.values())
+
+    def stage(self, bi, block, device):
+        return {
+            b: self.ex._device_value(
+                block[self.cols[b]], self.sts[b], device=device
+            )
+            for b in self.bases
+        }
+
+    def run(self, bi, inputs):
+        return self._run(inputs)
 
 
 class GroupedFrame:
@@ -447,16 +666,17 @@ class Executor:
             else self._block_run(program, donate)
         )
         pf = prefetch.Prefetcher(stage, len(starts))
-        if session is None:
+        outs: List[Dict[str, Any]] = []
+        for k, inputs in enumerate(pf):
             # chunk boundary = cancellation checkpoint (the streamed
-            # analog of the block-boundary check); a no-op contextvar
-            # read without an active scope
-            outs: List[Dict[str, Any]] = []
-            for inputs in pf:
-                cancellation.checkpoint()
+            # analog of the block-boundary check): a deadline cuts the
+            # streamed dispatch between chunks instead of waiting out
+            # the whole block; a no-op contextvar read without a scope
+            cancellation.checkpoint()
+            if session is None:
                 outs.append(run(inputs))
                 del inputs
-        else:
+                continue
             # chunk-granular retry: each chunk dispatch is its own
             # attempt unit (fault injection keys on the BLOCK index, so
             # a block-selected spec fires per chunk — deterministic
@@ -464,44 +684,37 @@ class Executor:
             # thread; its fresh buffers stay donation-eligible.  No OOM
             # split here: chunks are already the streaming granularity,
             # so a chunk OOM surfaces with its exact row range.
-            outs = []
-            for k, inputs in enumerate(pf):
-                # chunk boundary = cancellation checkpoint, same as the
-                # serial branch above (lint: checkpoint-coverage) — a
-                # deadline cuts the streamed dispatch between chunks
-                # instead of waiting out the whole block
-                cancellation.checkpoint()
-                lo = starts[k]
-                hi = min(starts[k] + per, n_rows)
-                holder = {"v": inputs}
-                del inputs
+            lo = starts[k]
+            hi = min(starts[k] + per, n_rows)
+            holder = {"v": inputs}
+            del inputs
 
-                def attempt(a, dev_i, _k=k, _h=holder):
-                    ins = _h.pop("v", None)
-                    if a > 0 or ins is None:
-                        # re-stage to the CURRENT effective device, so a
-                        # retried chunk follows a quarantine redirect
-                        dev_now = (
-                            device_resolver()[1]
-                            if device_resolver is not None
-                            else None
-                        )
-                        ins = stage(_k, dev_now)
-                    return run(ins)
-
-                outs.append(
-                    session.run(
-                        bi,
-                        hi - lo,
-                        attempt,
-                        device=(
-                            (lambda: device_resolver()[0])
-                            if device_resolver is not None
-                            else 0
-                        ),
-                        row_range=(lo, hi),
+            def attempt(a, dev_i, _k=k, _h=holder):
+                ins = _h.pop("v", None)
+                if a > 0 or ins is None:
+                    # re-stage to the CURRENT effective device, so a
+                    # retried chunk follows a quarantine redirect
+                    dev_now = (
+                        device_resolver()[1]
+                        if device_resolver is not None
+                        else None
                     )
+                    ins = stage(_k, dev_now)
+                return run(ins)
+
+            outs.append(
+                session.run(
+                    bi,
+                    hi - lo,
+                    attempt,
+                    device=(
+                        (lambda: device_resolver()[0])
+                        if device_resolver is not None
+                        else 0
+                    ),
+                    row_range=(lo, hi),
                 )
+            )
         if pf_stats is not None:
             pf_stats["items"] += pf.stats["items"]
             pf_stats["stage_s"] += pf.stats["stage_s"]
@@ -653,6 +866,43 @@ class Executor:
             times.done()
             return out
 
+    def _map_plan(
+        self, program: Program, frame: TensorFrame, infos, host_stage,
+        rows_level: bool, trim: bool,
+    ):
+        """``(placement, stream plans, bucket targets, donate)`` of one
+        map verb over ``frame`` — the ONE walk behind both the dispatch
+        and :meth:`warmup` (the warmup executables must carry the shapes,
+        the devices and the donation aliasing the first real dispatch
+        will, or the persistent-cache keys diverge).
+
+        Planned on the caller thread: ``_stream_plan`` and
+        ``_bucket_plan`` may trace (row-independence proofs); all jit
+        entry points stay off the staging workers.  A sharded-cached
+        frame never streams (its bytes are already in HBM) and never
+        donates (shards are shared state); bucket targets still apply
+        (device-side pad + slice)."""
+        nb = frame.num_blocks
+        placement = block_loop.place(self, frame, range(nb))
+        if placement.cache is not None:
+            plans: List[Optional[int]] = [None] * nb
+        else:
+            plans = [
+                self._stream_plan(
+                    program, frame.block(bi), infos, host_stage,
+                    check_independence=not rows_level,
+                )
+                for bi in range(nb)
+            ]
+        # shape-canonical bucket targets (one executable for every block
+        # size of this program); streamed blocks canonicalize at chunk
+        # granularity inside _run_block_streamed instead
+        pads = self._bucket_plan(
+            program, frame, infos, host_stage, rows_level, trim, plans
+        )
+        donate = prefetch.donate_inputs() and placement.fresh
+        return placement, plans, pads, donate
+
     def _map_dispatch(
         self,
         program: Program,
@@ -664,18 +914,12 @@ class Executor:
         trim: bool,
         times: "_MapTimes",
     ) -> List[Dict[str, Any]]:
-        """Shared block loop of the two map verbs, prefetched: up to
-        ``TFS_PREFETCH_BLOCKS`` blocks are staged (host cast + host_stage +
-        async ``device_put``) on a worker thread ahead of the compute
-        dispatches, and blocks whose every input buffer was freshly staged
-        run through a donating executable (``_block_run``/``_rows_run``) so
-        steady-state HBM holds at most the prefetch window of input blocks.
-        Blocks with device-resident inputs (cached frames, chained verbs)
-        keep the plain non-donating entries — donating a shared column
-        buffer would corrupt the frame (prefetch.py's safety contract).
-        Streamed blocks (``_stream_plan``) prefetch+donate at chunk
-        granularity instead."""
-        verb = "map_rows" if rows_level else "map_blocks"
+        """The two map verbs' dispatch: plan, then hand the blocks to the
+        one block loop (``ops/block_loop.py``), which stages them ahead
+        (up to ``TFS_PREFETCH_BLOCKS`` on a worker thread, or on
+        per-device lanes under the pool), runs them where the placement
+        says, and collects the outputs — device-resident under serial
+        placement, host-assembled by block index when pooled."""
         if frame.num_rows == 0 and not trim:
             # empty-frame contract: a non-trimmed map of an empty frame is
             # an empty frame with the program's inferred output schema —
@@ -688,168 +932,42 @@ class Executor:
                     program, frame, infos, host_stage, rows_level
                 )
             ]
-        # sharded frame cache (round 10, ops/frame_cache.py): when the
-        # frame's blocks are resident on their affinity devices, each
-        # block dispatches on the device that already holds it — no
-        # staging lanes, no H2D, no donation (shards are shared state).
-        # This path removes the old "device-resident frames stay serial"
-        # restriction for every map verb.
-        cache = frame_cache.active_cache(frame)
-        if cache is not None:
-            return self._map_dispatch_sharded(
-                program, frame, infos, host_stage, span, rows_level, trim,
-                cache, times,
-            )
-        # plan on the caller thread: _stream_plan and _bucket_plan may
-        # trace (row-independence proofs); all jit entry points stay off
-        # the worker
-        plans = [
-            self._stream_plan(
-                program, frame.block(bi), infos, host_stage,
-                check_independence=not rows_level,
-            )
-            for bi in range(frame.num_blocks)
-        ]
-        # shape-canonical bucket targets (one executable for every block
-        # size of this program); streamed blocks canonicalize at chunk
-        # granularity inside _run_block_streamed instead
-        pads = self._bucket_plan(
-            program, frame, infos, host_stage, rows_level, trim, plans
+        placement, plans, pads, donate = self._map_plan(
+            program, frame, infos, host_stage, rows_level, trim
         )
-        donate = prefetch.donate_inputs()
-        fresh = self._frame_fresh(frame)
-        # device-pool scheduler (ops/device_pool.py): a host-fresh multi-
-        # block frame spreads its independent blocks across all local
-        # devices — per-device staging lanes, async dispatch, overlapped
-        # readback.  Device-resident frames stay serial on their device
-        # (splitting a cached column across the pool would shuffle HBM),
-        # and the mesh executor opts out (supports_device_pool).
-        pool_devs = (
-            device_pool.pool_devices()
-            if (self.supports_device_pool and fresh and frame.num_blocks > 1)
-            else []
+        work = _MapWork(
+            self, program, frame, infos, host_stage, rows_level, trim,
+            plans, pads, donate, placement.fresh,
         )
-        # block-level fault tolerance (ops/fault_tolerance.py): None when
-        # TFS_BLOCK_RETRIES=0 and no fault injection — the default — so
-        # the dispatch loops below are byte-identical to the retry-free
-        # engine and the suite's trace/compile fences stay deterministic
-        session = fault_tolerance.frame_session(frame.num_blocks, verb=verb)
-        if len(pool_devs) >= 2:
-            return self._map_dispatch_pool(
-                program, frame, infos, host_stage, span, rows_level, trim,
-                plans, pads, donate, pool_devs, session, times,
+        out_blocks, (items, stage_s, wait_s) = block_loop.run_blocks(
+            placement, frame, work, span, times
+        )
+        if placement.kind != "affinity":  # no lanes: nothing staged ahead
+            span.annotate(
+                "prefetch",
+                {
+                    "items": items,
+                    "depth": prefetch.prefetch_depth(),
+                    "stage_s": round(stage_s, 6),
+                    "wait_s": round(wait_s, 6),
+                    "overlap_ratio": round(
+                        prefetch.overlap_ratio(stage_s, wait_s), 4
+                    ),
+                    # whether donation actually applied to this verb's
+                    # blocks, not just the knob: a device-resident frame
+                    # never donates
+                    "donate": donate,
+                },
             )
-        # only spin up a staging thread when some block will actually
-        # stage on it; otherwise (device-resident frame, or every block
-        # streamed at chunk level) keep the plain consumer loop
-        to_stage = fresh and any(p is None for p in plans)
-
-        def stage(bi):
-            if plans[bi] is not None:
-                return None  # streamed inline, chunk-level prefetch
-            return self._device_inputs(
-                program, frame.block(bi), infos, host_stage, pad_to=pads[bi]
-            )
-
-        pf = prefetch.Prefetcher(stage, frame.num_blocks) if to_stage else None
-        # chunk-prefetcher stats accumulate here, NOT into pf.stats: the
-        # block staging thread writes pf.stats concurrently with this
-        # consumer loop, and += on a shared dict entry would lose updates
-        chunk_stats = {"items": 0, "stage_s": 0.0, "wait_s": 0.0}
-        block_sizes = frame.block_sizes
-        out_blocks: List[Dict[str, Any]] = []
-        items = pf if pf is not None else (
-            None for _ in range(frame.num_blocks)
-        )
-        times.first_block()
-        for bi, staged in enumerate(items):
-            # cooperative cancellation (bridge deadlines / drain): the
-            # block boundary is the check granularity — one contextvar
-            # read when no scope is active
-            cancellation.checkpoint()
-            n_rows = block_sizes[bi]
-            sp = observability.span(
-                "engine.block", "serial",
-                verb=verb, block=bi, rows=n_rows, device=0,
-            )
-            if plans[bi] is not None:
-                outs = self._run_block_streamed(
-                    program, frame.block(bi), infos, plans[bi],
-                    rows_level=rows_level, pf_stats=chunk_stats,
-                    bi=bi, session=session,
-                )
-            elif session is not None:
-                outs = self._run_block_ft(
-                    session, program, frame, bi, infos, host_stage,
-                    pads[bi], rows_level, trim, donate and fresh, staged,
-                )
-                del staged
-            else:
-                inputs = (
-                    staged
-                    if staged is not None
-                    else self._device_inputs(  # device-resident block
-                        program, frame.block(bi), infos, host_stage,
-                        pad_to=pads[bi],
-                    )
-                )
-                if rows_level:
-                    outs = self._rows_run(program, donate and fresh)(inputs)
-                elif donate and fresh:
-                    outs = self._block_run(program, True)(inputs)
-                else:
-                    outs = self._run_block_program(program, inputs)
-                del inputs, staged  # drop staged refs (donation hygiene)
-                if pads[bi] is not None:
-                    # bucket-padded execution: slice the pad rows back off
-                    # (row-independence guarantees real rows' values are
-                    # bit-identical to the exact-shape path)
-                    outs = {k: v[:n_rows] for k, v in outs.items()}
-            self._check_block_outputs(program, outs, n_rows, rows_level, trim)
-            # request attribution (round 15): one contextvar read per
-            # block when no ledger is active — the documented hot-path
-            # cost of the attribution layer on the serial loop
-            observability.note_request_block(0, n_rows)
-            observability.note_dispatch_block(sp.end())
-            out_blocks.append(outs)
-        times.last_block()
-        # the loop consumed every item, so the staging thread has finished
-        # (its last stats write happened-before the last queue get): pf.stats
-        # is safe to read and merge with the chunk prefetchers' totals.
-        # ``items`` counts buffers actually staged ahead: whole blocks the
-        # worker staged plus streamed chunks — never the trivial None
-        # passes for streamed/device-resident blocks
-        staged_blocks = (
-            sum(1 for p in plans if p is None) if pf is not None else 0
-        )
-        stage_s = (pf.stats["stage_s"] if pf else 0.0) + chunk_stats["stage_s"]
-        wait_s = (pf.stats["wait_s"] if pf else 0.0) + chunk_stats["wait_s"]
-        span.annotate(
-            "prefetch",
-            {
-                "items": staged_blocks + chunk_stats["items"],
-                "depth": prefetch.prefetch_depth(),
-                "stage_s": round(stage_s, 6),
-                "wait_s": round(wait_s, 6),
-                "overlap_ratio": round(
-                    prefetch.overlap_ratio(stage_s, wait_s), 4
-                ),
-                # whether donation actually applied to this verb's blocks,
-                # not just the knob: a device-resident frame never donates
-                "donate": donate and fresh,
-            },
-        )
-        if session is not None and session.events():
-            span.annotate("fault_tolerance", session.record())
         return out_blocks
 
     def _check_block_outputs(
         self, program: Program, outs, n_rows: int, rows_level: bool,
         trim: bool,
     ) -> None:
-        """Per-block output validation shared by the serial and pooled
-        dispatch loops: the non-trimmed row-count contract, the trimmed
-        agreement contract, and the shape-hint check."""
+        """Per-block output validation of the map verbs: the non-trimmed
+        row-count contract, the trimmed agreement contract, and the
+        shape-hint check."""
         verb = "map_rows" if rows_level else "map_blocks"
         if rows_level:
             pass  # row programs are per-cell; no block row-count check
@@ -873,543 +991,6 @@ class Executor:
                     f"count: { {k: v.shape for k, v in outs.items()} }"
                 )
         _check_shape_hints(program, outs, verb, cell_level=rows_level)
-
-    # -- fault-tolerant dispatch (round 9, ops/fault_tolerance.py) ----------
-
-    def _lane_next(self, it, lane_dead, li: int, session, pool):
-        """Pull the next staged value from a pool lane.  Without a retry
-        session, staging failures propagate exactly as before.  With
-        one, a failed lane is marked dead (its worker has exited; its
-        Prefetcher raises once then StopIterations), the failure counts
-        against the lane's device, and the consumer re-stages every
-        later block of that lane itself — recovery trades the staging
-        overlap for completing the frame."""
-        if lane_dead[li]:
-            return None
-        try:
-            return next(it)
-        except StopIteration:
-            raise
-        except BaseException as exc:  # noqa: BLE001 - recovery below
-            if session is None:
-                raise
-            lane_dead[li] = True
-            if pool is not None and li < len(pool.devices):
-                pool.note_block_failure(li)
-            _log.warning(
-                "staging lane %d failed (%r); re-staging its remaining "
-                "blocks on the consumer thread",
-                li,
-                exc,
-            )
-            return None
-
-    def _run_block_ft(
-        self,
-        session,
-        program: Program,
-        frame: TensorFrame,
-        bi: int,
-        infos,
-        host_stage,
-        pad_to: Optional[int],
-        rows_level: bool,
-        trim: bool,
-        donate: bool,
-        staged,
-        devices: Optional[Sequence[Any]] = None,
-        pool=None,
-        di: Optional[int] = None,
-    ) -> Dict[str, Any]:
-        """One map-verb block dispatch under the retry session: attempt 0
-        consumes the prefetched ``staged`` inputs (when they target the
-        effective device), every later attempt RE-STAGES from the host
-        frame — a donated-then-failed buffer is never re-used, and a
-        quarantine redirect lands fresh buffers on the new device.  OOM
-        degrades via :meth:`_oom_split_closure`.  Shared by the serial
-        loop (``devices``/``pool`` None) and the pooled loop.
-
-        Re-staging re-runs any ``host_stage`` fn for the retried block —
-        the same semantics as Spark's lineage replay, which re-executes
-        the whole partition pipeline on task retry and therefore
-        requires deterministic tasks.  The retry contract requires the
-        same of stage fns: deterministic per (block, cells), like the
-        decode fns that motivate ``host_stage``.  A stage fn whose
-        output depends on invocation order cannot participate in block
-        retry (run it with ``TFS_BLOCK_RETRIES=0``, where every error
-        surfaces unretried)."""
-        n_rows = frame.block_sizes[bi]
-        holder = {"staged": staged}
-
-        def attempt(a: int, dev_i: Optional[int]) -> Dict[str, Any]:
-            first = holder.pop("staged", None)  # at most once, ever
-            inputs = first if (a == 0 and (pool is None or dev_i == di)) else None
-            if inputs is None:
-                dev = (
-                    devices[dev_i]
-                    if devices is not None and dev_i is not None
-                    else None
-                )
-                inputs = self._device_inputs(
-                    program, frame.block(bi), infos, host_stage,
-                    pad_to=pad_to, device=dev,
-                )
-            if rows_level:
-                outs = self._rows_run(program, donate)(inputs)
-            elif donate:
-                outs = self._block_run(program, True)(inputs)
-            else:
-                outs = self._run_block_program(program, inputs)
-            del inputs
-            if pad_to is not None:
-                outs = {k: v[:n_rows] for k, v in outs.items()}
-            return outs
-
-        device = (
-            (lambda: pool.effective_device(di))
-            if pool is not None
-            else (0 if di is None else di)  # serial dispatch = device 0
-        )
-        oom_split = self._oom_split_closure(
-            session, program, frame, bi, infos, host_stage, rows_level,
-            trim, devices, pool, di,
-        )
-        return session.run(
-            bi, n_rows, attempt, device=device, oom_split=oom_split
-        )
-
-    def _oom_split_closure(
-        self,
-        session,
-        program: Program,
-        frame: TensorFrame,
-        bi: int,
-        infos,
-        host_stage,
-        rows_level: bool,
-        trim: bool,
-        devices,
-        pool,
-        di,
-    ):
-        """The OOM-degradation policy for one map-verb block: split the
-        block in half and re-dispatch (recursively, floor
-        ``TFS_MIN_SPLIT_ROWS``) when that is provably semantics-safe —
-        ``map_rows`` is row-independent by construction, ``map_blocks``
-        must pass the jaxpr proof at EVERY size the split can reach.
-        Trimmed maps (program-defined output row count), host-staged
-        blocks (one-unit staging contract), and cross-row programs
-        surface a :class:`fault_tolerance.BlockExecutionError` naming
-        the block and row range instead."""
-        n_rows = frame.block_sizes[bi]
-        verb = "map_rows" if rows_level else "map_blocks"
-
-        def refuse(exc: BaseException, why: str):
-            raise fault_tolerance.BlockExecutionError(
-                f"{verb}: block {bi} rows [0, {n_rows}) exhausted device "
-                f"memory and cannot degrade by splitting: {why}"
-            ) from exc
-
-        def split(exc: BaseException) -> Dict[str, Any]:
-            floor = fault_tolerance.min_split_rows()
-            if trim:
-                refuse(exc, "trimmed maps define their own output row "
-                            "count, so half-block outputs cannot be "
-                            "reassembled")
-            if host_stage:
-                refuse(exc, "host-staged blocks stage as one unit")
-            if n_rows < 2 * floor:
-                refuse(
-                    exc,
-                    f"the block is already at the split floor "
-                    f"(TFS_MIN_SPLIT_ROWS={floor})",
-                )
-            if not rows_level:
-                # every size the recursive split can reach, proven
-                # row-independent in one shot (memoized on the program)
-                sizes = set()
-                stack = [(0, n_rows)]
-                while stack:
-                    lo, hi = stack.pop()
-                    sizes.add(hi - lo)
-                    if hi - lo >= 2 * floor:
-                        mid = (lo + hi) // 2
-                        stack += [(lo, mid), (mid, hi)]
-                specs = analysis.input_specs_for(program, infos)
-                if specs is None or not analysis.rows_independent(
-                    program, specs, sorted(sizes)
-                ):
-                    refuse(
-                        exc,
-                        "the program is not provably row-independent "
-                        "(cross-row outputs cannot be recomputed from "
-                        "half blocks)",
-                    )
-            dev_i = (
-                pool.effective_device(di)
-                if pool is not None
-                else (0 if di is None else di)
-            )
-            dev = devices[dev_i] if devices is not None else None
-            mid = n_rows // 2
-            left = self._split_range(
-                session, program, frame, bi, infos, rows_level, 0, mid,
-                dev, dev_i,
-            )
-            right = self._split_range(
-                session, program, frame, bi, infos, rows_level, mid,
-                n_rows, dev, dev_i,
-            )
-            session.note_split(bi)
-            return {
-                k: jnp.concatenate([left[k], right[k]]) for k in left
-            }
-
-        return split
-
-    def _split_range(
-        self,
-        session,
-        program: Program,
-        frame: TensorFrame,
-        bi: int,
-        infos,
-        rows_level: bool,
-        lo: int,
-        hi: int,
-        dev,
-        dev_i: Optional[int],
-    ) -> Dict[str, Any]:
-        """Dispatch rows ``[lo, hi)`` of block ``bi``, splitting again on
-        a further OOM until ``TFS_MIN_SPLIT_ROWS``.  Sub-dispatches use
-        the plain non-donating entries (fresh small buffers; donation
-        would fork another executable per split size for no HBM win) and
-        their injected-fault site is ``"split"`` so attempt-selected
-        specs never re-fire on recovery work."""
-        floor = fault_tolerance.min_split_rows()
-        try:
-            faults.maybe_inject(bi, 0, dev_i, hi - lo, site="split")
-            block = frame.block(bi)
-            sub = {k: v[lo:hi] for k, v in block.items()}
-            inputs = self._device_inputs(
-                program, sub, infos, None, device=dev
-            )
-            if rows_level:
-                return program.vmapped()(inputs)
-            return self._run_block_program(program, inputs)
-        except BaseException as exc:  # noqa: BLE001 - OOM-only recovery
-            if not faults.is_oom(exc):
-                raise
-            if hi - lo < 2 * floor:
-                raise fault_tolerance.BlockExecutionError(
-                    f"block {bi} rows [{lo}, {hi}) exhausted device "
-                    f"memory at the split floor (TFS_MIN_SPLIT_ROWS="
-                    f"{floor}); this row range does not fit on the device"
-                ) from exc
-            mid = (lo + hi) // 2
-            left = self._split_range(
-                session, program, frame, bi, infos, rows_level, lo, mid,
-                dev, dev_i,
-            )
-            right = self._split_range(
-                session, program, frame, bi, infos, rows_level, mid, hi,
-                dev, dev_i,
-            )
-            session.note_split(bi)
-            return {
-                k: jnp.concatenate([left[k], right[k]]) for k in left
-            }
-
-    def _map_dispatch_pool(
-        self,
-        program: Program,
-        frame: TensorFrame,
-        infos,
-        host_stage,
-        span,
-        rows_level: bool,
-        trim: bool,
-        plans: Sequence[Optional[int]],
-        pads: Sequence[Optional[int]],
-        donate: bool,
-        devices: Sequence[Any],
-        session,
-        times: "_MapTimes",
-    ) -> List[Dict[str, Any]]:
-        """Device-pool edition of the map-verb block loop: blocks dispatch
-        round-robin/least-loaded across ``devices`` with per-device
-        staging lanes and a bounded in-flight readback window per device
-        (``ops/device_pool.py``).
-
-        Each lane's worker stages its device's next blocks (host cast +
-        ``host_stage`` + bucket pad + async ``device_put`` TO that
-        device) while the consumer thread dispatches in global block
-        order — dispatch is async, so device k computes block N while the
-        consumer hands block N+1 to device k+1 and lane k stages block
-        N+2.  Completed blocks start their D2H copy immediately and are
-        materialised at most ``depth`` blocks behind dispatch, so output
-        assembly overlaps later blocks' compute.  Outputs land in
-        ``out_blocks[bi]`` (host numpy) strictly by block index — the
-        pooled result is bit-identical to the serial path, reassembled in
-        block order no matter which device finishes first.  Only called
-        for host-FRESH frames, so the donation rules carry over
-        unchanged: every staged buffer is fresh by construction (donate
-        when the backend supports it), and no shared device-resident
-        column can reach a donating executable.  Streamed blocks
-        (``plans``) keep chunk-granular staging, pointed at their
-        assigned device."""
-        verb = "map_rows" if rows_level else "map_blocks"
-        sizes = frame.block_sizes
-        nb = frame.num_blocks
-        assignment = device_pool.assign(sizes, len(devices))
-        depth = prefetch.prefetch_depth()
-        pool = device_pool.PoolRun(devices, assignment, depth or 1)
-        if session is not None:
-            session.pool = pool  # quarantine state lives on the PoolRun
-
-        def stage_block(bi, dev):
-            if plans[bi] is not None:
-                return None  # streamed inline, chunk-level staging below
-            return self._device_inputs(
-                program, frame.block(bi), infos, host_stage,
-                pad_to=pads[bi], device=dev,
-            )
-
-        if host_stage:
-            # the host_stage contract predates the pool: stage fns run on
-            # ONE staging thread in strict block order (they may be
-            # stateful or non-reentrant).  Pooling keeps that contract —
-            # a single lane stages every block in order, device_put
-            # pointed at each block's assigned device; compute dispatch
-            # and readback still parallelize across the pool.
-            single = prefetch.Prefetcher(
-                lambda bi: stage_block(bi, devices[assignment[bi]]),
-                nb,
-                name="tfs-pool-stage",
-            )
-            lanes = [single]
-            lane_iters = None
-            single_iter = iter(single)
-        else:
-            lanes = device_pool.lanes(devices, assignment, stage_block)
-            lane_iters = [iter(l) for l in lanes]
-            single_iter = None
-        chunk_stats = {"items": 0, "stage_s": 0.0, "wait_s": 0.0}
-        out_blocks: List[Optional[Dict[str, Any]]] = [None] * nb
-        lane_dead = [False] * (1 if single_iter is not None else len(devices))
-        times.first_block()
-        for bi in range(nb):
-            cancellation.checkpoint()  # block boundary (pooled loop)
-            di = assignment[bi]
-            sp = observability.span(
-                "engine.block", f"device/{di}",
-                verb=verb, block=bi, rows=sizes[bi], device=di,
-            )
-            li = 0 if single_iter is not None else di
-            it = single_iter if single_iter is not None else lane_iters[di]
-            # the shared host_stage lane stages blocks for EVERY device,
-            # so its death names no particular device — pass pool=None so
-            # no healthy device gets charged a failure it didn't cause
-            staged = self._lane_next(
-                it, lane_dead, li, session,
-                pool if single_iter is None else None,
-            )
-            n_rows = sizes[bi]
-            di_eff = pool.effective_device(di) if session is not None else di
-            if plans[bi] is not None:
-
-                def _resolve(_di=di):
-                    e = pool.effective_device(_di)
-                    return e, devices[e]
-
-                outs = self._run_block_streamed(
-                    program, frame.block(bi), infos, plans[bi],
-                    rows_level=rows_level, pf_stats=chunk_stats,
-                    device=devices[di_eff], bi=bi, session=session,
-                    device_resolver=_resolve if session is not None else None,
-                )
-            elif session is not None:
-                outs = self._run_block_ft(
-                    session, program, frame, bi, infos, host_stage,
-                    pads[bi], rows_level, trim, donate, staged,
-                    devices=devices, pool=pool, di=di,
-                )
-                del staged
-                di_eff = pool.effective_device(di)
-            else:
-                if rows_level:
-                    outs = self._rows_run(program, donate)(staged)
-                elif donate:
-                    outs = self._block_run(program, True)(staged)
-                else:
-                    outs = self._run_block_program(program, staged)
-                del staged  # drop staged refs (donation hygiene)
-                if pads[bi] is not None:
-                    outs = {k: v[:n_rows] for k, v in outs.items()}
-            self._check_block_outputs(program, outs, n_rows, rows_level, trim)
-            sp.track = f"device/{di_eff}"  # a quarantine may have moved it
-            observability.note_dispatch_block(sp.end(device=di_eff))
-            pool.submit(bi, di_eff, n_rows, outs, out_blocks)
-        times.last_block()
-        pool.finish(out_blocks)
-        staged_blocks = sum(1 for p in plans if p is None)
-        stage_s = (
-            sum(l.stats["stage_s"] for l in lanes) + chunk_stats["stage_s"]
-        )
-        wait_s = (
-            sum(l.stats["wait_s"] for l in lanes) + chunk_stats["wait_s"]
-        )
-        span.annotate("device_pool", pool.record(stage_s, wait_s))
-        span.annotate(
-            "prefetch",
-            {
-                "items": staged_blocks + chunk_stats["items"],
-                "depth": prefetch.prefetch_depth(),
-                "stage_s": round(stage_s, 6),
-                "wait_s": round(wait_s, 6),
-                "overlap_ratio": round(
-                    prefetch.overlap_ratio(stage_s, wait_s), 4
-                ),
-                "donate": donate,
-            },
-        )
-        if session is not None and session.events():
-            span.annotate("fault_tolerance", session.record())
-        return out_blocks
-
-    def _map_dispatch_sharded(
-        self,
-        program: Program,
-        frame: TensorFrame,
-        infos,
-        host_stage,
-        span,
-        rows_level: bool,
-        trim: bool,
-        cache,
-        times: "_MapTimes",
-    ) -> List[Dict[str, Any]]:
-        """Affinity-aware dispatch for sharded-cached frames
-        (``ops/frame_cache.py``): block ``bi``'s program runs on the
-        device that already holds its cached column slices — the
-        residency plan IS the schedule (both come from
-        ``device_pool.assign`` on the same block sizes), so there are no
-        staging lanes and no H2D for resident blocks.  This removes the
-        old "device-resident frames stay serial" restriction.
-
-        Contract deltas from the host-fresh pool path, all deliberate:
-
-        * **no donation, ever** — shards are shared frame state, and a
-          donated shard would corrupt every later verb (the prefetch
-          safety contract).  The executables here are the same plain
-          entries the serial device-resident path runs, so results are
-          bit-identical to it (and to the host path).
-        * **no chunk streaming** — the bytes are already in HBM.
-        * **evicted blocks re-stage inline** from the authoritative host
-          columns to their affinity device (counted in
-          ``h2d_bytes_staged``); residency is an accelerator, never a
-          correctness dependency.
-        * **fault tolerance re-stages from host**: a retry or a
-          quarantine redirect never touches the (possibly dead) shard —
-          every attempt past the first builds fresh buffers from the
-          host copy on the CURRENT effective device, the same
-          re-staging rule the pooled fresh path follows.
-
-        Outputs return host-assembled through the pool's overlapped
-        readback windows (the round-8 trade: cross-device parallelism
-        for device residency of the OUTPUT; adoption in
-        ``ops/pipeline.py`` recovers output residency for chained
-        epochs)."""
-        nb = frame.num_blocks
-        sizes = frame.block_sizes
-        verb = "map_rows" if rows_level else "map_blocks"
-        # bucket targets still apply (device-side pad + slice); chunk
-        # streaming never does — pass all-None stream plans
-        pads = self._bucket_plan(
-            program, frame, infos, host_stage, rows_level, trim,
-            [None] * nb,
-        )
-        devices = cache.devices
-        pool = device_pool.PoolRun(
-            devices, cache.assignment, prefetch.prefetch_depth() or 1,
-            affinity=True,
-        )
-        session = fault_tolerance.frame_session(nb, verb=verb, pool=pool)
-        staged_cols = {
-            program.column_for_input(n) for n in (host_stage or {})
-        }
-        out_blocks: List[Optional[Dict[str, Any]]] = [None] * nb
-        hits = 0
-        restaged = 0
-        times.first_block()
-        for bi in range(nb):
-            cancellation.checkpoint()  # block boundary (sharded loop)
-            di = cache.assignment[bi]
-            sp = observability.span(
-                "engine.block", f"device/{di}",
-                verb=verb, block=bi, rows=sizes[bi], device=di,
-            )
-            di_eff = pool.effective_device(di) if session is not None else di
-            shard = cache.shard(bi)
-            block = dict(frame.block(bi))
-            used = False
-            if shard is not None and di_eff == di:
-                for cname, v in shard.items():
-                    if cname not in staged_cols:
-                        block[cname] = v
-                        used = True
-            if used:
-                hits += 1
-                observability.note_cache_shard_hit()
-            else:
-                restaged += 1
-                if session is not None and shard is not None:
-                    session.note_cache_restage()
-            n_rows = sizes[bi]
-            if session is not None:
-                staged = (
-                    self._device_inputs(
-                        program, block, infos, host_stage,
-                        pad_to=pads[bi], device=devices[di_eff],
-                    )
-                    if used
-                    else None
-                )
-                outs = self._run_block_ft(
-                    session, program, frame, bi, infos, host_stage,
-                    pads[bi], rows_level, trim, False, staged,
-                    devices=devices, pool=pool, di=di,
-                )
-                del staged
-                di_eff = pool.effective_device(di)
-            else:
-                inputs = self._device_inputs(
-                    program, block, infos, host_stage,
-                    pad_to=pads[bi], device=devices[di_eff],
-                )
-                if rows_level:
-                    outs = self._rows_run(program, False)(inputs)
-                else:
-                    outs = self._run_block_program(program, inputs)
-                del inputs
-                if pads[bi] is not None:
-                    outs = {k: v[:n_rows] for k, v in outs.items()}
-            self._check_block_outputs(program, outs, n_rows, rows_level, trim)
-            sp.track = f"device/{di_eff}"  # a quarantine may have moved it
-            observability.note_dispatch_block(
-                sp.end(device=di_eff, shard_hit=used)
-            )
-            pool.submit(bi, di_eff, n_rows, outs, out_blocks)
-        times.last_block()
-        pool.finish(out_blocks)
-        span.annotate("device_pool", pool.record())
-        fc = cache.record()
-        fc["shard_hits"] = hits
-        fc["restaged_blocks"] = restaged
-        span.annotate("frame_cache", fc)
-        if session is not None and session.events():
-            span.annotate("fault_tolerance", session.record())
-        return out_blocks
 
     def _empty_map_outputs(
         self,
@@ -1767,25 +1348,13 @@ class Executor:
                     dtypes.coerce(dtypes.from_numpy(arr.dtype)),
                     arr.shape[1:],
                 )
-        # mirror the dispatch exactly: blocks the runtime would STREAM
-        # compile chunk-sized executables on first use (documented gap) —
-        # warming their whole-block signature would be dead weight.  A
-        # sharded-cached frame never streams (its bytes are already in
-        # HBM), so its plan is all-None like the dispatch's.
-        cache = frame_cache.active_cache(frame)
-        plans = (
-            [None] * frame.num_blocks
-            if cache is not None
-            else [
-                self._stream_plan(
-                    program, frame.block(bi), infos, host_stage,
-                    check_independence=not rows_level,
-                )
-                for bi in range(frame.num_blocks)
-            ]
-        )
-        pads = self._bucket_plan(
-            program, frame, infos, host_stage, rows_level, False, plans
+        # mirror the dispatch exactly (the same _map_plan): blocks the
+        # runtime would STREAM compile chunk-sized executables on first
+        # use (documented gap) — warming their whole-block signature
+        # would be dead weight — and donated entries lower to a different
+        # persistent-cache key
+        placement, plans, pads, donate = self._map_plan(
+            program, frame, infos, host_stage, rows_level, False
         )
         exec_sizes = sorted(
             {
@@ -1800,15 +1369,6 @@ class Executor:
             # empty (the non-trimmed map verbs short-circuit without
             # compiling) — warming any signature would be dead weight
             return []
-        # match the runtime's donation choice (_map_dispatch): donated
-        # entries lower to a different persistent-cache key.  Cached
-        # frames (sharded or single-device) never donate — shards and
-        # resident columns are shared state
-        donate = (
-            prefetch.donate_inputs()
-            and self._frame_fresh(frame)
-            and cache is None
-        )
         run = (
             self._rows_run(program, donate)
             if rows_level
@@ -1817,18 +1377,21 @@ class Executor:
         raw = getattr(run, "raw_jit", None) or (
             program._vmap_raw() if rows_level else program._jit_raw()
         )
+        cells = {
+            n: staged_specs[n]
+            if n in staged_specs
+            else (
+                dtypes.coerce(infos[n].scalar_type),
+                tuple(infos[n].cell_shape),
+            )
+            for n in program.input_names
+        }
         fps = []
         for n_rows in exec_sizes:
-            specs = {}
-            for n in program.input_names:
-                if n in staged_specs:
-                    st, cell = staged_specs[n]
-                else:
-                    st = dtypes.coerce(infos[n].scalar_type)
-                    cell = tuple(infos[n].cell_shape)
-                specs[n] = jax.ShapeDtypeStruct(
-                    (n_rows,) + tuple(cell), st.np_dtype
-                )
+            specs = {
+                n: jax.ShapeDtypeStruct((n_rows,) + tuple(cell), st.np_dtype)
+                for n, (st, cell) in cells.items()
+            }
             fn = program.aot_compile_raw(
                 raw, specs, ("aot", bool(rows_level), donate)
             )
@@ -1847,30 +1410,24 @@ class Executor:
         # device; a SHARDED-cached frame primes its shard devices; a
         # single-device cached frame primes its resident device — so a
         # cached loop's first epoch pays no compile either.
-        if cache is not None:
+        if placement.kind == "affinity":
             prime_devs = [
-                cache.devices[di] for di in sorted(set(cache.assignment))
+                placement.devices[di]
+                for di in sorted(set(placement.assignment))
             ]
-        elif not self._frame_fresh(frame):
+        elif placement.kind == "pool":
+            prime_devs = placement.devices
+        elif not placement.fresh:
             dev = self._resident_device(frame)
             prime_devs = [dev] if dev is not None else []
-        elif self.supports_device_pool and frame.num_blocks > 1:
-            pool_devs = device_pool.pool_devices()
-            prime_devs = pool_devs if len(pool_devs) >= 2 else []
         else:
             prime_devs = []
         if prime_devs:
             for n_rows in exec_sizes:
-                zeros = {}
-                for n in program.input_names:
-                    if n in staged_specs:
-                        st, cell = staged_specs[n]
-                    else:
-                        st = dtypes.coerce(infos[n].scalar_type)
-                        cell = tuple(infos[n].cell_shape)
-                    zeros[n] = np.zeros(
-                        (n_rows,) + tuple(cell), st.np_dtype
-                    )
+                zeros = {
+                    n: np.zeros((n_rows,) + tuple(cell), st.np_dtype)
+                    for n, (st, cell) in cells.items()
+                }
                 for dev in prime_devs:
                     inputs = {
                         k: jax.device_put(v, dev) for k, v in zeros.items()
@@ -2069,18 +1626,16 @@ class Executor:
         self, run, bases, reduced, frame: TensorFrame, span
     ) -> List[Dict[str, jnp.ndarray]]:
         """Per-block partials for the reduce verbs (empty blocks skipped),
-        device-pooled when the pool engages.
+        through the one block loop (``ops/block_loop.py``).
 
-        Pooled: each nonempty block's input arrays stage to its assigned
-        device on a per-device lane and ``run`` folds the block THERE —
-        the device-granularity analog of the reference's per-partition
-        reduce (SURVEY P1/P4).  Every partial then moves (async, one cell
-        per base column) to ONE combine device, in block order, so the
-        caller's final combine is byte-for-byte the single-device fold —
-        same stack, same fold shape, bit-identical results regardless of
-        completion order.  (A per-device local pre-fold would be one
-        combine cheaper but would change the fold shape; bit-identity
-        wins.)"""
+        Pooled (host-fresh frame) or on a sharded cache's affinity
+        devices, each nonempty block folds on ITS device; every partial
+        then moves (async, one cell per base column) to ONE combine
+        device, in block order, so the caller's final combine is
+        byte-for-byte the single-device fold — same stack, same fold
+        shape, bit-identical results regardless of completion order.  (A
+        per-device local pre-fold would be one combine cheaper but would
+        change the fold shape; bit-identity wins.)"""
         sizes = frame.block_sizes
         nonempty = [bi for bi in range(frame.num_blocks) if sizes[bi] > 0]
         sts = {b: dtypes.coerce(reduced[b].scalar_type) for b in bases}
@@ -2088,229 +1643,12 @@ class Executor:
         # check_reduce_* returns the fed column's ColumnInfo, so its
         # .name is what block dicts and cache shards key on
         cols = {b: reduced[b].name for b in bases}
-        session = fault_tolerance.frame_session(
-            frame.num_blocks, verb="reduce"
+        partials, _ = block_loop.run_blocks(
+            block_loop.place(self, frame, nonempty),
+            frame,
+            _ReduceWork(self, run, bases, sts, cols),
+            span,
         )
-        # sharded frame cache: per-block partials fold on each block's
-        # RESIDENT device (no H2D for resident shards), then hop — one
-        # reduced cell per base — to ONE combine device in block order,
-        # so the caller's final combine keeps the exact serial fold
-        # shape (bit-identity, like the round-8 pooled partials)
-        cache = frame_cache.active_cache(frame)
-        if cache is not None and len(nonempty) > 1:
-            return self._reduce_partials_sharded(
-                run, bases, sts, cols, frame, span, cache, session, sizes,
-                nonempty,
-            )
-        pool_devs = (
-            device_pool.pool_devices()
-            if (
-                self.supports_device_pool
-                and len(nonempty) > 1
-                and self._frame_fresh(frame)
-            )
-            else []
-        )
-        if len(pool_devs) < 2:
-            partials: List[Dict[str, jnp.ndarray]] = []
-            for bi in nonempty:
-                cancellation.checkpoint()  # block boundary (partials)
-                sp = observability.span(
-                    "engine.reduce_block", "serial",
-                    block=bi, rows=sizes[bi], device=0,
-                )
-
-                def attempt(a, dev_i, _bi=bi):
-                    block = frame.block(_bi)
-                    arrays = {
-                        b: self._device_value(block[cols[b]], sts[b])
-                        for b in bases
-                    }
-                    return run(arrays)
-
-                if session is None:
-                    partials.append(attempt(0, None))
-                else:
-                    # reduce partials are cross-row by definition: no OOM
-                    # split — an OOM surfaces with the block's row range
-                    partials.append(
-                        session.run(bi, sizes[bi], attempt, device=0)
-                    )
-                observability.note_request_block(0, sizes[bi])
-                sp.end()
-            if session is not None and session.events():
-                span.annotate("fault_tolerance", session.record())
-            span.mark("dispatch_partials")
-            return partials
-        assignment = device_pool.assign(
-            [sizes[bi] for bi in nonempty], len(pool_devs)
-        )
-        pool = device_pool.PoolRun(
-            pool_devs, assignment, prefetch.prefetch_depth() or 1
-        )
-        if session is not None:
-            session.pool = pool
-
-        def stage_block(k, dev):
-            block = frame.block(nonempty[k])
-            return {
-                b: self._device_value(block[cols[b]], sts[b], device=dev)
-                for b in bases
-            }
-
-        lanes = device_pool.lanes(pool_devs, assignment, stage_block)
-        lane_iters = [iter(l) for l in lanes]
-        lane_dead = [False] * len(pool_devs)
-        combine = pool_devs[0]
-        partials = []
-        for k, bi in enumerate(nonempty):
-            cancellation.checkpoint()  # block boundary (pooled partials)
-            di = assignment[k]
-            sp = observability.span(
-                "engine.reduce_block", f"device/{di}",
-                block=bi, rows=sizes[bi], device=di,
-            )
-            if session is None:
-                arrays = next(lane_iters[di])
-                p = run(arrays)
-                di_eff = di
-            else:
-                staged = self._lane_next(
-                    lane_iters[di], lane_dead, di, session, pool
-                )
-                holder = {"v": staged}
-                del staged
-
-                def attempt(a, dev_i, _k=k, _h=holder, _di=di):
-                    arrs = (
-                        _h.pop("v", None)
-                        if (a == 0 and dev_i == _di)
-                        else None
-                    )
-                    _h.clear()
-                    if arrs is None:
-                        arrs = stage_block(_k, pool_devs[dev_i])
-                    return run(arrs)
-
-                p = session.run(
-                    bi,
-                    sizes[bi],
-                    attempt,
-                    device=lambda _di=di: pool.effective_device(_di),
-                )
-                di_eff = pool.effective_device(di)
-            pool.note_dispatch(di_eff, sizes[bi])
-            sp.track = f"device/{di_eff}"
-            sp.end(device=di_eff)
-            # async hop to the combine device: one reduced cell per base
-            partials.append(
-                {b: jax.device_put(p[b], combine) for b in bases}
-            )
-        span.annotate(
-            "device_pool",
-            pool.record(
-                sum(l.stats["stage_s"] for l in lanes),
-                sum(l.stats["wait_s"] for l in lanes),
-            ),
-        )
-        if session is not None and session.events():
-            span.annotate("fault_tolerance", session.record())
-        span.mark("dispatch_partials")
-        return partials
-
-    def _reduce_partials_sharded(
-        self, run, bases, sts, cols, frame, span, cache, session, sizes,
-        nonempty,
-    ) -> List[Dict[str, jnp.ndarray]]:
-        """Affinity partials for the reduce verbs over a sharded-cached
-        frame: each nonempty block's fold runs on its resident device
-        (shards never donate; evicted blocks re-stage from the host copy
-        inline), every partial then moves async to ONE combine device in
-        block order.  Retries and quarantine redirects re-stage from the
-        authoritative host columns on the current effective device."""
-        devices = cache.devices
-        pool = device_pool.PoolRun(
-            devices,
-            [cache.assignment[bi] for bi in nonempty],
-            prefetch.prefetch_depth() or 1,
-            affinity=True,
-        )
-        if session is not None:
-            session.pool = pool
-        combine = devices[0]
-        partials: List[Dict[str, jnp.ndarray]] = []
-        hits = 0
-        for bi in nonempty:
-            cancellation.checkpoint()  # block boundary (sharded partials)
-            di = cache.assignment[bi]
-            sp = observability.span(
-                "engine.reduce_block", f"device/{di}",
-                block=bi, rows=sizes[bi], device=di,
-            )
-            shard0 = cache.shard(bi)
-            has_shard = shard0 is not None and any(
-                cols[b] in shard0 for b in bases
-            )
-            # whether the attempt that SUCCEEDED read the shard — a
-            # retried block re-stages from host, and the hit counter
-            # must not claim otherwise
-            used = {"v": False}
-
-            def stage(dev_i, use_shard, _bi=bi, _shard=shard0):
-                block = frame.block(_bi)
-                shard = _shard if use_shard else None
-                return {
-                    b: self._device_value(
-                        shard[cols[b]]
-                        if shard is not None and cols[b] in shard
-                        else block[cols[b]],
-                        sts[b],
-                        device=devices[dev_i],
-                    )
-                    for b in bases
-                }
-
-            if session is None:
-                used["v"] = has_shard
-                p = run(stage(di, True))
-                di_eff = di
-            else:
-
-                def attempt(
-                    a, dev_i, _stage=stage, _di=di, _has=has_shard,
-                    _used=used,
-                ):
-                    # only attempt 0 on the home device may read the
-                    # shard; every retry / redirect re-stages from host
-                    u = a == 0 and dev_i == _di and _has
-                    _used["v"] = u
-                    return run(_stage(dev_i, u))
-
-                p = session.run(
-                    bi,
-                    sizes[bi],
-                    attempt,
-                    device=lambda _di=di: pool.effective_device(_di),
-                )
-                di_eff = pool.effective_device(di)
-                if has_shard and not used["v"]:
-                    session.note_cache_restage()
-            if used["v"]:
-                hits += 1
-                observability.note_cache_shard_hit()
-            pool.note_dispatch(di_eff, sizes[bi])
-            sp.track = f"device/{di_eff}"
-            sp.end(device=di_eff, shard_hit=used["v"])
-            # async hop to the combine device: one reduced cell per base
-            partials.append(
-                {b: jax.device_put(p[b], combine) for b in bases}
-            )
-        span.annotate("device_pool", pool.record())
-        fc = cache.record()
-        fc["shard_hits"] = hits
-        span.annotate("frame_cache", fc)
-        if session is not None and session.events():
-            span.annotate("fault_tolerance", session.record())
         span.mark("dispatch_partials")
         return partials
 

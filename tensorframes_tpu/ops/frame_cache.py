@@ -17,7 +17,7 @@ deterministic least-loaded assignment the scheduler uses
 (:func:`tensorframes_tpu.ops.device_pool.assign`), so a later verb's
 block->device plan MATCHES the residency plan and every block executes
 on the device that already holds it.  The engine's affinity dispatch
-(``engine._map_dispatch_sharded``) then runs device-resident frames
+(``block_loop.place`` -> ``"affinity"``) then runs device-resident frames
 across the whole pool with no staging lanes and no H2D.
 
 Design rules:

@@ -67,6 +67,7 @@ from ..schema import ColumnInfo, Schema
 from ..shape import Shape, UNKNOWN
 from ..analysis import rowdep as analysis
 from . import (
+    block_loop,
     bucketing,
     device_pool,
     fault_tolerance,
@@ -806,9 +807,9 @@ class Pipeline:
         body (:meth:`_block_chain`) dispatches once per block on the
         block's assigned device, with per-device staging lanes and the
         bounded overlapped-readback window — the pipeline face of the
-        engine's ``_map_dispatch_pool``.  Entry buffers are fresh host
-        slices staged per block, so they donate exactly like the fused
-        path's entry columns.
+        engine's pooled placement (``ops/block_loop.py``).  Entry buffers
+        are fresh host slices staged per block, so they donate exactly
+        like the fused path's entry columns.
 
         ``cache`` (round 10, ``ops/frame_cache.py``): a sharded-cached
         entry frame runs AFFINITY dispatch instead — each block executes
@@ -980,7 +981,7 @@ class Pipeline:
                     del staged
                     di_eff = di
                 else:
-                    staged = _DEFAULT._lane_next(
+                    staged = block_loop.lane_next(
                         lane_iters[di], lane_dead, di, session, pool
                     )
                     holder = {"v": staged}

@@ -132,6 +132,7 @@ from ..frame import TensorFrame
 from ..program import Program
 from ..schema import ColumnInfo, Schema
 from . import (
+    block_loop,
     bucketing,
     device_pool,
     fault_tolerance,
@@ -1253,7 +1254,7 @@ def _run_pooled_chain(
                 if session is None:
                     next(lane_iters[di])
                 else:
-                    _DEFAULT._lane_next(
+                    block_loop.lane_next(
                         lane_iters[di], lane_dead, di, session, pool
                     )
             eff_assign.append(di)
@@ -1273,7 +1274,7 @@ def _run_pooled_chain(
         elif session is None:
             staged = next(lane_iters[di])
         else:
-            staged = _DEFAULT._lane_next(
+            staged = block_loop.lane_next(
                 lane_iters[di], lane_dead, di, session, pool
             )
         if session is None:

@@ -34,7 +34,6 @@ import pytest
 from tensorframes_tpu import observability
 from tensorframes_tpu.bridge import (
     BridgeClient,
-    ContinuousBatcher,
     DeadlineExceeded,
     ServerBusy,
     serve,
@@ -588,102 +587,6 @@ def test_client_honors_retry_after_hint():
                 fc.map_blocks(_add3_graph(), fetches=["z"])
     finally:
         srv.close(drain_s=1.0)
-
-
-# ---------------------------------------------------------------------------
-# continuous decode batching
-# ---------------------------------------------------------------------------
-
-
-def _toy_row_step(state, tok):
-    """Toy decode step: emit carry + token, advance carry."""
-    import jax.numpy as jnp
-
-    carry = state["c"]
-    return {"c": carry + 1.0}, carry + tok
-
-
-def _toy_solo(start, n):
-    c, t, out = float(start), 0.0, []
-    for _ in range(n):
-        t = c + t
-        out.append(t)
-        c += 1.0
-    return out
-
-
-def test_continuous_batch_join_and_early_retirement():
-    import jax.numpy as jnp
-
-    b = ContinuousBatcher(_toy_row_step, max_batch=4)
-    try:
-        results = {}
-
-        def run(k, start, n):
-            results[k] = [
-                float(x)
-                for x in b.submit(
-                    {"c": jnp.float64(start)},
-                    jnp.float64(0.0),
-                    max_new=n,
-                    timeout_s=60.0,
-                )
-            ]
-
-        # long enough that the short request reliably joins MID-run
-        # (each vmapped step is ~0.1-1ms on this box)
-        long_n = 4000
-        long_t = threading.Thread(target=run, args=(1, 10.0, long_n))
-        long_t.start()
-        _wait_until(lambda: b.steps >= 2, what="batch running")
-        short_t = threading.Thread(target=run, args=(2, 5.0, 3))
-        short_t.start()
-        short_t.join(timeout=60.0)
-        # EARLY RETIREMENT: the short request returns while the long
-        # one is still decoding
-        assert not short_t.is_alive()
-        assert long_t.is_alive() or len(results.get(1, [])) == long_n
-        long_t.join(timeout=120.0)
-        assert b.joined_mid_run >= 1
-        # bit-identity vs the solo reference recurrence
-        assert results[1] == _toy_solo(10.0, long_n)
-        assert results[2] == _toy_solo(5.0, 3)
-    finally:
-        b.close()
-
-
-def test_continuous_batch_until_stop_and_solo_parity():
-    import jax.numpy as jnp
-
-    batched = ContinuousBatcher(_toy_row_step, max_batch=4)
-    solo = ContinuousBatcher(_toy_row_step, max_batch=1)
-    try:
-        stop = lambda tok: float(tok) >= 40.0  # noqa: E731
-        kw = dict(max_new=64, until=stop, timeout_s=60.0)
-        results = {}
-
-        def run(k, start):
-            results[k] = [
-                float(x)
-                for x in batched.submit(
-                    {"c": jnp.float64(start)}, jnp.float64(0.0), **kw
-                )
-            ]
-
-        _run_workers(3, lambda k: run(k, 3.0 + k))
-        for k in range(3):
-            ref = [
-                float(x)
-                for x in solo.submit(
-                    {"c": jnp.float64(3.0 + k)}, jnp.float64(0.0), **kw
-                )
-            ]
-            assert results[k] == ref  # batch size never changes a row
-            assert results[k][-1] >= 40.0  # stopped by `until`
-            assert len(results[k]) < 64  # ...early, not by max_new
-    finally:
-        batched.close()
-        solo.close()
 
 
 # ---------------------------------------------------------------------------

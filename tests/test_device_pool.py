@@ -24,8 +24,9 @@ import jax
 import jax.numpy as jnp
 
 import tensorframes_tpu as tfs
+from tensorframes_tpu import cancellation
 from tensorframes_tpu import observability as obs
-from tensorframes_tpu.ops import device_pool, engine
+from tensorframes_tpu.ops import block_loop, device_pool, engine, frame_cache
 from tensorframes_tpu.ops.pipeline import pipeline
 
 
@@ -343,6 +344,105 @@ def test_pooled_reduce_partials_fold_shape(monkeypatch):
         np.testing.assert_array_equal(base[k], got[k], err_msg=k)
     assert span["device_pool"]["devices"] >= 2
     assert sum(span["device_pool"]["blocks_per_device"]) == 5
+
+
+class _CancelAtCheckpoint(cancellation.CancelScope):
+    """Cancels itself at its ``n``-th checkpoint (0-based).  With block
+    retries off — the suite's pin — the block loop's per-block
+    checkpoint is the only one a verb makes, so that is the boundary
+    before block ``n``."""
+
+    __slots__ = ("n", "seen")
+
+    def __init__(self, n):
+        super().__init__(label="test")
+        self.n = n
+        self.seen = 0
+
+    def check(self):
+        if self.seen == self.n:
+            self.cancel("cut before block %d" % self.n)
+        self.seen += 1
+        super().check()
+
+
+_LOOP_VERBS = {
+    # verb -> (program, run -> numpy result, the loop's span)
+    "map_blocks": (
+        lambda: tfs.Program.wrap(
+            lambda x: {"y": jnp.tanh(x) * 2.0 + x}, fetches=["y"]
+        ),
+        lambda prog, f: np.asarray(tfs.map_blocks(prog, f).column("y").data),
+        "engine.block",
+    ),
+    "map_rows": (
+        lambda: tfs.Program.wrap(
+            lambda x: {"r": x.sum() + x[0]}, fetches=["r"]
+        ),
+        lambda prog, f: np.asarray(tfs.map_rows(prog, f).column("r").data),
+        "engine.block",
+    ),
+    "reduce_blocks": (
+        lambda: tfs.Program.wrap(
+            lambda x_input: {"x": (x_input * 1.3).sum(0)}, fetches=["x"]
+        ),
+        lambda prog, f: tfs.reduce_blocks(prog, f)["x"],
+        "engine.reduce_block",
+    ),
+}
+
+
+@pytest.mark.parametrize("verb", sorted(_LOOP_VERBS))
+@pytest.mark.parametrize("placement", ["serial", "pool", "affinity"])
+def test_pooled_one_block_loop_equivalence(monkeypatch, placement, verb):
+    """The engine has ONE block loop (``ops/block_loop.py``) behind the
+    map and reduce verbs, whatever the placement: the bytes equal the
+    serial result, every block's span is there exactly once on the
+    track of the device it ran on, and a scope cancelled before block 2
+    stops the verb at block 2."""
+    make, run, span_name = _LOOP_VERBS[verb]
+    prog = make()
+    frame = _frame()  # 6 blocks of 20 rows
+    monkeypatch.setenv("TFS_DEVICE_POOL", "0")
+    base = run(prog, frame)
+    if placement == "serial":
+        want_tracks = ["serial"] * frame.num_blocks
+    else:
+        monkeypatch.setenv("TFS_DEVICE_POOL", "auto")
+        if placement == "affinity":
+            frame = frame.cache(sharded=True)
+            assignment = frame_cache.active_cache(frame).assignment
+        else:
+            assignment = device_pool.assign(
+                frame.block_sizes, len(device_pool.pool_devices())
+            )
+        assert len(set(assignment)) >= 2
+        want_tracks = [f"device/{di}" for di in assignment]
+    placed = block_loop.place(engine._DEFAULT, frame, range(6))
+    assert placed.kind == placement
+
+    def block_spans():
+        return [
+            (e["args"]["block"], e["args"]["rows"], e["track"])
+            for e in obs.trace_events()
+            if e["name"] == span_name
+        ]
+
+    obs.enable_trace()
+    try:
+        obs.clear_trace()
+        got = run(prog, frame)
+        spans = block_spans()
+        obs.clear_trace()
+        with cancellation.activate(_CancelAtCheckpoint(2)):
+            with pytest.raises(cancellation.Cancelled):
+                run(prog, frame)
+        cut = block_spans()
+    finally:
+        obs.disable_trace()
+    np.testing.assert_array_equal(base, got)
+    assert spans == [(bi, 20, want_tracks[bi]) for bi in range(6)]
+    assert cut == spans[:2]  # blocks 0 and 1 ran, block 2 never started
 
 
 def test_pooled_pipeline_map_chain(monkeypatch):

@@ -326,6 +326,73 @@ def test_scheduler_concurrent_mixed_streams_bit_identical(params):
         sched.close()
 
 
+@pytest.mark.parametrize("case", ["alone", "among_strangers"])
+def test_scheduler_until_stop_retires_at_that_token(params, case):
+    """A stream whose ``until`` fires retires AT that token — no token
+    past it is emitted or paid for, and its pages come back — and its
+    tokens are the prefix of the same request's solo contiguous run,
+    whether it is served alone (one slot, one request at a time) or
+    beside strangers that run to ``max_new``."""
+    jobs = _prompts(((6, 12), (9, 12), (4, 12)), seed=7)
+    strangers = (
+        _prompts(((5, 9), (12, 5)), seed=8)
+        if case == "among_strangers"
+        else []
+    )
+    sched = DecodeScheduler(
+        params, CFG, max_slots=4 if strangers else 1,
+        tokens_per_page=PAGE, max_seq=CAP,
+    )
+    try:
+        refs = [_reference(params, p, mn, cap=sched.cap) for p, mn in jobs]
+        # stop at the token the solo run emits third (or wherever that
+        # value shows up first): early, never by max_new
+        stops = [r[2] for r in refs]
+        want = [r[: r.index(s) + 1] for r, s in zip(refs, stops)]
+        want += [
+            _reference(params, p, mn, cap=sched.cap) for p, mn in strangers
+        ]
+        work = [
+            (p, mn, lambda tok, s=s: tok == s)
+            for (p, mn), s in zip(jobs, stops)
+        ] + [(p, mn, None) for p, mn in strangers]
+        results = [None] * len(work)
+        errs = []
+
+        def worker(i):
+            try:
+                p, mn, until = work[i]
+                results[i] = sched.submit(p, mn, until=until, timeout_s=120)
+            except BaseException as e:  # noqa: BLE001 — surfaced below
+                errs.append(e)
+
+        c0 = obs.counters()
+        ts = [
+            threading.Thread(target=worker, args=(i,))
+            for i in range(len(work))
+        ]
+        for t in ts:
+            t.start()
+            if not strangers:
+                t.join(timeout=120)  # alone: one request at a time
+        for t in ts:
+            t.join(timeout=120)
+            assert not t.is_alive()
+        if errs:
+            raise errs[0]
+        d = obs.counters_delta(c0)
+        assert results == want
+        for got, (_, mn) in zip(results, jobs):
+            assert len(got) < mn  # stopped by `until`, not by max_new
+        snap = sched.snapshot()
+        assert snap["retired"] == len(work)
+        assert snap["pages_used"] == 0, "pages leaked past retirement"
+        assert d["decode_tokens"] == sum(len(w) for w in want)
+        assert d["kv_pages_allocated"] == d["kv_pages_freed"] > 0
+    finally:
+        sched.close()
+
+
 def test_scheduler_prefills_each_admitted_request_alone(params):
     """Three requests admitted at ONE boundary make three dispatches,
     each at its own prompt's bucket, in admission order."""

@@ -27,9 +27,11 @@ Rules (each violation prints ``file:line: [rule] message``):
   delta duplicates; no registered gauge name may collide with a counter
   family (``tfs_<name>_total``) — the ``metrics_text`` no-dup-family
   rule, enforced at the source instead of scrape time.
-* **checkpoint-coverage** — in ``ops/engine.py`` / ``ops/pipeline.py``,
-  every block-dispatch loop (a ``for``/``while`` whose body dispatches
-  blocks: ``_run_block_*`` / ``session.run(...)`` / ``_split_range``)
+* **checkpoint-coverage** — in ``ops/block_loop.py`` (the engine's one
+  block loop), ``ops/engine.py`` (its chunk loops) and
+  ``ops/pipeline.py``, every block-dispatch loop (a ``for``/``while``
+  whose body dispatches blocks: ``run_streamed`` / ``session.run(...)``
+  / ``_split_range``)
   must call ``cancellation.checkpoint()`` inside the loop, so a bridge
   deadline/cancel can cut a verb at the next block boundary (the PR 6
   cooperative-cancellation contract).  Prefetch staging lanes are NOT
@@ -64,7 +66,7 @@ GAUGE_COUNTERS = {"peak_host_bytes"}
 
 # block-dispatch markers for checkpoint-coverage: a loop calling any of
 # these executes verbs block-by-block on the consumer thread
-DISPATCH_ATTRS = {"_run_block_streamed", "_run_block_ft", "_split_range"}
+DISPATCH_ATTRS = {"run_streamed", "_run_block_streamed", "_split_range"}
 DISPATCH_RECEIVER_RUN = "session"  # session.run(bi, ...) — the FT wrapper
 
 
@@ -391,7 +393,8 @@ def _loop_checkpoints(loop: ast.AST) -> bool:
 
 def check_checkpoints(root: str) -> List[Violation]:
     out: List[Violation] = []
-    for sub in (os.path.join(PKG, "ops", "engine.py"),
+    for sub in (os.path.join(PKG, "ops", "block_loop.py"),
+                os.path.join(PKG, "ops", "engine.py"),
                 os.path.join(PKG, "ops", "pipeline.py")):
         path = os.path.join(root, sub)
         if not os.path.exists(path):

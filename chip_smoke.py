@@ -477,7 +477,7 @@ def phase_bridge_decode(ctx: Dict[str, Any], sz: Sizes) -> Dict[str, Any]:
         f"{total['kv_pages_freed']} freed",
     )
     check_on_chip(
-        (sched.pool.k_pages, sched._kp), ctx["platform"], "kv page pool"
+        (sched._kp, sched._vp), ctx["platform"], "kv page pool"
     )
 
     # reported, not gated: bit-identity of paged vs contiguous decode was

@@ -1202,18 +1202,19 @@ class DecodeScheduler:
         self.pool = kv_pager.PagePool(
             cfg, n_pages, tokens_per_page=P, slots=self.max_slots
         )
-        self._kp = self.pool.k_pages
-        self._vp = self.pool.v_pages
+        # the scheduler TAKES the arrays: both executables donate the pools,
+        # so they have this one holder from here to ``close`` (the pool
+        # keeps shapes, free list and accounting, and no array).  ``_state``
+        # is a 'cca' block's convolution state, one row a slot (None for
+        # other blocks): an argument and a result of both executables,
+        # like the pages; a prefill overwrites the admitted slot's row
+        self._kp, self._vp, self._state = self.pool.take()
         # whether the step executable attends through the paged-attention
         # kernel: what ``kv_pager._paged_block`` will decide when it traces
         # this pool's one-token step, asked once (``decode_kernel_steps``)
         self._kernel_step = int(kv_pager.paged_kernel_fits(
             cfg, P, self.max_slots, 1, self._kp.dtype
         ))
-        # a 'cca' block's convolution state, one row a slot (None for
-        # other blocks): an argument and a result of both executables,
-        # like the pages; a prefill overwrites the admitted slot's row
-        self._state = self.pool.conv_state
         # ``routing_trace`` > 0 keeps, for that many retired requests, the
         # expert every fed position chose in every layer (``routing_of``):
         # what a reference needs to follow the served path, since top-1
@@ -1503,9 +1504,11 @@ class DecodeScheduler:
     def _run(self, fn, *args, slot=None):
         """Dispatch a serving executable on the current pools (and, for a
         block that is not the dense one, the convolution state, with the
-        admitted ``slot`` for a prefill), keep what it returns of them,
-        and hand back ``(tokens, stats)``: device arrays, ``stats`` the
-        dispatch's routing counts or None."""
+        admitted ``slot`` for a prefill), keep what it returns of them —
+        the executables donate the pools they are passed, so the arrays
+        held before the call are gone after it — and hand back ``(tokens,
+        stats)``: device arrays, ``stats`` the dispatch's routing counts
+        or None."""
         if self.cfg.block.stateless:
             toks, self._kp, self._vp = self._dispatch(
                 fn, self._params, *args, self._kp, self._vp, self.cfg
@@ -1544,20 +1547,24 @@ class DecodeScheduler:
     def _dispatch(self, fn, *args):
         """One compiled dispatch with chaos injection + bounded retry:
         ``faults.maybe_inject`` fires configured transients at the step
-        boundary (site='dispatch', so attempt selectors work), and the
-        functional (pages, tables, tokens) state means a retry
-        recomputes the identical step."""
+        boundary (site='dispatch', so attempt selectors work), BEFORE
+        ``fn`` is called: the pools ``fn`` donates are still whole when a
+        transient is retried, and the retry computes the identical step.
+        Only the injection is retried — once ``fn`` has started its
+        arguments may be consumed, so whatever it raises propagates and
+        is never answered with a second call on deleted buffers."""
         from .. import faults
 
         attempt = 0
         while True:
             try:
                 faults.maybe_inject(self.steps, attempt, site="dispatch")
-                return fn(*args)
             except faults.InjectedTransient:
                 attempt += 1
                 if attempt >= _DECODE_STEP_ATTEMPTS:
                     raise
+                continue
+            return fn(*args)
 
     def _drive(self) -> None:
         import jax.numpy as jnp
@@ -1696,6 +1703,12 @@ class DecodeScheduler:
                 self._tables[:] = 0
                 self._indices[:] = 0
                 self._toks[:] = 0
+                # a dispatch that failed after it started has consumed the
+                # pools it was given (they are donated).  No sequence is
+                # left to read them: the next request starts on fresh ones,
+                # the old dropped first so that two pairs never stand
+                self._kp = self._vp = self._state = None
+                self._kp, self._vp, self._state = self.pool.zeros()
 
     def _prefill(self, admitted, jnp) -> bool:
         """The disaggregated prefill lane: ONE dispatch per admitted

@@ -6,14 +6,22 @@ prompt/continuation lengths therefore reserves worst-case HBM for every
 sequence, which is exactly the fragmentation PagedAttention/Orca-style
 serving removed (PAPERS.md).  This module is the paged layout:
 
-* a process-level :class:`PagePool` owns ``[n_layers, kvh, n_pages, P,
-  Dh]`` k/v page arrays (``P = TFS_DECODE_PAGE_TOKENS``) and a free
-  list.  The layout is head-major because the decode kernel dictates it
-  (PR 30): one page of one head is ``[P, Dh]``, whole native tiles (one
-  4 KB tile at P=16, Dh=128 in bf16) that a DMA moves as they lie,
-  whatever ``kvh`` is; with the heads inside the page (``[P, kvh, Dh]``)
-  a model of 2 kv heads filled a quarter of each tile.  **Physical page
-  0 is the trash page** — never allocated, it absorbs the writes of pad
+* a process-level :class:`PagePool` builds ``[n_layers, kvh, n_pages, P,
+  Dh]`` k/v page arrays (``P = TFS_DECODE_PAGE_TOKENS``) and owns a free
+  list.  The ARRAYS have one owner, whoever runs the executables: the
+  decode scheduler takes them from its pool at construction
+  (:meth:`PagePool.take`) and from then on holds the one buffer pair
+  there is.  Both serving executables donate the pools, the layer scan
+  carries the stacks and writes a step's tokens into them where they
+  lie, and the kernel reads a layer's pages out of the stack by its
+  index — so no pool is ever sliced out of the stack, stacked back or
+  copied, from the scheduler's construction to its close (PR 33).  The
+  layout is head-major because the decode kernel dictates it (PR 30):
+  one page of one head is ``[P, Dh]``, whole native tiles (one 4 KB tile
+  at P=16, Dh=128 in bf16) that a DMA moves as they lie, whatever
+  ``kvh`` is; with the heads inside the page (``[P, kvh, Dh]``) a model
+  of 2 kv heads filled a quarter of each tile.  **Physical page 0 is
+  the trash page** — never allocated, it absorbs the writes of pad
   tokens and idle decode slots so no write path needs a validity mask;
 * each live sequence holds a **page table** (one int32 row mapping its
   ``pos // P`` slots to physical pages) and charges its reserved pages
@@ -27,9 +35,10 @@ serving removed (PAPERS.md).  This module is the paged layout:
 * :func:`apply_paged` runs a token chunk against the paged cache.  The
   general path is **gather-based attention that is bit-identical to the
   contiguous path**: the projection half is ``transformer._attn_qkv``
-  (the SAME ops, shared by construction), the gathered ``kp[:, tables]``
-  view hands the unmodified ``transformer._cache_attention`` a cache of
-  the same sequence capacity, and masked slots contribute exact zeros
+  (the SAME ops, shared by construction), the gathered ``kp[layer, :,
+  tables]`` view hands the unmodified ``transformer._cache_attention``
+  a cache of the same sequence capacity, and masked slots contribute
+  exact zeros
   (softmax of ``-inf`` is exactly 0, and ``0 * v`` terms are
   accumulation-neutral), so stale page contents never perturb a single
   bit;
@@ -128,11 +137,15 @@ class PagePool:
 
     ``k_pages``/``v_pages`` are ``[n_layers, kvh, n_pages, P, Dh]``
     functional jax arrays — head-major, so a page of one head is whole
-    native tiles whatever ``kvh`` is (the module docstring says why);
-    the serving driver threads them through the prefill/step executables
-    and stores the returned (updated) arrays.
+    native tiles whatever ``kvh`` is (the module docstring says why).
     The pool object itself only manages the free list and the budget
-    accounting — page CONTENTS are owned by whoever holds the arrays.
+    accounting — page CONTENTS are owned by whoever holds the arrays,
+    and the serving executables DONATE them, so they have one holder:
+    the serving driver takes them (:meth:`take`), threads them through
+    the prefill/step executables and keeps the returned (updated)
+    arrays, the same buffers from its construction to its close.  A pool
+    that was taken from keeps its shapes, free list and accounting and
+    holds no array (``k_pages`` / ``v_pages`` / ``conv_state`` are None).
 
     Page 0 is the trash page: idle slots and pad tokens write there, so
     every scatter is unconditional.  It is excluded from the free list
@@ -160,34 +173,55 @@ class PagePool:
         self.cfg = cfg
         self.tokens_per_page = P
         self.n_pages = int(n_pages)
-        dtype = dtype or cfg.dtype
-        kvh, dh, n = cfg.n_kv_heads, cfg.head_dim, cfg.n_layers
-        shape = (n, kvh, self.n_pages, P, dh)
-        self.k_pages = jnp.zeros(shape, dtype)
-        self.v_pages = jnp.zeros(shape, dtype)
+        self.dtype = jnp.dtype(dtype or cfg.dtype)
         # the second kind of per-sequence state: a ``cca`` block's decode
         # step needs the previous position's convolution inputs, a fixed
         # ``cca.state_width`` values a layer whatever the length.  One
         # array [n_layers, slots, width] beside the pages, indexed by the
         # decode slot, threaded through the executables like the pages
-        self.conv_state = None
+        self.slots = None
         if cfg.block.attention == "cca":
             if slots is None:
                 raise ValueError(
                     "a 'cca' block keeps a convolution state per decode "
                     "slot: PagePool(..., slots=max_slots)"
                 )
-            self.conv_state = cca.init_state(cfg, slots, dtype)
+            self.slots = int(slots)
+        self.k_pages, self.v_pages, self.conv_state = self.zeros()
         # one page's HBM across all layers, k and v together — the unit
         # the budget LRU accounts
         self.page_bytes = int(
-            2 * n * P * kvh * dh * jnp.dtype(dtype).itemsize
+            2 * cfg.n_layers * P * cfg.n_kv_heads * cfg.head_dim
+            * self.dtype.itemsize
         )
         self._lock = threading.Lock()
         # LIFO free list (page 0 reserved as trash)
         self._free = list(range(self.n_pages - 1, 0, -1))
         self.allocated_total = 0  # monotonic (telemetry)
         self.freed_total = 0
+
+    def zeros(self):
+        """Arrays of the pool's shapes, all zero: ``(k_pages, v_pages,
+        conv_state)``, the last None unless the block is ``cca``."""
+        cfg = self.cfg
+        shape = (
+            cfg.n_layers, cfg.n_kv_heads, self.n_pages, self.tokens_per_page,
+            cfg.head_dim,
+        )
+        state = None
+        if self.slots is not None:
+            state = cca.init_state(cfg, self.slots, self.dtype)
+        k, v = (jnp.zeros(shape, self.dtype) for _ in range(2))
+        return k, v, state
+
+    def take(self):
+        """Hand the arrays to their one holder: ``(k_pages, v_pages,
+        conv_state)``, of which the pool then keeps no reference — a
+        second holder would pin a copy the executables' donation could
+        not reuse (and read a deleted buffer after the first dispatch)."""
+        arrays = self.k_pages, self.v_pages, self.conv_state
+        self.k_pages = self.v_pages = self.conv_state = None
+        return arrays
 
     # -- allocation ----------------------------------------------------------
 
@@ -281,10 +315,12 @@ def init_tables(batch: int, max_pages: int) -> jnp.ndarray:
 
 
 @jax.named_scope("page_write")
-def _page_write(kp, vp, k, v, positions, tables, from_zero=False):
-    """Scatter a chunk's k/v ``[B, L, kvh, Dh]`` into one layer's pages
-    ``[kvh, n_pages, P, Dh]`` at ``tables[b, pos // P]``, offset ``pos %
-    P``, of every head.  Returns the updated ``(kp, vp)``.
+def _page_write(kp, vp, k, v, positions, tables, layer, from_zero=False):
+    """Scatter a chunk's k/v ``[B, L, kvh, Dh]`` into ``layer``'s pages of
+    the stacked pools ``[n_layers, kvh, n_pages, P, Dh]`` at ``tables[b,
+    pos // P]``, offset ``pos % P``, of every head.  Returns the updated
+    ``(kp, vp)``: the same stacks, written where they lie — no layer's
+    pool is sliced out or put back (PR 33).
 
     The pool is written as windows of ``w`` rows of Dh, in the layout the
     kernel reads (a scatter windowed over (kvh, Dh) makes XLA turn the
@@ -299,7 +335,7 @@ def _page_write(kp, vp, k, v, positions, tables, from_zero=False):
     its last page padded with zeros where no query can look before a
     decode step has written there."""
     B, L, kvh, dh = k.shape
-    _, n_pages, P, _ = kp.shape
+    n_layers, _, n_pages, P, _ = kp.shape
     max_pages = tables.shape[1]
     if from_zero:
         w, n = P, -(-L // P)
@@ -319,14 +355,16 @@ def _page_write(kp, vp, k, v, positions, tables, from_zero=False):
         ),
         0,
     ).reshape(B * n)
-    heads = jnp.arange(kvh, dtype=jnp.int32)[:, None]
+    heads = layer * kvh + jnp.arange(kvh, dtype=jnp.int32)[:, None]
     at = ((heads * n_pages + dest) * (P // w) + window).reshape(kvh * B * n)
 
     def put(pages, x):
         x = jnp.pad(
             x.astype(pages.dtype), ((0, 0), (0, n * w - L), (0, 0), (0, 0))
         ).reshape(B * n, w, kvh, dh).transpose(2, 0, 1, 3)
-        return pages.reshape(kvh * n_pages * (P // w), w, dh).at[at].set(
+        return pages.reshape(
+            n_layers * kvh * n_pages * (P // w), w, dh
+        ).at[at].set(
             x.reshape(kvh * B * n, w, dh), mode="drop"
         ).reshape(pages.shape)
 
@@ -343,18 +381,18 @@ def _attn_out(bp, x, att, cfg):
     )
 
 
-def _feed_forward(bp, x, cfg, route):
+def _feed_forward(bp, x, cfg, layer, route):
     """The feed-forward half by the spec's kind.  ``route`` is None for a
-    block that routes nothing, else ``(r_prev, live, experts, layer)``: the
+    block that routes nothing, else ``(r_prev, live, experts)``: the
     router's carry, the tokens that count, and the expert weights of all
-    layers with this layer's index (``moe.stack_experts``).  Returns
-    ``(x', routed)``: ``routed`` is ``(r, counts, chosen)``, the carry for
-    the layer above, the live tokens each expert got and each token's
-    expert; None without a router."""
+    layers, read at ``layer`` (``moe.stack_experts``).  Returns ``(x',
+    routed)``: ``routed`` is ``(r, counts, chosen)``, the carry for the
+    layer above, the live tokens each expert got and each token's expert;
+    None without a router."""
     if route is None:
         x, _aux = tfm._mlp_residual(bp, x, cfg)
         return x, None
-    r_prev, live, experts, layer = route
+    r_prev, live, experts = route
     y = tfm._rms_norm(x, bp["ln2"], cfg.block.norm_eps)
     out, *routed = moe.experts_top1(bp, y, r_prev, live, cfg, experts, layer)
     return x + out, tuple(routed)
@@ -389,41 +427,43 @@ def paged_kernel_fits(cfg, P: int, B: int, L: int, dtype) -> bool:
     )
 
 
-def _paged_block(bp, x, positions, cfg, kp, vp, tables, st=None,
+def _paged_block(bp, x, positions, cfg, kp, vp, tables, layer, st=None,
                  route=None):
-    """One decoder block against one layer's page arrays.
+    """One decoder block against ``layer``'s pages of the stacked pools.
 
-    ``kp``/``vp``: [kvh, n_pages, P, Dh]; ``tables``: [B, max_pages];
+    ``kp``/``vp``: [n_layers, kvh, n_pages, P, Dh], written and read at
+    ``layer`` where they lie; ``tables``: [B, max_pages];
     ``positions``: [B, L] absolute positions (per-row frontiers).  The
     chunk's k/v scatter to ``tables[b, pos // P]`` at offset ``pos %
     P`` — table slots a sequence never reserved hold 0, so pad tokens
     and idle slots write the trash page.  A one-token chunk that fits
     (:func:`paged_kernel_fits`) then attends over its pages in place,
     through the table, up to its frontier; any other gathers the table's
-    pages into a [B, max_pages * P] contiguous view and runs the
-    UNMODIFIED ``transformer._cache_attention`` on it.  Either way
+    pages of the layer into a [B, max_pages * P] contiguous view and runs
+    the UNMODIFIED ``transformer._cache_attention`` on it.  Either way
     positions past a row's frontier have exact zero weight, so stale page
     contents (previous tenants included) never contribute a bit.
 
-    A ``cca`` block (``L`` = 1) also takes the layer's convolution state
-    ``st`` [B, width] and returns the state this position leaves; an
-    ``experts_top1`` block takes ``route`` (:func:`_feed_forward`).
-    Returns ``(x', kp', vp', st', routed)``, the last two None where the
-    spec has no such thing."""
+    A ``cca`` block (``L`` = 1) also takes the convolution state of all
+    layers ``st`` [n_layers, B, width] and leaves this position's in the
+    layer's rows; an ``experts_top1`` block takes ``route``
+    (:func:`_feed_forward`).  Returns ``(x', kp', vp', st', routed)``,
+    the last two None where the spec has no such thing."""
     B, L = x.shape[:2]
     dt = cfg.dtype
-    P = kp.shape[2]
+    P = kp.shape[3]
     cap = tables.shape[1] * P
     # scope names are metadata: a profiler session groups the device
     # operations of a step under attention / page_write / paged_kernel
     # (or page_gather, on the general path)
     with jax.named_scope("attention"):
         if cfg.block.attention == "cca":
-            q, k, v, st = cca.qkv_step(bp, x, positions, st, cfg)
+            q, k, v, row = cca.qkv_step(bp, x, positions, st[layer], cfg)
+            st = st.at[layer].set(row)
         else:
             q, k, v = tfm._attn_qkv(bp, x, positions, cfg)
         kvh, dh = k.shape[2:]
-        kp, vp = _page_write(kp, vp, k, v, positions, tables)
+        kp, vp = _page_write(kp, vp, k, v, positions, tables, layer)
         if paged_kernel_fits(cfg, P, B, L, kp.dtype):
             with jax.named_scope("paged_kernel"):
                 # an idle row (any index, its table all trash) attends
@@ -431,24 +471,26 @@ def _paged_block(bp, x, positions, cfg, kp, vp, tables, st=None,
                 # at most the capacity
                 lengths = jnp.clip(positions[:, 0] + 1, 1, cap)
                 att = paged_attention.paged_attention(
-                    q[:, 0], kp, vp, tables, lengths
+                    q[:, 0], kp, vp, tables, lengths, layer
                 )[:, None]
         else:
             with jax.named_scope("page_gather"):
-                # gather each row's pages into its contiguous cache view
+                # gather each row's pages of the layer, straight from the
+                # stack, into its contiguous cache view: the two advanced
+                # indices lead, [B, max_pages, kvh, P, Dh]
                 ck, cv = (
-                    jnp.moveaxis(pages[:, tables], 0, 3).reshape(
+                    jnp.moveaxis(pages[layer, :, tables], 2, 3).reshape(
                         B, cap, kvh, dh
                     ).astype(dt)
                     for pages in (kp, vp)
                 )
             att = tfm._cache_attention(q, ck, cv, positions)
         x = _attn_out(bp, x, att, cfg)
-    x, routed = _feed_forward(bp, x, cfg, route)
+    x, routed = _feed_forward(bp, x, cfg, layer, route)
     return x, kp, vp, st, routed
 
 
-def _prefill_block(bp, x, positions, cfg, kp, vp, tables, route=None):
+def _prefill_block(bp, x, positions, cfg, kp, vp, tables, layer, route=None):
     """:func:`_paged_block` for a chunk that STARTS its sequence
     (``positions`` count from 0): the same projections and page write,
     but the only keys such a chunk's queries may see are its own, which
@@ -464,41 +506,47 @@ def _prefill_block(bp, x, positions, cfg, kp, vp, tables, route=None):
             q, k, v, tail = cca.qkv_sequence(bp, x, positions, cfg)
         else:
             q, k, v = tfm._attn_qkv(bp, x, positions, cfg)
-        kp, vp = _page_write(kp, vp, k, v, positions, tables, from_zero=True)
+        kp, vp = _page_write(
+            kp, vp, k, v, positions, tables, layer, from_zero=True
+        )
         att = tfm._cache_attention(
             q, k.astype(kp.dtype).astype(dt), v.astype(vp.dtype).astype(dt),
             positions,
         )
         x = _attn_out(bp, x, att, cfg)
-    x, routed = _feed_forward(bp, x, cfg, route)
+    x, routed = _feed_forward(bp, x, cfg, layer, route)
     return x, kp, vp, tail, routed
 
 
 def _scan_layers(block, x, params, k_pages, v_pages, state, live, cfg):
     """The layer scan both forwards share.  ``block(bp, x, kp, vp, st,
-    route) -> (x, kp, vp, st, routed)`` is one layer; the scan slices
-    the stacked block params, the pools and the state a layer at a time,
-    carries ``x`` (and a router's ``r``), and stacks what each layer
-    returns.  For the dense block every extra is None, an empty pytree:
+    layer, route) -> (x, kp, vp, st, routed)`` is one layer.  The scan
+    slices the stacked block params a layer at a time and hands each its
+    index; the stacked pools and the state are its CARRY beside ``x``
+    (and a router's ``r``), so a layer writes its pages into the one
+    buffer every layer shares and no pool is sliced out of the stack or
+    stacked back (PR 33: as ``xs`` / ``ys`` each layer of each step copied
+    its 25-50 MB pool twice).  What each layer reports of its routing is
+    stacked.  For the dense block every extra is None, an empty pytree:
     the lowered program is the one without them."""
-    blocks, experts, r0, layers = params["blocks"], None, None, None
+    blocks, experts, r0 = params["blocks"], None, None
     if cfg.block.ffn == "experts_top1":
         blocks, experts = moe.stack_experts(blocks, cfg)
         r0 = jnp.zeros(x.shape[:2] + (cfg.block.router_hidden,), jnp.float32)
-        layers = jnp.arange(cfg.n_layers, dtype=jnp.int32)
 
-    def step(carry, layer):
-        x, r = carry
-        bp, kp, vp, st, i = layer
-        route = None if experts is None else (r, live, experts, i)
-        x, kp, vp, st, routed = block(bp, x, kp, vp, st, route)
+    def step(carry, xs):
+        x, r, kp, vp, st = carry
+        bp, layer = xs
+        route = None if experts is None else (r, live, experts)
+        x, kp, vp, st, routed = block(bp, x, kp, vp, st, layer, route)
         r, report = (None, None) if routed is None else (routed[0], routed[1:])
-        return (x, r), (kp, vp, st, report)
+        return (x, r, kp, vp, st), report
 
-    (x, _), out = jax.lax.scan(
-        step, (x, r0), (blocks, k_pages, v_pages, state, layers)
+    (x, _, k_pages, v_pages, state), routed = jax.lax.scan(
+        step, (x, r0, k_pages, v_pages, state),
+        (blocks, jnp.arange(cfg.n_layers, dtype=jnp.int32)),
     )
-    return (x,) + out
+    return x, k_pages, v_pages, state, routed
 
 
 def _routing(routed):
@@ -543,8 +591,10 @@ def _step_forward(params, tokens, tables, indices, k_pages, v_pages, cfg,
     if cfg.block.ffn == "experts_top1":
         live = jnp.broadcast_to(tables[:, :1] > 0, (B, L))
 
-    def block(bp, x, kp, vp, st, route):
-        return _paged_block(bp, x, positions, cfg, kp, vp, tables, st, route)
+    def block(bp, x, kp, vp, st, layer, route):
+        return _paged_block(
+            bp, x, positions, cfg, kp, vp, tables, layer, st, route
+        )
 
     x, kps, vps, state, routed = _scan_layers(
         block, x, params, k_pages, v_pages, state, live, cfg
@@ -595,16 +645,16 @@ def _prefill_forward(params, toks, table, last_pos, k_pages, v_pages, cfg,
     if cfg.block.ffn == "experts_top1":
         live = positions <= last_pos[:, None]
 
-    def block(bp, x, kp, vp, st, route):
+    def block(bp, x, kp, vp, st, layer, route):
         x, kp, vp, tail, routed = _prefill_block(
-            bp, x, positions, cfg, kp, vp, table, route
+            bp, x, positions, cfg, kp, vp, table, layer, route
         )
         if tail is not None:
             with jax.named_scope("attention/conv_state"):
                 last = jnp.take_along_axis(
                     tail, last_pos[:, None, None], axis=1
                 )[:, 0]
-                st = st.at[slot].set(last.astype(st.dtype))
+                st = st.at[layer, slot].set(last.astype(st.dtype))
         return x, kp, vp, st, routed
 
     x, k_pages, v_pages, state, routed = _scan_layers(
@@ -619,6 +669,15 @@ def _prefill_forward(params, toks, table, last_pos, k_pages, v_pages, cfg,
 # serving executables (the decode scheduler's two compiled dispatches)
 # ---------------------------------------------------------------------------
 
+# Both DONATE the pools (PR 33): the arrays passed in are consumed, and the
+# ones returned are the same buffers, written where they lie.  A caller
+# keeps what a call returns and never what it passed.  A ``cca`` block's
+# state (5 MB at ZAYA1's widths, against the pools' 1 GB) is carried by the
+# scan like the pools but NOT donated: the benchmark's fault test
+# (``perfbench/tests/test_correct_zaya.py``) hands back the state it
+# passed, to see that a stale one is caught.
+_DONATED = ("k_pages", "v_pages")
+
 
 def _results(tokens, k_pages, v_pages, state, stats, cfg):
     """What a serving executable returns: the dense block's three, and for
@@ -629,7 +688,9 @@ def _results(tokens, k_pages, v_pages, state, stats, cfg):
     return tokens, k_pages, v_pages, state, stats
 
 
-@functools.partial(jax.jit, static_argnames=("cfg",))
+@functools.partial(
+    jax.jit, static_argnames=("cfg",), donate_argnames=_DONATED
+)
 def paged_decode_step(params, toks, tables, indices, k_pages, v_pages, cfg,
                       state=None):
     """One greedy decode step for the whole slot batch: toks [B] ->
@@ -638,7 +699,8 @@ def paged_decode_step(params, toks, tables, indices, k_pages, v_pages, cfg,
     slots decode garbage into the trash page that nobody reads).
     Returns ``(next, k_pages', v_pages')``; a block that is not the dense
     one (``cfg.block``) also takes the pool's convolution ``state`` and
-    returns ``(..., state', stats)`` (:func:`_results`)."""
+    returns ``(..., state', stats)`` (:func:`_results`).  The pools are
+    donated: the call consumes them and returns them updated in place."""
     logits, k_pages, v_pages, state, stats = _step_forward(
         params, toks[:, None], tables, indices, k_pages, v_pages, cfg, state
     )
@@ -646,7 +708,9 @@ def paged_decode_step(params, toks, tables, indices, k_pages, v_pages, cfg,
     return _results(nxt, k_pages, v_pages, state, stats, cfg)
 
 
-@functools.partial(jax.jit, static_argnames=("cfg",))
+@functools.partial(
+    jax.jit, static_argnames=("cfg",), donate_argnames=_DONATED
+)
 def paged_prefill(params, toks, table, last_pos, k_pages, v_pages, cfg,
                   state=None, slot=None):
     """Prefill of ONE newly admitted sequence, and of nothing else: toks
@@ -663,8 +727,9 @@ def paged_prefill(params, toks, table, last_pos, k_pages, v_pages, cfg,
     contiguous ``generate`` samples from ``logits[:, -1]`` — and the
     pools; a block that is not the dense one also takes the pool's
     convolution ``state`` and the sequence's ``slot`` [1] and returns
-    ``(..., state', stats)`` as :func:`paged_decode_step` does.  One
-    executable per prompt bucket (the ladder bounds the grid)."""
+    ``(..., state', stats)`` as :func:`paged_decode_step` does, the pools
+    donated as there.  One executable per prompt bucket (the ladder
+    bounds the grid)."""
     logits, k_pages, v_pages, state, stats = _prefill_forward(
         params, toks, table, last_pos, k_pages, v_pages, cfg, state, slot
     )

@@ -13,8 +13,11 @@ over the capacity exist.
 Layout it dictates: a layer's pool is ``[kvh, n_pages, P, Dh]``, so one
 page of one head is ``[P, Dh]`` — with ``P`` a whole number of sublane
 tiles (16 rows of bf16) a run of native tiles, 4 KB at P=16/Dh=128 — and
-``pool.at[:, page]`` is one strided DMA that brings a page of EVERY kv
-head.
+``pool.at[layer, :, page]`` is one strided DMA that brings a page of
+EVERY kv head.  The kernel is handed the pools of ALL layers, stacked
+``[n_layers, kvh, n_pages, P, Dh]``, with the layer to read: the stack
+stays in HBM where it lies and no layer's pool is sliced out of it for
+the call (PR 33).
 
 Shape of the kernel: ONE program, no grid.  ``q`` [B, kvh, g, Dh] and the
 output sit whole in VMEM (a decode step's are a few hundred KB), tables
@@ -74,6 +77,7 @@ def vmem_bytes(B: int, h: int, kvh: int, dh: int, P: int, dtype) -> int:
 
 
 def _paged_kernel(
+    layer_ref,
     lengths_ref,
     tables_ref,
     q_ref,
@@ -94,6 +98,7 @@ def _paged_kernel(
     # loop bounds and counters are int32 by hand: with x64 on, a Python
     # bound makes an int64 index, which Mosaic has no use for
     zero, ring_ = jnp.int32(0), jnp.int32(ring)
+    layer = layer_ref[0]
 
     def n_blocks(b):
         return jax.lax.div(lengths_ref[b] + (T - 1), jnp.int32(T))
@@ -114,7 +119,8 @@ def _paged_kernel(
         for x, (hbm, buf) in enumerate(kv):
             for j, page in enumerate(pages):
                 pltpu.make_async_copy(
-                    hbm.at[:, page], buf.at[slot, :, j], sems.at[x, slot]
+                    hbm.at[layer, :, page], buf.at[slot, :, j],
+                    sems.at[x, slot],
                 ).start()
         last = i + 1 >= n_blocks(b)
         return (
@@ -128,7 +134,7 @@ def _paged_kernel(
         hbm, buf = kv[x]
         for j in range(ppb):
             pltpu.make_async_copy(
-                hbm.at[:, 0], buf.at[slot, :, j], sems.at[x, slot]
+                hbm.at[0, :, 0], buf.at[slot, :, j], sems.at[x, slot]
             ).wait()
 
     def row(b, carry):
@@ -193,21 +199,25 @@ def paged_attention(
     v_pages,
     tables,
     lengths,
+    layer,
     interpret: Optional[bool] = None,
 ):
     """softmax(q K^T / sqrt(d)) V of one query position a row over the
-    keys the row holds in its pages.
+    keys the row holds in its pages of ``layer``.
 
-    q: [B, h, Dh]; k_pages/v_pages: one layer's pools [kvh, n_pages, P,
-    Dh] with ``h % kvh == 0``; tables: int32 [B, max_pages], physical
-    page of each of the row's page slots; lengths: int32 [B], keys the
-    row attends (positions ``0 .. length - 1``), AT LEAST 1 — an idle
-    row reads its table's first page like any other.  Returns [B, h, Dh]
-    in ``q.dtype``.  Keys past a row's frontier — the tail of its last
-    page, pages it never reserved — get exact zero weight; they must be
-    finite, as zero times them is added."""
+    q: [B, h, Dh]; k_pages/v_pages: the stacked pools [n_layers, kvh,
+    n_pages, P, Dh] with ``h % kvh == 0``, of which the kernel reads
+    ``layer`` (an int32 scalar, traced or not) and nothing else — a
+    caller with one layer's pool passes ``pool[None]`` and 0; tables:
+    int32 [B, max_pages], physical page of each of the row's page slots;
+    lengths: int32 [B], keys the row attends (positions ``0 .. length -
+    1``), AT LEAST 1 — an idle row reads its table's first page like any
+    other.  Returns [B, h, Dh] in ``q.dtype``.  Keys past a row's
+    frontier — the tail of its last page, pages it never reserved — get
+    exact zero weight; they must be finite, as zero times them is
+    added."""
     B, h, dh = q.shape
-    kvh, _, P, _ = k_pages.shape
+    _, kvh, _, P, _ = k_pages.shape
     g = h // kvh
     max_pages = tables.shape[1]
     buf = pltpu.VMEM((_RING, kvh, pages_per_block(P), P, dh), k_pages.dtype)
@@ -220,13 +230,14 @@ def paged_attention(
             scale=1.0 / np.sqrt(dh),
             max_pages=max_pages,
         ),
-        in_specs=[smem, smem, vmem, hbm, hbm],
+        in_specs=[smem, smem, smem, vmem, hbm, hbm],
         out_specs=vmem,
         out_shape=jax.ShapeDtypeStruct((B, kvh, g, dh), q.dtype),
         scratch_shapes=[buf, buf, pltpu.SemaphoreType.DMA((2, _RING))],
         interpret=_resolve_interpret(interpret),
         name=KERNEL_NAME,
     )(
+        jnp.asarray(layer, jnp.int32).reshape(1),
         lengths.astype(jnp.int32),
         tables.astype(jnp.int32).reshape(-1),
         q.reshape(B, kvh, g, dh),

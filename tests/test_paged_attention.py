@@ -10,6 +10,9 @@ The contract under test:
 * **exact zero weight** — what lies past a frontier (the tail of the last
   page, pages never reserved, a previous tenant's leftovers) changes no
   output bit, however large;
+* **one layer of the stack** — the kernel is handed the pools of all
+  layers and an index (PR 33) and reads that layer alone: the other layers
+  hold large finite values in every case here;
 * **it engages by what it can observe** — ``kv_pager.paged_kernel_fits``
   decides at trace time; the step it serves gives the gather path's greedy
   tokens; ``decode_kernel_steps`` says how often it ran.
@@ -50,15 +53,24 @@ def _gathered(q, kp, vp, tables, lengths):
     return tfm._cache_attention(q[:, None], ck, cv, (lengths - 1)[:, None])[:, 0]
 
 
-def _problem(kvh, dtype, lengths, seed=0, shuffled=True):
-    """Pools, queries and tables for rows of ``lengths`` keys; a length of
-    None is an idle slot (its table all trash, index 0)."""
+LAYERS = 3
+POISON = 1e30  # large and finite, in bf16 as in f32
+
+
+def _problem(kvh, dtype, lengths, seed=0, shuffled=True, layer=1):
+    """Stacked pools, queries and tables for rows of ``lengths`` keys; a
+    length of None is an idle slot (its table all trash, index 0).  The
+    pools are stacks of ``LAYERS`` layers of which ``layer`` holds the
+    problem and the others poison: +-1e30 everywhere."""
     rng = np.random.default_rng(seed)
     B = len(lengths)
     n_pages = B * MAX_PAGES + 1
     kp, vp = (
-        jnp.asarray(rng.standard_normal((kvh, n_pages, P, DH)), dtype)
-        for _ in range(2)
+        jnp.full((LAYERS, kvh, n_pages, P, DH), sign * POISON, dtype)
+        .at[layer].set(
+            jnp.asarray(rng.standard_normal((kvh, n_pages, P, DH)), dtype)
+        )
+        for sign in (1, -1)
     )
     q = jnp.asarray(rng.standard_normal((B, kvh * G, DH)), dtype)
     pages = np.arange(1, n_pages)
@@ -87,15 +99,21 @@ CASES = {
     "idle_slot_on_the_trash_page": [40, None, 9, None, 130],
 }
 _kernel = jax.jit(pa.paged_attention)
+# the layer is traced: one lowering serves the three
+WHICH_LAYER = {"first_layer": 0, "middle_layer": 1, "last_layer": LAYERS - 1}
 
 
+@pytest.mark.parametrize("layer", list(WHICH_LAYER))
 @pytest.mark.parametrize("case", list(CASES))
 @pytest.mark.parametrize("dtype", ["bfloat16", "float32"])
 @pytest.mark.parametrize("kvh", [2, 8])
-def test_kernel_matches_cache_attention(kvh, dtype, case):
-    q, kp, vp, tables, lengths = _problem(kvh, jnp.dtype(dtype), CASES[case])
-    got = _kernel(q, kp, vp, tables, lengths)
-    want = _gathered(q, kp, vp, tables, lengths)
+def test_kernel_matches_cache_attention(kvh, dtype, case, layer):
+    layer = WHICH_LAYER[layer]
+    q, kp, vp, tables, lengths = _problem(
+        kvh, jnp.dtype(dtype), CASES[case], layer=layer
+    )
+    got = _kernel(q, kp, vp, tables, lengths, layer)
+    want = _gathered(q, kp[layer], vp[layer], tables, lengths)
     assert got.shape == want.shape and got.dtype == want.dtype
     assert bool(jnp.isfinite(got.astype(jnp.float32)).all())
     np.testing.assert_allclose(
@@ -115,14 +133,15 @@ def test_kernel_table_order_is_the_sequence_order(dtype):
     flat = np.asarray(tables).reshape(-1)
     order = np.concatenate([[0], flat[flat > 0]])
     kp2, vp2 = (
-        jnp.zeros_like(x).at[:, : len(order)].set(x[:, order]) for x in (kp, vp)
+        jnp.zeros_like(x).at[:, :, : len(order)].set(x[:, :, order])
+        for x in (kp, vp)
     )
     renumbered = np.zeros_like(np.asarray(tables)).reshape(-1)
     renumbered[flat > 0] = np.arange(1, len(order))
     tables2 = jnp.asarray(renumbered.reshape(tables.shape))
     assert not np.array_equal(np.asarray(tables), np.asarray(tables2))
-    got = _kernel(q, kp, vp, tables, lens)
-    same = _kernel(q, kp2, vp2, tables2, lens)
+    got = _kernel(q, kp, vp, tables, lens, 1)
+    same = _kernel(q, kp2, vp2, tables2, lens, 1)
     assert np.array_equal(np.asarray(got), np.asarray(same))
 
 
@@ -132,16 +151,17 @@ def test_kernel_gives_what_it_must_not_see_exact_zero_weight(dtype):
     filled with large finite values: no output bit moves."""
     lengths = [129, 1, 77, None, 16]
     q, kp, vp, tables, lens = _problem(2, jnp.dtype(dtype), lengths)
-    clean = _kernel(q, kp, vp, tables, lens)
-    held = np.zeros(kp.shape[1:3], bool)  # [n_pages, P]: keys some row holds
+    clean = _kernel(q, kp, vp, tables, lens, 1)
+    held = np.zeros(kp.shape[2:4], bool)  # [n_pages, P]: keys some row holds
     for row, n in zip(np.asarray(tables), lengths):
         for pos in range(n or 0):
             held[row[pos // P], pos % P] = True
     assert 0 < held.sum() == sum(n or 0 for n in lengths)
-    poison = jnp.asarray(~held)[None, :, :, None]
-    big = jnp.asarray(1e30, kp.dtype)
+    poison = jnp.asarray(~held)[None, None, :, :, None]
+    big = jnp.asarray(POISON, kp.dtype)
     dirty = _kernel(
-        q, jnp.where(poison, big, kp), jnp.where(poison, -big, vp), tables, lens
+        q, jnp.where(poison, big, kp), jnp.where(poison, -big, vp), tables,
+        lens, 1,
     )
     live = [b for b, n in enumerate(lengths) if n is not None]
     assert np.array_equal(np.asarray(clean)[live], np.asarray(dirty)[live])

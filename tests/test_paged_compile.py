@@ -1,5 +1,6 @@
-"""The decode step's Pallas kernel, compiled at the benchmark's real widths
-for a TPU v5e that is described and not attached (PR 30).
+"""The decode step's Pallas kernel and both serving executables, compiled at
+the benchmark's real widths for a TPU v5e that is described and not
+attached (PR 30; PR 33: the pools stay where they lie).
 
 Interpret mode (``tests/test_paged_attention.py``) checks the kernel's
 arithmetic; it cannot see a slice that is not aligned to the tiling, a
@@ -14,6 +15,7 @@ this one file.
 """
 
 import json
+import math
 import os
 import re
 import sys
@@ -133,11 +135,13 @@ def test_kernel_compiles_at_real_widths(config, one_chip, compiled_not_interpret
     )
     compiled = jax.jit(pa.paged_attention).lower(
         shape(toks.shape[0], cfg.n_heads, cfg.head_dim),
-        shape(*kp.shape[1:]), shape(*kp.shape[1:]), tables, indices,
+        shape(*kp.shape), shape(*kp.shape), tables, indices,
+        shape(dt=jnp.int32),
     ).compile()
     text = compiled.as_text()
     assert "tpu_custom_call" in text and pa.KERNEL_NAME in text
-    # q and the output whole in VMEM: nothing else is asked of the device
+    # q and the output whole in VMEM, the stacks read in HBM at the layer
+    # asked for: nothing else is asked of the device
     assert compiled.memory_analysis().temp_size_in_bytes == 0
 
 
@@ -157,6 +161,7 @@ def test_decode_step_compiles_with_the_kernel_and_no_gather(
     assert f"bf16[{slots * max_pages},{P}," not in text
     assert f"[{slots},{max_pages * P},{cfg.n_kv_heads},{dh}]" not in text
     _pool_keeps_its_layout(text, args[4].shape)
+    _pools_stay_where_they_lie(compiled, args[4])
     mem = compiled.memory_analysis()
     assert (
         mem.argument_size_in_bytes + mem.output_size_in_bytes
@@ -164,14 +169,56 @@ def test_decode_step_compiles_with_the_kernel_and_no_gather(
     )
 
 
+# `%name = bf16[8,8,1537,16,128]{4,3,2,1,0:T(8,128)(2,1)} opcode(...)`,
+# in the entry computation, a loop's body or a fusion's
+_INSTRUCTION = re.compile(
+    r"^\s*(?:ROOT )?%?[\w.\-]+ = (\w+)\[([0-9,]*)\]\{([0-9,]*)\S* ([\w\-]+)\("
+)
+
+
+def _pool_values(text, pool_shape):
+    """``(opcode, layout, line)`` of every instruction whose result holds
+    the stack's or one layer's pool, in whatever shape: the stack, a layer
+    of it, or either flattened to windows for the page write.  (A pool has
+    ``n_pages`` = slots x pages + 1 among its factors, which no weight
+    has.)"""
+    sizes = {math.prod(pool_shape), math.prod(pool_shape[1:])}
+    for line in text.splitlines():
+        m = _INSTRUCTION.match(line)
+        if m and m.group(2):
+            dims = [int(d) for d in m.group(2).split(",")]
+            if math.prod(dims) in sizes:
+                yield m.group(4), m.group(3), line.strip()
+
+
 def _pool_keeps_its_layout(text, pool_shape):
-    """Every value of a layer's pool shape in the compiled program lies
-    row-major, heads outermost: a page write windowed over (kvh, Dh) made
-    XLA turn the whole pool head-minor (``{3,0,2,1:T(2,128)}``) and back
-    around every write, 18% of a traced window (PERF.md §6, PR 30)."""
-    dims = ",".join(str(d) for d in pool_shape[1:])
-    layouts = set(re.findall(r"\[(?:1,)?" + dims + r"\]\{([0-9,]+)", text))
-    assert layouts and layouts <= {"3,2,1,0", "4,3,2,1,0"}, layouts
+    """Every value that holds the stacked pools (or a layer's) in the
+    compiled program lies row-major, layers then heads outermost: a page
+    write windowed over (kvh, Dh) made XLA turn the whole pool head-minor
+    (``{3,0,2,1:T(2,128)}``) and back around every write, 18% of a traced
+    window (PERF.md §6, PR 30)."""
+    layouts = {layout for _, layout, _ in _pool_values(text, pool_shape)}
+    assert layouts and layouts <= {"4,3,2,1,0", "2,1,0", "1,0"}, layouts
+
+
+def _pools_stay_where_they_lie(compiled, pool):
+    """The compiled executable moves no pool (PR 33): no ``dynamic-slice``,
+    ``dynamic-update-slice`` or ``copy`` gives a layer's pool or the stack
+    (as the scan's ``xs`` / ``ys`` every layer of every step sliced its
+    pool out and wrote it back, a sixth of both decode windows), the only
+    fusions that give one are the page write's scatters, and both stacks
+    are aliased to the arguments that were donated, with no buffer of a
+    pool's size among the temporaries."""
+    moved = [
+        line[:200] for op, _, line in _pool_values(compiled.as_text(), pool.shape)
+        if op in ("copy", "dynamic-slice", "dynamic-update-slice")
+        or (op == "fusion" and "page_write/scatter" not in line)
+    ]
+    assert not moved, moved
+    stack = math.prod(pool.shape) * pool.dtype.itemsize
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes >= 2 * stack
+    assert mem.temp_size_in_bytes < stack // pool.shape[0]
 
 
 @pytest.mark.parametrize("config", list(CELLS))
@@ -179,17 +226,20 @@ def test_prefill_writes_whole_pages_and_keeps_the_layout(
     config, one_chip, compiled_not_interpreted
 ):
     """The 256 bucket's prefill: the page write scatters pages of a head,
-    ``[kvh * n_pages, P, Dh]`` windows, into a pool left as it lies."""
+    ``[n_layers * kvh * n_pages, P, Dh]`` windows, into the stack left as
+    it lies."""
     cfg, (weights, _, tables, _, kp, vp), kwargs = _cell(config, one_chip)
     i32 = lambda *shape: jax.ShapeDtypeStruct(  # noqa: E731
         shape, jnp.int32, sharding=one_chip
     )
     if kwargs:
         kwargs["slot"] = i32(1)
-    text = kv_pager.paged_prefill.lower(
+    compiled = kv_pager.paged_prefill.lower(
         weights, i32(1, 256), i32(1, tables.shape[1]), i32(1), kp, vp, cfg,
         **kwargs,
-    ).compile().as_text()
+    ).compile()
+    text = compiled.as_text()
     _pool_keeps_its_layout(text, kp.shape)
-    _, kvh, n_pages, P, dh = kp.shape
-    assert f"[{kvh * n_pages},{P},{dh}]" in text
+    _pools_stay_where_they_lie(compiled, kp)
+    n, kvh, n_pages, P, dh = kp.shape
+    assert f"[{n * kvh * n_pages},{P},{dh}]" in text

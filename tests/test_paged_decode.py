@@ -175,6 +175,9 @@ def test_paged_prefill_matches_apply_paged_and_spares_neighbors(params, case):
     logits, kp_ref, vp_ref = kv_pager.apply_paged(
         cp, toks, row, jnp.zeros((1,), jnp.int32), kp0, vp0, CFG
     )
+    # the executable donates the pools it is given: what they held is
+    # read before the call, what they hold after it from what it returns
+    kp_before, vp_before = np.asarray(kp0), np.asarray(vp0)
     tok0, kp, vp = kv_pager.paged_prefill(
         cp, toks, row, jnp.asarray([lp - 1], jnp.int32), kp0, vp0, CFG
     )
@@ -192,8 +195,8 @@ def test_paged_prefill_matches_apply_paged_and_spares_neighbors(params, case):
         assert np.array_equal(got[0], want[0])
         # above it they differ by the softmax's reduction extent only
         np.testing.assert_allclose(got[1:], want[1:], rtol=2e-5, atol=2e-6)
-    for got, before in ((kp, kp0), (vp, vp0)):
-        got, before = np.asarray(got), np.asarray(before)
+    for got, before in ((kp, kp_before), (vp, vp_before)):
+        got = np.asarray(got)
         assert np.array_equal(
             got[:, :, neighbor_pages], before[:, :, neighbor_pages]
         )
@@ -217,10 +220,11 @@ def _eqn_avals(jaxpr):
 def test_paged_prefill_scales_with_the_bucket_alone(params):
     """Shape guard: traced at two capacities and two slot counts, same
     bucket, every intermediate of ``paged_prefill`` other than the page
-    pools (whole or a layer's) and the table row has the same shape in
-    all four, and the only vocabulary-wide ones are one position's
-    ``[1, vocabulary]`` — neither the slot batch, nor the gathered
-    capacity, nor every position's logits can come back unseen."""
+    pools (the stacks: no layer's is sliced out) and the table row has
+    the same shape in all four, and the only vocabulary-wide ones are one
+    position's ``[1, vocabulary]`` — neither the slot batch, nor the
+    gathered capacity, nor every position's logits can come back
+    unseen."""
     bucket = 32
     kvh, dh, n = CFG.n_kv_heads, CFG.head_dim, CFG.n_layers
     seen = []
@@ -236,10 +240,10 @@ def test_paged_prefill_scales_with_the_bucket_alone(params):
                 lambda *a: kv_pager.paged_prefill(*a, CFG)
             )(params, i32(1, bucket), i32(1, max_pages), i32(1), pools, pools)
             exempt = {
-                (n, kvh, n_pages, PAGE, dh), (kvh, n_pages, PAGE, dh),
-                # a layer's pool as pages of a head, which a prefill's page
-                # write scatters whole
-                (kvh * n_pages, PAGE, dh), (1, max_pages),
+                (n, kvh, n_pages, PAGE, dh),
+                # the stacked pool as pages of a head, which a prefill's
+                # page write scatters whole
+                (n * kvh * n_pages, PAGE, dh), (1, max_pages),
             }
             avals = [
                 a for a in _eqn_avals(closed.jaxpr)
@@ -625,6 +629,7 @@ def test_scheduler_chaos_transients_bit_identical(params, monkeypatch):
         params, CFG, max_slots=4, tokens_per_page=PAGE, max_seq=CAP
     )
     try:
+        first_pools = (sched._kp, sched._vp)
         refs = [_reference(params, p, mn, cap=sched.cap) for p, mn in jobs]
         results = [None] * len(jobs)
         errs = []
@@ -652,6 +657,71 @@ def test_scheduler_chaos_transients_bit_identical(params, monkeypatch):
         for i in range(len(jobs)):
             assert results[i] == refs[i], f"stream {i} diverged under chaos"
         assert sched.snapshot()["pages_used"] == 0
+        # the retried dispatches ran on DONATED pools: the transient fires
+        # before the executable is called, so a retry finds them whole
+        assert all(a.is_deleted() for a in first_pools)
+        assert not sched._kp.is_deleted() and not sched._vp.is_deleted()
+    finally:
+        sched.close()
+
+
+def test_scheduler_takes_the_pool_arrays_and_the_executables_donate_them(
+    params,
+):
+    """Ownership (PR 33): a scheduler built on a pool leaves the pool
+    holding no array — shapes, free list and accounting stay — and every
+    dispatch consumes the pools it is given and hands back the one pair
+    there is."""
+    sched = DecodeScheduler(
+        params, CFG, max_slots=2, tokens_per_page=PAGE, max_seq=CAP
+    )
+    try:
+        pool = sched.pool
+        assert pool.k_pages is None and pool.v_pages is None
+        assert pool.conv_state is None
+        assert pool.free_count() == pool.capacity == 2 * (CAP // PAGE)
+        first = (sched._kp, sched._vp)
+        shape = (CFG.n_layers, CFG.n_kv_heads, pool.n_pages, PAGE, CFG.head_dim)
+        assert {a.shape for a in first} == {shape}
+        ((p, mn),) = _prompts(((5, 4),), seed=3)
+        assert sched.submit(p, mn, timeout_s=120) == _reference(
+            params, p, mn, cap=sched.cap
+        )
+        assert all(a.is_deleted() for a in first)
+        assert not sched._kp.is_deleted() and sched._kp.shape == shape
+        assert not sched._vp.is_deleted() and sched._vp.shape == shape
+        assert pool.k_pages is None and pool.free_count() == pool.capacity
+    finally:
+        sched.close()
+
+
+def test_scheduler_never_recalls_a_dispatch_that_started(params, monkeypatch):
+    """A failure AFTER the executable was called is not retried, whatever
+    it is — the call consumed the pools it was given — and fails the
+    waiters; the scheduler then serves the next request on fresh pools."""
+    from tensorframes_tpu import faults
+
+    sound = kv_pager.paged_decode_step
+    calls = []
+
+    def consumed(*args):
+        calls.append(sound(*args))
+        raise faults.InjectedTransient("UNAVAILABLE: after the call started")
+
+    ((p, mn),) = _prompts(((6, 4),), seed=5)
+    sched = DecodeScheduler(
+        params, CFG, max_slots=2, tokens_per_page=PAGE, max_seq=CAP
+    )
+    try:
+        monkeypatch.setattr(kv_pager, "paged_decode_step", consumed)
+        with pytest.raises(faults.InjectedTransient):
+            sched.submit(p, mn, timeout_s=120)
+        assert len(calls) == 1
+        assert sched.snapshot()["pages_used"] == 0
+        monkeypatch.setattr(kv_pager, "paged_decode_step", sound)
+        assert sched.submit(p, mn, timeout_s=120) == _reference(
+            params, p, mn, cap=sched.cap
+        )
     finally:
         sched.close()
 

@@ -367,6 +367,26 @@ def test_moe_counters_ride_the_dispatch_and_a_dense_model_bumps_none(weights):
     assert d["decode_steps"] == 4 and all(d[k] == 0 for k in keys)
 
 
+def test_scheduler_takes_the_convolution_state_with_the_pools(weights):
+    """Ownership (PR 33): the scheduler holds the pools AND the convolution state, the pool none of
+    them; both executables donate the pools and hand the state on."""
+    sched = DecodeScheduler(weights, CFG, max_slots=2, tokens_per_page=PAGE, max_seq=CAP)
+    try:
+        pool = sched.pool
+        assert pool.k_pages is None and pool.v_pages is None and pool.conv_state is None
+        first = (sched._kp, sched._vp, sched._state)
+        assert first[2].shape == (M["num_hidden_layers"], 2, cca.state_width(CFG))
+        assert float(jnp.abs(first[2]).max()) == 0.0
+        assert len(sched.submit(_tokens(6, 42), 3, timeout_s=120)) == 3
+        assert first[0].is_deleted() and first[1].is_deleted()
+        held = (sched._kp, sched._vp, sched._state)
+        assert [a.shape for a in held] == [a.shape for a in first]
+        assert not any(a.is_deleted() for a in held)
+        assert float(jnp.abs(held[2]).max()) > 0.0  # the admitted slot's rows were written
+    finally:
+        sched.close()
+
+
 def test_cca_pool_needs_its_slot_count():
     with pytest.raises(ValueError):
         kv_pager.PagePool(CFG, 9, tokens_per_page=PAGE)

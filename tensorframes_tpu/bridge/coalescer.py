@@ -1072,8 +1072,9 @@ class _PagedSeq:
         self.error: Optional[BaseException] = None
         self.abandoned = False
         # with the scheduler's ``routing_trace`` on: the experts the timed
-        # path chose for this sequence, columns int32 [n_layers, positions
-        # fed by one dispatch], which retirement joins and hands over
+        # path chose for this sequence, columns int32 [expert layers,
+        # positions fed by one dispatch] (a top-k router's: [.., k]), which
+        # retirement joins and hands over
         self.routing: List[np.ndarray] = []
 
     def timing(self) -> Dict[str, float]:
@@ -1102,7 +1103,7 @@ _DECODE_TIME_KEYS = (
 # order of ``kv_pager._routing``'s ``stats``
 _MOE_KEYS = (
     "moe_route_calls", "moe_routed_tokens", "moe_busiest_expert_tokens",
-    "moe_experts_touched",
+    "moe_experts_touched", "moe_picked_pairs",
 )
 
 
@@ -1216,9 +1217,10 @@ class DecodeScheduler:
             cfg, P, self.max_slots, 1, self._kp.dtype
         ))
         # ``routing_trace`` > 0 keeps, for that many retired requests, the
-        # expert every fed position chose in every layer (``routing_of``):
-        # what a reference needs to follow the served path, since top-1
-        # routing flips on rounding.  Off, the choices are read back with
+        # expert (a top-k router's k) every fed position chose in every
+        # expert layer (``routing_of``): what a reference needs to follow
+        # the served path, since routing flips on rounding.  Off, the
+        # choices are read back with
         # the tokens all the same (a few KB) and dropped
         self._routing_keep = int(routing_trace)
         self._routing_done: "collections.OrderedDict[bytes, np.ndarray]" = (
@@ -1537,8 +1539,9 @@ class DecodeScheduler:
 
     def routing_of(self, prompt) -> Optional[np.ndarray]:
         """The experts the served path chose for a retired request with
-        this prompt: int32 [n_layers, prompt + emitted - 1] (the last
-        token was never fed), or None (``routing_trace`` off, a model
+        this prompt: int32 [expert layers, prompt + emitted - 1] (the
+        last token was never fed; a top-k router's picks add a last axis
+        of k), or None (``routing_trace`` off, a model
         without experts, or the request fell out of the window kept)."""
         key = np.asarray(prompt, np.int32).tobytes()
         with self._lock:
@@ -1664,6 +1667,9 @@ class DecodeScheduler:
                     with span("decode.step.emit", _DECODE_TRACK):
                         with self._cv:
                             n_tok = len(self._active)
+                            # what the step attended over: every live
+                            # slot's tokens, the one it fed among them
+                            held = int(self._indices.sum()) + n_tok
                             for slot, req in list(self._active.items()):
                                 self._indices[slot] += 1
                                 if keep:
@@ -1685,6 +1691,7 @@ class DecodeScheduler:
                 tally["decode_steps"] += 1
                 tally["decode_kernel_steps"] += self._kernel_step
                 tally["decode_tokens"] += n_tok
+                tally["decode_tokens_held"] += held
                 tally["decode_step_wait_ns"] += sp_w.ns
                 self._flush_tally()
         except BaseException as e:  # noqa: BLE001 — fail every waiter

@@ -50,6 +50,15 @@ serving removed (PAPERS.md).  This module is the paged layout:
   its capacity.  Same mathematics (f32 scores, softmax and accumulation;
   exact zero weight past the frontier), another order of summation: it
   agrees with the gather path to rounding, not bit for bit;
+* a latent block (``BlockSpec(attention="mla")``, ``models/mla.py``) has
+  ONE pool where the others have a pair: ``[n_layers, 1, n_pages, P,
+  row_width]``, a token's normed latent and rotated key part as one row.
+  It travels where the K pool does (``v_pages`` is None), is written by
+  the same :func:`_page_write`, donated and carried by the scan alike; a
+  decode step gathers the table's pages as rows and attends over them in
+  the latent space, a prefill attends over its own rows expanded by head
+  (:func:`_latent_attention`).  The kernel does not read such a pool
+  (:func:`paged_kernel_fits` refuses the block);
 * :func:`paged_prefill` is the serving prefill — ONE admitted prompt
   and nothing else.  A prefill starts at position 0, so the only keys
   its queries may see are the chunk's own: it writes them to the pages
@@ -80,7 +89,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from . import cca, moe
+from . import cca, mla, moe
 from . import transformer as tfm
 from .. import observability
 from ..envutil import env_int as _env_int
@@ -188,11 +197,13 @@ class PagePool:
                 )
             self.slots = int(slots)
         self.k_pages, self.v_pages, self.conv_state = self.zeros()
-        # one page's HBM across all layers, k and v together — the unit
-        # the budget LRU accounts
-        self.page_bytes = int(
-            2 * cfg.n_layers * P * cfg.n_kv_heads * cfg.head_dim
-            * self.dtype.itemsize
+        # one page's HBM across all layers, of every pool there is (K and
+        # V together, or the one latent pool) — the unit the budget LRU
+        # accounts, read off the pools' own shapes
+        self.page_bytes = sum(
+            pages.size // self.n_pages * self.dtype.itemsize
+            for pages in (self.k_pages, self.v_pages)
+            if pages is not None
         )
         self._lock = threading.Lock()
         # LIFO free list (page 0 reserved as trash)
@@ -202,16 +213,25 @@ class PagePool:
 
     def zeros(self):
         """Arrays of the pool's shapes, all zero: ``(k_pages, v_pages,
-        conv_state)``, the last None unless the block is ``cca``."""
+        conv_state)``, the last None unless the block is ``cca``.  A
+        latent block (``mla``) has ONE pool, a token's row of
+        ``mla.row_width`` values laid out as one head; it travels where
+        the K pool does, and ``v_pages`` is None."""
         cfg = self.cfg
-        shape = (
-            cfg.n_layers, cfg.n_kv_heads, self.n_pages, self.tokens_per_page,
-            cfg.head_dim,
-        )
+        if cfg.block.attention == "mla":
+            heads, widths = 1, (mla.row_width(cfg), None)
+        else:
+            heads, widths = cfg.n_kv_heads, (cfg.head_dim,) * 2
         state = None
         if self.slots is not None:
             state = cca.init_state(cfg, self.slots, self.dtype)
-        k, v = (jnp.zeros(shape, self.dtype) for _ in range(2))
+        k, v = (
+            None if w is None else jnp.zeros(
+                (cfg.n_layers, heads, self.n_pages, self.tokens_per_page, w),
+                self.dtype,
+            )
+            for w in widths
+        )
         return k, v, state
 
     def take(self):
@@ -320,7 +340,8 @@ def _page_write(kp, vp, k, v, positions, tables, layer, from_zero=False):
     the stacked pools ``[n_layers, kvh, n_pages, P, Dh]`` at ``tables[b,
     pos // P]``, offset ``pos % P``, of every head.  Returns the updated
     ``(kp, vp)``: the same stacks, written where they lie — no layer's
-    pool is sliced out or put back (PR 33).
+    pool is sliced out or put back (PR 33).  A latent block's rows go the
+    same way as the one pool there is (``vp`` and ``v`` None).
 
     The pool is written as windows of ``w`` rows of Dh, in the layout the
     kernel reads (a scatter windowed over (kvh, Dh) makes XLA turn the
@@ -334,7 +355,7 @@ def _page_write(kp, vp, k, v, positions, tables, layer, from_zero=False):
     P, a 4 KB tile: 8 x 64 windows for a 1,024 bucket, not 8 x 1,024),
     its last page padded with zeros where no query can look before a
     decode step has written there."""
-    B, L, kvh, dh = k.shape
+    B, L, kvh, _ = k.shape
     n_layers, _, n_pages, P, _ = kp.shape
     max_pages = tables.shape[1]
     if from_zero:
@@ -359,6 +380,9 @@ def _page_write(kp, vp, k, v, positions, tables, layer, from_zero=False):
     at = ((heads * n_pages + dest) * (P // w) + window).reshape(kvh * B * n)
 
     def put(pages, x):
+        if pages is None:  # a latent block's one pool travels as ``kp``
+            return None
+        dh = x.shape[-1]
         x = jnp.pad(
             x.astype(pages.dtype), ((0, 0), (0, n * w - L), (0, 0), (0, 0))
         ).reshape(B * n, w, kvh, dh).transpose(2, 0, 1, 3)
@@ -373,9 +397,9 @@ def _page_write(kp, vp, k, v, positions, tables, layer, from_zero=False):
 
 def _attn_out(bp, x, att, cfg):
     """x + Wo(att): the residual half both paged blocks end their
-    attention with.  att: [B, L, h, Dh]."""
+    attention with.  att: [B, L, h, Dh], or the heads joined already."""
     B, L = att.shape[:2]
-    att = att.reshape(B, L, cfg.n_heads * cfg.head_dim)
+    att = att.reshape(B, L, -1)
     return x + tfm.shard(
         att @ tfm.weight(bp["wo"], cfg.dtype), ("dp", "ep"), "sp", None
     )
@@ -383,17 +407,23 @@ def _attn_out(bp, x, att, cfg):
 
 def _feed_forward(bp, x, cfg, layer, route):
     """The feed-forward half by the spec's kind.  ``route`` is None for a
-    block that routes nothing, else ``(r_prev, live, experts)``: the
-    router's carry, the tokens that count, and the expert weights of all
-    layers, read at ``layer`` (``moe.stack_experts``).  Returns ``(x',
-    routed)``: ``routed`` is ``(r, counts, chosen)``, the carry for the
-    layer above, the live tokens each expert got and each token's expert;
-    None without a router."""
+    layer that routes nothing (the dense block's, and the leading dense
+    layers of any), else ``(r_prev, live, experts)``: the router's carry
+    (None for a router that keeps none), the tokens that count, and the
+    expert weights of all expert layers (``moe.stack_experts``), read at
+    ``layer`` less the dense layers before it.  Returns ``(x', routed)``:
+    ``routed`` is ``(r, counts, chosen)``, the carry for the layer above,
+    the live tokens (or pairs) each held expert got and each token's
+    expert (or ``k`` of them); None without a router."""
     if route is None:
         x, _aux = tfm._mlp_residual(bp, x, cfg)
         return x, None
     r_prev, live, experts = route
     y = tfm._rms_norm(x, bp["ln2"], cfg.block.norm_eps)
+    layer = layer - cfg.block.dense_layers if cfg.block.dense_layers else layer
+    if cfg.block.ffn == "experts_topk":
+        out, *routed = moe.experts_topk(bp, y, live, cfg, experts, layer)
+        return x + out, (None, *routed)
     out, *routed = moe.experts_top1(bp, y, r_prev, live, cfg, experts, layer)
     return x + out, tuple(routed)
 
@@ -408,8 +438,10 @@ def paged_kernel_fits(cfg, P: int, B: int, L: int, dtype) -> bool:
     pools in the compute dtype, its buffers within VMEM (query heads
     come in whole groups by the configuration's own check) — and no mesh
     axis left to partition over, which a Mosaic kernel cannot be
-    (``flash._per_shard``; the scheduler runs on one device).  Whatever
-    it refuses takes the gather path."""
+    (``flash._per_shard``; the scheduler runs on one device).  It reads a
+    K and a V pool by head: a latent block's one pool, whose values are
+    its keys' first part, it cannot.  Whatever it refuses takes the
+    gather path."""
     dtype = jnp.dtype(dtype)
     mesh = jax.sharding.get_abstract_mesh()
     partitioned = any(
@@ -417,6 +449,7 @@ def paged_kernel_fits(cfg, P: int, B: int, L: int, dtype) -> bool:
     )
     return (
         L == 1
+        and cfg.block.attention != "mla"
         and cfg.head_dim % 128 == 0
         and P % (32 // dtype.itemsize) == 0
         and dtype == jnp.dtype(cfg.dtype)
@@ -425,6 +458,35 @@ def paged_kernel_fits(cfg, P: int, B: int, L: int, dtype) -> bool:
             B, cfg.n_heads, cfg.n_kv_heads, cfg.head_dim, P, dtype
         ) <= paged_attention.VMEM_BUDGET_BYTES
     )
+
+
+def _latent_attention(bp, x, positions, cfg, pages, tables, layer,
+                      from_zero=False):
+    """The attention half of an ``mla`` block against ``layer``'s pages of
+    the ONE stacked latent pool ``[n_layers, 1, n_pages, P, width]``:
+    ``(x', pages')``.  The chunk's rows (``mla.project``) are written as
+    any K would be.  A chunk that starts its sequence (``from_zero``, a
+    prefill) then attends over its own rows expanded by head, as the
+    pages hold them; any other gathers the table's pages into a ``[B,
+    max_pages * P, width]`` view and attends over it in the latent space
+    (``mla.attend_absorbed``), nothing expanded by head."""
+    dt = cfg.dtype
+    q_n, q_r, row = mla.project(bp, x, positions, cfg)
+    with jax.named_scope("latent_write"):
+        pages, _ = _page_write(
+            pages, None, row, None, positions, tables, layer, from_zero
+        )
+    if from_zero:
+        rows = row[:, :, 0].astype(pages.dtype).astype(dt)
+        att = mla.attend_expanded(bp, q_n, q_r, rows, positions, cfg)
+    else:
+        with jax.named_scope("page_gather"):
+            B, max_pages = tables.shape
+            rows = pages[layer, 0, tables].reshape(
+                B, max_pages * pages.shape[3], -1
+            ).astype(dt)
+        att = mla.attend_absorbed(bp, q_n, q_r, rows, positions, cfg)
+    return _attn_out(bp, x, att, cfg), pages
 
 
 def _paged_block(bp, x, positions, cfg, kp, vp, tables, layer, st=None,
@@ -457,35 +519,38 @@ def _paged_block(bp, x, positions, cfg, kp, vp, tables, layer, st=None,
     # operations of a step under attention / page_write / paged_kernel
     # (or page_gather, on the general path)
     with jax.named_scope("attention"):
-        if cfg.block.attention == "cca":
-            q, k, v, row = cca.qkv_step(bp, x, positions, st[layer], cfg)
-            st = st.at[layer].set(row)
+        if cfg.block.attention == "mla":
+            x, kp = _latent_attention(bp, x, positions, cfg, kp, tables, layer)
         else:
-            q, k, v = tfm._attn_qkv(bp, x, positions, cfg)
-        kvh, dh = k.shape[2:]
-        kp, vp = _page_write(kp, vp, k, v, positions, tables, layer)
-        if paged_kernel_fits(cfg, P, B, L, kp.dtype):
-            with jax.named_scope("paged_kernel"):
-                # an idle row (any index, its table all trash) attends
-                # the trash page like the gather path: at least one key,
-                # at most the capacity
-                lengths = jnp.clip(positions[:, 0] + 1, 1, cap)
-                att = paged_attention.paged_attention(
-                    q[:, 0], kp, vp, tables, lengths, layer
-                )[:, None]
-        else:
-            with jax.named_scope("page_gather"):
-                # gather each row's pages of the layer, straight from the
-                # stack, into its contiguous cache view: the two advanced
-                # indices lead, [B, max_pages, kvh, P, Dh]
-                ck, cv = (
-                    jnp.moveaxis(pages[layer, :, tables], 2, 3).reshape(
-                        B, cap, kvh, dh
-                    ).astype(dt)
-                    for pages in (kp, vp)
-                )
-            att = tfm._cache_attention(q, ck, cv, positions)
-        x = _attn_out(bp, x, att, cfg)
+            if cfg.block.attention == "cca":
+                q, k, v, row = cca.qkv_step(bp, x, positions, st[layer], cfg)
+                st = st.at[layer].set(row)
+            else:
+                q, k, v = tfm._attn_qkv(bp, x, positions, cfg)
+            kvh, dh = k.shape[2:]
+            kp, vp = _page_write(kp, vp, k, v, positions, tables, layer)
+            if paged_kernel_fits(cfg, P, B, L, kp.dtype):
+                with jax.named_scope("paged_kernel"):
+                    # an idle row (any index, its table all trash) attends
+                    # the trash page like the gather path: at least one key,
+                    # at most the capacity
+                    lengths = jnp.clip(positions[:, 0] + 1, 1, cap)
+                    att = paged_attention.paged_attention(
+                        q[:, 0], kp, vp, tables, lengths, layer
+                    )[:, None]
+            else:
+                with jax.named_scope("page_gather"):
+                    # gather each row's pages of the layer, straight from the
+                    # stack, into its contiguous cache view: the two advanced
+                    # indices lead, [B, max_pages, kvh, P, Dh]
+                    ck, cv = (
+                        jnp.moveaxis(pages[layer, :, tables], 2, 3).reshape(
+                            B, cap, kvh, dh
+                        ).astype(dt)
+                        for pages in (kp, vp)
+                    )
+                att = tfm._cache_attention(q, ck, cv, positions)
+            x = _attn_out(bp, x, att, cfg)
     x, routed = _feed_forward(bp, x, cfg, layer, route)
     return x, kp, vp, st, routed
 
@@ -502,18 +567,23 @@ def _prefill_block(bp, x, positions, cfg, kp, vp, tables, layer, route=None):
     dt = cfg.dtype
     tail = None
     with jax.named_scope("attention"):
-        if cfg.block.attention == "cca":
-            q, k, v, tail = cca.qkv_sequence(bp, x, positions, cfg)
+        if cfg.block.attention == "mla":
+            x, kp = _latent_attention(
+                bp, x, positions, cfg, kp, tables, layer, from_zero=True
+            )
         else:
-            q, k, v = tfm._attn_qkv(bp, x, positions, cfg)
-        kp, vp = _page_write(
-            kp, vp, k, v, positions, tables, layer, from_zero=True
-        )
-        att = tfm._cache_attention(
-            q, k.astype(kp.dtype).astype(dt), v.astype(vp.dtype).astype(dt),
-            positions,
-        )
-        x = _attn_out(bp, x, att, cfg)
+            if cfg.block.attention == "cca":
+                q, k, v, tail = cca.qkv_sequence(bp, x, positions, cfg)
+            else:
+                q, k, v = tfm._attn_qkv(bp, x, positions, cfg)
+            kp, vp = _page_write(
+                kp, vp, k, v, positions, tables, layer, from_zero=True
+            )
+            att = tfm._cache_attention(
+                q, k.astype(kp.dtype).astype(dt), v.astype(vp.dtype).astype(dt),
+                positions,
+            )
+            x = _attn_out(bp, x, att, cfg)
     x, routed = _feed_forward(bp, x, cfg, layer, route)
     return x, kp, vp, tail, routed
 
@@ -528,37 +598,57 @@ def _scan_layers(block, x, params, k_pages, v_pages, state, live, cfg):
     stacked back (PR 33: as ``xs`` / ``ys`` each layer of each step copied
     its 25-50 MB pool twice).  What each layer reports of its routing is
     stacked.  For the dense block every extra is None, an empty pytree:
-    the lowered program is the one without them."""
-    blocks, experts, r0 = params["blocks"], None, None
-    if cfg.block.ffn == "experts_top1":
+    the lowered program is the one without them.
+
+    Layers of two kinds are two runs of the one scan over the one carry
+    (ROADMAP M2): the spec's leading ``dense_layers`` first, on their own
+    stack ``params["dense_blocks"]`` and with no route, so that
+    :func:`_feed_forward` gives them the dense SwiGLU; then the rest."""
+    first = cfg.block.dense_layers
+    blocks, experts, r = params["blocks"], None, None
+    if cfg.block.routes:
         blocks, experts = moe.stack_experts(blocks, cfg)
-        r0 = jnp.zeros(x.shape[:2] + (cfg.block.router_hidden,), jnp.float32)
+    if cfg.block.ffn == "experts_top1":
+        r = jnp.zeros(x.shape[:2] + (cfg.block.router_hidden,), jnp.float32)
 
-    def step(carry, xs):
-        x, r, kp, vp, st = carry
-        bp, layer = xs
-        route = None if experts is None else (r, live, experts)
-        x, kp, vp, st, routed = block(bp, x, kp, vp, st, layer, route)
-        r, report = (None, None) if routed is None else (routed[0], routed[1:])
-        return (x, r, kp, vp, st), report
+    def run(carry, blocks, layers, experts):
+        def step(carry, xs):
+            x, r, kp, vp, st = carry
+            bp, layer = xs
+            route = None if experts is None else (r, live, experts)
+            x, kp, vp, st, routed = block(bp, x, kp, vp, st, layer, route)
+            report = None
+            if routed is not None:
+                r, report = routed[0], routed[1:]
+            return (x, r, kp, vp, st), report
 
-    (x, _, k_pages, v_pages, state), routed = jax.lax.scan(
-        step, (x, r0, k_pages, v_pages, state),
-        (blocks, jnp.arange(cfg.n_layers, dtype=jnp.int32)),
+        return jax.lax.scan(
+            step, carry, (blocks, jnp.arange(*layers, dtype=jnp.int32))
+        )
+
+    carry = (x, r, k_pages, v_pages, state)
+    if first:
+        carry, _ = run(carry, params["dense_blocks"], (0, first), None)
+    (x, _, k_pages, v_pages, state), routed = run(
+        carry, blocks, (first, cfg.n_layers), experts
     )
     return x, k_pages, v_pages, state, routed
 
 
-def _routing(routed):
+def _routing(routed, n_experts):
     """What a dispatch reports of its routing, read back with the tokens:
     ``(stats, chosen)``, or None for a model that routes nothing.
-    ``stats`` int32 [4] is what the dispatch adds to the ``moe_*``
-    counters, from the per-layer expert counts [n_layers, E]: layer-steps
-    routed, tokens routed, the fullest expert's tokens and the experts
-    that got any, each summed over the layers.  ``chosen`` int32
-    [n_layers, B * L] is every token's expert in every layer (``E`` for a
-    token that is not live): the decisions themselves, which a reference
-    needs beside the tokens because top-1 routing is discontinuous."""
+    ``stats`` int32 [5] is what the dispatch adds to the ``moe_*``
+    counters, from the per-layer counts [expert layers, held experts] of
+    what each expert HELD here got: layer-steps routed, tokens (for top-k,
+    token-expert pairs) computed here, the fullest expert's and the
+    experts that got any, each summed over the layers; and last the pairs
+    the router picked for live tokens, whoever holds their experts.
+    ``chosen`` int32 [expert layers, B * L] (top-k: [.., B * L, k]) is
+    every token's expert in every layer, in the router's own numbering
+    (``n_experts`` for a token that is not live): the decisions
+    themselves, which a reference needs beside the tokens because routing
+    is discontinuous."""
     if routed is None:
         return None
     counts, chosen = routed
@@ -567,8 +657,9 @@ def _routing(routed):
         jnp.sum(counts),
         jnp.sum(jnp.max(counts, axis=1)),
         jnp.sum(counts > 0),
+        jnp.sum(chosen < n_experts),
     ]).astype(jnp.int32)
-    return stats, chosen.reshape(chosen.shape[0], -1)
+    return stats, chosen.reshape((chosen.shape[0], -1) + chosen.shape[3:])
 
 
 def _head_logits(params, x, cfg, lead):
@@ -588,7 +679,7 @@ def _step_forward(params, tokens, tables, indices, k_pages, v_pages, cfg,
     positions = indices[:, None] + jnp.arange(L, dtype=jnp.int32)[None, :]
     x = tfm.embed_lookup(params["embed"], tokens, cfg.dtype)
     live = None
-    if cfg.block.ffn == "experts_top1":
+    if cfg.block.routes:
         live = jnp.broadcast_to(tables[:, :1] > 0, (B, L))
 
     def block(bp, x, kp, vp, st, layer, route):
@@ -600,7 +691,7 @@ def _step_forward(params, tokens, tables, indices, k_pages, v_pages, cfg,
         block, x, params, k_pages, v_pages, state, live, cfg
     )
     logits = _head_logits(params, x, cfg, "bl")
-    return logits, kps, vps, state, _routing(routed)
+    return logits, kps, vps, state, _routing(routed, cfg.moe_experts)
 
 
 def apply_paged(
@@ -642,7 +733,7 @@ def _prefill_forward(params, toks, table, last_pos, k_pages, v_pages, cfg,
     positions = jnp.broadcast_to(jnp.arange(L, dtype=jnp.int32), (B, L))
     x = tfm.embed_lookup(params["embed"], toks, cfg.dtype)
     live = None
-    if cfg.block.ffn == "experts_top1":
+    if cfg.block.routes:
         live = positions <= last_pos[:, None]
 
     def block(bp, x, kp, vp, st, layer, route):
@@ -662,7 +753,7 @@ def _prefill_forward(params, toks, table, last_pos, k_pages, v_pages, cfg,
     )
     x = jnp.take_along_axis(x, last_pos[:, None, None], axis=1)[:, 0]
     logits = _head_logits(params, x, cfg, "b")
-    return logits, k_pages, v_pages, state, _routing(routed)
+    return logits, k_pages, v_pages, state, _routing(routed, cfg.moe_experts)
 
 
 # ---------------------------------------------------------------------------

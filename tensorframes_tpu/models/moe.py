@@ -271,7 +271,8 @@ def moe_mlp(
 
 
 # ---------------------------------------------------------------------------
-# dropless top-1 experts behind an MLP router (the serving path's layer)
+# dropless experts (the serving path's layers): top-1 behind an MLP router,
+# and top-k behind a sigmoid router beside a shared expert
 # ---------------------------------------------------------------------------
 #
 # The capacity path above gives every expert ``ceil(1.25 S / E)`` places a
@@ -331,6 +332,48 @@ def stack_experts(blocks, cfg):
     return rest, experts
 
 
+def _grouped_experts(yt, picks, gates, experts, layer, held, dt):
+    """The sorted grouped products both expert layers end in.  ``yt`` [T,
+    D] tokens; ``picks`` [T, k] int32, each pair's expert among the
+    ``held`` this program holds (``held`` itself for a pair that goes to
+    no group: a token that is not live, or an expert held elsewhere);
+    ``gates`` [T, k] f32.  The expert weights are the groups ``layer *
+    held ..`` of ``experts``, the stack of all layers
+    (:func:`stack_experts`).  Returns ``(out [T, D], sizes [held])``:
+    ``sum_k gates[t, k] * Wdown(e)(silu(y Wgate(e)) * y Wup(e))`` over a
+    token's pairs that have a group, exact zeros for a token with none,
+    and the pairs each held expert got."""
+    from .transformer import weight
+
+    T, k = picks.shape
+    flat = picks.reshape(T * k)
+    with jax.named_scope("moe/sort"):
+        order = jnp.argsort(flat)  # stable: ties keep token order
+        sizes = jnp.bincount(flat, length=held + 1)[:held].astype(jnp.int32)
+        rows = order if k == 1 else order // k  # the token of a sorted pair
+        xs = yt[rows]
+        # every other layer's groups are empty
+        groups = jax.lax.dynamic_update_slice(
+            jnp.zeros((experts["we_gate"].shape[0],), jnp.int32),
+            sizes, (layer * held,),
+        )
+    with jax.named_scope("moe/experts"):
+        grouped = lambda a, w: jax.lax.ragged_dot(
+            a, weight(w, dt), groups, preferred_element_type=jnp.float32
+        )
+        hidden = jax.nn.silu(grouped(xs, experts["we_gate"])) * grouped(xs, experts["we_up"])
+        ys = grouped(hidden.astype(dt), experts["we_down"])
+    with jax.named_scope("moe/combine"):
+        # rows past the groups (pairs routed nowhere) hold nothing defined
+        keep = (flat < held)[order]
+        ys = jnp.where(keep[:, None], ys * gates.reshape(T * k)[order][:, None], 0.0)
+        if k == 1:  # a row a token: put each back where it came from
+            out = jnp.zeros((T, yt.shape[1]), dt).at[order].set(ys.astype(dt))
+        else:  # a token's pairs add up, in float32
+            out = jnp.zeros((T, yt.shape[1]), jnp.float32).at[rows].add(ys).astype(dt)
+    return out, sizes
+
+
 def experts_top1(bp, y: jnp.ndarray, r_prev: jnp.ndarray, live: jnp.ndarray,
                  cfg, experts, layer):
     """The dropless expert layer: ``y`` [B, L, D] (post-RMSNorm) ->
@@ -343,35 +386,73 @@ def experts_top1(bp, y: jnp.ndarray, r_prev: jnp.ndarray, live: jnp.ndarray,
     live token at any load, exact zeros elsewhere; ``r`` is the router's
     carry for the layer above; ``counts`` the live tokens each expert got;
     ``chosen`` each token's expert (``E`` for a token that is not live)."""
-    from .transformer import weight
-
     B, L, D = y.shape
-    dt = cfg.dtype
     T = B * L
     yt, live = y.reshape(T, D), live.reshape(T)
     with jax.named_scope("moe/router"):
         expert, gate_p, r = router_top1(
             bp, yt, r_prev.reshape(T, -1), live, cfg.block.norm_eps
         )
-    E = cfg.moe_experts
-    with jax.named_scope("moe/sort"):
-        order = jnp.argsort(expert)  # stable: ties keep token order
-        sizes = jnp.bincount(expert, length=E + 1)[:E].astype(jnp.int32)
-        xs = yt[order]
-        # every other layer's groups are empty
-        groups = jax.lax.dynamic_update_slice(
-            jnp.zeros((experts["we_gate"].shape[0],), jnp.int32),
-            sizes, (layer * E,),
-        )
-    with jax.named_scope("moe/experts"):
-        grouped = lambda a, w: jax.lax.ragged_dot(
-            a, weight(w, dt), groups, preferred_element_type=jnp.float32
-        )
-        hidden = jax.nn.silu(grouped(xs, experts["we_gate"])) * grouped(xs, experts["we_up"])
-        ys = grouped(hidden.astype(dt), experts["we_down"])
-    with jax.named_scope("moe/combine"):
-        # rows past the groups (tokens routed nowhere) hold nothing defined
-        keep = (expert < E)[order]
-        ys = jnp.where(keep[:, None], ys * gate_p[order][:, None], 0.0)
-        out = jnp.zeros((T, D), dt).at[order].set(ys.astype(dt))
+    out, sizes = _grouped_experts(
+        yt, expert[:, None], gate_p[:, None], experts, layer,
+        cfg.moe_experts, cfg.dtype,
+    )
     return out.reshape(B, L, D), r.reshape(B, L, -1), sizes, expert.reshape(B, L)
+
+
+def router_sigmoid(bp, y: jnp.ndarray, live: jnp.ndarray, k: int,
+                   scale: float):
+    """The DeepSeek-V3 style router without its group limit or bias on
+    tokens ``y`` [T, D]: ``s = sigmoid(y Wr)`` over all ``E`` routed
+    experts; the picks are the ``k`` largest ``s`` (the lower index on a
+    tie); their weights ``s[picks] / (sum s[picks] + 1e-20) * scale``.  In
+    float32 at full matmul precision, as :func:`router_top1`.  Returns
+    ``(picks [T, k] int32, weights [T, k] f32)``; a token that is not
+    ``live`` gets expert ``E`` ``k`` times."""
+    s = jax.nn.sigmoid(jnp.matmul(
+        y.astype(jnp.float32), bp["router"].astype(jnp.float32),
+        precision=jax.lax.Precision.HIGHEST,
+    ))
+    top, picks = jax.lax.top_k(s, k)
+    w = top / (jnp.sum(top, axis=-1, keepdims=True) + 1e-20) * scale
+    return jnp.where(live[:, None], picks.astype(jnp.int32), s.shape[-1]), w
+
+
+def experts_topk(bp, y: jnp.ndarray, live: jnp.ndarray, cfg, experts, layer):
+    """The expert layer of shared and routed experts, for the share of the
+    routed ones this program holds (``cfg.block.experts_share``): ``y``
+    [B, L, D] (post-RMSNorm) -> ``(out [B, L, D], counts [held] int32,
+    chosen [B, L, k] int32)``.  ``bp`` holds the layer's ``router`` [D, E]
+    and the shared expert's ``ws_*``; the held experts' weights are the
+    groups of layer ``layer`` in ``experts`` (:func:`stack_experts`).
+
+    Every live token is routed over ALL ``E`` experts and its ``k``
+    weights are normalised over all its picks; ``out`` is the shared
+    expert (every token's, counted once whoever holds what) plus the
+    weighted outputs of the picks that fall on the held experts — what the
+    holders of the other experts would add is left out, and the sum over
+    all holders is the whole layer.  ``counts`` are the pairs each held
+    expert got; ``chosen`` every token's picks in the router's numbering
+    (``E`` for a token that is not live)."""
+    from .transformer import swiglu
+
+    B, L, D = y.shape
+    T = B * L
+    yt = y.reshape(T, D)
+    held, index = cfg.experts_held, cfg.block.experts_share[0]
+    with jax.named_scope("moe/router"):
+        picks, w = router_sigmoid(
+            bp, yt, live.reshape(T), cfg.moe_top_k, cfg.block.routed_scale
+        )
+        local = picks - index * held
+        local = jnp.where((local >= 0) & (local < held), local, held)
+    out, sizes = _grouped_experts(
+        yt, local, w, experts, layer, held, cfg.dtype
+    )
+    out = out.reshape(B, L, D)
+    if cfg.block.shared_experts:
+        with jax.named_scope("moe/shared"):
+            out = out + swiglu(
+                y, bp["ws_gate"], bp["ws_up"], bp["ws_down"], cfg.dtype
+            )
+    return out, sizes, picks.reshape(B, L, -1)

@@ -33,7 +33,7 @@ constraints over axes absent from the ambient mesh are dropped.
 from __future__ import annotations
 
 import dataclasses
-from typing import Any, Dict, NamedTuple, Optional
+from typing import Any, Dict, NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -71,6 +71,32 @@ def embed_lookup(emb: "QTensor | jnp.ndarray", tokens, dt) -> jnp.ndarray:
 
 
 @dataclasses.dataclass(frozen=True)
+class LatentSpec:
+    """The widths of latent attention (MLA, ``models/mla.py``): the
+    query's and the key-value's low ranks, and a head's three sizes —
+    what of a query or key is not rotated, what is, and a value."""
+
+    q_rank: int
+    kv_rank: int
+    nope_dim: int
+    rope_dim: int
+    v_dim: int
+
+
+@dataclasses.dataclass(frozen=True)
+class Yarn:
+    """YaRN's rescaling of the rotary frequencies (:func:`rope_table`)
+    and of the attention scores (:func:`yarn_mscale`)."""
+
+    factor: float
+    original_max: int
+    beta_fast: float = 32.0
+    beta_slow: float = 1.0
+    mscale: float = 1.0
+    mscale_all_dim: float = 0.0
+
+
+@dataclasses.dataclass(frozen=True)
 class BlockSpec:
     """What one decoder block is made of (ROADMAP D8): the kinds of its
     two sublayers and the numbers they share.  A model with another
@@ -80,30 +106,64 @@ class BlockSpec:
 
     # "gqa": grouped-query attention over pages of K and V;
     # "cca": compressed convolutional attention (``models/cca.py``), whose
-    #   decode step also needs a fixed-size per-sequence convolution state
+    #   decode step also needs a fixed-size per-sequence convolution state;
+    # "mla": latent attention (``models/mla.py``, sizes in ``latent``):
+    #   ONE pool of pages, a token's normed latent and its rotated key
+    #   part side by side, which a decode step attends over as they lie
     attention: str = "gqa"
     # "swiglu": the dense SwiGLU (or the capacity-routed ``moe.moe_mlp``
     #   when ``moe_experts`` > 0, the training path);
     # "experts_top1": the dropless top-1 expert layer behind an MLP
     #   router (``moe.experts_top1``): ``moe_experts`` experts of width
-    #   ``moe_d_ff``, router width ``router_hidden``
+    #   ``moe_d_ff``, router width ``router_hidden``;
+    # "experts_topk": ``moe_top_k`` of ``moe_experts`` a token behind a
+    #   sigmoid router, beside ``shared_experts`` that every token takes
+    #   (``moe.experts_topk``), of which this program holds the share
+    #   ``experts_share``
     ffn: str = "swiglu"
     norm_eps: float = 1e-6
     rotary_share: float = 1.0  # share of a head's dimensions RoPE rotates
     head_dim: Optional[int] = None  # None: d_model // n_heads
     router_hidden: int = 0
+    # the first ``dense_layers`` layers take the dense SwiGLU (width
+    # ``d_ff``) whatever ``ffn`` says of the rest: a run of its own in the
+    # layer scan, with its own stack of parameters (``dense_blocks``)
+    dense_layers: int = 0
+    shared_experts: int = 0  # each of width ``moe_d_ff``
+    routed_scale: float = 1.0  # on the normalised weights of the picks
+    # (index, of): the routed experts are divided over ``of`` holders and
+    # this one holds run ``index`` of them, ``moe_experts // of`` experts;
+    # it routes over all and computes what its own give
+    experts_share: Tuple[int, int] = (0, 1)
+    latent: Optional[LatentSpec] = None
+    yarn: Optional[Yarn] = None
 
     def __post_init__(self):
-        if self.attention not in ("gqa", "cca"):
-            raise ValueError(f"attention {self.attention!r}: 'gqa' or 'cca'")
-        if self.ffn not in ("swiglu", "experts_top1"):
-            raise ValueError(f"ffn {self.ffn!r}: 'swiglu' or 'experts_top1'")
+        if self.attention not in ("gqa", "cca", "mla"):
+            raise ValueError(
+                f"attention {self.attention!r}: 'gqa', 'cca' or 'mla'"
+            )
+        if self.ffn not in ("swiglu", "experts_top1", "experts_topk"):
+            raise ValueError(
+                f"ffn {self.ffn!r}: 'swiglu', 'experts_top1' or 'experts_topk'"
+            )
+        if (self.attention == "mla") != (self.latent is not None):
+            raise ValueError("attention 'mla' and `latent` go together")
+        index, of = self.experts_share
+        if not 0 <= index < of:
+            raise ValueError(f"experts_share {self.experts_share}: (index, of)")
 
     @property
     def stateless(self) -> bool:
         """The dense block: pages are its only per-sequence state and it
         routes nothing, so its executables take and return no more."""
         return self.attention == "gqa" and self.ffn == "swiglu"
+
+    @property
+    def routes(self) -> bool:
+        """Whether the layers after the leading dense ones send tokens to
+        experts through a router whose picks the executables report."""
+        return self.ffn in ("experts_top1", "experts_topk")
 
 
 @dataclasses.dataclass(frozen=True)
@@ -179,6 +239,16 @@ class TransformerConfig:
             raise ValueError(
                 "ffn 'experts_top1' needs moe_experts and block.router_hidden"
             )
+        if self.block.ffn == "experts_topk" and (
+            not self.moe_experts
+            or self.moe_experts % self.block.experts_share[1]
+        ):
+            raise ValueError(
+                "ffn 'experts_topk' needs moe_experts, a multiple of the "
+                "holders in block.experts_share"
+            )
+        if not 0 <= self.block.dense_layers <= self.n_layers:
+            raise ValueError("block.dense_layers must lie within n_layers")
         if self.n_heads % self.n_kv_heads:
             raise ValueError("n_heads must be divisible by n_kv_heads")
         if self.moe_experts and self.moe_top_k > self.moe_experts:
@@ -188,7 +258,17 @@ class TransformerConfig:
 
     @property
     def head_dim(self) -> int:
+        if self.block.latent is not None:
+            # a query head, a value head and a page's row are three sizes
+            raise ValueError(
+                "a latent block has no one head size: see cfg.block.latent"
+            )
         return self.block.head_dim or self.d_model // self.n_heads
+
+    @property
+    def experts_held(self) -> int:
+        """The routed experts whose weights this program holds."""
+        return self.moe_experts // self.block.experts_share[1]
 
 
 def shard(x: jnp.ndarray, *spec) -> jnp.ndarray:
@@ -367,21 +447,52 @@ def _rms_norm(x: jnp.ndarray, w: jnp.ndarray, eps: float = 1e-6):
     return (x32 * scale).astype(x.dtype) * w.astype(x.dtype)
 
 
+def rope_table(theta: float, dims: int, yarn: Optional[Yarn] = None):
+    """The ``dims // 2`` rotary frequencies as a float32 table: ``f_i =
+    theta ** (-2i / dims)``, and under YaRN (arXiv:2309.00071, as
+    DeepSeek-V2 applies it) blended with ``f_i / factor`` by a ramp over
+    the dimensions whose wavelength the original context holds between
+    ``beta_fast`` and ``beta_slow`` times: the fast ones keep their
+    frequency, the slow ones are interpolated."""
+    half = dims // 2
+    f = float(theta) ** (-np.arange(half, dtype=np.float64) / half)
+    if yarn is not None and yarn.factor != 1:
+
+        def corr(turns):  # the dimension that makes ``turns`` turns
+            return dims * np.log(
+                yarn.original_max / (2 * np.pi * turns)
+            ) / (2 * np.log(theta))
+
+        low = max(int(np.floor(corr(yarn.beta_fast))), 0)
+        high = min(int(np.ceil(corr(yarn.beta_slow))), dims - 1)
+        ramp = np.clip((np.arange(half) - low) / max(high - low, 1e-3), 0, 1)
+        f = (1 - ramp) * f + ramp * f / yarn.factor
+    return f.astype(np.float32)
+
+
+def yarn_mscale(factor: float, mscale: float) -> float:
+    """YaRN's attention temperature ``0.1 mscale ln(factor) + 1``."""
+    return 0.1 * mscale * float(np.log(factor)) + 1.0 if factor > 1 else 1.0
+
+
 def _rope(
-    x: jnp.ndarray, positions: jnp.ndarray, theta: float, share: float = 1.0
+    x: jnp.ndarray, positions: jnp.ndarray, freqs, share: float = 1.0
 ):
     """Rotary embedding.  x: [B, L, H, Dh]; positions: [B, L] (absolute).
+    ``freqs`` is the table of ``Dh // 2`` frequencies
+    (:func:`rope_table`), or the base ``theta`` of the plain one.
     ``share`` < 1 rotates the first ``share * Dh`` dimensions of each
     head and passes the rest through (partial rotary)."""
     if share < 1.0:
         rot = int(x.shape[-1] * share)
         return jnp.concatenate(
-            [_rope(x[..., :rot], positions, theta), x[..., rot:]], -1
+            [_rope(x[..., :rot], positions, freqs), x[..., rot:]], -1
         )
     dh = x.shape[-1]
-    freqs = theta ** (
-        -jnp.arange(0, dh // 2, dtype=jnp.float32) / (dh // 2)
-    )
+    if np.ndim(freqs) == 0:
+        freqs = freqs ** (
+            -jnp.arange(0, dh // 2, dtype=jnp.float32) / (dh // 2)
+        )
     ang = positions[..., None].astype(jnp.float32) * freqs  # [B, L, Dh/2]
     cos = jnp.cos(ang)[:, :, None, :].astype(x.dtype)
     sin = jnp.sin(ang)[:, :, None, :].astype(x.dtype)
@@ -469,20 +580,25 @@ def _mlp_residual(bp, x, cfg, segments=None):
     Split out of ``_block`` (round 22) so the paged decode block
     (``models/kv_pager.py``) composes the same halves in the same
     order."""
-    dt = cfg.dtype
     y = _saved(_rms_norm(x, bp["ln2"], cfg.block.norm_eps))
-    if cfg.moe_experts:
+    if cfg.moe_experts and cfg.block.ffn == "swiglu":
         from .moe import moe_mlp
 
         ff_out, aux = moe_mlp(bp, y, cfg, segments)
         x = x + ff_out
     else:
-        gate = jax.nn.silu(y @ weight(bp["w_gate"], dt))
-        up = y @ weight(bp["w_up"], dt)
-        ff = _saved(shard(gate * up, ("dp", "ep"), "sp", "tp"))
-        x = x + shard(ff @ weight(bp["w_down"], dt), ("dp", "ep"), "sp", None)
+        x = x + swiglu(y, bp["w_gate"], bp["w_up"], bp["w_down"], cfg.dtype)
         aux = jnp.zeros((), jnp.float32)
     return x, aux
+
+
+def swiglu(y, w_gate, w_up, w_down, dt):
+    """``W_down(silu(y W_gate) * y W_up)``: the dense feed-forward, and an
+    expert layer's shared expert."""
+    gate = jax.nn.silu(y @ weight(w_gate, dt))
+    up = y @ weight(w_up, dt)
+    ff = _saved(shard(gate * up, ("dp", "ep"), "sp", "tp"))
+    return shard(ff @ weight(w_down, dt), ("dp", "ep"), "sp", None)
 
 
 @jax.named_scope("attention")

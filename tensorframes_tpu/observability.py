@@ -224,16 +224,23 @@ _counters: Dict[str, int] = {
     # executables computed (rows x bucket) — the rest is padding
     "decode_prefill_prompt_tokens": 0,
     "decode_prefill_run_tokens": 0,
-    # expert routing of a served model (``moe.experts_top1``), counted on
-    # the device over live tokens only and read back with a dispatch's
-    # tokens: layer-steps routed (layers x dispatches), tokens routed
-    # (summed over layers), and over the same layer-steps the sum of the
-    # fullest expert's tokens and of the experts that got any.  A dense
-    # model bumps none of them
+    # the tokens a decode step attended over, summed over steps: what
+    # every live slot held, the token it fed among them
+    "decode_tokens_held": 0,
+    # expert routing of a served model (``moe.experts_top1`` /
+    # ``experts_topk``), counted on the device over live tokens only and
+    # read back with a dispatch's tokens: layer-steps routed (expert
+    # layers x dispatches), tokens routed (summed over layers; for top-k
+    # the token-expert pairs computed HERE, on the experts this program
+    # holds), over the same layer-steps the sum of the fullest held
+    # expert's and of the held experts that got any, and the pairs the
+    # router picked, whoever holds their experts.  A dense model bumps
+    # none of them
     "moe_route_calls": 0,
     "moe_routed_tokens": 0,
     "moe_busiest_expert_tokens": 0,
     "moe_experts_touched": 0,
+    "moe_picked_pairs": 0,
     # time counters (nanoseconds of time.perf_counter_ns, monotonic),
     # taken at the boundaries of the spans of the same name and bumped
     # once per step / prefill / block / verb.  Decode scheduler: steps,
@@ -1201,6 +1208,8 @@ def counters_delta(
             "moe_routed_tokens",
             "moe_busiest_expert_tokens",
             "moe_experts_touched",
+            "moe_picked_pairs",
+            "decode_tokens_held",
             "decode_steps",
             "decode_kernel_steps",
             "decode_host_ns",

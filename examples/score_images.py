@@ -10,8 +10,9 @@ The TPU-native shape of the same pipeline:
 * a ``host_stage`` decodes bytes -> uint8 pixels on the host (XLA cannot
   host string tensors — the reference documents the same Binary limitation,
   ``datatypes.scala:571-622``);
-* the device program (here Inception-v3, bf16 on the MXU) normalises and
-  scores; outputs come back as new columns.
+* the device program (here Inception-v3: activations stored in bf16,
+  float32 accumulation and logits) normalises and scores; outputs come
+  back as new columns.
 
 Run: ``python examples/score_images.py``  (uses tiny random "images"; swap
 ``decode`` for a real JPEG decoder and ``inception.init`` for restored

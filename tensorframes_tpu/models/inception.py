@@ -5,9 +5,19 @@ and feeding JPEG bytes through ``tfs.map_rows``/``map_blocks``
 (``/root/reference/src/main/python/tensorframes_snippets/read_image.py:108-167``;
 its VGG flow is the same shape as the Inception flow named in
 BASELINE.json's north star).  Here the model is a native jax definition —
-NHWC convs on the MXU, bf16 compute with f32 accumulation — wrapped into a
-block program for ``map_blocks``; weights are Program-style closures, the
-TPU analog of "variables frozen into the graph".
+NHWC convs on the MXU — wrapped into a block program for ``map_blocks``;
+weights are Program-style closures, the TPU analog of "variables frozen
+into the graph".
+
+Precision follows the ``dtype`` the caller hands ``scoring_program``
+(default bf16): that is the type every activation is STORED in between
+layers, and the type the MXU's operands have.  Each convolution accumulates
+in float32, adds its bias and applies the ReLU on that accumulator, and
+rounds once; the average pools and the global mean sum in float32; the
+logits stay float32.  With ``dtype=float32`` (the frozen-GraphDef parity
+path) every one of those casts is the identity.  Nothing strongly typed
+(a NumPy scalar, a float32 array) may meet an activation: jax would
+promote the whole network to float32 (``tests/test_inception_dtype.py``).
 
 Architecture follows the standard Inception-v3 (googlenet v3) layout:
 stem convs -> 3x InceptionA -> B -> 4x InceptionC -> D -> 2x InceptionE ->
@@ -49,19 +59,22 @@ def _conv_init(key, kh, kw, cin, cout, dtype):
 
 
 def _conv(p, x, stride=1, padding="SAME"):
-    y = jax.lax.conv_general_dilated(
+    """conv -> (folded) BN -> ReLU in ``x``'s type.  The MXU accumulates in
+    float32 and the epilogue runs on that accumulator; the result is rounded
+    ONCE, to the type the activation is stored in."""
+    acc = jax.lax.conv_general_dilated(
         x,
         p["w"].astype(x.dtype),
         window_strides=(stride, stride),
         padding=padding,
         dimension_numbers=("NHWC", "HWIO", "NHWC"),
         preferred_element_type=jnp.float32,
-    ).astype(x.dtype)
+    )
     if "scale" in p:  # unfolded inference BN: y * scale + shift
-        return jax.nn.relu(
-            y * p["scale"].astype(x.dtype) + p["shift"].astype(x.dtype)
-        )
-    return jax.nn.relu(y + p["b"].astype(x.dtype))  # folded: bias only
+        acc = acc * p["scale"].astype(acc.dtype) + p["shift"].astype(acc.dtype)
+    else:  # folded: bias only
+        acc = acc + p["b"].astype(acc.dtype)
+    return jax.nn.relu(acc).astype(x.dtype)
 
 
 def fold_bn(params: Params) -> Params:
@@ -116,16 +129,22 @@ def _pool(x, kind, size=3, stride=1, padding="SAME"):
             (1, stride, stride, 1),
             padding,
         )
+    # the window sum runs in float32 whatever the activations are stored in
     s = jax.lax.reduce_window(
-        x, 0.0, jax.lax.add, (1, size, size, 1), (1, stride, stride, 1), padding
+        x.astype(jnp.float32),
+        0.0,
+        jax.lax.add,
+        (1, size, size, 1),
+        (1, stride, stride, 1),
+        padding,
     )
     if padding == "VALID":
-        return s / np.float32(size * size)
+        return (s / (size * size)).astype(x.dtype)
     h, w = x.shape[1], x.shape[2]
     counts = np.outer(
         _avg_counts_1d(h, size, stride), _avg_counts_1d(w, size, stride)
     )[None, :, :, None]
-    return s / jnp.asarray(counts, s.dtype)
+    return (s / counts).astype(x.dtype)
 
 
 # branch spec: list of (kernel_h, kernel_w, cout, stride, padding)
@@ -356,11 +375,15 @@ def apply(params: Params, images: jnp.ndarray) -> jnp.ndarray:
         with jax.named_scope(f"mixed{i}_{variant}"):
             x = _block_apply(bp, x, variant, **kw_)
     with jax.named_scope("head"):
-        x = jnp.mean(x, axis=(1, 2))  # global average pool
-        return (
-            x @ params["fc_w"].astype(x.dtype)
-            + params["fc_b"].astype(x.dtype)
-        ).astype(jnp.float32)
+        # global average pool and fc accumulate in float32; the logits stay
+        # float32 (bf16 logits of magnitude 4 lie 0.016 apart)
+        feats = jnp.mean(x, axis=(1, 2), dtype=jnp.float32).astype(x.dtype)
+        logits = jnp.dot(
+            feats,
+            params["fc_w"].astype(x.dtype),
+            preferred_element_type=jnp.float32,
+        )
+        return logits + params["fc_b"].astype(jnp.float32)
 
 
 def scoring_program(params: Params, dtype=jnp.bfloat16, fold: bool = True):
@@ -378,7 +401,10 @@ def scoring_program(params: Params, dtype=jnp.bfloat16, fold: bool = True):
 
     def fn(image):
         x = image.reshape(-1, INPUT_SIZE, INPUT_SIZE, 3)
-        x = x.astype(dtype) / np.float32(127.5) - np.float32(1.0)
+        # normalise in float32, round once: the network is traced in, and
+        # stores every activation in, the type it is handed here (weak
+        # scalars only: a NumPy scalar would promote it all to float32)
+        x = (x.astype(jnp.float32) / 127.5 - 1.0).astype(dtype)
         logits = apply(params, x)
         return {
             "prediction": jnp.argmax(logits, axis=-1),

@@ -31,8 +31,10 @@ if ROOT not in sys.path:
 
 from perfbench.drivers import bridge_decode_axk1  # noqa: E402
 from perfbench.drivers.bridge_decode_zaya import transformer_config  # noqa: E402
-from perfbench.refs import axk1_decoder, transformer_decoder, zaya_decoder  # noqa: E402
-from tensorframes_tpu.models import cca, kv_pager, mla  # noqa: E402
+from perfbench.refs import (  # noqa: E402
+    axk1_decoder, inception_v3, transformer_decoder, zaya_decoder,
+)
+from tensorframes_tpu.models import cca, inception, kv_pager, mla  # noqa: E402
 from tensorframes_tpu.models import transformer as tfm  # noqa: E402
 from tensorframes_tpu.parallel import paged_attention as pa  # noqa: E402
 
@@ -330,3 +332,55 @@ def test_a_ragged_latent_row_would_turn_the_pool(
     compiled = kv_pager.paged_decode_step.lower(*args, cfg, **kwargs).compile()
     with pytest.raises(AssertionError):
         _pool_keeps_its_layout(compiled.as_text(), args[4].shape)
+
+
+# ---------------------------------------------------------------------------
+# the scoring executable (Inception-v3): activations cross HBM in bf16
+# ---------------------------------------------------------------------------
+
+# an ENTRY instruction's result type(s), up to its opcode: one array, or
+# the tuple of a multi-output fusion
+_RESULT = re.compile(r"^\s*(?:ROOT )?%?[\w.\-]+ = (\(.*?\)|\S+) [\w\-]+\(")
+_F32_ARRAY = re.compile(r"\bf32\[([0-9,]+)\]")
+
+
+def _entry_f32_results(text):
+    """Bytes of every float32 array an instruction of the ENTRY computation
+    gives: what one executable's fusions write to HBM at four bytes."""
+    entry = text[text.index("\nENTRY "):]
+    for line in entry[: entry.index("\n}")].splitlines():
+        m = _RESULT.match(line)
+        for dims in _F32_ARRAY.findall(m.group(1)) if m else ():
+            yield 4 * math.prod(int(d) for d in dims.split(","))
+
+
+def test_scoring_executable_stores_no_float32_activation(
+    one_chip, compiled_not_interpreted
+):
+    """``jit__run`` of the scoring cells, a 1,024-row block with the weights
+    as arguments (as ``perfbench/drivers/frame_score.py`` builds it).  Traced
+    in float32 by one strongly typed scalar, its fusions wrote 32.7 GB of
+    float32 activations a block (``f32[1024,147,147,64]`` alone 5.66 GB)
+    and held 7.09 GB of temporaries, with bf16 operands on the MXU all the
+    same; in the type it is given they write 0.01 GB and hold 4.26
+    (PERF.md §6, PR 36)."""
+    m, traffic = _load("configs", "inception_v3"), _load("traffic", "score_cached")
+    dtype, kwargs = jnp.dtype(m["dtype"]), m["program"]["kwargs"]
+    rows = traffic["rows_per_chip"] // traffic["blocks_per_chip"]
+    assert rows == 1024 and dtype == jnp.bfloat16
+
+    def on_chip(a):
+        return jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip)
+
+    weights = jax.tree.map(
+        on_chip, jax.eval_shape(lambda: inception_v3.make_weights(0, dtype))
+    )
+    image = on_chip(jax.ShapeDtypeStruct(
+        (rows, math.prod(m["input"]["row_shape"])), jnp.dtype(m["input"]["dtype"])
+    ))
+    compiled = jax.jit(
+        lambda w, x: inception.scoring_program(w, dtype=dtype, **kwargs)(x)
+    ).lower(weights, image).compile()
+    large = [b for b in _entry_f32_results(compiled.as_text()) if b >= 100e6]
+    assert sum(large) < 1e9, (len(large), sum(large))
+    assert compiled.memory_analysis().temp_size_in_bytes < 5e9

@@ -63,6 +63,7 @@ suite, ``run_tests.sh``'s serving tier runs them live):
 from __future__ import annotations
 
 import collections
+import functools
 import hashlib
 import logging
 import threading
@@ -1149,6 +1150,15 @@ class DecodeScheduler:
     attention sums the same terms in another order: equal to rounding,
     and in the suite the same greedy tokens, not the same bits.
 
+    A model whose block keeps a STATE and no pages (``BlockSpec(attention=
+    "retention")``) is admitted by slot alone: a sequence costs one slot's
+    state at token 1 and at token 32,768, so ``submit`` reserves nothing,
+    the slot's state is charged to the budget when the slot is taken, and
+    length never refuses.  Its prompt goes in dispatches of at most
+    ``retention.PREFILL_TOKENS`` tokens, each at its own bucket, the
+    second and later RESUMING from the state the one before left in the
+    slot — what a decode step does with one token.
+
     ``speculative`` runs the draft/verify path (B=1 by its contract)
     solo in the caller's thread — an opt-in per-request latency knob,
     verified bit-exactly by the target model inside
@@ -1190,11 +1200,14 @@ class DecodeScheduler:
             else kv_pager.page_tokens()
         )
         cap = int(max_seq) if max_seq is not None else int(cfg.max_seq)
+        # a block that keeps a state a slot and no pages: the table row is
+        # one entry that says the slot is live, a "page" is a slot's state
+        self._by_slot = cfg.block.attention == "retention"
         # capacity rounds UP to a whole page: the gathered attention
         # extent is max_pages * P, and bit-identity vs the contiguous
         # path is pinned at exactly this capacity (``cache_len=cap``)
-        self.max_pages = kv_pager.pages_for(cap, P)
-        self.cap = self.max_pages * P
+        self.max_pages = 1 if self._by_slot else kv_pager.pages_for(cap, P)
+        self.cap = cap if self._by_slot else self.max_pages * P
         n_pages = (
             int(pool_pages)
             if pool_pages is not None
@@ -1210,12 +1223,16 @@ class DecodeScheduler:
         # other blocks): an argument and a result of both executables,
         # like the pages; a prefill overwrites the admitted slot's row
         self._kp, self._vp, self._state = self.pool.take()
+        # a retention block's state, its only pool: donated like the pages
+        self._ret = self.pool.take_retention()
         # whether the step executable attends through the paged-attention
         # kernel: what ``kv_pager._paged_block`` will decide when it traces
         # this pool's one-token step, asked once (``decode_kernel_steps``)
-        self._kernel_step = int(kv_pager.paged_kernel_fits(
-            cfg, P, self.max_slots, 1, self._kp.dtype
-        ))
+        self._kernel_step = 0 if self._by_slot else int(
+            kv_pager.paged_kernel_fits(
+                cfg, P, self.max_slots, 1, self._kp.dtype
+            )
+        )
         # ``routing_trace`` > 0 keeps, for that many retired requests, the
         # expert (a top-k router's k) every fed position chose in every
         # expert layer (``routing_of``): what a reference needs to follow
@@ -1319,11 +1336,14 @@ class DecodeScheduler:
                 )
         # reserve the FULL span up front — outside the scheduler lock
         # (the pool has its own) so a slow budget walk never stalls the
-        # step loop
+        # step loop.  A block without pages reserves nothing here: its
+        # slot's state is charged when the driver gives it the slot
         try:
-            charge, pages = self.pool.allocate(
-                self._kv.pages_for(total, self.pool.tokens_per_page),
-                tenant=tenant,
+            charge, pages = (None, [1]) if self._by_slot else (
+                self.pool.allocate(
+                    self._kv.pages_for(total, self.pool.tokens_per_page),
+                    tenant=tenant,
+                )
             )
         except self._kv.PagesExhausted as e:
             with self._cv:
@@ -1479,6 +1499,19 @@ class DecodeScheduler:
         )
         req.done.set()
 
+    def _charge_slot(self, req: _PagedSeq) -> bool:
+        """Charge one slot's state to the budget for a request about to
+        be admitted (a block without pages).  A budget that cannot pay
+        refuses the request, typed, as a page reservation would have."""
+        try:
+            req.charge, _ = self.pool.allocate(1, tenant=req.tenant)
+        except self._kv.PagesExhausted as e:
+            self.refusals["pages"] += 1
+            req.error = DecodeRefused("pages", e.retry_after_ms, detail=str(e))
+            req.done.set()
+            return False
+        return True
+
     def _flush_tally(self) -> None:
         """The driver's one counter bump a step or prefill.  Closes the
         busy interval — the loop's wall time since ``_busy_mark``, which
@@ -1503,10 +1536,11 @@ class DecodeScheduler:
         observability.note_decode_driver(tally)
         tally.clear()
 
-    def _run(self, fn, *args, slot=None):
+    def _run(self, fn, *args, slot=None, start=None):
         """Dispatch a serving executable on the current pools (and, for a
         block that is not the dense one, the convolution state, with the
-        admitted ``slot`` for a prefill), keep what it returns of them —
+        admitted ``slot`` for a prefill, and for a chunk that may resume
+        the position it starts at), keep what it returns of them —
         the executables donate the pools they are passed, so the arrays
         held before the call are gone after it — and hand back ``(tokens,
         stats)``: device arrays, ``stats`` the dispatch's routing counts
@@ -1514,6 +1548,15 @@ class DecodeScheduler:
         if self.cfg.block.stateless:
             toks, self._kp, self._vp = self._dispatch(
                 fn, self._params, *args, self._kp, self._vp, self.cfg
+            )
+            return toks, None
+        if self._by_slot:
+            at = {} if slot is None else {
+                "slot": np.array([slot], np.int32), "start": start,
+            }
+            toks, self._ret = self._dispatch(
+                functools.partial(fn, retention=self._ret, **at),
+                self._params, *args, None, None, self.cfg,
             )
             return toks, None
         extra = () if slot is None else (np.array([slot], np.int32),)
@@ -1626,6 +1669,8 @@ class DecodeScheduler:
                             self.pool.free(req.charge)
                             req.done.set()
                             continue
+                        if self._by_slot and not self._charge_slot(req):
+                            continue
                         slot = self._free.pop()
                         self._tables[slot] = req.table_row
                         self._indices[slot] = 0
@@ -1714,8 +1759,9 @@ class DecodeScheduler:
                 # pools it was given (they are donated).  No sequence is
                 # left to read them: the next request starts on fresh ones,
                 # the old dropped first so that two pairs never stand
-                self._kp = self._vp = self._state = None
+                self._kp = self._vp = self._state = self._ret = None
                 self._kp, self._vp, self._state = self.pool.zeros()
+                self._ret = self.pool.retention_zeros()
 
     def _prefill(self, admitted, jnp) -> bool:
         """The disaggregated prefill lane: ONE dispatch per admitted
@@ -1726,6 +1772,25 @@ class DecodeScheduler:
         with self._cv:
             return bool(self._active)
 
+    def _first_token(self, slot: int, req: _PagedSeq, tok: int) -> None:
+        """A prefill's token is the request's first: the slot's frontier,
+        the stamps, and retirement if the stream is one token long."""
+        with self._cv:
+            self._indices[slot] = int(req.prompt.size)
+            self._toks[slot] = tok
+            req.out.append(tok)
+            req.emitted += 1
+            req.t_first = time.perf_counter_ns()
+            observability.instant(
+                "decode.first_token", _DECODE_TRACK, cid=req.cid,
+                ttft_us=(req.t_first - req.t_submit) // 1000,
+            )
+            if req.emitted >= req.max_new or (
+                req.until is not None and bool(req.until(tok))
+            ):
+                self._retire_locked(slot, req)
+            self.total_tokens += 1
+
     def _prefill_one(self, slot: int, req: _PagedSeq, jnp) -> None:
         """One request's prefill (``kv_pager.paged_prefill``: one row,
         its table row, the head at its last position) at the bucket of
@@ -1734,6 +1799,8 @@ class DecodeScheduler:
         and its stamps follow it, not the boundary."""
         tally = self._tally
         lp = int(req.prompt.size)
+        if self._by_slot:
+            return self._prefill_resuming(slot, req, jnp)
         lb = min(max(bucketing.bucket_for(lp), 1), self.cap)
         lb = max(lb, lp)
         with observability.span(
@@ -1754,23 +1821,9 @@ class DecodeScheduler:
                 tok = int(tok0[0])
             if self._routing_keep and chosen is not None:
                 req.routing.append(chosen[:, :lp])
-            with self._cv:
-                self.prefill_batches += 1
-                self._indices[slot] = lp
-                self._toks[slot] = tok
-                req.out.append(tok)
-                req.emitted += 1
-                req.t_first = time.perf_counter_ns()
-                ttft = req.t_first - req.t_submit
-                observability.instant(
-                    "decode.first_token", _DECODE_TRACK,
-                    cid=req.cid, ttft_us=ttft // 1000,
-                )
-                if req.emitted >= req.max_new or (
-                    req.until is not None and bool(req.until(tok))
-                ):
-                    self._retire_locked(slot, req)
-                self.total_tokens += 1
+            self._first_token(slot, req, tok)
+            self.prefill_batches += 1
+            ttft = req.t_first - req.t_submit
         tally["decode_prefill_ns"] += sp.ns
         tally["decode_prefill_batches"] += 1
         tally["decode_prefill_prompt_tokens"] += lp
@@ -1778,5 +1831,52 @@ class DecodeScheduler:
         tally["decode_admitted"] += 1
         tally["decode_first_tokens"] += 1
         tally["decode_ttft_ns"] += ttft
+        tally["decode_tokens"] += 1
+        self._flush_tally()
+
+    def _prefill_resuming(self, slot: int, req: _PagedSeq, jnp) -> None:
+        """:meth:`_prefill_one` for a block whose state resumes: the prompt
+        in dispatches of at most ``retention.PREFILL_TOKENS`` tokens, each
+        padded to its own bucket, the first from zeros and every later one
+        from the state the one before left in the slot.  Only the last
+        one's token is the request's first, and only it is waited for."""
+        from ..models import retention
+
+        tally = self._tally
+        lp, fed = int(req.prompt.size), 0
+        while fed < lp:
+            n = min(lp - fed, retention.PREFILL_TOKENS)
+            lb = max(min(bucketing.bucket_for(n), retention.PREFILL_TOKENS), n)
+            with observability.span(
+                "decode.prefill", _DECODE_TRACK,
+                bucket=lb, admitted=1, slots=str(slot), start=fed,
+            ) as sp:
+                toks = np.zeros((1, lb), np.int32)
+                toks[0, :n] = req.prompt[fed:fed + n]
+                tok0, _ = self._run(
+                    self._kv.paged_prefill,
+                    jnp.asarray(toks),
+                    None,
+                    jnp.asarray(np.array([n - 1], np.int32)),
+                    slot=slot,
+                    start=jnp.asarray(np.array([fed], np.int32)),
+                )
+                last = fed + n >= lp
+                if last:
+                    with observability.span(
+                        "decode.prefill.wait", _DECODE_TRACK
+                    ):
+                        tok = int(np.asarray(tok0)[0])
+                    self._first_token(slot, req, tok)
+            tally["decode_prefill_ns"] += sp.ns
+            tally["decode_prefill_batches"] += 1
+            tally["decode_prefill_resumes"] += int(fed > 0)
+            self.prefill_batches += 1
+            tally["decode_prefill_prompt_tokens"] += n
+            tally["decode_prefill_run_tokens"] += lb
+            fed += n
+        tally["decode_admitted"] += 1
+        tally["decode_first_tokens"] += 1
+        tally["decode_ttft_ns"] += req.t_first - req.t_submit
         tally["decode_tokens"] += 1
         self._flush_tally()

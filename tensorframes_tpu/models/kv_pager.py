@@ -59,6 +59,18 @@ serving removed (PAPERS.md).  This module is the paged layout:
   the latent space, a prefill attends over its own rows expanded by head
   (:func:`_latent_attention`).  The kernel does not read such a pool
   (:func:`paged_kernel_fits` refuses the block);
+* a retention block (``BlockSpec(attention="retention")``,
+  ``models/retention.py``) has NO pages: a sequence's whole past is a
+  float32 state of fixed size a layer (34 MB at heads of 128), held per
+  decode slot in ``PagePool.retention`` — ``(S [n_layers, slots, kvh, O,
+  dh, dh], z [n_layers, slots, kvh, O, dh])`` — an argument and a result
+  of both executables, donated and carried by the scan like the pools.  A
+  decode step updates and reads a live slot's state in one pass of the
+  kernel ``parallel/retention.py`` (or the ``jnp`` step where it does not
+  fit); a prefill runs the chunked form from the slot's state, or from
+  zeros where the dispatch starts the sequence, and leaves the state of
+  the prompt's last real token.  A "page" of such a pool is one slot's
+  state, all layers: what a sequence costs at any length;
 * :func:`paged_prefill` is the serving prefill — ONE admitted prompt
   and nothing else.  A prefill starts at position 0, so the only keys
   its queries may see are the chunk's own: it writes them to the pages
@@ -89,12 +101,13 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from . import cca, mla, moe
+from . import cca, mla, moe, retention
 from . import transformer as tfm
 from .. import observability
 from ..envutil import env_int as _env_int
 from ..ops import frame_cache
 from ..parallel import paged_attention
+from ..parallel import retention as retention_kernel
 
 ENV_PAGE_TOKENS = "TFS_DECODE_PAGE_TOKENS"
 DEFAULT_PAGE_TOKENS = 16
@@ -161,7 +174,11 @@ class PagePool:
     and from capacity accounting.
 
     For a ``cca`` block the pool also holds ``conv_state``, the per-slot
-    convolution state (``slots`` rows a layer; None for other blocks)."""
+    convolution state (``slots`` rows a layer; None for other blocks).
+    For a ``retention`` block it holds NO pages (``k_pages`` / ``v_pages``
+    None) and ``retention``, the per-slot state ``(S, z)`` in float32,
+    taken by :meth:`take_retention`; a page is then one slot's state, so
+    ``n_pages`` is the slots and one more, and a sequence allocates ONE."""
 
     def __init__(
         self,
@@ -189,21 +206,26 @@ class PagePool:
         # array [n_layers, slots, width] beside the pages, indexed by the
         # decode slot, threaded through the executables like the pages
         self.slots = None
-        if cfg.block.attention == "cca":
+        if cfg.block.attention in ("cca", "retention"):
             if slots is None:
                 raise ValueError(
-                    "a 'cca' block keeps a convolution state per decode "
-                    "slot: PagePool(..., slots=max_slots)"
+                    f"a {cfg.block.attention!r} block keeps a state per "
+                    f"decode slot: PagePool(..., slots=max_slots)"
                 )
             self.slots = int(slots)
         self.k_pages, self.v_pages, self.conv_state = self.zeros()
+        self.retention = self.retention_zeros()
         # one page's HBM across all layers, of every pool there is (K and
         # V together, or the one latent pool) — the unit the budget LRU
-        # accounts, read off the pools' own shapes
+        # accounts, read off the pools' own shapes; a retention block's
+        # unit is one slot's state, read off the state's
         self.page_bytes = sum(
             pages.size // self.n_pages * self.dtype.itemsize
             for pages in (self.k_pages, self.v_pages)
             if pages is not None
+        ) + sum(
+            a.size // self.slots * a.dtype.itemsize
+            for a in self.retention or ()
         )
         self._lock = threading.Lock()
         # LIFO free list (page 0 reserved as trash)
@@ -218,12 +240,14 @@ class PagePool:
         ``mla.row_width`` values laid out as one head; it travels where
         the K pool does, and ``v_pages`` is None."""
         cfg = self.cfg
+        if cfg.block.attention == "retention":
+            return None, None, None
         if cfg.block.attention == "mla":
             heads, widths = 1, (mla.row_width(cfg), None)
         else:
             heads, widths = cfg.n_kv_heads, (cfg.head_dim,) * 2
         state = None
-        if self.slots is not None:
+        if cfg.block.attention == "cca":
             state = cca.init_state(cfg, self.slots, self.dtype)
         k, v = (
             None if w is None else jnp.zeros(
@@ -242,6 +266,19 @@ class PagePool:
         arrays = self.k_pages, self.v_pages, self.conv_state
         self.k_pages = self.v_pages = self.conv_state = None
         return arrays
+
+    def retention_zeros(self):
+        """A retention block's state of the pool's slots, all zero: ``(S,
+        z)`` in float32 whatever the pool's dtype; None for other blocks."""
+        if self.cfg.block.attention != "retention":
+            return None
+        return retention.init_state(self.cfg, self.slots)
+
+    def take_retention(self):
+        """:meth:`take` for the retention state: both executables donate
+        it, and 5 GB have no room for a second holder."""
+        state, self.retention = self.retention, None
+        return state
 
     # -- allocation ----------------------------------------------------------
 
@@ -294,8 +331,8 @@ class PagePool:
         unreachable through any live table and masked to exact zero
         weight even when a recycled page sits inside a new sequence's
         gather window."""
-        pages = charge.pages
-        if not pages:
+        pages = charge.pages if charge is not None else None
+        if not pages:  # nothing held (a request no slot was ever given)
             return
         charge.pages = []
         with self._lock:
@@ -428,6 +465,14 @@ def _feed_forward(bp, x, cfg, layer, route):
     return x + out, tuple(routed)
 
 
+def _mesh_partitions() -> bool:
+    """Whether the ambient mesh has an axis left to partition over, which
+    a Mosaic kernel cannot be (``flash._per_shard``; the scheduler runs on
+    one device)."""
+    mesh = jax.sharding.get_abstract_mesh()
+    return any(t != jax.sharding.AxisType.Manual for t in mesh.axis_types)
+
+
 def paged_kernel_fits(cfg, P: int, B: int, L: int, dtype) -> bool:
     """Whether a chunk attends through the Pallas kernel
     (``parallel/paged_attention.py``), decided at trace time from what
@@ -443,17 +488,13 @@ def paged_kernel_fits(cfg, P: int, B: int, L: int, dtype) -> bool:
     its keys' first part, it cannot.  Whatever it refuses takes the
     gather path."""
     dtype = jnp.dtype(dtype)
-    mesh = jax.sharding.get_abstract_mesh()
-    partitioned = any(
-        t != jax.sharding.AxisType.Manual for t in mesh.axis_types
-    )
     return (
         L == 1
         and cfg.block.attention != "mla"
         and cfg.head_dim % 128 == 0
         and P % (32 // dtype.itemsize) == 0
         and dtype == jnp.dtype(cfg.dtype)
-        and not partitioned
+        and not _mesh_partitions()
         and paged_attention.vmem_bytes(
             B, cfg.n_heads, cfg.n_kv_heads, cfg.head_dim, P, dtype
         ) <= paged_attention.VMEM_BUDGET_BYTES
@@ -489,6 +530,70 @@ def _latent_attention(bp, x, positions, cfg, pages, tables, layer,
     return _attn_out(bp, x, att, cfg), pages
 
 
+def retention_kernel_fits(cfg) -> bool:
+    """Whether a retention block's decode step runs through the Pallas
+    kernel (``parallel/retention.py``), decided at trace time like
+    :func:`paged_kernel_fits`: heads of one lane tile and no mesh axis
+    left to partition over.  What it refuses takes ``retention.step``."""
+    return retention_kernel.fits(cfg.head_dim) and not _mesh_partitions()
+
+
+def _retention_step(bp, x, positions, cfg, st, live, layer):
+    """The attention half of a ``retention`` block for ONE token a row
+    against ``layer`` of the stacked state ``st = (S, z)``: ``(x', st')``.
+    A live row's state is decayed, takes the token and is read out, in one
+    pass where the kernel fits; a row that holds no sequence keeps its
+    state untouched and reads zeros."""
+    if x.shape[1] != 1:
+        raise NotImplementedError(
+            "a retention block steps one token a row; a chunk is a prefill"
+        )
+    S, z = st
+    q, k, v, log_g = (
+        t[:, 0] for t in retention.project(bp, x, positions, cfg)
+    )
+    with jax.named_scope("retention_step"):
+        if retention_kernel_fits(cfg):
+            y, S, z = retention_kernel.retention_step(
+                retention.scaled(q), retention.scaled(k),
+                v.astype(jnp.float32), log_g, S, z, live, layer,
+            )
+        else:
+            y, s1, z1 = retention.step(q, k, v, log_g, S[layer], z[layer], live)
+            S, z = S.at[layer].set(s1), z.at[layer].set(z1)
+    return _attn_out(bp, x, y.astype(cfg.dtype)[:, None], cfg), (S, z)
+
+
+def _retention_prefill(bp, x, positions, cfg, st, layer, slot, last_pos,
+                       resume):
+    """The attention half of a ``retention`` block for a chunk of ONE
+    sequence: the chunked form from the slot's state of ``layer`` where
+    the dispatch resumes a sequence, from zeros where it starts one — so
+    nothing of the slot's previous tenant survives admission — leaving in
+    the slot what the last real token leaves.  ``(x', st')``."""
+    S, z = st
+    q, k, v, log_g = (t[0] for t in retention.project(bp, x, positions, cfg))
+    valid = jnp.arange(x.shape[1], dtype=jnp.int32) <= last_pos[0]
+    # int32 by hand: with x64 on, a Python 0 beside them is an int64
+    at = (jnp.asarray(layer, jnp.int32), slot[0].astype(jnp.int32))
+    zero = jnp.int32(0)
+
+    def held(a):
+        mine = jax.lax.dynamic_slice(
+            a, at + (zero,) * (a.ndim - 2), (1, 1) + a.shape[2:]
+        )[0, 0]
+        return jnp.where(resume, mine, 0.0)
+
+    y, s1, z1 = retention.chunked(q, k, v, log_g, held(S), held(z), valid)
+    S, z = (
+        jax.lax.dynamic_update_slice(
+            a, new[None, None], at + (zero,) * new.ndim
+        )
+        for a, new in ((S, s1), (z, z1))
+    )
+    return _attn_out(bp, x, y.astype(cfg.dtype)[None], cfg), (S, z)
+
+
 def _paged_block(bp, x, positions, cfg, kp, vp, tables, layer, st=None,
                  route=None):
     """One decoder block against ``layer``'s pages of the stacked pools.
@@ -511,6 +616,14 @@ def _paged_block(bp, x, positions, cfg, kp, vp, tables, layer, st=None,
     layer's rows; an ``experts_top1`` block takes ``route``
     (:func:`_feed_forward`).  Returns ``(x', kp', vp', st', routed)``,
     the last two None where the spec has no such thing."""
+    if cfg.block.attention == "retention":
+        # no pages: ``st`` is the state, ``tables[:, 0]`` says who is live
+        with jax.named_scope("attention"):
+            x, st = _retention_step(
+                bp, x, positions, cfg, st, tables[:, 0] > 0, layer
+            )
+        x, routed = _feed_forward(bp, x, cfg, layer, route)
+        return x, kp, vp, st, routed
     B, L = x.shape[:2]
     dt = cfg.dtype
     P = kp.shape[3]
@@ -673,7 +786,8 @@ def _step_forward(params, tokens, tables, indices, k_pages, v_pages, cfg,
     """A token chunk against the paged cache, whatever the block:
     ``(logits, k_pages', v_pages', state', stats)``; see
     :func:`apply_paged`.  ``state`` [n_layers, B, width] is the ``cca``
-    convolution state of the rows, ``stats`` is :func:`_routing` over the
+    convolution state of the rows (for a ``retention`` block the rows'
+    ``(S, z)``, and no pages), ``stats`` is :func:`_routing` over the
     rows that hold a sequence (a reserved page: ``tables[:, 0]``)."""
     B, L = tokens.shape
     positions = indices[:, None] + jnp.arange(L, dtype=jnp.int32)[None, :]
@@ -722,13 +836,16 @@ def apply_paged(
 
 
 def _prefill_forward(params, toks, table, last_pos, k_pages, v_pages, cfg,
-                     state=None, slot=None):
+                     state=None, slot=None, start=None):
     """ONE sequence from position 0, whatever the block: ``(logits [1, V]
     at last_pos, k_pages', v_pages', state', stats)``; see
     :func:`paged_prefill`.  A ``cca`` block's state row of ``slot`` [1] is
     OVERWRITTEN with what the prompt's last real position leaves, so
     nothing of the slot's previous tenant survives admission; tokens past
-    ``last_pos`` are padding, routed to no expert and counted nowhere."""
+    ``last_pos`` are padding, routed to no expert and counted nowhere.  A
+    ``retention`` block's chunk may also CONTINUE its sequence, from
+    position ``start`` [1] and the slot's state (``state`` is ``(S, z)``):
+    a chunk at ``start`` 0 starts from zeros."""
     B, L = toks.shape
     positions = jnp.broadcast_to(jnp.arange(L, dtype=jnp.int32), (B, L))
     x = tfm.embed_lookup(params["embed"], toks, cfg.dtype)
@@ -737,6 +854,14 @@ def _prefill_forward(params, toks, table, last_pos, k_pages, v_pages, cfg,
         live = positions <= last_pos[:, None]
 
     def block(bp, x, kp, vp, st, layer, route):
+        if cfg.block.attention == "retention":
+            with jax.named_scope("attention"):
+                x, st = _retention_prefill(
+                    bp, x, positions + start[:, None], cfg, st, layer, slot,
+                    last_pos, start[0] > 0,
+                )
+            x, routed = _feed_forward(bp, x, cfg, layer, route)
+            return x, kp, vp, st, routed
         x, kp, vp, tail, routed = _prefill_block(
             bp, x, positions, cfg, kp, vp, table, layer, route
         )
@@ -766,16 +891,21 @@ def _prefill_forward(params, toks, table, last_pos, k_pages, v_pages, cfg,
 # state (5 MB at ZAYA1's widths, against the pools' 1 GB) is carried by the
 # scan like the pools but NOT donated: the benchmark's fault test
 # (``perfbench/tests/test_correct_zaya.py``) hands back the state it
-# passed, to see that a stale one is caught.
-_DONATED = ("k_pages", "v_pages")
+# passed, to see that a stale one is caught.  A ``retention`` block's state
+# IS its pool (5.45 GB at 20 slots of 8 layers: no second copy fits), an
+# argument of its own, ``retention``, donated like the pools.
+_DONATED = ("k_pages", "v_pages", "retention")
 
 
 def _results(tokens, k_pages, v_pages, state, stats, cfg):
     """What a serving executable returns: the dense block's three, and for
     any other block also its state and its routing (:func:`_routing`;
-    either may be None), which a dense model has none of."""
+    either may be None), which a dense model has none of.  A retention
+    block has no pages to return: its tokens and its state."""
     if cfg.block.stateless:
         return tokens, k_pages, v_pages
+    if cfg.block.attention == "retention":
+        return tokens, state
     return tokens, k_pages, v_pages, state, stats
 
 
@@ -783,7 +913,7 @@ def _results(tokens, k_pages, v_pages, state, stats, cfg):
     jax.jit, static_argnames=("cfg",), donate_argnames=_DONATED
 )
 def paged_decode_step(params, toks, tables, indices, k_pages, v_pages, cfg,
-                      state=None):
+                      state=None, retention=None):
     """One greedy decode step for the whole slot batch: toks [B] ->
     next tokens [B].  Fixed [max_slots] shapes — the ONE executable the
     scheduler reuses for every step of every request population (idle
@@ -791,9 +921,13 @@ def paged_decode_step(params, toks, tables, indices, k_pages, v_pages, cfg,
     Returns ``(next, k_pages', v_pages')``; a block that is not the dense
     one (``cfg.block``) also takes the pool's convolution ``state`` and
     returns ``(..., state', stats)`` (:func:`_results`).  The pools are
-    donated: the call consumes them and returns them updated in place."""
+    donated: the call consumes them and returns them updated in place.  A
+    ``retention`` block takes no pools (None) but its state ``retention``
+    ``(S, z)``, donated, and ``tables`` [B, 1] that say which rows hold a
+    sequence (> 0): ``(next, (S', z'))``."""
     logits, k_pages, v_pages, state, stats = _step_forward(
-        params, toks[:, None], tables, indices, k_pages, v_pages, cfg, state
+        params, toks[:, None], tables, indices, k_pages, v_pages, cfg,
+        state if retention is None else retention,
     )
     nxt = jnp.argmax(logits[:, -1], axis=-1).astype(jnp.int32)
     return _results(nxt, k_pages, v_pages, state, stats, cfg)
@@ -803,7 +937,7 @@ def paged_decode_step(params, toks, tables, indices, k_pages, v_pages, cfg,
     jax.jit, static_argnames=("cfg",), donate_argnames=_DONATED
 )
 def paged_prefill(params, toks, table, last_pos, k_pages, v_pages, cfg,
-                  state=None, slot=None):
+                  state=None, slot=None, retention=None, start=None):
     """Prefill of ONE newly admitted sequence, and of nothing else: toks
     [1, Lb] (the prompt padded to its own bucket), ``table`` [1,
     max_pages] its page-table row, ``last_pos`` [1] its final REAL
@@ -820,9 +954,13 @@ def paged_prefill(params, toks, table, last_pos, k_pages, v_pages, cfg,
     convolution ``state`` and the sequence's ``slot`` [1] and returns
     ``(..., state', stats)`` as :func:`paged_decode_step` does, the pools
     donated as there.  One executable per prompt bucket (the ladder
-    bounds the grid)."""
+    bounds the grid).  A ``retention`` block takes no pools and no table
+    (None) but its state ``retention``, donated, the ``slot`` and the
+    position ``start`` [1] the chunk continues its sequence from (0: it
+    starts one), and returns ``(tok, (S', z'))``."""
     logits, k_pages, v_pages, state, stats = _prefill_forward(
-        params, toks, table, last_pos, k_pages, v_pages, cfg, state, slot
+        params, toks, table, last_pos, k_pages, v_pages, cfg,
+        state if retention is None else retention, slot, start,
     )
     tok0 = jnp.argmax(logits, axis=-1).astype(jnp.int32)
     return _results(tok0, k_pages, v_pages, state, stats, cfg)

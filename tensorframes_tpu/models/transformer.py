@@ -109,7 +109,10 @@ class BlockSpec:
     #   decode step also needs a fixed-size per-sequence convolution state;
     # "mla": latent attention (``models/mla.py``, sizes in ``latent``):
     #   ONE pool of pages, a token's normed latent and its rotated key
-    #   part side by side, which a decode step attends over as they lie
+    #   part side by side, which a decode step attends over as they lie;
+    # "retention": power retention (``models/retention.py``): NO pages, a
+    #   float32 state of fixed size a sequence a layer, with per-head
+    #   RMSNorm on q and k and a gate a KV head
     attention: str = "gqa"
     # "swiglu": the dense SwiGLU (or the capacity-routed ``moe.moe_mlp``
     #   when ``moe_experts`` > 0, the training path);
@@ -139,9 +142,10 @@ class BlockSpec:
     yarn: Optional[Yarn] = None
 
     def __post_init__(self):
-        if self.attention not in ("gqa", "cca", "mla"):
+        if self.attention not in ("gqa", "cca", "mla", "retention"):
             raise ValueError(
-                f"attention {self.attention!r}: 'gqa', 'cca' or 'mla'"
+                f"attention {self.attention!r}: 'gqa', 'cca', 'mla' or "
+                f"'retention'"
             )
         if self.ffn not in ("swiglu", "experts_top1", "experts_topk"):
             raise ValueError(

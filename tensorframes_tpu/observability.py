@@ -227,6 +227,10 @@ _counters: Dict[str, int] = {
     # the tokens a decode step attended over, summed over steps: what
     # every live slot held, the token it fed among them
     "decode_tokens_held": 0,
+    # a block that keeps a state a slot and no pages (retention): the
+    # prefill dispatches that resumed from a state left in the slot (a
+    # prompt longer than one dispatch)
+    "decode_prefill_resumes": 0,
     # expert routing of a served model (``moe.experts_top1`` /
     # ``experts_topk``), counted on the device over live tokens only and
     # read back with a dispatch's tokens: layer-steps routed (expert
@@ -1210,6 +1214,7 @@ def counters_delta(
             "moe_experts_touched",
             "moe_picked_pairs",
             "decode_tokens_held",
+            "decode_prefill_resumes",
             "decode_steps",
             "decode_kernel_steps",
             "decode_host_ns",

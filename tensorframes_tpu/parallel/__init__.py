@@ -20,7 +20,7 @@ sizes it; ``pool_devices()``/``pool_enabled()`` report the resolved pool.
 
 import sys
 
-# This package's Pallas kernels (``flash``, ``paged_attention``) are TPU
+# This package's Pallas kernels (``flash``, ``paged_attention``, ``retention``) are TPU
 # kernels.  ``jax.experimental.pallas`` imports its GPU interpreter beside
 # the TPU backend — an LLVM dialect and Mosaic GPU, 0.7 s of the 1.2 s the
 # import takes on a v5e host, in the set-up of every process that serves

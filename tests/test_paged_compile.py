@@ -29,14 +29,15 @@ ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 if ROOT not in sys.path:
     sys.path.insert(0, ROOT)
 
-from perfbench.drivers import bridge_decode_axk1  # noqa: E402
+from perfbench.drivers import bridge_decode_axk1, bridge_decode_brumby  # noqa: E402
 from perfbench.drivers.bridge_decode_zaya import transformer_config  # noqa: E402
 from perfbench.refs import (  # noqa: E402
-    axk1_decoder, inception_v3, transformer_decoder, zaya_decoder,
+    axk1_decoder, brumby_decoder, inception_v3, transformer_decoder, zaya_decoder,
 )
-from tensorframes_tpu.models import cca, inception, kv_pager, mla  # noqa: E402
+from tensorframes_tpu.models import cca, inception, kv_pager, mla, retention  # noqa: E402
 from tensorframes_tpu.models import transformer as tfm  # noqa: E402
 from tensorframes_tpu.parallel import paged_attention as pa  # noqa: E402
+from tensorframes_tpu.parallel import retention as rk  # noqa: E402
 
 # (configuration, the traffic file whose slots and capacity serve it)
 CELLS = {
@@ -79,6 +80,7 @@ def compiled_not_interpreted(monkeypatch):
     jax.config.update("jax_enable_compilation_cache", False)
     compilation_cache.reset_cache()
     monkeypatch.setattr(pa, "_resolve_interpret", lambda interpret: False)
+    monkeypatch.setattr(rk, "_resolve_interpret", lambda interpret: False)
     with jax.enable_x64(False):
         yield
     jax.config.update("jax_enable_compilation_cache", was)
@@ -384,3 +386,128 @@ def test_scoring_executable_stores_no_float32_activation(
     large = [b for b in _entry_f32_results(compiled.as_text()) if b >= 100e6]
     assert sum(large) < 1e9, (len(large), sum(large))
     assert compiled.memory_analysis().temp_size_in_bytes < 5e9
+
+
+# ---------------------------------------------------------------------------
+# the retention block (Brumby): no pages, a float32 state a slot, a kernel
+# of its own for the step
+# ---------------------------------------------------------------------------
+
+
+def _retention_cell(sharding):
+    """``(cfg, weights, state, slots)`` of ``brumby_14b_l8.decode_documents``
+    as shapes on the described chip."""
+    m = _load("configs", "brumby_14b_l8")
+    serve = _load("traffic", "decode_documents")["serve"]
+    dtype = jnp.dtype(m["dtype"])
+    cfg = bridge_decode_brumby.transformer_config(m, serve["max_seq"], dtype)
+
+    def on_chip(tree):
+        return jax.tree.map(
+            lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=sharding),
+            tree,
+        )
+
+    weights = on_chip(jax.eval_shape(lambda: brumby_decoder.make_weights(0, m, dtype)))
+    state = on_chip(
+        jax.eval_shape(lambda: retention.init_state(cfg, serve["max_slots"]))
+    )
+    return cfg, weights, state, serve["max_slots"]
+
+
+def _state_values(text, state):
+    """``(opcode, line)`` of every instruction whose result is as large as
+    the stacked state, one layer of it, or one slot's of a layer."""
+    S = state[0].shape
+    sizes = {math.prod(S), math.prod(S[1:]), math.prod(S[2:])}
+    for line in text.splitlines():
+        m = _INSTRUCTION.match(line)
+        if m and m.group(2):
+            dims = [int(d) for d in m.group(2).split(",")]
+            if math.prod(dims) in sizes:
+                yield m.group(4), line.strip()
+
+
+def test_retention_kernel_compiles_at_real_widths(one_chip, compiled_not_interpreted):
+    cfg, _, (S, z), slots = _retention_cell(one_chip)
+    assert kv_pager.retention_kernel_fits(cfg) and S.dtype == jnp.float32
+    assert S.shape == (8, 20, 8, 65, 128, 128) and z.shape == (8, 20, 8, 65, 128)
+    f32 = lambda *s: jax.ShapeDtypeStruct(s, jnp.float32, sharding=one_chip)  # noqa: E731
+    compiled = jax.jit(rk.retention_step, donate_argnums=(4, 5)).lower(
+        f32(slots, cfg.n_heads, 128), f32(slots, cfg.n_kv_heads, 128),
+        f32(slots, cfg.n_kv_heads, 128), f32(slots, cfg.n_kv_heads), S, z,
+        jax.ShapeDtypeStruct((slots,), jnp.bool_, sharding=one_chip),
+        jax.ShapeDtypeStruct((), jnp.int32, sharding=one_chip),
+    ).compile()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text and rk.KERNEL_NAME in text
+    mem = compiled.memory_analysis()
+    state_bytes = 4 * (math.prod(S.shape) + math.prod(z.shape))
+    assert mem.alias_size_in_bytes >= state_bytes  # written where it lies
+    assert mem.temp_size_in_bytes < 2**20  # the packed tiles and the walk's order
+
+
+def test_retention_step_moves_the_state_through_the_kernel_alone(
+    one_chip, compiled_not_interpreted
+):
+    """The scheduler's whole step at the cell's 20 slots of 8 layers: the
+    kernel is in it, the state (5.5 GB: a second copy does not fit) is
+    donated and aliased, and no other instruction gives a value as large as
+    the stack, a layer of it or a slot's of a layer."""
+    cfg, weights, state, slots = _retention_cell(one_chip)
+    i32 = lambda *shape: jax.ShapeDtypeStruct(  # noqa: E731
+        shape, jnp.int32, sharding=one_chip
+    )
+    compiled = kv_pager.paged_decode_step.lower(
+        weights, i32(slots), i32(slots, 1), i32(slots), None, None, cfg,
+        retention=state,
+    ).compile()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text and rk.KERNEL_NAME in text
+    assert pa.KERNEL_NAME not in text
+    moved = [
+        line[:200] for op, line in _state_values(text, state)
+        if op not in ("parameter", "get-tuple-element", "tuple", "bitcast",
+                      "while", "custom-call")
+    ]
+    assert not moved, moved
+    mem = compiled.memory_analysis()
+    state_bytes = sum(4 * math.prod(a.shape) for a in state)
+    assert mem.alias_size_in_bytes >= state_bytes
+    assert mem.temp_size_in_bytes < 2**28
+    assert (
+        mem.argument_size_in_bytes + mem.output_size_in_bytes
+        + mem.temp_size_in_bytes - mem.alias_size_in_bytes < 14.5 * 2**30
+    )
+
+
+def test_retention_prefill_fits_beside_weights_and_state(
+    one_chip, compiled_not_interpreted
+):
+    """The largest prefill dispatch (``retention.PREFILL_TOKENS`` tokens, a
+    chunk of ``retention.CHUNK`` at a time): the state stays one buffer,
+    only a slot's state of a layer is sliced out and written back, and the
+    chunk's transients fit in what weights and state leave of 16 GB."""
+    cfg, weights, state, _ = _retention_cell(one_chip)
+    i32 = lambda *shape: jax.ShapeDtypeStruct(  # noqa: E731
+        shape, jnp.int32, sharding=one_chip
+    )
+    compiled = kv_pager.paged_prefill.lower(
+        weights, i32(1, retention.PREFILL_TOKENS), None, i32(1), None, None,
+        cfg, slot=i32(1), retention=state, start=i32(1),
+    ).compile()
+    S = state[0].shape
+    whole = [
+        line[:200] for op, line in _state_values(compiled.as_text(), state)
+        if op in ("copy", "fusion", "dynamic-slice")
+        and f"[{','.join(map(str, S))}]" in line.split(" = ")[1].split("{")[0]
+        and "dynamic-update-slice" not in line
+    ]
+    assert not whole, whole  # nothing copies or recomputes the whole stack
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes >= sum(4 * math.prod(a.shape) for a in state)
+    assert mem.temp_size_in_bytes < 2**30
+    assert (
+        mem.argument_size_in_bytes + mem.output_size_in_bytes
+        + mem.temp_size_in_bytes - mem.alias_size_in_bytes < 15 * 2**30
+    )

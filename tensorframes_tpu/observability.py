@@ -281,6 +281,14 @@ _counters: Dict[str, int] = {
     "dispatch_blocks": 0,
     "dispatch_host_ns": 0,
     "readback_wait_ns": 0,
+    # a program's params resident on the devices its blocks run on
+    # (program._Residency): the block dispatches of the map verbs that
+    # found the live params on the block's device (the originals' own
+    # device, or one holding a replica; of ``dispatch_blocks``), and the
+    # bytes copied to place replicas (the params' bytes once a device
+    # and params state, not once a block)
+    "param_replica_hits": 0,
+    "param_bytes_placed": 0,
 }
 _by_verb: Dict[str, Dict[str, int]] = {}
 
@@ -1016,10 +1024,20 @@ def note_decode_driver(deltas: Mapping[str, int]) -> None:
     _bump_many(deltas)
 
 
-def note_dispatch_block(ns: int) -> None:
+def note_dispatch_block(ns: int, params_resident: bool = False) -> None:
     """One iteration of a map verb's block loop (``engine.block``):
-    start -> outputs enqueued took ``ns``."""
-    _bump_many({"dispatch_blocks": 1, "dispatch_host_ns": ns})
+    start -> outputs enqueued took ``ns``; ``params_resident``: the
+    program's live params were on the device the block ran on."""
+    deltas = {"dispatch_blocks": 1, "dispatch_host_ns": ns}
+    if params_resident:
+        deltas["param_replica_hits"] = 1
+    _bump_many(deltas)
+
+
+def note_params_placed(nbytes: int) -> None:
+    """One replica of a program's params placed on a device its blocks
+    run on (``program._Residency.replica``): ``nbytes`` copied there."""
+    _bump("param_bytes_placed", int(nbytes))
 
 
 def note_map_verb(verb_ns: int, head_ns: int, tail_ns: int) -> None:
@@ -1234,6 +1252,8 @@ def counters_delta(
             "dispatch_blocks",
             "dispatch_host_ns",
             "readback_wait_ns",
+            "param_replica_hits",
+            "param_bytes_placed",
         )
     }
 

@@ -85,6 +85,11 @@ class Work:
     def check(self, bi: int, outs) -> None:
         """Validate one block's outputs before they are collected."""
 
+    def params_resident(self, outs) -> bool:
+        """Whether the program's live params are on the device that
+        produced ``outs`` (the ``param_replica_hits`` counter)."""
+        return False
+
     def oom_split(self, bi: int, session, devices, pool, di):
         """The block's OOM-degradation closure for ``session.run``."""
         return None
@@ -354,7 +359,7 @@ def run_blocks(
                     }
                 )
         if times is not None:
-            observability.note_dispatch_block(ns)
+            observability.note_dispatch_block(ns, work.params_resident(outs))
     if times is not None:
         times.last_block()
     if to_host:

@@ -45,7 +45,7 @@ import numpy as np
 
 from .. import cancellation, dtypes, envutil, faults, observability
 from ..frame import Column, TensorFrame
-from ..program import Program
+from ..program import Program, device_of
 from ..schema import ColumnInfo, Schema
 from ..shape import Shape, ShapeError, UNKNOWN
 from . import (
@@ -213,6 +213,13 @@ class _MapWork(block_loop.Work):
     def check(self, bi, outs):
         self.ex._check_block_outputs(
             self.program, outs, self.sizes[bi], self.rows_level, self.trim
+        )
+
+    def params_resident(self, outs):
+        # a block's outputs lie where it ran, whatever the placement
+        # (and wherever a quarantine redirect or a retry landed it)
+        return self.program.params_resident(
+            device_of(next(iter(outs.values())))
         )
 
     # -- OOM degradation (round 9, ops/fault_tolerance.py) ------------------

@@ -26,6 +26,7 @@ from __future__ import annotations
 import dataclasses
 import hashlib
 import inspect
+import threading
 from typing import Any, Callable, Dict, List, Mapping, Optional, Sequence
 
 import jax
@@ -57,6 +58,91 @@ class GraphNodeSummary:
     def __repr__(self):
         role = "input" if self.is_input else "output"
         return f"{self.name}[{role}]: {self.scalar_type}{self.shape}"
+
+
+def device_of(array):
+    """The one device ``array`` lies on; None for a host array, a tracer
+    or an array laid out over several devices."""
+    sharding = getattr(array, "sharding", None)
+    if sharding is None or len(sharding.device_set) != 1:
+        return None
+    (dev,) = sharding.device_set
+    return dev
+
+
+def _committed_device(args):
+    """The one device the array arguments of a call are committed to.
+
+    jax runs a computation where its committed arguments live and moves
+    every uncommitted argument there AT EVERY CALL.  None when nothing
+    is committed (host arrays, fresh ``jnp`` values: the call runs on
+    the default device), when an argument spans several devices (the
+    mesh / ``shard_map`` entries) or when two arguments disagree (jax
+    raises on those itself)."""
+    found = None
+    for leaf in jax.tree_util.tree_leaves(args):
+        # tracers and host arrays have no ``committed``
+        if not getattr(leaf, "committed", False):
+            continue
+        dev = device_of(leaf)
+        if dev is None or (found is not None and dev != found):
+            return None
+        found = dev
+    return found
+
+
+class _Residency:
+    """Where ONE state of a program's params lives: the originals on
+    their ``home`` device and, made on demand, one committed replica on
+    each other device a block of the program ran on.
+
+    Keyed on the identity of the ``_params`` values it was made from
+    (kept in ``params``): ``update_params`` and the planner's direct write
+    (``_sync_probe_params``) both replace those objects, so a replica
+    of an older state is never handed out — :meth:`current` fails and
+    the program starts a new residency.  A replica is the params' bytes
+    once a device; it is freed with this object, i.e. with the program
+    or at the next params state."""
+
+    __slots__ = ("params", "home", "spread", "replicas", "_lock")
+
+    def __init__(self, params: Mapping[str, Any]):
+        self.params = dict(params)
+        devs = set()
+        self.spread = False
+        for leaf in jax.tree_util.tree_leaves(self.params):
+            on = leaf.sharding.device_set
+            # params laid out over a mesh are the mesh entries' business
+            self.spread = self.spread or len(on) != 1
+            devs |= on
+        self.home = next(iter(devs)) if len(devs) == 1 else None
+        self.replicas: Dict[Any, Dict[str, Any]] = {}
+        self._lock = threading.Lock()
+
+    def current(self, params: Mapping[str, Any]) -> bool:
+        return all(
+            a is b for a, b in zip(self.params.values(), params.values())
+        )
+
+    def resident(self, device) -> bool:
+        return device == self.home or device in self.replicas
+
+    def replica(self, device) -> Dict[str, Any]:
+        """The params committed to ``device``, placed by the first call."""
+        rep = self.replicas.get(device)
+        if rep is None:
+            with self._lock:
+                rep = self.replicas.get(device)
+                if rep is None:
+                    moved = sum(
+                        leaf.nbytes
+                        for leaf in jax.tree_util.tree_leaves(self.params)
+                        if device not in leaf.sharding.device_set
+                    )
+                    rep = jax.device_put(self.params, device)
+                    observability.note_params_placed(moved)
+                    self.replicas[device] = rep
+        return rep
 
 
 def deserialize_program(data: bytes) -> "Program":
@@ -125,6 +211,9 @@ class Program:
         # registry) can tell two states of one Program apart without
         # holding or hashing the arrays themselves
         self._params_version = 0
+        # where the current params state lives, device by device
+        # (_Residency); None until a call with committed inputs asks
+        self._residency: Optional[_Residency] = None
         for k in self._params:
             if k not in all_names:
                 raise ProgramError(
@@ -339,6 +428,9 @@ class Program:
             validated[k] = new
         self._params.update(validated)
         self._params_version += 1
+        # the replicas of the old state go with it (a direct write past
+        # this door cannot leave a stale one either: _Residency.current)
+        self._residency = None
         return self
 
     def column_for_input(self, name: str) -> str:
@@ -463,8 +555,41 @@ class Program:
 
     def _bind_live_params(self, compiled):
         """Bind the CURRENT params as the trailing traced argument at every
-        call — the one place where the live-params calling convention lives."""
-        return lambda *args: compiled(*args, self._params)
+        call — the one place where the live-params calling convention lives.
+
+        The params handed over are the ones resident on the device the
+        call's inputs are committed to (:meth:`_params_at`), so a block
+        on a pool device does not begin by copying every weight across."""
+        return lambda *args: compiled(
+            *args, self._params_at(_committed_device(args))
+        )
+
+    def _residency_now(self) -> _Residency:
+        res = self._residency
+        if res is None or not res.current(self._params):
+            res = self._residency = _Residency(self._params)
+        return res
+
+    def _params_at(self, device) -> Mapping[str, Any]:
+        """The live params for a call whose inputs are committed to
+        ``device``: the originals when it is None (nothing committed, or
+        a sharding over several devices) or their own device, else the
+        replica resident there, placed once by the first such call."""
+        params = self._params
+        if device is None or not params:
+            return params
+        res = self._residency_now()
+        if device == res.home or res.spread:
+            return params
+        return res.replica(device)
+
+    def params_resident(self, device) -> bool:
+        """Whether the live params are on ``device`` — the originals'
+        own device or one that holds a replica of the current state — so
+        that a block which ran there was called without a copy."""
+        if not self._params:
+            return True
+        return device is not None and self._residency_now().resident(device)
 
     # cap on derived compiled callables kept per Program; least-recently
     # USED evicted first so a Program reused across many short-lived

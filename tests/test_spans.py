@@ -59,7 +59,7 @@ NEW_METRICS = (
     "ttft_ms.decode", "itl_ms.decode", "dispatch_host_ms.score",
     "verb_head_ms.score", "verb_tail_ms.score",
     "readback_wait_share.score", "prefill_pad_share.decode",
-    "paged_kernel_step_share.decode",
+    "paged_kernel_step_share.decode", "params_resident_share.score",
 )
 
 
@@ -530,6 +530,7 @@ def test_benchmark_metric_file_reads_the_counters(name):
         "counters.map_head_ns": 75_000_000,
         "counters.map_tail_ns": 125_000_000,
         "counters.dispatch_blocks": 200,
+        "counters.param_replica_hits": 150,
         "counters.dispatch_host_ns": 100_000_000,
         "counters.readback_wait_ns": 5_000_000_000,
     }
@@ -546,6 +547,7 @@ def test_benchmark_metric_file_reads_the_counters(name):
         "readback_wait_share.score": 10.0,
         "prefill_pad_share.decode": 41.40625,
         "paged_kernel_step_share.decode": 100.0,
+        "params_resident_share.score": 75.0,
     }[name]
     assert read_metric(name, obs_) == pytest.approx(want)
     # the parent commit has no such counter: nothing to read, no raise

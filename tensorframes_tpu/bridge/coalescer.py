@@ -230,7 +230,7 @@ class WarmPool:
             if ent is not None:
                 self._lru.move_to_end(key)
                 ent.requests += 1
-                observability.note_warm_program(True)
+                observability.note_warm_program_hit()
                 return key, ent, True
         # build OUTSIDE the lock: GraphDef import is the expensive part
         program = compile_program(
@@ -239,7 +239,6 @@ class WarmPool:
         )
         ent = _WarmEntry(program)
         ent.requests = 1
-        observability.note_warm_program(False)
         if self.spec.cap > 0:
             with self._lock:
                 # a racing builder may have inserted the same key: keep
@@ -748,7 +747,7 @@ class Coalescer:
         finally:
             self._unregister_scope(scope)
         sp.end()
-        observability.note_coalesced_batch(len(alive), total)
+        observability.note_coalesced_batch(len(alive))
         with self._lock:
             k = len(alive)
             self._batch_hist[k] = self._batch_hist.get(k, 0) + 1

@@ -23,7 +23,7 @@ from typing import Any, Dict, Iterable, List, Mapping, Optional, Sequence, Tuple
 
 import numpy as np
 
-from . import dtypes
+from . import dtypes, observability
 from .dtypes import ScalarType
 from .schema import ColumnInfo, Schema, SchemaError
 from .shape import UNKNOWN, Shape
@@ -516,8 +516,19 @@ class TensorFrame:
                 "sharded=True requests block-affinity placement across "
                 "the pool — pass one or the other."
             )
-        if device is None and sharded is not False:
-            devs = frame_cache.shard_devices(sharded)
+        devs = (
+            frame_cache.shard_devices(sharded)
+            if device is None and sharded is not False
+            else None
+        )
+        # the placement, whichever layout runs.  The span times the
+        # calls: device_put returns before the bytes have landed, and
+        # whoever first waits on the columns pays the rest
+        with observability.span(
+            "cache.place", "cache",
+            bytes=sum(frame_cache.array_nbytes(v) for v in host.values()),
+            devices=len(devs) if devs else 1,
+        ):
             if devs:
                 # windowed frames (streaming/reader.py sets
                 # _host_windowed) have no durable host authority — the
@@ -549,7 +560,7 @@ class TensorFrame:
                         # spill-backed stand-ins
                         frame_cache.release_host_columns(out)
                     return out
-        staged = prefetch.stage_columns(host, device)
+            staged = prefetch.stage_columns(host, device)
         cols = [
             Column(c.info, staged[c.info.name])
             if c.info.name in staged

@@ -18,10 +18,14 @@ The reference's observability is a Logging trait + log4j config + pervasive
   (``jax.profiler.start_trace(dir)`` ... ``stop_trace()``) shows the
   program's host spans on the device trace's clock, arguments as event
   stats — the real tool for on-device timeline analysis — and, with the
-  flight recorder on, an event in the ring below.  The decode
-  scheduler's step and request life, the engine's block loop and the
-  pool's readback also feed always-on time counters (``*_ns``) taken at
-  the spans' own boundaries;
+  flight recorder on, an event in the ring below.  Always, whatever
+  is on: where a span ends it adds its count and its nanoseconds to one
+  table keyed by its name, which ``counters()`` carries as
+  ``span_n.<name>`` / ``span_ns.<name>`` — every span is a counter and
+  is declared nowhere else.  jax's compile durations, reported after
+  the fact, go into the same table (``compile.frontend`` / ``.backend``
+  / ``.cache_load``).  The ``*_ns`` time counters of PR 26 are twins of
+  six of those entries, kept for the benchmark files that read them;
 * ``last_spans()`` — the most recent spans as dicts (programmatic access;
   what ``bench.py`` surfaces as its phase breakdown).
 * **retrace counters** (round 7) — always-on cumulative counts of
@@ -73,7 +77,8 @@ Deliberately cheap: a disabled verb span is one ``if``; a counter bump is
 one dict increment under an uncontended lock (bridge handler threads bump
 concurrently since round 11; the paths are at most per-block, never
 per-element); a span with no profiler session and the recorder off is an
-idle ``TraceAnnotation``, two clock reads and one boolean check.
+idle ``TraceAnnotation``, two clock reads, two adds into the calling
+thread's own table (no lock) and one boolean check.
 """
 
 from __future__ import annotations
@@ -113,6 +118,23 @@ _state: Dict[str, Any] = {
 # jax.monitoring event names (stable since jax 0.4.x): one duration event
 # per XLA backend compile; one plain event per persistent-cache hit/miss
 _BACKEND_COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
+# the duration events kept in the span table (jax 0.9.0), by the name
+# each is kept under.  ``backend_compile_duration`` is taken around
+# ``compiler.compile_or_get_cached``, so ``compile.backend`` CONTAINS the
+# persistent cache's load where there was one (``compile.cache_load``:
+# ``cache_retrieval_time_sec``, read and deserialise, reported on a hit
+# only).  ``compile.frontend`` is tracing to a jaxpr plus lowering it to
+# an MLIR module.  A jit traced inside another's trace reports its own
+# trace too (hundreds of them in a model's set-up), inside the outer
+# one's time: jax announces each of the two events as it STARTS
+# (``record_scalar`` under the same name), so the depth is known and
+# only the outermost is kept — every stretch of a thread's time once.
+_DURATION_SPANS = {
+    "/jax/core/compile/jaxpr_trace_duration": "compile.frontend",
+    "/jax/core/compile/jaxpr_to_mlir_module_duration": "compile.frontend",
+    _BACKEND_COMPILE_EVENT: "compile.backend",
+    "/jax/compilation_cache/cache_retrieval_time_sec": "compile.cache_load",
+}
 _CACHE_HIT_EVENT = "/jax/compilation_cache/cache_hits"
 _CACHE_MISS_EVENT = "/jax/compilation_cache/cache_misses"
 
@@ -168,13 +190,11 @@ _counters: Dict[str, int] = {
     # multi-tenant serving throughput (round 16, bridge/coalescer.py):
     # micro-batches dispatched, requests they carried, requests that
     # dispatched ALONE on a hot program (the coalesce_miss evidence),
-    # warm program-pool traffic, and SLO-scheduler sheds by reason
+    # warm program-pool hits, and SLO-scheduler sheds by reason
     "coalesced_batches": 0,
     "coalesced_requests": 0,
-    "coalesced_rows": 0,
     "coalesce_solo_requests": 0,
     "warm_program_hits": 0,
-    "warm_program_misses": 0,
     "fair_share_sheds": 0,
     "slo_sheds": 0,
     # static program analysis (round 17, tensorframes_tpu/analysis/):
@@ -355,8 +375,10 @@ class RequestLedger:
     Mirrors every counter bump made while the ledger is the active
     request context — including bumps from prefetch staging lanes, which
     inherit the context — so ``ledger.counters`` equals the
-    process-global :func:`counters_delta` over the request's window (bit
-    for bit when no other request runs concurrently; per-request exact
+    process-global :func:`counters_delta` over the request's window,
+    over the declared counters (the span table's ``span_n.*`` /
+    ``span_ns.*`` entries are process-wide and feed no ledger; bit for
+    bit when no other request runs concurrently; per-request exact
     always, because each bump lands in exactly the ledgers active on its
     thread).  Also tracks blocks/rows per device (the pool scheduler and
     serial loops report them) and a bounded per-verb latency summary.
@@ -813,18 +835,17 @@ def note_bridge_verb_executed() -> None:
     _bump("bridge_verbs_executed")
 
 
-def note_coalesced_batch(requests: int, rows: int) -> None:
+def note_coalesced_batch(requests: int) -> None:
     """One coalesced micro-batch dispatched by the bridge coalescer
-    (``bridge/coalescer.py``) carrying ``requests`` requests totalling
-    ``rows`` rows.  A batch of one request counts as a *solo* dispatch
-    instead (:func:`note_coalesce_solo`) — the split feeds the
-    ``coalesce_miss`` doctor rule."""
+    (``bridge/coalescer.py``) carrying ``requests`` requests.  A batch
+    of one request counts as a *solo* dispatch instead
+    (:func:`note_coalesce_solo`) — the split feeds the ``coalesce_miss``
+    doctor rule."""
     if requests <= 1:
         note_coalesce_solo()
         return
     _bump("coalesced_batches")
     _bump("coalesced_requests", requests)
-    _bump("coalesced_rows", rows)
 
 
 def note_coalesce_solo() -> None:
@@ -833,10 +854,11 @@ def note_coalesce_solo() -> None:
     _bump("coalesce_solo_requests")
 
 
-def note_warm_program(hit: bool) -> None:
-    """One warm-program-pool lookup by the bridge (hit = the compiled
-    Program was resident; miss = it was rebuilt from GraphDef bytes)."""
-    _bump("warm_program_hits" if hit else "warm_program_misses")
+def note_warm_program_hit() -> None:
+    """One warm-program-pool lookup by the bridge that found the
+    compiled Program resident (a miss rebuilds it from GraphDef bytes
+    and counts nothing)."""
+    _bump("warm_program_hits")
 
 
 def note_fair_share_shed() -> None:
@@ -1117,7 +1139,23 @@ def _on_event(name: str, **kw) -> None:
         _bump("persistent_cache_misses")
 
 
+_frontend_tls = threading.local()  # .depth: frontend events open on the thread
+
+
+def _on_scalar(name: str, value: float, **kw) -> None:
+    if _DURATION_SPANS.get(name) == "compile.frontend":
+        _frontend_tls.depth = getattr(_frontend_tls, "depth", 0) + 1
+
+
 def _on_event_duration(name: str, duration: float, **kw) -> None:
+    kept = _DURATION_SPANS.get(name)
+    if kept == "compile.frontend":
+        # unannounced (another jax): depth 0, and every event is kept
+        depth = _frontend_tls.depth = getattr(_frontend_tls, "depth", 1) - 1
+        if depth > 0:
+            kept = None  # inside another's: that one's time holds it
+    if kept is not None:
+        _span_add(kept, int(duration * 1e9))
     if name == _BACKEND_COMPILE_EVENT:
         _bump("backend_compiles")
         _verb_bump("backend_compiles")
@@ -1129,14 +1167,21 @@ def install_counters() -> None:
     Idempotent; called at package import (jax is already a hard
     dependency of the engine by then).  jax offers no per-listener
     deregistration, so the listeners live for the process — they are two
-    dict increments per compile, nothing on the hot path."""
+    dict increments per compile, nothing on the hot path.  The span
+    table's ``compile.*`` names start at zero here, so a run in which
+    jax reports no such duration reads 0, not nothing."""
     global _listeners_installed
     if _listeners_installed:
         return
     import jax.monitoring
 
+    with _span_lock:
+        for kept in _DURATION_SPANS.values():
+            _span_ended.setdefault(kept, [0, 0])
+
     jax.monitoring.register_event_listener(_on_event)
     jax.monitoring.register_event_duration_secs_listener(_on_event_duration)
+    jax.monitoring.register_scalar_listener(_on_scalar)
     _listeners_installed = True
 
 
@@ -1148,114 +1193,34 @@ def counters() -> Dict[str, Any]:
     ``backend_compiles`` counts XLA compiles process-wide, including the
     engine's eager glue ops (slices/concats), so it is an upper bound on
     program compiles; ``by_verb`` attributes both to the verb that was
-    running.  Diff two snapshots (:func:`counters_delta`) to meter one
-    region."""
+    running.  The span table rides along: ``span_n.<name>`` spans ended
+    and ``span_ns.<name>`` their nanoseconds, one pair for every span
+    name that has ended in the process.  Diff two snapshots
+    (:func:`counters_delta`) to meter one region."""
     with _counters_lock:
         snap: Dict[str, Any] = dict(_counters)
         snap["by_verb"] = {k: dict(v) for k, v in _by_verb.items()}
+    for name, (n, ns) in _span_totals().items():
+        snap[_SPAN_N + name] = n
+        snap[_SPAN_NS + name] = ns
     return snap
+
+
+# high-water gauges among ``_counters``: read absolute (after
+# ``reset_peak_host_bytes()``), never differenced
+_GAUGE_COUNTERS = frozenset({"peak_host_bytes"})
 
 
 def counters_delta(
     before: Dict[str, Any], after: Optional[Dict[str, Any]] = None
 ) -> Dict[str, int]:
     """``after - before`` for the scalar counters (``after`` defaults to
-    a fresh snapshot)."""
+    a fresh snapshot): every key ``_counters`` declares but the gauges,
+    and every entry of the span table ``after`` holds."""
     after = after if after is not None else counters()
-    return {
-        k: after[k] - before.get(k, 0)
-        for k in (
-            "program_traces",
-            "backend_compiles",
-            "persistent_cache_hits",
-            "persistent_cache_misses",
-            "pool_blocks",
-            "block_retries",
-            "block_oom_splits",
-            "devices_quarantined",
-            "faults_injected",
-            "pool_copy_fallbacks",
-            "h2d_bytes_staged",
-            "cache_shard_hits",
-            "cache_evictions",
-            "bridge_deadline_exceeded",
-            "bridge_shed",
-            "bridge_retries",
-            "bridge_cancels",
-            "bridge_idem_hits",
-            "bridge_verbs_executed",
-            # peak_host_bytes is a high-water GAUGE, not a monotonic
-            # counter, so it stays out of the delta (read it absolute
-            # from counters() after reset_peak_host_bytes())
-            "stream_windows",
-            "spill_bytes_written",
-            "spill_bytes_read",
-            "plan_fused_dispatches",
-            "plan_columns_pruned",
-            "plan_cache_inserts",
-            "plan_fused_reduces",
-            "plan_cse_hits",
-            "plan_stream_windows",
-            "d2h_bytes_assembled",
-            "coalesced_batches",
-            "coalesced_requests",
-            "coalesced_rows",
-            "coalesce_solo_requests",
-            "warm_program_hits",
-            "warm_program_misses",
-            "fair_share_sheds",
-            "slo_sheds",
-            "analysis_static_hits",
-            "analysis_probe_fallbacks",
-            "shuffle_partitions_written",
-            "shuffle_bytes_spilled",
-            "join_build_rows",
-            "join_probe_rows",
-            "journal_appends",
-            "journal_bytes_written",
-            "journal_windows_skipped",
-            "journal_resumes",
-            "journal_fence_rejections",
-            "fleet_failovers",
-            "fleet_jobs_migrated",
-            "fleet_quarantines",
-            "fleet_replica_restarts",
-            "decode_tokens",
-            "kv_pages_allocated",
-            "kv_pages_freed",
-            "decode_prefill_batches",
-            "decode_prefill_prompt_tokens",
-            "decode_prefill_run_tokens",
-            "moe_route_calls",
-            "moe_routed_tokens",
-            "moe_busiest_expert_tokens",
-            "moe_experts_touched",
-            "moe_picked_pairs",
-            "decode_tokens_held",
-            "decode_prefill_resumes",
-            "decode_steps",
-            "decode_kernel_steps",
-            "decode_host_ns",
-            "decode_step_wait_ns",
-            "decode_prefill_ns",
-            "decode_busy_ns",
-            "decode_admitted",
-            "decode_queue_wait_ns",
-            "decode_first_tokens",
-            "decode_ttft_ns",
-            "decode_stream_ns",
-            "decode_stream_tokens",
-            "map_verbs",
-            "map_verb_ns",
-            "map_head_ns",
-            "map_tail_ns",
-            "dispatch_blocks",
-            "dispatch_host_ns",
-            "readback_wait_ns",
-            "param_replica_hits",
-            "param_bytes_placed",
-        )
-    }
+    keys = [k for k in _counters if k not in _GAUGE_COUNTERS]
+    keys += [k for k in after if k.startswith(_SPAN_KEYS)]
+    return {k: after[k] - before.get(k, 0) for k in keys}
 
 
 # -- flight recorder (round 13) -----------------------------------------------
@@ -1370,9 +1335,98 @@ def _ring_event(
     _trace_append(ev)
 
 
+# -- the span table ------------------------------------------------------------
+#
+# Where a span ends it adds (1, its nanoseconds) to ``[count, ns]`` under
+# its name; an instant adds (1, 0).  Always on, and the only declaration
+# a span's counter has: ``counters()`` reads the table out as
+# ``span_n.<name>`` / ``span_ns.<name>``.  A thread adds into a table of
+# its own, so the path every span takes holds no lock (measured beside
+# one lock for all: +150 ns a span against +350 on the dev box, and
+# handler threads end ``decode.request`` / ``bridge.request`` while the
+# driver thread ends ``decode.step.*``); ``_span_lock`` is taken by a
+# thread's FIRST span of a name, which makes the entry, and by a
+# snapshot, which therefore never meets a table that grows under it.
+# Exact: N threads x M spans read N x M once the threads are quiet.  A
+# snapshot of a thread in mid-span-end may see the count one ahead of
+# the time.  The table relies on names being stable (a name built from
+# a block index would grow it without bound); the request ledger is not
+# fed from it.
+
+_SPAN_N = "span_n."
+_SPAN_NS = "span_ns."
+_SPAN_KEYS = (_SPAN_N, _SPAN_NS)
+
+_span_tls = threading.local()  # .table: the calling thread's {name: [n, ns]}
+_span_lock = threading.Lock()
+_span_tables: List[Tuple[threading.Thread, Dict[str, List[int]]]] = []
+_span_ended: Dict[str, List[int]] = {}  # tables of threads that are gone
+
+
+def _span_fold(
+    into: Dict[str, List[int]], table: Dict[str, List[int]]
+) -> None:
+    for name, (n, ns) in table.items():
+        e = into.get(name)
+        if e is None:
+            into[name] = [n, ns]
+        else:
+            e[0] += n
+            e[1] += ns
+
+
+def _span_sweep() -> None:
+    """Fold the tables of threads that have ended into ``_span_ended``
+    (``_span_lock`` held).  Liveness is asked before the table is read:
+    a dead thread's table is final."""
+    live = []
+    for thread, table in _span_tables:
+        if thread.is_alive():
+            live.append((thread, table))
+        else:
+            _span_fold(_span_ended, table)
+    _span_tables[:] = live
+
+
+def _span_entry(name: str) -> List[int]:
+    """The calling thread's ``[count, ns]`` for ``name``, made here on
+    the thread's first span of that name."""
+    with _span_lock:
+        table = getattr(_span_tls, "table", None)
+        if table is None:
+            # a new thread: the moment to drop those that are gone, so a
+            # server that is never scraped keeps one table a LIVE thread
+            _span_sweep()
+            table = _span_tls.table = {}
+            _span_tables.append((threading.current_thread(), table))
+        return table.setdefault(name, [0, 0])
+
+
+def _span_add(name: str, ns: int) -> None:
+    """One span of ``ns`` under ``name``: ``span.end``'s, an instant's
+    (no time), or one timed by somebody else (jax's compile
+    durations)."""
+    try:
+        e = _span_tls.table[name]
+    except (AttributeError, KeyError):
+        e = _span_entry(name)
+    e[0] += 1
+    e[1] += ns
+
+
+def _span_totals() -> Dict[str, List[int]]:
+    """``{name: [count, ns]}`` summed over every thread, live or gone."""
+    with _span_lock:
+        _span_sweep()
+        total = {name: list(e) for name, e in _span_ended.items()}
+        for _, table in _span_tables:
+            _span_fold(total, table)
+    return total
+
+
 class span(_TraceAnnotation):
-    """THE span primitive: one program span, written in one call to both
-    places a span can go.
+    """THE span primitive: one program span, written in one call to
+    every place a span can go.
 
     * Always: it IS a ``jax.profiler.TraceAnnotation`` named
       ``"tfs:" + name`` on the calling thread.  With no profiler session
@@ -1381,11 +1435,15 @@ class span(_TraceAnnotation):
       workload, the benchmark's or an operator's — the span lands in the
       profiler's own trace, on the device trace's clock, with ``args``
       as event stats.
+    * Always: where it ends, its count and its nanoseconds go into the
+      span table under ``name`` (``span_n.<name>`` / ``span_ns.<name>``
+      in :func:`counters`), the calling thread's own, no lock.
     * Only when the flight recorder is on (:func:`trace_enabled`): one
       complete ("X") event on ``track`` in the ring, for
       :func:`dump_trace`.
 
-    Names are STABLE (``engine.block``, never ``map_blocks b3``): what
+    Names are STABLE (``engine.block``, never ``map_blocks b3``; the
+    span table keeps an entry a name for the life of the process): what
     varies rides in ``args`` (JSON-safe primitives).  The active
     request's ``cid`` is added to both.  The span starts when it is
     made and ends at :meth:`end`: ``with span(...) as sp:`` calls it, or
@@ -1415,6 +1473,7 @@ class span(_TraceAnnotation):
     def end(self, **late: Any) -> int:
         ns = self.ns = _now_ns() - self._t0
         _TraceAnnotation.__exit__(self, None, None, None)
+        _span_add(self.name, ns)
         if _trace_state["on"]:
             if late:
                 self.args.update(late)
@@ -1422,9 +1481,16 @@ class span(_TraceAnnotation):
         return ns
 
     def __exit__(self, exc_type, exc, tb) -> bool:
-        # end(), spelled out: a call less on the path every span takes
+        # end() and _span_add, spelled out: two calls less on the path
+        # every ``with span(...)`` takes (a decode step ends four)
         ns = self.ns = _now_ns() - self._t0
         _TraceAnnotation.__exit__(self, None, None, None)
+        try:
+            e = _span_tls.table[self.name]
+        except (AttributeError, KeyError):
+            e = _span_entry(self.name)
+        e[0] += 1
+        e[1] += ns
         if _trace_state["on"]:
             _ring_event(self.name, "X", self.track, self._t0, ns, self.args)
         return False
@@ -1434,13 +1500,15 @@ def instant(name: str, track: str = "events", **args: Any) -> None:
     """The instant form of :class:`span` — retries, quarantines,
     evictions, a request's admit / first-token / retire stamps: things
     that happen AT a moment.  A zero-length ``tfs:`` annotation in the
-    profiler's trace, an "i" event in the ring when the recorder is
+    profiler's trace, one more under ``span_n.<name>`` in the span
+    table (no time), an "i" event in the ring when the recorder is
     on."""
     led = _request_ctx.get()
     if led is not None and "cid" not in args:
         args["cid"] = led.correlation_id
     with _TraceAnnotation("tfs:" + name, **args):
         pass
+    _span_add(name, 0)
     if _trace_state["on"]:
         _ring_event(name, "i", track, _now_ns(), None, args)
 
@@ -1716,7 +1784,9 @@ def metrics_text(
     extra_gauges: Optional[Mapping[str, Any]] = None
 ) -> str:
     """The process's metrics in Prometheus text exposition format
-    (0.0.4): every scalar counter as ``tfs_<name>_total``, the gauges
+    (0.0.4): every scalar counter as ``tfs_<name>_total``, the span
+    table as ``tfs_span_total{span=...}`` and
+    ``tfs_span_seconds_total{span=...}``, the gauges
     (host-byte high-water, HBM budget occupancy, trace-recorder
     depth/drops, registered providers, ``extra_gauges``), and the
     latency histograms with derived p50/p95/p99 quantile gauges.  Served
@@ -1726,12 +1796,26 @@ def metrics_text(
     emitted: set = set()  # family names already declared (no dup TYPEs)
     c = counters()
     for k in sorted(c):
-        if k in ("by_verb", "peak_host_bytes"):
-            continue  # peak_host_bytes is a gauge, not a counter
+        if k == "by_verb" or k in _GAUGE_COUNTERS or k.startswith(_SPAN_KEYS):
+            # peak_host_bytes is a gauge, not a counter; the span table
+            # is two labelled families, below
+            continue
         name = f"tfs_{k}_total"
         emitted.add(name)
         lines.append(f"# TYPE {name} counter")
         lines.append(f"{name} {_fmt_metric(c[k])}")
+    spans = sorted(_span_totals().items())
+    for fam, field, per in (
+        ("tfs_span_total", 0, 1),
+        ("tfs_span_seconds_total", 1, 1e9),
+    ):
+        emitted.add(fam)
+        lines.append(f"# TYPE {fam} counter")
+        for name, entry in spans:
+            lines.append(
+                f'{fam}{{span="{_escape_label(name)}"}} '
+                f"{_fmt_metric(entry[field] / per)}"
+            )
     gauges: Dict[str, Any] = {
         "tfs_peak_host_bytes": c["peak_host_bytes"],
         "tfs_live_host_bytes": live_host_bytes(),
@@ -1964,12 +2048,11 @@ class _Span:
             # request it ran under, like every trace event does
             meta.setdefault("cid", led.correlation_id)
         self.phases: Dict[str, float] = {}
-        # snapshot UNDER the counters lock: bridge handler threads (and
-        # pool lane fallbacks) bump concurrently, and an unlocked
-        # dict(_counters) can observe a torn mid-update view exactly when
-        # the span's retrace delta matters most
-        with _counters_lock:
-            self._counters0 = dict(_counters)
+        # counters() copies UNDER the counters lock: bridge handler
+        # threads (and pool lane fallbacks) bump concurrently, and an
+        # unlocked dict(_counters) can observe a torn mid-update view
+        # exactly when the span's retrace delta matters most
+        self._counters0 = counters()
         self._t0 = time.perf_counter()
         self._last = self._t0
 

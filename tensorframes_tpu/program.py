@@ -139,7 +139,12 @@ class _Residency:
                         for leaf in jax.tree_util.tree_leaves(self.params)
                         if device not in leaf.sharding.device_set
                     )
-                    rep = jax.device_put(self.params, device)
+                    # times the call: the copies land after it returns,
+                    # under the first dispatch that reads them
+                    with observability.span(
+                        "program.place_params", "programs", bytes=moved
+                    ):
+                        rep = jax.device_put(self.params, device)
                     observability.note_params_placed(moved)
                     self.replicas[device] = rep
         return rep

@@ -254,6 +254,8 @@ def test_coalesced_ledger_row_shares_sum_to_global_delta():
             for key, v in led["counters"].items():
                 summed[key] = summed.get(key, 0) + v
         for key, v in delta.items():
+            if key.startswith(("span_n.", "span_ns.")):
+                continue  # the span table feeds no request ledger
             assert summed.get(key, 0) == v, (
                 f"ledger shares sum {summed.get(key, 0)} != global "
                 f"delta {v} for {key}"

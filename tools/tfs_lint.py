@@ -22,11 +22,16 @@ Rules (each violation prints ``file:line: [rule] message``):
   block that keeps the main suite's trace/compile fences deterministic).
 * **counter-decl** — every counter key ``observability._bump`` (or a
   literal dict given to ``_bump_many``) is called with must be declared
-  in the ``_counters`` init dict; every declared
-  counter (gauges excepted) must be listed in ``counters_delta``; no
-  delta duplicates; no registered gauge name may collide with a counter
-  family (``tfs_<name>_total``) — the ``metrics_text`` no-dup-family
-  rule, enforced at the source instead of scrape time.
+  in the ``_counters`` init dict, the one place a counter is declared
+  (``counters_delta`` and ``metrics_text`` read their keys from it); no
+  registered gauge name may collide with a counter family
+  (``tfs_<name>_total``) — the ``metrics_text`` no-dup-family rule,
+  enforced at the source instead of scrape time.
+* **span-name** — inside ``tensorframes_tpu/``, the name given to
+  ``observability.span`` / ``instant`` is never built at run time (an
+  f-string, ``%``, ``+``, ``.format``): the always-on span table keeps
+  one entry a name for the life of the process, so what varies rides in
+  the arguments.
 * **checkpoint-coverage** — in ``ops/block_loop.py`` (the engine's one
   block loop), ``ops/engine.py`` (its chunk loops) and
   ``ops/pipeline.py``, every block-dispatch loop (a ``for``/``while``
@@ -48,7 +53,7 @@ import ast
 import os
 import re
 import sys
-from typing import Dict, List, Optional, Set, Tuple
+from typing import Dict, List, Optional, Tuple
 
 PKG = "tensorframes_tpu"
 
@@ -59,10 +64,6 @@ ENV_READ_ALLOWLIST = {
     # multihost auto-detection, no TFS_* keys involved
     os.path.join(PKG, "parallel", "multihost.py"),
 }
-
-# counter keys that are GAUGES (absolute values, not monotonic deltas):
-# deliberately excluded from counters_delta
-GAUGE_COUNTERS = {"peak_host_bytes"}
 
 # block-dispatch markers for checkpoint-coverage: a loop calling any of
 # these executes verbs block-by-block on the consumer thread
@@ -242,7 +243,6 @@ def check_counters(root: str) -> List[Violation]:
     tree = ast.parse(open(path).read())
 
     declared: Dict[str, int] = {}
-    delta: List[Tuple[str, int]] = []
     bumps: List[Tuple[str, int]] = []
     gauge_names: List[Tuple[str, int]] = []
 
@@ -287,16 +287,6 @@ def check_counters(root: str) -> List[Violation]:
                     ):
                         bumps.append((k.value, k.lineno))
         if isinstance(node, ast.FunctionDef) and node.name == (
-            "counters_delta"
-        ):
-            for inner in ast.walk(node):
-                if isinstance(inner, ast.Tuple):
-                    for el in inner.elts:
-                        if isinstance(el, ast.Constant) and isinstance(
-                            el.value, str
-                        ):
-                            delta.append((el.value, el.lineno))
-        if isinstance(node, ast.FunctionDef) and node.name == (
             "metrics_text"
         ):
             for inner in ast.walk(node):
@@ -316,28 +306,6 @@ def check_counters(root: str) -> List[Violation]:
                 f"_bump({key!r}) has no declaration in the _counters "
                 f"init dict",
             ))
-    seen: Set[str] = set()
-    for key, line in delta:
-        if key not in declared:
-            out.append(Violation(
-                rel, line, "counter-decl",
-                f"counters_delta lists undeclared counter {key!r}",
-            ))
-        if key in seen:
-            out.append(Violation(
-                rel, line, "counter-decl",
-                f"counters_delta lists {key!r} twice",
-            ))
-        seen.add(key)
-    for key, line in declared.items():
-        if key in GAUGE_COUNTERS:
-            continue
-        if key not in seen:
-            out.append(Violation(
-                rel, line, "counter-decl",
-                f"counter {key!r} is declared but missing from "
-                f"counters_delta (gauges go in GAUGE_COUNTERS)",
-            ))
     families = {f"tfs_{k}_total" for k in declared}
     for name, line in gauge_names:
         if name in families:
@@ -346,6 +314,38 @@ def check_counters(root: str) -> List[Violation]:
                 f"gauge {name!r} collides with a counter family "
                 f"(metrics_text no-dup-family rule)",
             ))
+    return out
+
+
+def check_span_names(root: str) -> List[Violation]:
+    out: List[Violation] = []
+    for path in _iter_py(root, PKG):
+        for node in ast.walk(ast.parse(open(path).read())):
+            if not (isinstance(node, ast.Call) and node.args):
+                continue
+            fn = node.func
+            called = fn.id if isinstance(fn, ast.Name) else (
+                fn.attr
+                if isinstance(fn, ast.Attribute)
+                and isinstance(fn.value, ast.Name)
+                and fn.value.id == "observability"
+                else None
+            )
+            if called not in ("span", "instant"):
+                continue
+            name = node.args[0]
+            built = isinstance(name, (ast.JoinedStr, ast.BinOp)) or (
+                isinstance(name, ast.Call)
+                and isinstance(name.func, ast.Attribute)
+                and name.func.attr == "format"
+            )
+            if built:
+                out.append(Violation(
+                    _rel(root, path), node.lineno, "span-name",
+                    f"the name of {called}() is built at run time; span "
+                    f"names are stable literals (the span table keeps an "
+                    f"entry a name), what varies is an argument",
+                ))
     return out
 
 
@@ -420,6 +420,7 @@ def run(root: str) -> List[Violation]:
         check_env_routing,
         check_knobs,
         check_counters,
+        check_span_names,
         check_checkpoints,
     )
     out: List[Violation] = []

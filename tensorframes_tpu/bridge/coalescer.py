@@ -1087,6 +1087,85 @@ class _PagedSeq:
         }
 
 
+class _StepInputs:
+    """The decode step's three small inputs — the fed tokens ``toks``
+    [slots], the positions ``indices`` [slots] and the page tables
+    ``tables`` [slots, max_pages] — as host mirrors, which the driver
+    thread writes through the methods below, and a copy on the device,
+    ``device``, which the step is dispatched on.
+
+    Between two steps at which no sequence joined or left nothing the host
+    knows is new, so the copy is advanced ON the device, right after the
+    step's call (:meth:`advance`: ``kv_pager.advance_step_inputs``), and
+    :meth:`emit` writes the same into the mirrors once the tokens are read:
+    copy and mirrors stay equal, idle slots included, and the next step
+    uploads nothing.  Every other write to a mirror — :meth:`admit`,
+    :meth:`first_token`, :meth:`retire`, :meth:`reset` — drops the copy
+    (``device`` None), and the next step uploads the mirrors first
+    (:meth:`upload`)."""
+
+    __slots__ = ("toks", "tables", "indices", "device", "_like")
+
+    def __init__(self, slots: int, max_pages: int):
+        self.toks = np.zeros((slots,), np.int32)
+        self.tables = np.zeros((slots, max_pages), np.int32)
+        self.indices = np.zeros((slots,), np.int32)
+        self.device: Optional[Tuple[Any, Any, Any]] = None
+        self._like = None  # where a step's tokens came back committed to
+
+    def admit(self, slot: int, table_row: np.ndarray) -> None:
+        self.tables[slot] = table_row
+        self.indices[slot] = 0
+        self.device = None
+
+    def first_token(self, slot: int, position: int, tok: int) -> None:
+        self.indices[slot] = position
+        self.toks[slot] = tok
+        self.device = None
+
+    def emit(self, slot: int, tok: int) -> None:
+        """A live slot's token of the step just read: what :meth:`advance`
+        already did to the copy."""
+        self.indices[slot] += 1
+        self.toks[slot] = tok
+
+    def retire(self, slot: int) -> None:
+        """Back to an idle row: token 0 at index 0 under an all-trash
+        table, so the slot's writes land on page 0."""
+        self.tables[slot] = 0
+        self.indices[slot] = 0
+        self.toks[slot] = 0
+        self.device = None
+
+    def reset(self) -> None:
+        for mirror in (self.toks, self.tables, self.indices):
+            mirror[:] = 0
+        self.device = None
+
+    def upload(self) -> None:
+        """The mirrors as they stand, to the device.  What is handed over
+        is a snapshot: the mirrors are written in place at every step and
+        a backend may alias a numpy argument instead of copying it (the
+        CPU's does).  The arrays are placed as a step's own tokens come
+        back (committed to their device or not), so an uploaded copy and
+        an advanced one are the same arguments to the step's executable."""
+        import jax
+
+        self.device = jax.device_put(
+            (self.toks.copy(), self.tables.copy(), self.indices.copy()),
+            self._like,
+        )
+
+    def advance(self, fn, nxt) -> None:
+        """The copy after the step that returned ``nxt`` (a device array,
+        not waited for), by ``fn`` (``kv_pager.advance_step_inputs``):
+        dispatched behind the step, ahead of the wait."""
+        _, tables, indices = self.device
+        self._like = nxt.sharding if nxt.committed else None
+        toks, indices = fn(nxt, tables, indices)
+        self.device = (toks, tables, indices)
+
+
 # ring track of the decode driver's spans (the profiler's trace places
 # them by thread: the driver's is ``tfs-paged-decode``)
 _DECODE_TRACK = "decode/driver"
@@ -1242,11 +1321,7 @@ class DecodeScheduler:
         self._routing_done: "collections.OrderedDict[bytes, np.ndarray]" = (
             collections.OrderedDict()
         )
-        self._tables = np.zeros(
-            (self.max_slots, self.max_pages), np.int32
-        )
-        self._indices = np.zeros((self.max_slots,), np.int32)
-        self._toks = np.zeros((self.max_slots,), np.int32)
+        self._inputs = _StepInputs(self.max_slots, self.max_pages)
         self._lock = threading.Lock()
         self._cv = threading.Condition(self._lock)
         self._pending: "collections.deque[_PagedSeq]" = collections.deque()
@@ -1477,9 +1552,7 @@ class DecodeScheduler:
         pool lock nests under no other."""
         del self._active[slot]
         self._free.append(slot)
-        self._tables[slot] = 0
-        self._indices[slot] = 0
-        self._toks[slot] = 0
+        self._inputs.retire(slot)
         self.retired += 1
         if req.routing:
             self._routing_done[req.prompt.tobytes()] = np.concatenate(
@@ -1616,6 +1689,7 @@ class DecodeScheduler:
 
         kv = self._kv
         tally = self._tally
+        inputs = self._inputs
         now = time.perf_counter_ns
         span = observability.span
         self._busy_mark = now()
@@ -1671,8 +1745,7 @@ class DecodeScheduler:
                         if self._by_slot and not self._charge_slot(req):
                             continue
                         slot = self._free.pop()
-                        self._tables[slot] = req.table_row
-                        self._indices[slot] = 0
+                        inputs.admit(slot, req.table_row)
                         self._active[slot] = req
                         admitted.append((slot, req))
                         if was_running:
@@ -1698,12 +1771,17 @@ class DecodeScheduler:
                     step=self.steps, active=len(self._active),
                 ):
                     with span("decode.step.dispatch", _DECODE_TRACK):
+                        if inputs.device is None:
+                            # a boundary wrote a mirror since the last step
+                            with span("decode.step.upload", _DECODE_TRACK):
+                                inputs.upload()
                         toks, stats = self._run(
-                            kv.paged_decode_step,
-                            jnp.asarray(self._toks),
-                            jnp.asarray(self._tables),
-                            jnp.asarray(self._indices),
+                            kv.paged_decode_step, *inputs.device
                         )
+                    # the next step's inputs, made on the device behind
+                    # this step: host time the device does not wait for
+                    with span("decode.step.advance", _DECODE_TRACK):
+                        inputs.advance(kv.advance_step_inputs, toks)
                     with span("decode.step.wait", _DECODE_TRACK) as sp_w:
                         emitted, chosen = self._fetch(toks, stats)
                     keep = self._routing_keep and chosen is not None
@@ -1713,13 +1791,12 @@ class DecodeScheduler:
                             n_tok = len(self._active)
                             # what the step attended over: every live
                             # slot's tokens, the one it fed among them
-                            held = int(self._indices.sum()) + n_tok
+                            held = int(inputs.indices.sum()) + n_tok
                             for slot, req in list(self._active.items()):
-                                self._indices[slot] += 1
                                 if keep:
                                     req.routing.append(chosen[:, slot, None])
                                 tok = int(emitted[slot])
-                                self._toks[slot] = tok
+                                inputs.emit(slot, tok)
                                 req.out.append(tok)
                                 req.emitted += 1
                                 stop = req.emitted >= req.max_new or (
@@ -1751,13 +1828,12 @@ class DecodeScheduler:
                 self._active.clear()
                 self._pending.clear()
                 self._free = list(range(self.max_slots))
-                self._tables[:] = 0
-                self._indices[:] = 0
-                self._toks[:] = 0
                 # a dispatch that failed after it started has consumed the
                 # pools it was given (they are donated).  No sequence is
                 # left to read them: the next request starts on fresh ones,
-                # the old dropped first so that two pairs never stand
+                # the old dropped first so that two pairs never stand, and
+                # the step inputs' copy on the device goes with them
+                inputs.reset()
                 self._kp = self._vp = self._state = self._ret = None
                 self._kp, self._vp, self._state = self.pool.zeros()
                 self._ret = self.pool.retention_zeros()
@@ -1775,8 +1851,7 @@ class DecodeScheduler:
         """A prefill's token is the request's first: the slot's frontier,
         the stamps, and retirement if the stream is one token long."""
         with self._cv:
-            self._indices[slot] = int(req.prompt.size)
-            self._toks[slot] = tok
+            self._inputs.first_token(slot, int(req.prompt.size), tok)
             req.out.append(tok)
             req.emitted += 1
             req.t_first = time.perf_counter_ns()

@@ -933,6 +933,17 @@ def paged_decode_step(params, toks, tables, indices, k_pages, v_pages, cfg,
     return _results(nxt, k_pages, v_pages, state, stats, cfg)
 
 
+@jax.jit
+def advance_step_inputs(nxt, tables, indices):
+    """What the step after :func:`paged_decode_step` is fed, while no
+    sequence joins or leaves: ``(toks', indices')``.  A row that holds a
+    sequence (``tables[:, 0] > 0``) is fed the token the step just produced
+    at one position further; an idle row keeps token 0 at index 0, so its
+    writes keep landing on the trash page.  The tables do not change."""
+    live = tables[:, 0] > 0
+    return jnp.where(live, nxt, 0), indices + live.astype(indices.dtype)
+
+
 @functools.partial(
     jax.jit, static_argnames=("cfg",), donate_argnames=_DONATED
 )

@@ -777,6 +777,8 @@ SPAN_METRICS = {
     "setup_compile_backend_s": 3.5,
     "setup_cache_load_s": 1.25,
     "setup_cache_place_s.score": 0.0625,
+    # PR 40: 80 of the 500 steps followed a boundary that wrote a mirror
+    "step_inputs_resident_share.decode": 84.0,
 }
 
 
@@ -798,6 +800,7 @@ def test_benchmark_metric_file_reads_the_span_table(name):
         "counters.span_ns.decode.boundary": 75_000_000,
         "counters.span_ns.decode.step.dispatch": 450_000_000,
         "counters.span_ns.decode.step.emit": 125_000_000,
+        "counters.span_n.decode.step.upload": 80,
         "counters.span_ns.decode.prefill.wait": 6_600_000_000,
         "setup.span_ns.compile.frontend": 2_000_000_000,
         "setup.span_ns.compile.backend": 3_500_000_000,

@@ -1235,7 +1235,10 @@ class DecodeScheduler:
     length never refuses.  Its prompt goes in dispatches of at most
     ``retention.PREFILL_TOKENS`` tokens, each at its own bucket, the
     second and later RESUMING from the state the one before left in the
-    slot — what a decode step does with one token.
+    slot — what a decode step does with one token.  A block whose pool
+    holds pages AND a state a slot (a state-space mixer beside attention,
+    ``BlockSpec(mixer=...)``) reserves both at ``submit``: its pages and
+    its slot's state are one charge.
 
     ``speculative`` runs the draft/verify path (B=1 by its contract)
     solo in the caller's thread — an opt-in per-request latency knob,
@@ -1278,9 +1281,9 @@ class DecodeScheduler:
             else kv_pager.page_tokens()
         )
         cap = int(max_seq) if max_seq is not None else int(cfg.max_seq)
-        # a block that keeps a state a slot and no pages: the table row is
-        # one entry that says the slot is live, a "page" is a slot's state
-        self._by_slot = cfg.block.attention == "retention"
+        # a pool that holds no pages: the table row is one entry that says
+        # the slot is live, a "page" is a slot's state
+        self._by_slot = not kv_pager.holds_pages(cfg)
         # capacity rounds UP to a whole page: the gathered attention
         # extent is max_pages * P, and bit-identity vs the contiguous
         # path is pinned at exactly this capacity (``cache_len=cap``)
@@ -1301,7 +1304,8 @@ class DecodeScheduler:
         # other blocks): an argument and a result of both executables,
         # like the pages; a prefill overwrites the admitted slot's row
         self._kp, self._vp, self._state = self.pool.take()
-        # a retention block's state, its only pool: donated like the pages
+        # the state a slot retains (a retention block's, its only pool; a
+        # mixer's, beside the pages): donated like the pages
         self._ret = self.pool.take_retention()
         # whether the step executable attends through the paged-attention
         # kernel: what ``kv_pager._paged_block`` will decide when it traces
@@ -1310,6 +1314,11 @@ class DecodeScheduler:
             kv_pager.paged_kernel_fits(
                 cfg, P, self.max_slots, 1, self._kp.dtype
             )
+        )
+        # and whether it steps a mixer's state through ``tfs_ssm_step``
+        # (``decode_ssm_kernel_steps``)
+        self._ssm_kernel_step = int(
+            cfg.block.mixer is not None and kv_pager.ssm_kernel_fits(cfg)
         )
         # ``routing_trace`` > 0 keeps, for that many retired requests, the
         # expert (a top-k router's k) every fed position chose in every
@@ -1632,10 +1641,17 @@ class DecodeScheduler:
             )
             return toks, None
         extra = () if slot is None else (np.array([slot], np.int32),)
-        toks, self._kp, self._vp, self._state, stats = self._dispatch(
-            fn, self._params, *args, self._kp, self._vp, self.cfg,
-            self._state, *extra,
+        # a state retained beside the pages goes as ``retention``, donated,
+        # and comes back where a 'cca' block's state does
+        held = {} if self._ret is None else {"retention": self._ret}
+        toks, self._kp, self._vp, state, stats = self._dispatch(
+            functools.partial(fn, **held), self._params, *args, self._kp,
+            self._vp, self.cfg, self._state, *extra,
         )
+        if self._ret is None:
+            self._state = state
+        else:
+            self._ret = state
         return toks, stats
 
     def _fetch(self, toks, routing):
@@ -1811,8 +1827,11 @@ class DecodeScheduler:
                             # all-zero tables
                 tally["decode_steps"] += 1
                 tally["decode_kernel_steps"] += self._kernel_step
+                tally["decode_ssm_kernel_steps"] += self._ssm_kernel_step
                 tally["decode_tokens"] += n_tok
                 tally["decode_tokens_held"] += held
+                if self._ret is not None:
+                    tally["decode_state_slots_held"] += n_tok
                 tally["decode_step_wait_ns"] += sp_w.ns
                 self._flush_tally()
         except BaseException as e:  # noqa: BLE001 — fail every waiter

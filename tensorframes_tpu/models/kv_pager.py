@@ -71,6 +71,16 @@ serving removed (PAPERS.md).  This module is the paged layout:
   zeros where the dispatch starts the sequence, and leaves the state of
   the prompt's last real token.  A "page" of such a pool is one slot's
   state, all layers: what a sequence costs at any length;
+* a block with a state-space mixer beside its attention
+  (``BlockSpec(mixer=SSMSpec(...))``, ``models/ssm.py``: Falcon-H1's
+  Mamba-2) keeps BOTH: the pages of its attention and, in
+  ``PagePool.retention``, a state a slot — ``(S [n_layers, slots, heads,
+  P, N] float32, tail [n_layers, slots, d_conv - 1, conv_dim])`` — passed
+  to both executables as ``retention`` and donated, as a retention
+  block's is.  A decode step steps a live slot's state in one pass of the
+  kernel ``parallel/ssm.py`` (or ``ssm.step``); a prefill runs SSD's
+  chunked form and overwrites the slot's state and tail whole.  A
+  sequence is charged its pages and its slot's state together;
 * :func:`paged_prefill` is the serving prefill — ONE admitted prompt
   and nothing else.  A prefill starts at position 0, so the only keys
   its queries may see are the chunk's own: it writes them to the pages
@@ -101,13 +111,14 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from . import cca, mla, moe, retention
+from . import cca, mla, moe, retention, ssm
 from . import transformer as tfm
 from .. import observability
 from ..envutil import env_int as _env_int
 from ..ops import frame_cache
 from ..parallel import paged_attention
 from ..parallel import retention as retention_kernel
+from ..parallel import ssm as ssm_kernel
 
 ENV_PAGE_TOKENS = "TFS_DECODE_PAGE_TOKENS"
 DEFAULT_PAGE_TOKENS = 16
@@ -175,10 +186,15 @@ class PagePool:
 
     For a ``cca`` block the pool also holds ``conv_state``, the per-slot
     convolution state (``slots`` rows a layer; None for other blocks).
-    For a ``retention`` block it holds NO pages (``k_pages`` / ``v_pages``
-    None) and ``retention``, the per-slot state ``(S, z)`` in float32,
-    taken by :meth:`take_retention`; a page is then one slot's state, so
-    ``n_pages`` is the slots and one more, and a sequence allocates ONE."""
+    ``retention`` is the per-slot state a block retains and the
+    executables donate, taken by :meth:`take_retention`: for a
+    ``retention`` block ``(S, z)`` in float32 and NO pages (``k_pages`` /
+    ``v_pages`` None; a page is then one slot's state, so ``n_pages`` is
+    the slots and one more, and a sequence allocates ONE); for a block
+    with a mixer ``(S, tail)`` (``models/ssm.py``) beside its pages; None
+    for the others.  A sequence's charge is its pages and its slot's
+    state, ``n * page_bytes + state_bytes``, both read off the arrays'
+    own shapes."""
 
     def __init__(
         self,
@@ -206,27 +222,35 @@ class PagePool:
         # array [n_layers, slots, width] beside the pages, indexed by the
         # decode slot, threaded through the executables like the pages
         self.slots = None
-        if cfg.block.attention in ("cca", "retention"):
+        if (
+            cfg.block.attention in ("cca", "retention")
+            or cfg.block.mixer is not None
+        ):
             if slots is None:
                 raise ValueError(
-                    f"a {cfg.block.attention!r} block keeps a state per "
-                    f"decode slot: PagePool(..., slots=max_slots)"
+                    f"a {cfg.block.attention!r} block with mixer "
+                    f"{cfg.block.mixer} keeps a state per decode slot: "
+                    f"PagePool(..., slots=max_slots)"
                 )
             self.slots = int(slots)
         self.k_pages, self.v_pages, self.conv_state = self.zeros()
         self.retention = self.retention_zeros()
+        # what the budget LRU accounts, read off the arrays' own shapes:
         # one page's HBM across all layers, of every pool there is (K and
-        # V together, or the one latent pool) — the unit the budget LRU
-        # accounts, read off the pools' own shapes; a retention block's
-        # unit is one slot's state, read off the state's
+        # V together, or the one latent pool), and one slot's retained
+        # state, all layers.  A pool with no pages (retention) has the
+        # slot's state as its page
         self.page_bytes = sum(
             pages.size // self.n_pages * self.dtype.itemsize
             for pages in (self.k_pages, self.v_pages)
             if pages is not None
-        ) + sum(
+        )
+        self.state_bytes = sum(
             a.size // self.slots * a.dtype.itemsize
             for a in self.retention or ()
         )
+        if not self.page_bytes:
+            self.page_bytes, self.state_bytes = self.state_bytes, 0
         self._lock = threading.Lock()
         # LIFO free list (page 0 reserved as trash)
         self._free = list(range(self.n_pages - 1, 0, -1))
@@ -240,7 +264,7 @@ class PagePool:
         ``mla.row_width`` values laid out as one head; it travels where
         the K pool does, and ``v_pages`` is None."""
         cfg = self.cfg
-        if cfg.block.attention == "retention":
+        if not holds_pages(cfg):
             return None, None, None
         if cfg.block.attention == "mla":
             heads, widths = 1, (mla.row_width(cfg), None)
@@ -268,15 +292,18 @@ class PagePool:
         return arrays
 
     def retention_zeros(self):
-        """A retention block's state of the pool's slots, all zero: ``(S,
-        z)`` in float32 whatever the pool's dtype; None for other blocks."""
-        if self.cfg.block.attention != "retention":
-            return None
-        return retention.init_state(self.cfg, self.slots)
+        """The retained state of the pool's slots, all zero: a retention
+        block's ``(S, z)`` in float32 whatever the pool's dtype, a mixer's
+        ``(S, tail)`` (``ssm.init_state``); None for other blocks."""
+        if self.cfg.block.attention == "retention":
+            return retention.init_state(self.cfg, self.slots)
+        if self.cfg.block.mixer is not None:
+            return ssm.init_state(self.cfg, self.slots, self.dtype)
+        return None
 
     def take_retention(self):
-        """:meth:`take` for the retention state: both executables donate
-        it, and 5 GB have no room for a second holder."""
+        """:meth:`take` for the retained state: both executables donate
+        it, and 2-5 GB have no room for a second holder."""
         state, self.retention = self.retention, None
         return state
 
@@ -315,7 +342,8 @@ class PagePool:
             # to make room, live pages never are — an unpayable charge
             # is a refusal here, not an OOM three steps from now
             if not frame_cache._budget.charge(
-                charge, 0, n * self.page_bytes, pinned=True
+                charge, 0, n * self.page_bytes + self.state_bytes,
+                pinned=True,
             ):
                 raise PagesExhausted(n, len(self._free), reason="budget")
             pages = [self._free.pop() for _ in range(n)]
@@ -350,9 +378,16 @@ class PagePool:
             "pages_free": free,
             "pages_used": self.capacity - free,
             "page_bytes": self.page_bytes,
+            "state_bytes": self.state_bytes,
             "allocated_total": self.allocated_total,
             "freed_total": self.freed_total,
         }
+
+
+def holds_pages(cfg) -> bool:
+    """Whether a block's pool holds pages: every kind's but a retention
+    block's, whose whole past is its state."""
+    return cfg.block.attention != "retention"
 
 
 def pages_for(tokens: int, tokens_per_page: int) -> int:
@@ -437,8 +472,18 @@ def _attn_out(bp, x, att, cfg):
     attention with.  att: [B, L, h, Dh], or the heads joined already."""
     B, L = att.shape[:2]
     att = att.reshape(B, L, -1)
-    return x + tfm.shard(
+    out = tfm.shard(
         att @ tfm.weight(bp["wo"], cfg.dtype), ("dp", "ep"), "sp", None
+    )
+    return x + tfm.times(
+        out, cfg.block.multipliers.attention_out
+    )
+
+
+def _embed(params, tokens, cfg):
+    return tfm.times(
+        tfm.embed_lookup(params["embed"], tokens, cfg.dtype),
+        cfg.block.multipliers.embedding,
     )
 
 
@@ -538,6 +583,18 @@ def retention_kernel_fits(cfg) -> bool:
     return retention_kernel.fits(cfg.head_dim) and not _mesh_partitions()
 
 
+def ssm_kernel_fits(cfg) -> bool:
+    """Whether a mixer's decode step runs through the Pallas kernel
+    (``parallel/ssm.py``), decided at trace time like
+    :func:`retention_kernel_fits`: the state's shape and no mesh axis left
+    to partition over.  What it refuses takes ``ssm.step``."""
+    m = cfg.block.mixer
+    return (
+        ssm_kernel.fits(m.heads // m.groups, m.head_dim, m.d_state)
+        and not _mesh_partitions()
+    )
+
+
 def _retention_step(bp, x, positions, cfg, st, live, layer):
     """The attention half of a ``retention`` block for ONE token a row
     against ``layer`` of the stacked state ``st = (S, z)``: ``(x', st')``.
@@ -613,9 +670,11 @@ def _paged_block(bp, x, positions, cfg, kp, vp, tables, layer, st=None,
 
     A ``cca`` block (``L`` = 1) also takes the convolution state of all
     layers ``st`` [n_layers, B, width] and leaves this position's in the
-    layer's rows; an ``experts_top1`` block takes ``route``
-    (:func:`_feed_forward`).  Returns ``(x', kp', vp', st', routed)``,
-    the last two None where the spec has no such thing."""
+    layer's rows; a block with a mixer takes its state ``st = (S, tail)``
+    and adds the mixer's output (:func:`ssm.mix_step`, on the same input
+    as attention) to attention's; an ``experts_top1`` block takes
+    ``route`` (:func:`_feed_forward`).  Returns ``(x', kp', vp', st',
+    routed)``, the last two None where the spec has no such thing."""
     if cfg.block.attention == "retention":
         # no pages: ``st`` is the state, ``tables[:, 0]`` says who is live
         with jax.named_scope("attention"):
@@ -628,6 +687,7 @@ def _paged_block(bp, x, positions, cfg, kp, vp, tables, layer, st=None,
     dt = cfg.dtype
     P = kp.shape[3]
     cap = tables.shape[1] * P
+    x_in = x
     # scope names are metadata: a profiler session groups the device
     # operations of a step under attention / page_write / paged_kernel
     # (or page_gather, on the general path)
@@ -664,21 +724,32 @@ def _paged_block(bp, x, positions, cfg, kp, vp, tables, layer, st=None,
                     )
                 att = tfm._cache_attention(q, ck, cv, positions)
             x = _attn_out(bp, x, att, cfg)
+    if cfg.block.mixer is not None:
+        with jax.named_scope("mixer"):
+            m, st = ssm.mix_step(
+                bp, x_in, st, tables[:, 0] > 0, layer, cfg,
+                ssm_kernel_fits(cfg),
+            )
+        x = x + m
     x, routed = _feed_forward(bp, x, cfg, layer, route)
     return x, kp, vp, st, routed
 
 
-def _prefill_block(bp, x, positions, cfg, kp, vp, tables, layer, route=None):
-    """:func:`_paged_block` for a chunk that STARTS its sequence
+def _prefill_block(bp, x, positions, cfg, kp, vp, tables, layer, st, slot,
+                   last_pos, route=None):
+    """:func:`_paged_block` for ONE sequence whose chunk STARTS it
     (``positions`` count from 0): the same projections and page write,
     but the only keys such a chunk's queries may see are its own, which
     the layer has just computed — so attention runs causally over the
     chunk's k/v (rounded to the page dtype, as the pages hold them) and
-    nothing is gathered back from the pages.  In the state's place it
-    returns ``tail`` [B, L, width], what every position of a ``cca``
-    block would leave behind (None otherwise)."""
+    nothing is gathered back from the pages.  A ``cca`` block's state
+    row of ``slot`` [1] is OVERWRITTEN with what the prompt's last real
+    position ``last_pos`` [1] leaves, and so are a mixer's state and tail
+    (:func:`ssm.mix_prefill`), so nothing of the slot's previous tenant
+    survives admission.  Returns ``(x', kp', vp', st', routed)``."""
     dt = cfg.dtype
     tail = None
+    x_in = x
     with jax.named_scope("attention"):
         if cfg.block.attention == "mla":
             x, kp = _latent_attention(
@@ -697,8 +768,18 @@ def _prefill_block(bp, x, positions, cfg, kp, vp, tables, layer, route=None):
                 positions,
             )
             x = _attn_out(bp, x, att, cfg)
+    if cfg.block.mixer is not None:
+        with jax.named_scope("mixer"):
+            m, st = ssm.mix_prefill(bp, x_in, st, layer, slot, last_pos, cfg)
+        x = x + m
     x, routed = _feed_forward(bp, x, cfg, layer, route)
-    return x, kp, vp, tail, routed
+    if tail is not None:
+        with jax.named_scope("attention/conv_state"):
+            last = jnp.take_along_axis(
+                tail, last_pos[:, None, None], axis=1
+            )[:, 0]
+            st = st.at[layer, slot].set(last.astype(st.dtype))
+    return x, kp, vp, st, routed
 
 
 def _scan_layers(block, x, params, k_pages, v_pages, state, live, cfg):
@@ -787,11 +868,11 @@ def _step_forward(params, tokens, tables, indices, k_pages, v_pages, cfg,
     ``(logits, k_pages', v_pages', state', stats)``; see
     :func:`apply_paged`.  ``state`` [n_layers, B, width] is the ``cca``
     convolution state of the rows (for a ``retention`` block the rows'
-    ``(S, z)``, and no pages), ``stats`` is :func:`_routing` over the
+    ``(S, z)``, and no pages; for a mixer's ``(S, tail)``), ``stats`` is :func:`_routing` over the
     rows that hold a sequence (a reserved page: ``tables[:, 0]``)."""
     B, L = tokens.shape
     positions = indices[:, None] + jnp.arange(L, dtype=jnp.int32)[None, :]
-    x = tfm.embed_lookup(params["embed"], tokens, cfg.dtype)
+    x = _embed(params, tokens, cfg)
     live = None
     if cfg.block.routes:
         live = jnp.broadcast_to(tables[:, :1] > 0, (B, L))
@@ -839,16 +920,17 @@ def _prefill_forward(params, toks, table, last_pos, k_pages, v_pages, cfg,
                      state=None, slot=None, start=None):
     """ONE sequence from position 0, whatever the block: ``(logits [1, V]
     at last_pos, k_pages', v_pages', state', stats)``; see
-    :func:`paged_prefill`.  A ``cca`` block's state row of ``slot`` [1] is
-    OVERWRITTEN with what the prompt's last real position leaves, so
-    nothing of the slot's previous tenant survives admission; tokens past
+    :func:`paged_prefill`.  A ``cca`` block's state row of ``slot`` [1]
+    (a mixer's state and tail) is OVERWRITTEN with what the prompt's last
+    real position leaves, so nothing of the slot's previous tenant
+    survives admission; tokens past
     ``last_pos`` are padding, routed to no expert and counted nowhere.  A
     ``retention`` block's chunk may also CONTINUE its sequence, from
     position ``start`` [1] and the slot's state (``state`` is ``(S, z)``):
     a chunk at ``start`` 0 starts from zeros."""
     B, L = toks.shape
     positions = jnp.broadcast_to(jnp.arange(L, dtype=jnp.int32), (B, L))
-    x = tfm.embed_lookup(params["embed"], toks, cfg.dtype)
+    x = _embed(params, toks, cfg)
     live = None
     if cfg.block.routes:
         live = positions <= last_pos[:, None]
@@ -862,16 +944,10 @@ def _prefill_forward(params, toks, table, last_pos, k_pages, v_pages, cfg,
                 )
             x, routed = _feed_forward(bp, x, cfg, layer, route)
             return x, kp, vp, st, routed
-        x, kp, vp, tail, routed = _prefill_block(
-            bp, x, positions, cfg, kp, vp, table, layer, route
+        return _prefill_block(
+            bp, x, positions, cfg, kp, vp, table, layer, st, slot, last_pos,
+            route,
         )
-        if tail is not None:
-            with jax.named_scope("attention/conv_state"):
-                last = jnp.take_along_axis(
-                    tail, last_pos[:, None, None], axis=1
-                )[:, 0]
-                st = st.at[layer, slot].set(last.astype(st.dtype))
-        return x, kp, vp, st, routed
 
     x, k_pages, v_pages, state, routed = _scan_layers(
         block, x, params, k_pages, v_pages, state, live, cfg
@@ -893,7 +969,9 @@ def _prefill_forward(params, toks, table, last_pos, k_pages, v_pages, cfg,
 # (``perfbench/tests/test_correct_zaya.py``) hands back the state it
 # passed, to see that a stale one is caught.  A ``retention`` block's state
 # IS its pool (5.45 GB at 20 slots of 8 layers: no second copy fits), an
-# argument of its own, ``retention``, donated like the pools.
+# argument of its own, ``retention``, donated like the pools; a mixer's
+# (2.16 GB at 128 slots of Falcon-H1's 4 layers) travels the same way,
+# beside the pages.
 _DONATED = ("k_pages", "v_pages", "retention")
 
 
@@ -924,7 +1002,9 @@ def paged_decode_step(params, toks, tables, indices, k_pages, v_pages, cfg,
     donated: the call consumes them and returns them updated in place.  A
     ``retention`` block takes no pools (None) but its state ``retention``
     ``(S, z)``, donated, and ``tables`` [B, 1] that say which rows hold a
-    sequence (> 0): ``(next, (S', z'))``."""
+    sequence (> 0): ``(next, (S', z'))``.  A block with a mixer takes the
+    pools and its state as ``retention``, donated, and returns it where
+    a ``cca`` block returns its ``state'``."""
     logits, k_pages, v_pages, state, stats = _step_forward(
         params, toks[:, None], tables, indices, k_pages, v_pages, cfg,
         state if retention is None else retention,

@@ -97,6 +97,60 @@ class Yarn:
 
 
 @dataclasses.dataclass(frozen=True)
+class SSMSpec:
+    """The sizes of a Mamba-2 mixer (``models/ssm.py``) that runs beside a
+    block's attention on the same normed input: ``heads`` heads of
+    ``head_dim`` (``d_ssm`` in all), ``groups`` groups of heads that share
+    B and C of ``d_state`` each, a causal depthwise convolution of
+    ``d_conv`` over x, B and C, and SSD's ``chunk`` for a prefill.  The
+    gated RMSNorm after the gate normalises ``groups`` groups of
+    ``d_ssm / groups``."""
+
+    heads: int
+    head_dim: int
+    groups: int
+    d_state: int
+    d_conv: int = 4
+    chunk: int = 128
+
+    @property
+    def d_ssm(self) -> int:
+        return self.heads * self.head_dim
+
+    @property
+    def conv_dim(self) -> int:
+        """x, B and C side by side: what the convolution mixes."""
+        return self.d_ssm + 2 * self.groups * self.d_state
+
+
+@dataclasses.dataclass(frozen=True)
+class Multipliers:
+    """Falcon-H1's named scalars (its config's ``*_multiplier`` keys), each
+    applied once where that model applies it: on the embedding, on the
+    attention's input and keys and on its output, on the mixer's input,
+    on the five segments of its projection (z, x, B, C, dt) and on its
+    output, inside the SwiGLU's gate and on its output, and on the
+    logits.  A multiplier of 1 (every one, by default) traces no
+    operation."""
+
+    embedding: float = 1.0
+    attention_in: float = 1.0
+    key: float = 1.0
+    attention_out: float = 1.0
+    ssm_in: float = 1.0
+    ssm_segments: Tuple[float, ...] = (1.0,) * 5
+    ssm_out: float = 1.0
+    mlp_gate: float = 1.0
+    mlp_down: float = 1.0
+    head: float = 1.0
+
+
+def times(x, s):
+    """``x * s`` for a multiplier ``s``, and ``x`` itself where it is 1."""
+    return x if s == 1.0 else x * s
+
+
+@dataclasses.dataclass(frozen=True)
 class BlockSpec:
     """What one decoder block is made of (ROADMAP D8): the kinds of its
     two sublayers and the numbers they share.  A model with another
@@ -140,6 +194,11 @@ class BlockSpec:
     experts_share: Tuple[int, int] = (0, 1)
     latent: Optional[LatentSpec] = None
     yarn: Optional[Yarn] = None
+    # a Mamba-2 mixer beside a "gqa" attention, on the same normed input,
+    # its output added to the residual with attention's (Falcon-H1): the
+    # pool then holds pages AND a state a slot
+    mixer: Optional[SSMSpec] = None
+    multipliers: Multipliers = Multipliers()
 
     def __post_init__(self):
         if self.attention not in ("gqa", "cca", "mla", "retention"):
@@ -153,6 +212,8 @@ class BlockSpec:
             )
         if (self.attention == "mla") != (self.latent is not None):
             raise ValueError("attention 'mla' and `latent` go together")
+        if self.mixer is not None and self.attention != "gqa":
+            raise ValueError("a mixer runs beside attention 'gqa' only")
         index, of = self.experts_share
         if not 0 <= index < of:
             raise ValueError(f"experts_share {self.experts_share}: (index, of)")
@@ -161,7 +222,10 @@ class BlockSpec:
     def stateless(self) -> bool:
         """The dense block: pages are its only per-sequence state and it
         routes nothing, so its executables take and return no more."""
-        return self.attention == "gqa" and self.ffn == "swiglu"
+        return (
+            self.attention == "gqa" and self.ffn == "swiglu"
+            and self.mixer is None and self.multipliers == Multipliers()
+        )
 
     @property
     def routes(self) -> bool:
@@ -513,8 +577,11 @@ def head(params: Params, x: jnp.ndarray, cfg, lead: str = "bl"):
         w, eq = params["lm_head"], f"{lead}d,dv->{lead}v"
     else:
         w, eq = params["embed"], f"{lead}d,vd->{lead}v"
-    return jnp.einsum(
-        eq, x, weight(w, cfg.dtype), preferred_element_type=jnp.float32
+    return times(
+        jnp.einsum(
+            eq, x, weight(w, cfg.dtype), preferred_element_type=jnp.float32
+        ),
+        cfg.block.multipliers.head,
     )
 
 
@@ -561,9 +628,11 @@ def _attn_qkv(bp, x, positions, cfg):
     B, L, D = x.shape
     h, kvh, dh = cfg.n_heads, cfg.n_kv_heads, cfg.head_dim
     dt = cfg.dtype
+    mult = cfg.block.multipliers
     y = _saved(_rms_norm(x, bp["ln1"], cfg.block.norm_eps))
+    y = times(y, mult.attention_in)
     q = (y @ weight(bp["wq"], dt)).reshape(B, L, h, dh)
-    k = (y @ weight(bp["wk"], dt)).reshape(B, L, kvh, dh)
+    k = times((y @ weight(bp["wk"], dt)).reshape(B, L, kvh, dh), mult.key)
     v = (y @ weight(bp["wv"], dt)).reshape(B, L, kvh, dh)
     q = _saved(
         shard(_rope(q, positions, cfg.rope_theta, cfg.block.rotary_share),
@@ -591,18 +660,25 @@ def _mlp_residual(bp, x, cfg, segments=None):
         ff_out, aux = moe_mlp(bp, y, cfg, segments)
         x = x + ff_out
     else:
-        x = x + swiglu(y, bp["w_gate"], bp["w_up"], bp["w_down"], cfg.dtype)
+        mult = cfg.block.multipliers
+        x = x + swiglu(
+            y, bp["w_gate"], bp["w_up"], bp["w_down"], cfg.dtype,
+            (mult.mlp_gate, mult.mlp_down),
+        )
         aux = jnp.zeros((), jnp.float32)
     return x, aux
 
 
-def swiglu(y, w_gate, w_up, w_down, dt):
+def swiglu(y, w_gate, w_up, w_down, dt, scales=(1.0, 1.0)):
     """``W_down(silu(y W_gate) * y W_up)``: the dense feed-forward, and an
-    expert layer's shared expert."""
-    gate = jax.nn.silu(y @ weight(w_gate, dt))
+    expert layer's shared expert.  ``scales``: multipliers inside the
+    gate's silu and on the output (Falcon-H1's ``mlp_multipliers``)."""
+    gate = jax.nn.silu(times(y @ weight(w_gate, dt), scales[0]))
     up = y @ weight(w_up, dt)
     ff = _saved(shard(gate * up, ("dp", "ep"), "sp", "tp"))
-    return shard(ff @ weight(w_down, dt), ("dp", "ep"), "sp", None)
+    return times(
+        shard(ff @ weight(w_down, dt), ("dp", "ep"), "sp", None), scales[1]
+    )
 
 
 @jax.named_scope("attention")

@@ -251,6 +251,10 @@ _counters: Dict[str, int] = {
     # prefill dispatches that resumed from a state left in the slot (a
     # prompt longer than one dispatch)
     "decode_prefill_resumes": 0,
+    # a block that keeps a state a slot (retention, or a mixer beside
+    # attention): the live slots whose state a decode step stepped, summed
+    # over steps (the host's, at ``decode.step.emit``)
+    "decode_state_slots_held": 0,
     # expert routing of a served model (``moe.experts_top1`` /
     # ``experts_topk``), counted on the device over live tokens only and
     # read back with a dispatch's tokens: layer-steps routed (expert
@@ -280,6 +284,10 @@ _counters: Dict[str, int] = {
     # first -> last token time over the tokens after the first
     "decode_steps": 0,
     "decode_kernel_steps": 0,
+    # of them, those whose executable steps a mixer's state through the
+    # Pallas kernel ``tfs_ssm_step`` (``kv_pager.ssm_kernel_fits``, asked
+    # once a scheduler: all of its steps or none)
+    "decode_ssm_kernel_steps": 0,
     "decode_host_ns": 0,
     "decode_step_wait_ns": 0,
     "decode_prefill_ns": 0,

@@ -20,8 +20,8 @@ sizes it; ``pool_devices()``/``pool_enabled()`` report the resolved pool.
 
 import sys
 
-# This package's Pallas kernels (``flash``, ``paged_attention``, ``retention``) are TPU
-# kernels.  ``jax.experimental.pallas`` imports its GPU interpreter beside
+# This package's Pallas kernels (``flash``, ``paged_attention``, ``retention``,
+# ``ssm``) are TPU kernels.  ``jax.experimental.pallas`` imports its GPU interpreter beside
 # the TPU backend — an LLVM dialect and Mosaic GPU, 0.7 s of the 1.2 s the
 # import takes on a v5e host, in the set-up of every process that serves
 # decode (PERF.md §6, PR 30) — and is written to do without it (``except

@@ -29,15 +29,21 @@ ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 if ROOT not in sys.path:
     sys.path.insert(0, ROOT)
 
-from perfbench.drivers import bridge_decode_axk1, bridge_decode_brumby  # noqa: E402
+from perfbench.drivers import (  # noqa: E402
+    bridge_decode_axk1, bridge_decode_brumby, bridge_decode_falcon_h1,
+)
 from perfbench.drivers.bridge_decode_zaya import transformer_config  # noqa: E402
 from perfbench.refs import (  # noqa: E402
-    axk1_decoder, brumby_decoder, inception_v3, transformer_decoder, zaya_decoder,
+    axk1_decoder, brumby_decoder, falcon_h1_decoder, inception_v3,
+    transformer_decoder, zaya_decoder,
 )
-from tensorframes_tpu.models import cca, inception, kv_pager, mla, retention  # noqa: E402
+from tensorframes_tpu.models import (  # noqa: E402
+    cca, inception, kv_pager, mla, retention, ssm,
+)
 from tensorframes_tpu.models import transformer as tfm  # noqa: E402
 from tensorframes_tpu.parallel import paged_attention as pa  # noqa: E402
 from tensorframes_tpu.parallel import retention as rk  # noqa: E402
+from tensorframes_tpu.parallel import ssm as sk  # noqa: E402
 
 # (configuration, the traffic file whose slots and capacity serve it)
 CELLS = {
@@ -81,6 +87,7 @@ def compiled_not_interpreted(monkeypatch):
     compilation_cache.reset_cache()
     monkeypatch.setattr(pa, "_resolve_interpret", lambda interpret: False)
     monkeypatch.setattr(rk, "_resolve_interpret", lambda interpret: False)
+    monkeypatch.setattr(sk, "_resolve_interpret", lambda interpret: False)
     with jax.enable_x64(False):
         yield
     jax.config.update("jax_enable_compilation_cache", was)
@@ -506,6 +513,123 @@ def test_retention_prefill_fits_beside_weights_and_state(
     assert not whole, whole  # nothing copies or recomputes the whole stack
     mem = compiled.memory_analysis()
     assert mem.alias_size_in_bytes >= sum(4 * math.prod(a.shape) for a in state)
+    assert mem.temp_size_in_bytes < 2**30
+    assert (
+        mem.argument_size_in_bytes + mem.output_size_in_bytes
+        + mem.temp_size_in_bytes - mem.alias_size_in_bytes < 15 * 2**30
+    )
+
+
+# ---------------------------------------------------------------------------
+# the hybrid block (Falcon-H1): pages AND a float32 state a slot, a Mamba-2
+# mixer beside attention, a kernel of its own for the state's step
+# ---------------------------------------------------------------------------
+
+
+def _hybrid_cell(sharding):
+    """``(cfg, weights, pools, state, serve)`` of
+    ``falcon_h1_34b_l4.decode_crowd`` as shapes on the described chip."""
+    m = _load("configs", "falcon_h1_34b_l4")
+    serve = _load("traffic", "decode_crowd")["serve"]
+    dtype = jnp.dtype(m["dtype"])
+    cfg = bridge_decode_falcon_h1.transformer_config(m, serve["max_seq"], dtype)
+    slots, P = serve["max_slots"], serve["tokens_per_page"]
+    max_pages = kv_pager.pages_for(serve["max_seq"], P)
+
+    def on_chip(tree):
+        return jax.tree.map(
+            lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=sharding),
+            tree,
+        )
+
+    pool = jax.eval_shape(
+        lambda: kv_pager.PagePool(
+            cfg, slots * max_pages + 1, tokens_per_page=P, slots=slots
+        ).k_pages
+    )
+    weights = on_chip(jax.eval_shape(lambda: falcon_h1_decoder.make_weights(0, m, dtype)))
+    state = on_chip(jax.eval_shape(lambda: ssm.init_state(cfg, slots, dtype)))
+    return cfg, weights, on_chip((pool, pool)), state, {**serve, "max_pages": max_pages}
+
+
+def test_ssm_kernel_compiles_at_real_widths(one_chip, compiled_not_interpreted):
+    cfg, _, _, (S, tail), serve = _hybrid_cell(one_chip)
+    slots = serve["max_slots"]
+    assert kv_pager.ssm_kernel_fits(cfg) and S.dtype == jnp.float32
+    assert S.shape == (4, 128, 32, 128, 256) and tail.shape == (4, 128, 3, 5120)
+    f32 = lambda *s: jax.ShapeDtypeStruct(s, jnp.float32, sharding=one_chip)  # noqa: E731
+    compiled = jax.jit(sk.ssm_step, donate_argnums=(6,)).lower(
+        f32(slots, 32, 128), f32(slots, 2, 256), f32(slots, 2, 256), f32(slots, 32),
+        f32(32), f32(32), S,
+        jax.ShapeDtypeStruct((slots,), jnp.bool_, sharding=one_chip),
+        jax.ShapeDtypeStruct((), jnp.int32, sharding=one_chip),
+    ).compile()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text and sk.KERNEL_NAME in text
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes >= 4 * math.prod(S.shape)  # written where it lies
+    assert mem.temp_size_in_bytes < 2**24  # the packed tiles and the walk's order
+
+
+def test_hybrid_step_runs_both_kernels_and_moves_no_state(
+    one_chip, compiled_not_interpreted
+):
+    """The scheduler's whole step at the cell's 128 slots of 4 layers: both
+    kernels are in it, pages and state (2.15 GB each) are donated and
+    aliased, no instruction gives a value as large as the state's stack, a
+    layer of it or a slot's of a layer, and the whole fits the chip."""
+    cfg, weights, (kp, vp), state, serve = _hybrid_cell(one_chip)
+    slots = serve["max_slots"]
+    i32 = lambda *shape: jax.ShapeDtypeStruct(  # noqa: E731
+        shape, jnp.int32, sharding=one_chip
+    )
+    compiled = kv_pager.paged_decode_step.lower(
+        weights, i32(slots), i32(slots, serve["max_pages"]), i32(slots), kp, vp,
+        cfg, retention=state,
+    ).compile()
+    text = compiled.as_text()
+    assert sk.KERNEL_NAME in text and pa.KERNEL_NAME in text
+    moved = [
+        line[:200] for op, line in _state_values(text, state)
+        if op not in ("parameter", "get-tuple-element", "tuple", "bitcast",
+                      "while", "custom-call")
+    ]
+    assert not moved, moved
+    mem = compiled.memory_analysis()
+    held = sum(a.dtype.itemsize * math.prod(a.shape) for a in (kp, vp, *state))
+    assert mem.alias_size_in_bytes >= held
+    assert mem.temp_size_in_bytes < 2**29
+    assert (
+        mem.argument_size_in_bytes + mem.output_size_in_bytes
+        + mem.temp_size_in_bytes - mem.alias_size_in_bytes < 14 * 2**30
+    )
+
+
+def test_hybrid_prefill_fits_beside_weights_pages_and_state(
+    one_chip, compiled_not_interpreted
+):
+    """The largest prefill of the cell (a 1,024 bucket, SSD in chunks of
+    128): pages and state stay where they lie, and the transients fit in
+    what weights, pages and state leave of 16 GB."""
+    cfg, weights, (kp, vp), state, serve = _hybrid_cell(one_chip)
+    i32 = lambda *shape: jax.ShapeDtypeStruct(  # noqa: E731
+        shape, jnp.int32, sharding=one_chip
+    )
+    compiled = kv_pager.paged_prefill.lower(
+        weights, i32(1, 1024), i32(1, serve["max_pages"]), i32(1), kp, vp, cfg,
+        slot=i32(1), retention=state,
+    ).compile()
+    S = state[0].shape
+    whole = [
+        line[:200] for op, line in _state_values(compiled.as_text(), state)
+        if op in ("copy", "fusion", "dynamic-slice")
+        and f"[{','.join(map(str, S))}]" in line.split(" = ")[1].split("{")[0]
+        and "dynamic-update-slice" not in line
+    ]
+    assert not whole, whole  # nothing copies or recomputes the whole stack
+    mem = compiled.memory_analysis()
+    held = sum(a.dtype.itemsize * math.prod(a.shape) for a in (kp, vp, *state))
+    assert mem.alias_size_in_bytes >= held
     assert mem.temp_size_in_bytes < 2**30
     assert (
         mem.argument_size_in_bytes + mem.output_size_in_bytes

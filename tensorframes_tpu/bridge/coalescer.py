@@ -1266,7 +1266,12 @@ class DecodeScheduler:
         self._decode = decode_mod
         self.cfg = cfg
         self._raw_params = params  # speculative casts per-model itself
-        self._params = decode_mod.cast_params(params, cfg.dtype)
+        # cast once, and the q/k/v projections turned once to the layout
+        # the executables' dots read in place
+        self._params = kv_pager.serving_params(
+            decode_mod.cast_params(params, cfg.dtype), cfg
+        )
+        self._proj_in_place = int(kv_pager.projects_in_place(self._params))
         self.draft_params = draft_params
         self.draft_cfg = draft_cfg
         self.max_slots = max(
@@ -1828,6 +1833,7 @@ class DecodeScheduler:
                 tally["decode_steps"] += 1
                 tally["decode_kernel_steps"] += self._kernel_step
                 tally["decode_ssm_kernel_steps"] += self._ssm_kernel_step
+                tally["decode_proj_in_place_steps"] += self._proj_in_place
                 tally["decode_tokens"] += n_tok
                 tally["decode_tokens_held"] += held
                 if self._ret is not None:

@@ -974,6 +974,55 @@ def _prefill_forward(params, toks, table, last_pos, k_pages, v_pages, cfg,
 # beside the pages.
 _DONATED = ("k_pages", "v_pages", "retention")
 
+# the projections ``transformer._attn_qkv`` and ``retention.project`` read
+# through ``transformer.linear``
+_QKV = ("wq", "wk", "wv")
+
+
+def serving_params(params: tfm.Params, cfg) -> tfm.Params:
+    """The params the serving executables are handed, turned once: a block
+    that projects through ``transformer._attn_qkv`` or ``retention.project``
+    holds each layer stack's ``wq``, ``wk`` and ``wv`` as
+    :class:`transformer.OutIn` ``[L, out, in]``, the layout the chip's dot
+    reads a layer's slice in.  Held ``[L, in, out]``, every layer of every
+    step and prefill copied its slice to that layout before the product
+    (48 MB a layer at Mistral's widths, 70 MB at Brumby's).  Other blocks
+    and quantized leaves are returned as they are; a leaf sharded by name
+    keeps its spec, turned with it."""
+    if cfg.block.attention not in ("gqa", "retention"):
+        return params
+
+    def turn(a):
+        if isinstance(a, tfm.QTensor):
+            return a
+        t = jnp.swapaxes(a, -1, -2)
+        s = getattr(a, "sharding", None)
+        if isinstance(s, jax.sharding.NamedSharding):
+            spec = list(s.spec) + [None] * (a.ndim - len(s.spec))
+            spec[-2], spec[-1] = spec[-1], spec[-2]
+            t = jax.device_put(t, jax.sharding.NamedSharding(
+                s.mesh, jax.sharding.PartitionSpec(*spec)
+            ))
+        return tfm.OutIn(t)
+
+    out = dict(params)
+    for stack in ("dense_blocks", "blocks"):
+        if stack in out:
+            out[stack] = {
+                k: turn(v) if k in _QKV else v for k, v in out[stack].items()
+            }
+    return out
+
+
+def projects_in_place(params: tfm.Params) -> bool:
+    """Whether every q, k and v projection of the params is held as
+    :class:`transformer.OutIn` (``decode_proj_in_place_steps``)."""
+    held = [
+        params[stack][k] for stack in ("dense_blocks", "blocks")
+        if stack in params for k in _QKV if k in params[stack]
+    ]
+    return bool(held) and all(isinstance(w, tfm.OutIn) for w in held)
+
 
 def _results(tokens, k_pages, v_pages, state, stats, cfg):
     """What a serving executable returns: the dense block's three, and for

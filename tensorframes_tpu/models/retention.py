@@ -136,9 +136,9 @@ def project(bp, x, positions, cfg):
     h, kvh, dh = cfg.n_heads, cfg.n_kv_heads, cfg.head_dim
     dt, eps = cfg.dtype, cfg.block.norm_eps
     y = tfm._rms_norm(x, bp["ln1"], eps)
-    q = (y @ tfm.weight(bp["wq"], dt)).reshape(B, L, h, dh)
-    k = (y @ tfm.weight(bp["wk"], dt)).reshape(B, L, kvh, dh)
-    v = (y @ tfm.weight(bp["wv"], dt)).reshape(B, L, kvh, dh)
+    q = tfm.linear(y, bp["wq"], dt).reshape(B, L, h, dh)
+    k = tfm.linear(y, bp["wk"], dt).reshape(B, L, kvh, dh)
+    v = tfm.linear(y, bp["wv"], dt).reshape(B, L, kvh, dh)
     q = tfm._rope(tfm._rms_norm(q, bp["q_norm"], eps), positions, cfg.rope_theta)
     k = tfm._rope(tfm._rms_norm(k, bp["k_norm"], eps), positions, cfg.rope_theta)
     with jax.named_scope("retention_gate"):

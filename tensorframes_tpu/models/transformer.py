@@ -52,13 +52,35 @@ class QTensor(NamedTuple):
     scale: jnp.ndarray
 
 
-def weight(w: "QTensor | jnp.ndarray", dt) -> jnp.ndarray:
+class OutIn(NamedTuple):
+    """A projection weight held turned, ``[..., out, in]``: the contraction
+    axis minor, which is how the chip's dot reads a decode step's weight
+    where it lies (``kv_pager.serving_params``; held ``[in, out]`` every
+    layer of every step copied its slice to this layout first).  A type of
+    its own, so that :func:`weight` and :func:`linear` tell the two
+    orientations apart where neither a key nor a shape can (``wq`` is
+    square in Mistral and Brumby)."""
+
+    w: jnp.ndarray
+
+
+def weight(w: "QTensor | OutIn | jnp.ndarray", dt) -> jnp.ndarray:
     """Weight accessor: dequantise a QTensor to ``dt`` (XLA fuses the
-    int8->dt multiply into the consuming matmul's operand read) or cast a
-    plain array."""
+    int8->dt multiply into the consuming matmul's operand read), turn an
+    :class:`OutIn` back to ``[in, out]``, or cast a plain array."""
     if isinstance(w, QTensor):
         return w.q.astype(dt) * w.scale.astype(dt)
+    if isinstance(w, OutIn):
+        return jnp.swapaxes(w.w, -1, -2).astype(dt)
     return w.astype(dt)
+
+
+def linear(y: jnp.ndarray, w: "QTensor | OutIn | jnp.ndarray", dt):
+    """``y @ W`` for a weight held ``[in, out]``, or as :class:`OutIn`,
+    whose minor axis the product contracts where it lies."""
+    if isinstance(w, OutIn):
+        return jnp.einsum("...d,kd->...k", y, w.w.astype(dt))
+    return y @ weight(w, dt)
 
 
 def embed_lookup(emb: "QTensor | jnp.ndarray", tokens, dt) -> jnp.ndarray:
@@ -631,9 +653,9 @@ def _attn_qkv(bp, x, positions, cfg):
     mult = cfg.block.multipliers
     y = _saved(_rms_norm(x, bp["ln1"], cfg.block.norm_eps))
     y = times(y, mult.attention_in)
-    q = (y @ weight(bp["wq"], dt)).reshape(B, L, h, dh)
-    k = times((y @ weight(bp["wk"], dt)).reshape(B, L, kvh, dh), mult.key)
-    v = (y @ weight(bp["wv"], dt)).reshape(B, L, kvh, dh)
+    q = linear(y, bp["wq"], dt).reshape(B, L, h, dh)
+    k = times(linear(y, bp["wk"], dt).reshape(B, L, kvh, dh), mult.key)
+    v = linear(y, bp["wv"], dt).reshape(B, L, kvh, dh)
     q = _saved(
         shard(_rope(q, positions, cfg.rope_theta, cfg.block.rotary_share),
               ("dp", "ep"), "sp", "tp", None)

@@ -288,6 +288,10 @@ _counters: Dict[str, int] = {
     # Pallas kernel ``tfs_ssm_step`` (``kv_pager.ssm_kernel_fits``, asked
     # once a scheduler: all of its steps or none)
     "decode_ssm_kernel_steps": 0,
+    # of them, those whose params held every q, k and v projection turned
+    # to the layout the step's dots read in place (``transformer.OutIn``,
+    # ``kv_pager.serving_params``; decided once a scheduler)
+    "decode_proj_in_place_steps": 0,
     "decode_host_ns": 0,
     "decode_step_wait_ns": 0,
     "decode_prefill_ns": 0,

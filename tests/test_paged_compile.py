@@ -1,6 +1,7 @@
 """The decode step's Pallas kernel and both serving executables, compiled at
 the benchmark's real widths for a TPU v5e that is described and not
-attached (PR 30; PR 33: the pools stay where they lie).
+attached (PR 30; PR 33: the pools stay where they lie; PR 42: the q, k and
+v projections too).
 
 Interpret mode (``tests/test_paged_attention.py``) checks the kernel's
 arithmetic; it cannot see a slice that is not aligned to the tiling, a
@@ -96,7 +97,8 @@ def compiled_not_interpreted(monkeypatch):
 
 def _cell(config, sharding):
     """``(cfg, args, kwargs)`` of the scheduler's ``paged_decode_step`` for
-    a benchmark configuration, as shapes on the described chip."""
+    a benchmark configuration, as shapes on the described chip (the params
+    as the scheduler hands them: ``kv_pager.serving_params``)."""
     traffic = {**CELLS, **LATENT}[config]
     m, serve = _load("configs", config), _load("traffic", traffic)["serve"]
     dtype = jnp.dtype(m["dtype"])
@@ -131,7 +133,7 @@ def _cell(config, sharding):
     )
     i32 = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.int32)  # noqa: E731
     args = on_chip((
-        jax.eval_shape(lambda: make(0, m, dtype)),
+        jax.eval_shape(lambda: kv_pager.serving_params(make(0, m, dtype), cfg)),
         i32(slots), i32(slots, max_pages), i32(slots), pool,
         None if cfg.block.attention == "mla" else pool,  # the one pool
     ))
@@ -415,7 +417,9 @@ def _retention_cell(sharding):
             tree,
         )
 
-    weights = on_chip(jax.eval_shape(lambda: brumby_decoder.make_weights(0, m, dtype)))
+    weights = on_chip(jax.eval_shape(
+        lambda: kv_pager.serving_params(brumby_decoder.make_weights(0, m, dtype), cfg)
+    ))
     state = on_chip(
         jax.eval_shape(lambda: retention.init_state(cfg, serve["max_slots"]))
     )
@@ -547,7 +551,9 @@ def _hybrid_cell(sharding):
             cfg, slots * max_pages + 1, tokens_per_page=P, slots=slots
         ).k_pages
     )
-    weights = on_chip(jax.eval_shape(lambda: falcon_h1_decoder.make_weights(0, m, dtype)))
+    weights = on_chip(jax.eval_shape(
+        lambda: kv_pager.serving_params(falcon_h1_decoder.make_weights(0, m, dtype), cfg)
+    ))
     state = on_chip(jax.eval_shape(lambda: ssm.init_state(cfg, slots, dtype)))
     return cfg, weights, on_chip((pool, pool)), state, {**serve, "max_pages": max_pages}
 
@@ -635,3 +641,126 @@ def test_hybrid_prefill_fits_beside_weights_pages_and_state(
         mem.argument_size_in_bytes + mem.output_size_in_bytes
         + mem.temp_size_in_bytes - mem.alias_size_in_bytes < 15 * 2**30
     )
+
+
+# ---------------------------------------------------------------------------
+# the q, k and v projections (PR 42): read where they lie, a layer at a time
+# ---------------------------------------------------------------------------
+
+# `%constant_dynamic-slice_fusion.6 = bf16[1,4096,4096]{2,1,0:...} fusion(`,
+# with the instruction's name and, for a transpose, its permutation
+_NAMED = re.compile(
+    r"^\s*(?:ROOT )?%?([\w.\-]+) = \w+\[([0-9,]*)\]\S* ([\w\-]+)\("
+    r"(?:.*dimensions=\{([0-9,]*)\})?"
+)
+
+
+def _served(case, sharding, turned=True):
+    """``(cfg, compiled)``: one serving executable of a cell, compiled for
+    the described chip with the params the scheduler hands it (``turned``;
+    a Mistral case also as ``make_weights`` holds them)."""
+    i32 = lambda *shape: jax.ShapeDtypeStruct(  # noqa: E731
+        shape, jnp.int32, sharding=sharding
+    )
+    if case.startswith("mistral"):
+        cfg, (w, toks, tables, indices, kp, vp), _ = _cell("mistral_7b_l8", sharding)
+        if not turned:
+            m = _load("configs", "mistral_7b_l8")
+            w = jax.tree.map(
+                lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=sharding),
+                jax.eval_shape(lambda: transformer_decoder.make_weights(
+                    0, m, jnp.dtype(m["dtype"]))),
+            )
+        if case == "mistral_step":
+            lowered = kv_pager.paged_decode_step.lower(
+                w, toks, tables, indices, kp, vp, cfg
+            )
+        else:
+            bucket = int(case.rsplit("_", 1)[1])
+            lowered = kv_pager.paged_prefill.lower(
+                w, i32(1, bucket), i32(1, tables.shape[1]), i32(1), kp, vp, cfg
+            )
+    elif case == "retention_step":
+        cfg, w, state, slots = _retention_cell(sharding)
+        lowered = kv_pager.paged_decode_step.lower(
+            w, i32(slots), i32(slots, 1), i32(slots), None, None, cfg,
+            retention=state,
+        )
+    else:
+        cfg, w, (kp, vp), state, serve = _hybrid_cell(sharding)
+        slots = serve["max_slots"]
+        lowered = kv_pager.paged_decode_step.lower(
+            w, i32(slots), i32(slots, serve["max_pages"]), i32(slots), kp, vp,
+            cfg, retention=state,
+        )
+    return cfg, lowered.compile()
+
+
+def _projection_values(text, cfg):
+    """``(name, opcode, dims, permutation, line)`` of every instruction
+    whose result has the shape of one layer's q, k or v projection, in
+    either orientation (leading unit axes dropped)."""
+    d = cfg.d_model
+    outs = {cfg.n_heads * cfg.head_dim, cfg.n_kv_heads * cfg.head_dim}
+    shapes = {(o, d) for o in outs} | {(d, o) for o in outs}
+    for line in text.splitlines():
+        m = _NAMED.match(line)
+        if not m or not m.group(2):
+            continue
+        dims = tuple(int(x) for x in m.group(2).split(","))
+        core = dims[next((i for i, x in enumerate(dims) if x != 1), len(dims)):]
+        if core in shapes:
+            yield m.group(1), m.group(3), dims, m.group(4), line.strip()
+
+
+def _projection_moves(text, cfg):
+    """The instructions that turn a layer's projection to another layout:
+    a copy, a transpose that permutes, or a fusion named after either (the
+    parent's ``copy.19`` gave ``bf16[1,4096,4096]{1,2,0}`` from each
+    layer's ``wq``, ``copy.21`` and ``copy.22`` its ``wk`` and ``wv``)."""
+    return [
+        line[:200] for name, op, _, perm, line in _projection_values(text, cfg)
+        if op == "copy"
+        or (op == "transpose" and perm is not None
+            and perm.split(",") != sorted(perm.split(","), key=int))
+        or (op == "fusion" and ("copy" in name or "transpose" in name))
+    ]
+
+
+_SERVED = ("mistral_step", "mistral_prefill_128", "mistral_prefill_1024",
+           "retention_step", "hybrid_step")
+
+
+@pytest.mark.parametrize("case", _SERVED)
+def test_projections_are_read_where_they_lie(case, one_chip, compiled_not_interpreted):
+    """The served step and prefills of every block that projects through
+    ``transformer._attn_qkv`` or ``retention.project``: no instruction turns
+    a layer's q, k or v projection to another layout (48 MB a layer at
+    Mistral's widths, 70 MB at Brumby's, 35 MB at Falcon-H1's, every step),
+    and each is still read once a layer: the scan's slice of the stack at
+    the layer, ``[1, out, in]``, into the product as it lies."""
+    cfg, compiled = _served(case, one_chip)
+    text = compiled.as_text()
+    moved = _projection_moves(text, cfg)
+    assert not moved, moved
+    read = {
+        dims for name, op, dims, _, _ in _projection_values(text, cfg)
+        if op == "fusion" and "dynamic-slice" in name
+    }
+    d = cfg.d_model
+    for out in (cfg.n_heads * cfg.head_dim, cfg.n_kv_heads * cfg.head_dim):
+        assert (1, out, d) in read, (out, read)
+        if out != d:  # the stack as it used to be held is read nowhere
+            assert (1, d, out) not in read, read
+
+
+def test_projections_held_in_by_out_are_copied_every_layer(
+    one_chip, compiled_not_interpreted
+):
+    """Why the scheduler turns them (``kv_pager.serving_params``): held
+    ``[L, in, out]`` as ``make_weights`` and training keep them, each
+    layer's slice of ``wq``, ``wk`` and ``wv`` is copied to the layout the
+    dot reads before the product.  If this starts failing the turn can go."""
+    cfg, compiled = _served("mistral_step", one_chip, turned=False)
+    moved = _projection_moves(compiled.as_text(), cfg)
+    assert len(moved) == 3, moved

@@ -65,6 +65,7 @@ NEW_METRICS = (
     "verb_head_ms.score", "verb_tail_ms.score",
     "readback_wait_share.score", "prefill_pad_share.decode",
     "paged_kernel_step_share.decode", "params_resident_share.score",
+    "proj_in_place_step_share.decode",
 )
 
 
@@ -723,6 +724,7 @@ def test_benchmark_metric_file_reads_the_counters(name):
     obs_ = {
         "counters.decode_steps": 500,
         "counters.decode_kernel_steps": 500,
+        "counters.decode_proj_in_place_steps": 500,
         "counters.decode_host_ns": 1_250_000_000,
         "counters.decode_step_wait_ns": 5_500_000_000,
         "counters.decode_prefill_ns": 8_250_000_000,
@@ -760,6 +762,7 @@ def test_benchmark_metric_file_reads_the_counters(name):
         "prefill_pad_share.decode": 41.40625,
         "paged_kernel_step_share.decode": 100.0,
         "params_resident_share.score": 75.0,
+        "proj_in_place_step_share.decode": 100.0,
     }[name]
     assert read_metric(name, obs_) == pytest.approx(want)
     # the parent commit has no such counter: nothing to read, no raise

@@ -1238,7 +1238,11 @@ class DecodeScheduler:
     slot — what a decode step does with one token.  A block whose pool
     holds pages AND a state a slot (a state-space mixer beside attention,
     ``BlockSpec(mixer=...)``) reserves both at ``submit``: its pages and
-    its slot's state are one charge.
+    its slot's state are one charge.  A stack of window layers among full
+    ones (``BlockSpec(layer_types=..., window=...)``) reserves its full
+    pages and is charged for them and for what it holds of the ring of
+    window pages its slot owns (``PagePool.ring_of``); a slot's table row
+    is the two tables end to end, the ring's written at admission.
 
     ``speculative`` runs the draft/verify path (B=1 by its contract)
     solo in the caller's thread — an opt-in per-request latency knob,
@@ -1294,6 +1298,9 @@ class DecodeScheduler:
         # path is pinned at exactly this capacity (``cache_len=cap``)
         self.max_pages = 1 if self._by_slot else kv_pager.pages_for(cap, P)
         self.cap = cap if self._by_slot else self.max_pages * P
+        # a window layer's ring of pages, which ends each table row (0:
+        # no window layers)
+        self.ring = kv_pager.ring_pages(cfg, P) if cfg.block.window else 0
         n_pages = (
             int(pool_pages)
             if pool_pages is not None
@@ -1317,9 +1324,11 @@ class DecodeScheduler:
         # this pool's one-token step, asked once (``decode_kernel_steps``)
         self._kernel_step = 0 if self._by_slot else int(
             kv_pager.paged_kernel_fits(
-                cfg, P, self.max_slots, 1, self._kp.dtype
+                cfg, P, self.max_slots, 1, self.pool.dtype
             )
         )
+        # and whether its window layers do (``decode_window_kernel_steps``)
+        self._window_kernel_step = self._kernel_step if self.ring else 0
         # and whether it steps a mixer's state through ``tfs_ssm_step``
         # (``decode_ssm_kernel_steps``)
         self._ssm_kernel_step = int(
@@ -1335,7 +1344,7 @@ class DecodeScheduler:
         self._routing_done: "collections.OrderedDict[bytes, np.ndarray]" = (
             collections.OrderedDict()
         )
-        self._inputs = _StepInputs(self.max_slots, self.max_pages)
+        self._inputs = _StepInputs(self.max_slots, self.max_pages + self.ring)
         self._lock = threading.Lock()
         self._cv = threading.Condition(self._lock)
         self._pending: "collections.deque[_PagedSeq]" = collections.deque()
@@ -1445,7 +1454,7 @@ class DecodeScheduler:
             prompt, max_new, until, tenant,
             cancellation.current_scope(), charge,
         )
-        row = np.zeros((self.max_pages,), np.int32)
+        row = np.zeros((self.max_pages + self.ring,), np.int32)
         row[: len(pages)] = pages
         req.table_row = row
         with observability.span(
@@ -1766,6 +1775,10 @@ class DecodeScheduler:
                         if self._by_slot and not self._charge_slot(req):
                             continue
                         slot = self._free.pop()
+                        if self.ring:  # the ring of window pages it owns
+                            req.table_row[self.max_pages:] = (
+                                self.pool.ring_of(slot)
+                            )
                         inputs.admit(slot, req.table_row)
                         self._active[slot] = req
                         admitted.append((slot, req))
@@ -1813,6 +1826,11 @@ class DecodeScheduler:
                             # what the step attended over: every live
                             # slot's tokens, the one it fed among them
                             held = int(inputs.indices.sum()) + n_tok
+                            if self.ring:  # what the window layers read
+                                held_w = int(np.minimum(
+                                    inputs.indices[list(self._active)] + 1,
+                                    self.cfg.block.window,
+                                ).sum())
                             for slot, req in list(self._active.items()):
                                 if keep:
                                     req.routing.append(chosen[:, slot, None])
@@ -1836,6 +1854,11 @@ class DecodeScheduler:
                 tally["decode_proj_in_place_steps"] += self._proj_in_place
                 tally["decode_tokens"] += n_tok
                 tally["decode_tokens_held"] += held
+                if self.ring:
+                    tally["decode_window_tokens_held"] += held_w
+                    tally["decode_window_kernel_steps"] += (
+                        self._window_kernel_step
+                    )
                 if self._ret is not None:
                     tally["decode_state_slots_held"] += n_tok
                 tally["decode_step_wait_ns"] += sp_w.ns

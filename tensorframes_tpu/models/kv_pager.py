@@ -81,6 +81,18 @@ serving removed (PAPERS.md).  This module is the paged layout:
   kernel ``parallel/ssm.py`` (or ``ssm.step``); a prefill runs SSD's
   chunked form and overwrites the slot's state and tail whole.  A
   sequence is charged its pages and its slot's state together;
+* a stack whose attention differs by layer (``BlockSpec(layer_types=...,
+  window=...)``: Trinity's window layers among full ones) keeps each
+  kind's pages in a pool of its own, ``k_pages`` and ``v_pages`` each a
+  pair ``(full [n_full, kvh, n_pages, P, Dh], window [n_window, kvh,
+  window_pages, P, Dh])``, a layer indexed by its rank among its kind.
+  A sequence's table row is its full table and then its RING, the
+  :func:`ring_pages` pages a window layer holds of it at any length:
+  logical page ``p`` lies in ring slot ``p % ring``, so a window layer
+  writes over the page that has fallen behind its window, and a prompt
+  longer than the ring writes only what the ring keeps (the earlier pages
+  to the trash page).  The layer scan runs the stack as runs of like
+  layers (:func:`_scan_layers`), a sequence is charged both pools' pages;
 * :func:`paged_prefill` is the serving prefill — ONE admitted prompt
   and nothing else.  A prefill starts at position 0, so the only keys
   its queries may see are the chunk's own: it writes them to the pages
@@ -105,7 +117,7 @@ from __future__ import annotations
 
 import functools
 import threading
-from typing import Dict, List, Optional, Tuple
+from typing import Dict, List, NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -116,7 +128,7 @@ from . import transformer as tfm
 from .. import observability
 from ..envutil import env_int as _env_int
 from ..ops import frame_cache
-from ..parallel import paged_attention
+from ..parallel import flash, paged_attention
 from ..parallel import retention as retention_kernel
 from ..parallel import ssm as ssm_kernel
 
@@ -194,7 +206,18 @@ class PagePool:
     with a mixer ``(S, tail)`` (``models/ssm.py``) beside its pages; None
     for the others.  A sequence's charge is its pages and its slot's
     state, ``n * page_bytes + state_bytes``, both read off the arrays'
-    own shapes."""
+    own shapes.
+
+    A stack whose attention differs by layer (``cfg.block.layer_types``)
+    has TWO pools of pages: ``k_pages`` and ``v_pages`` are pairs ``(full,
+    window)``, the full layers' ``n_pages`` pages and the window layers'
+    ``window_pages``, each with its own trash page 0.  A window layer holds
+    at most its :attr:`ring` of pages of a sequence at any length, so the
+    window pool is every slot's ring and no more, ``slots * ring + 1``
+    pages, and slot ``s`` owns the ring :meth:`ring_of` ``(s)``: nothing
+    there is allocated or refused.  A sequence takes ``n`` full pages from
+    the free list and is charged ``n * page_bytes + min(n, ring) *
+    window_page_bytes``, what it holds of both pools."""
 
     def __init__(
         self,
@@ -233,18 +256,42 @@ class PagePool:
                     f"PagePool(..., slots=max_slots)"
                 )
             self.slots = int(slots)
+        # a window layer's ring, and the window pool's pages
+        self.ring = ring_pages(cfg, P) if cfg.block.window else 0
+        self.window_pages = 0
+        if self.ring:
+            if slots is None:
+                raise ValueError(
+                    "a stack with window layers holds a ring of pages a "
+                    "decode slot: PagePool(..., slots=max_slots)"
+                )
+            self.window_pages = int(slots) * self.ring + 1
         self.k_pages, self.v_pages, self.conv_state = self.zeros()
         self.retention = self.retention_zeros()
+
+        def page_bytes(pools, n):
+            return sum(
+                pages.size // n * self.dtype.itemsize
+                for pages in pools if pages is not None
+            )
+
         # what the budget LRU accounts, read off the arrays' own shapes:
         # one page's HBM across all layers, of every pool there is (K and
         # V together, or the one latent pool), and one slot's retained
         # state, all layers.  A pool with no pages (retention) has the
         # slot's state as its page
-        self.page_bytes = sum(
-            pages.size // self.n_pages * self.dtype.itemsize
-            for pages in (self.k_pages, self.v_pages)
-            if pages is not None
-        )
+        self.window_page_bytes = 0
+        if self.ring:
+            self.page_bytes = page_bytes(
+                (self.k_pages[0], self.v_pages[0]), self.n_pages
+            )
+            self.window_page_bytes = page_bytes(
+                (self.k_pages[1], self.v_pages[1]), self.window_pages
+            )
+        else:
+            self.page_bytes = page_bytes(
+                (self.k_pages, self.v_pages), self.n_pages
+            )
         self.state_bytes = sum(
             a.size // self.slots * a.dtype.itemsize
             for a in self.retention or ()
@@ -273,13 +320,21 @@ class PagePool:
         state = None
         if cfg.block.attention == "cca":
             state = cca.init_state(cfg, self.slots, self.dtype)
-        k, v = (
-            None if w is None else jnp.zeros(
-                (cfg.n_layers, heads, self.n_pages, self.tokens_per_page, w),
-                self.dtype,
+
+        def pool(layers, n_pages, w):
+            return None if w is None else jnp.zeros(
+                (layers, heads, n_pages, self.tokens_per_page, w), self.dtype
             )
-            for w in widths
-        )
+
+        if self.ring:
+            n_full = cfg.block.layer_types.count("full")
+            k, v = (
+                (pool(n_full, self.n_pages, w),
+                 pool(cfg.n_layers - n_full, self.window_pages, w))
+                for w in widths
+            )
+            return k, v, state
+        k, v = (pool(cfg.n_layers, self.n_pages, w) for w in widths)
         return k, v, state
 
     def take(self):
@@ -330,7 +385,8 @@ class PagePool:
         lifetime — the LRU holds it weakly) and the page ids.  Raises
         :class:`PagesExhausted` when the free list or the pinned budget
         charge refuses — atomically: a refused allocation takes
-        nothing."""
+        nothing.  With window layers the charge also holds the sequence's
+        pages of its slot's ring, ``min(n, ring)``."""
         n = int(n)
         if n <= 0:
             raise ValueError(f"allocate({n}): need a positive page count")
@@ -342,7 +398,10 @@ class PagePool:
             # to make room, live pages never are — an unpayable charge
             # is a refusal here, not an OOM three steps from now
             if not frame_cache._budget.charge(
-                charge, 0, n * self.page_bytes + self.state_bytes,
+                charge, 0,
+                n * self.page_bytes
+                + min(n, self.ring) * self.window_page_bytes
+                + self.state_bytes,
                 pinned=True,
             ):
                 raise PagesExhausted(n, len(self._free), reason="budget")
@@ -351,6 +410,10 @@ class PagePool:
         charge.pages = pages
         observability.note_kv_pages_allocated(n)
         return charge, pages
+
+    def ring_of(self, slot: int) -> List[int]:
+        """The window pool's pages slot ``slot`` owns: its ring."""
+        return list(range(1 + slot * self.ring, 1 + (slot + 1) * self.ring))
 
     def free(self, charge: _SeqPages) -> None:
         """Return a sequence's pages to the free list and refund its
@@ -372,7 +435,7 @@ class PagePool:
     def stats(self) -> Dict[str, int]:
         with self._lock:
             free = len(self._free)
-        return {
+        out = {
             "page_tokens": self.tokens_per_page,
             "pages_total": self.capacity,
             "pages_free": free,
@@ -382,6 +445,12 @@ class PagePool:
             "allocated_total": self.allocated_total,
             "freed_total": self.freed_total,
         }
+        if self.ring:
+            out.update(
+                window_pages_total=self.window_pages - 1,
+                window_page_bytes=self.window_page_bytes,
+            )
+        return out
 
 
 def holds_pages(cfg) -> bool:
@@ -393,6 +462,13 @@ def holds_pages(cfg) -> bool:
 def pages_for(tokens: int, tokens_per_page: int) -> int:
     """Pages needed to hold ``tokens`` sequence positions."""
     return max(1, -(-int(tokens) // int(tokens_per_page)))
+
+
+def ring_pages(cfg, tokens_per_page: int) -> int:
+    """The pages a window layer holds of a sequence at any length: the
+    window's and one more, so that the page a step writes is never one its
+    window still reads."""
+    return -(-int(cfg.block.window) // int(tokens_per_page)) + 1
 
 
 def init_tables(batch: int, max_pages: int) -> jnp.ndarray:
@@ -407,7 +483,8 @@ def init_tables(batch: int, max_pages: int) -> jnp.ndarray:
 
 
 @jax.named_scope("page_write")
-def _page_write(kp, vp, k, v, positions, tables, layer, from_zero=False):
+def _page_write(kp, vp, k, v, positions, tables, layer, from_zero=False,
+                ring=False, last_pos=None):
     """Scatter a chunk's k/v ``[B, L, kvh, Dh]`` into ``layer``'s pages of
     the stacked pools ``[n_layers, kvh, n_pages, P, Dh]`` at ``tables[b,
     pos // P]``, offset ``pos % P``, of every head.  Returns the updated
@@ -426,7 +503,14 @@ def _page_write(kp, vp, k, v, positions, tables, layer, from_zero=False):
     each table's first and is written a page of a head a window (``w`` =
     P, a 4 KB tile: 8 x 64 windows for a 1,024 bucket, not 8 x 1,024),
     its last page padded with zeros where no query can look before a
-    decode step has written there."""
+    decode step has written there.
+
+    ``ring`` (static): ``tables`` [B, ring] is a window layer's ring, which
+    holds logical page ``p`` in slot ``p % ring``.  A prefill (``from_zero``)
+    then writes only the pages the ring keeps, those of the last ``ring``
+    up to the page of ``last_pos`` [B]: the earlier ones and the bucket's
+    padding past it go to the trash page, since two writes to one slot in
+    one scatter land in no defined order."""
     B, L, kvh, _ = k.shape
     n_layers, _, n_pages, P, _ = kp.shape
     max_pages = tables.shape[1]
@@ -438,16 +522,27 @@ def _page_write(kp, vp, k, v, positions, tables, layer, from_zero=False):
         w, n = 1, L
         page_slot = positions // P  # [B, L]
         window = (positions % P).reshape(B * n)
-    # positions past a row's table (bucket padding that overruns
-    # the sequence capacity) write the trash page, never a
-    # clamped real slot
-    dest = jnp.where(
-        page_slot < max_pages,
-        jnp.take_along_axis(
-            tables, jnp.minimum(page_slot, max_pages - 1), axis=1
-        ),
-        0,
-    ).reshape(B * n)
+    if ring:
+        keep = True
+        if from_zero:
+            last = (last_pos // P)[:, None]
+            keep = (page_slot <= last) & (page_slot > last - max_pages)
+        dest = jnp.where(
+            keep,
+            jnp.take_along_axis(tables, page_slot % max_pages, axis=1),
+            0,
+        ).reshape(B * n)
+    else:
+        # positions past a row's table (bucket padding that overruns
+        # the sequence capacity) write the trash page, never a
+        # clamped real slot
+        dest = jnp.where(
+            page_slot < max_pages,
+            jnp.take_along_axis(
+                tables, jnp.minimum(page_slot, max_pages - 1), axis=1
+            ),
+            0,
+        ).reshape(B * n)
     heads = layer * kvh + jnp.arange(kvh, dtype=jnp.int32)[:, None]
     at = ((heads * n_pages + dest) * (P // w) + window).reshape(kvh * B * n)
 
@@ -469,15 +564,32 @@ def _page_write(kp, vp, k, v, positions, tables, layer, from_zero=False):
 
 def _attn_out(bp, x, att, cfg):
     """x + Wo(att): the residual half both paged blocks end their
-    attention with.  att: [B, L, h, Dh], or the heads joined already."""
+    attention with.  att: [B, L, h, Dh], or the heads joined already; ``x``
+    the block's input.  A spec with ``attn_gate`` gates att elementwise by
+    ``transformer.attn_gate`` of ``x`` first, one with ``sandwich`` norms
+    Wo(att) before it joins the residual."""
     B, L = att.shape[:2]
     att = att.reshape(B, L, -1)
+    if cfg.block.attn_gate:
+        with jax.named_scope("gate"):
+            att = att * tfm.attn_gate(bp, x, cfg)
     out = tfm.shard(
         att @ tfm.weight(bp["wo"], cfg.dtype), ("dp", "ep"), "sp", None
     )
+    if cfg.block.sandwich:
+        out = tfm._rms_norm(out, bp["ln_post_attn"], cfg.block.norm_eps)
     return x + tfm.times(
         out, cfg.block.multipliers.attention_out
     )
+
+
+def _mlp_out(bp, out, cfg):
+    """An expert layer's output as it joins the residual: normed under a
+    ``sandwich`` spec (``transformer._mlp_residual`` does the same for the
+    dense SwiGLU)."""
+    if cfg.block.sandwich:
+        return tfm._rms_norm(out, bp["ln_post_mlp"], cfg.block.norm_eps)
+    return out
 
 
 def _embed(params, tokens, cfg):
@@ -505,9 +617,9 @@ def _feed_forward(bp, x, cfg, layer, route):
     layer = layer - cfg.block.dense_layers if cfg.block.dense_layers else layer
     if cfg.block.ffn == "experts_topk":
         out, *routed = moe.experts_topk(bp, y, live, cfg, experts, layer)
-        return x + out, (None, *routed)
+        return x + _mlp_out(bp, out, cfg), (None, *routed)
     out, *routed = moe.experts_top1(bp, y, r_prev, live, cfg, experts, layer)
-    return x + out, tuple(routed)
+    return x + _mlp_out(bp, out, cfg), tuple(routed)
 
 
 def _mesh_partitions() -> bool:
@@ -651,8 +763,69 @@ def _retention_prefill(bp, x, positions, cfg, st, layer, slot, last_pos,
     return _attn_out(bp, x, y.astype(cfg.dtype)[None], cfg), (S, z)
 
 
+class _Site(NamedTuple):
+    """Where a layer of a stack whose attention differs by layer
+    (``cfg.block.layer_types``) keeps its pages: its kind's pool of the
+    pair (0 the full layers', 1 the window layers'), its index in that pool
+    (its rank among its kind; traced), its window (0 for a full layer) and
+    the width of a sequence's ring, which ends its table row."""
+
+    pool: int
+    at: jnp.ndarray
+    window: int
+    ring: int
+
+    def select(self, kp, vp, tables):
+        """This layer's pools and table out of the pairs and the row."""
+        split = tables.shape[1] - self.ring
+        table = tables[:, split:] if self.window else tables[:, :split]
+        return kp[self.pool], vp[self.pool], table
+
+    def put(self, pools, kp, vp):
+        """The pairs with this layer's kind's pools written back."""
+        pair = list(zip(*pools))
+        pair[self.pool] = (kp, vp)
+        return tuple(zip(*pair))
+
+
+def _rotates(cfg, site) -> bool:
+    """Whether a layer rotates q and k: all do but a full layer of a stack
+    with ``layer_types`` (NoPE among window layers)."""
+    return site is None or bool(site.window)
+
+
+def _ring_positions(positions, P: int, ring: int):
+    """The positions of a window layer's keys as a ring table gathers them,
+    ``[B, ring * P]``, for one query a row at ``positions[:, 0]``: slot
+    ``s`` holds the latest logical page ``p`` at or before the query's with
+    ``p % ring == s``; a slot whose page would lie before the sequence holds
+    no key, and is given a position past every query's."""
+    cur = positions[:, :1] // P
+    page = cur - (cur - jnp.arange(ring, dtype=jnp.int32)[None]) % ring
+    pos = page[:, :, None] * P + jnp.arange(P, dtype=jnp.int32)
+    return jnp.where(
+        page[:, :, None] >= 0, pos, jnp.iinfo(jnp.int32).max
+    ).reshape(pos.shape[0], ring * P)
+
+
+# a prefill's own float32 scores [kvh, g, L, L] are held whole up to this
+# many bytes (``transformer._cache_attention``); a longer chunk attends
+# through the flash kernel, which holds a block of them at a time
+PREFILL_SCORES_BYTES = 1 << 28
+
+
+def _prefill_attention(q, k, v, positions, window=0):
+    """Causal attention of a chunk that starts its sequence, over its own
+    keys (``window`` > 0: the last ``window`` of them, static)."""
+    B, L, h, _ = q.shape
+    if B * h * L * L * 4 <= PREFILL_SCORES_BYTES:
+        return tfm._cache_attention(q, k, v, positions, window)
+    with jax.named_scope("flash_prefill"):
+        return flash.flash_prefill(q, k, v, window)
+
+
 def _paged_block(bp, x, positions, cfg, kp, vp, tables, layer, st=None,
-                 route=None):
+                 route=None, site=None):
     """One decoder block against ``layer``'s pages of the stacked pools.
 
     ``kp``/``vp``: [n_layers, kvh, n_pages, P, Dh], written and read at
@@ -674,7 +847,13 @@ def _paged_block(bp, x, positions, cfg, kp, vp, tables, layer, st=None,
     and adds the mixer's output (:func:`ssm.mix_step`, on the same input
     as attention) to attention's; an ``experts_top1`` block takes
     ``route`` (:func:`_feed_forward`).  Returns ``(x', kp', vp', st',
-    routed)``, the last two None where the spec has no such thing."""
+    routed)``, the last two None where the spec has no such thing.
+
+    A layer of a stack whose attention differs by layer takes its
+    :class:`_Site`, ``kp`` / ``vp`` the pairs of pools and ``tables`` the
+    whole rows: it reads and writes its kind's pool at its rank, a window
+    layer through its ring (``window_write``; ``window_kernel``, or the
+    ring's gather under the window's mask), and returns the pairs."""
     if cfg.block.attention == "retention":
         # no pages: ``st`` is the state, ``tables[:, 0]`` says who is live
         with jax.named_scope("attention"):
@@ -685,12 +864,21 @@ def _paged_block(bp, x, positions, cfg, kp, vp, tables, layer, st=None,
         return x, kp, vp, st, routed
     B, L = x.shape[:2]
     dt = cfg.dtype
+    pools, at, window = None, layer, 0
+    if site is not None:
+        if site.window and L != 1:
+            raise NotImplementedError(
+                "a window layer steps one token a row; a chunk is a prefill"
+            )
+        pools, at, window = (kp, vp), site.at, site.window
+        kp, vp, tables = site.select(kp, vp, tables)
     P = kp.shape[3]
     cap = tables.shape[1] * P
     x_in = x
     # scope names are metadata: a profiler session groups the device
     # operations of a step under attention / page_write / paged_kernel
-    # (or page_gather, on the general path)
+    # (or page_gather, on the general path; window_write / window_kernel
+    # for a window layer)
     with jax.named_scope("attention"):
         if cfg.block.attention == "mla":
             x, kp = _latent_attention(bp, x, positions, cfg, kp, tables, layer)
@@ -699,17 +887,30 @@ def _paged_block(bp, x, positions, cfg, kp, vp, tables, layer, st=None,
                 q, k, v, row = cca.qkv_step(bp, x, positions, st[layer], cfg)
                 st = st.at[layer].set(row)
             else:
-                q, k, v = tfm._attn_qkv(bp, x, positions, cfg)
+                q, k, v = tfm._attn_qkv(
+                    bp, x, positions, cfg, _rotates(cfg, site)
+                )
             kvh, dh = k.shape[2:]
-            kp, vp = _page_write(kp, vp, k, v, positions, tables, layer)
+            if window:
+                with jax.named_scope("window_write"):
+                    kp, vp = _page_write(
+                        kp, vp, k, v, positions, tables, at, ring=True
+                    )
+            else:
+                kp, vp = _page_write(kp, vp, k, v, positions, tables, at)
             if paged_kernel_fits(cfg, P, B, L, kp.dtype):
-                with jax.named_scope("paged_kernel"):
+                with jax.named_scope(
+                    "window_kernel" if window else "paged_kernel"
+                ):
                     # an idle row (any index, its table all trash) attends
                     # the trash page like the gather path: at least one key,
-                    # at most the capacity
-                    lengths = jnp.clip(positions[:, 0] + 1, 1, cap)
+                    # at most the capacity (a ring holds any length)
+                    lengths = (
+                        jnp.maximum(positions[:, 0] + 1, 1) if window
+                        else jnp.clip(positions[:, 0] + 1, 1, cap)
+                    )
                     att = paged_attention.paged_attention(
-                        q[:, 0], kp, vp, tables, lengths, layer
+                        q[:, 0], kp, vp, tables, lengths, at, window=window
                     )[:, None]
             else:
                 with jax.named_scope("page_gather"):
@@ -717,12 +918,16 @@ def _paged_block(bp, x, positions, cfg, kp, vp, tables, layer, st=None,
                     # stack, into its contiguous cache view: the two advanced
                     # indices lead, [B, max_pages, kvh, P, Dh]
                     ck, cv = (
-                        jnp.moveaxis(pages[layer, :, tables], 2, 3).reshape(
+                        jnp.moveaxis(pages[at, :, tables], 2, 3).reshape(
                             B, cap, kvh, dh
                         ).astype(dt)
                         for pages in (kp, vp)
                     )
-                att = tfm._cache_attention(q, ck, cv, positions)
+                k_pos = (
+                    _ring_positions(positions, P, tables.shape[1]) if window
+                    else None
+                )
+                att = tfm._cache_attention(q, ck, cv, positions, window, k_pos)
             x = _attn_out(bp, x, att, cfg)
     if cfg.block.mixer is not None:
         with jax.named_scope("mixer"):
@@ -732,11 +937,13 @@ def _paged_block(bp, x, positions, cfg, kp, vp, tables, layer, st=None,
             )
         x = x + m
     x, routed = _feed_forward(bp, x, cfg, layer, route)
+    if pools is not None:
+        kp, vp = site.put(pools, kp, vp)
     return x, kp, vp, st, routed
 
 
 def _prefill_block(bp, x, positions, cfg, kp, vp, tables, layer, st, slot,
-                   last_pos, route=None):
+                   last_pos, route=None, site=None):
     """:func:`_paged_block` for ONE sequence whose chunk STARTS it
     (``positions`` count from 0): the same projections and page write,
     but the only keys such a chunk's queries may see are its own, which
@@ -746,10 +953,20 @@ def _prefill_block(bp, x, positions, cfg, kp, vp, tables, layer, st, slot,
     row of ``slot`` [1] is OVERWRITTEN with what the prompt's last real
     position ``last_pos`` [1] leaves, and so are a mixer's state and tail
     (:func:`ssm.mix_prefill`), so nothing of the slot's previous tenant
-    survives admission.  Returns ``(x', kp', vp', st', routed)``."""
+    survives admission.  Returns ``(x', kp', vp', st', routed)``.
+
+    A chunk whose float32 scores would pass ``PREFILL_SCORES_BYTES``
+    attends through the flash kernel (``attention/flash_prefill``).  A
+    layer with a :class:`_Site` takes its kind's pool as
+    :func:`_paged_block` does; a window layer writes the pages its ring
+    keeps and attends over the last ``window`` keys of each query."""
     dt = cfg.dtype
     tail = None
     x_in = x
+    pools, at, window = None, layer, 0
+    if site is not None:
+        pools, at, window = (kp, vp), site.at, site.window
+        kp, vp, tables = site.select(kp, vp, tables)
     with jax.named_scope("attention"):
         if cfg.block.attention == "mla":
             x, kp = _latent_attention(
@@ -759,13 +976,22 @@ def _prefill_block(bp, x, positions, cfg, kp, vp, tables, layer, st, slot,
             if cfg.block.attention == "cca":
                 q, k, v, tail = cca.qkv_sequence(bp, x, positions, cfg)
             else:
-                q, k, v = tfm._attn_qkv(bp, x, positions, cfg)
-            kp, vp = _page_write(
-                kp, vp, k, v, positions, tables, layer, from_zero=True
-            )
-            att = tfm._cache_attention(
+                q, k, v = tfm._attn_qkv(
+                    bp, x, positions, cfg, _rotates(cfg, site)
+                )
+            if window:
+                with jax.named_scope("window_write"):
+                    kp, vp = _page_write(
+                        kp, vp, k, v, positions, tables, at, from_zero=True,
+                        ring=True, last_pos=last_pos,
+                    )
+            else:
+                kp, vp = _page_write(
+                    kp, vp, k, v, positions, tables, at, from_zero=True
+                )
+            att = _prefill_attention(
                 q, k.astype(kp.dtype).astype(dt), v.astype(vp.dtype).astype(dt),
-                positions,
+                positions, window,
             )
             x = _attn_out(bp, x, att, cfg)
     if cfg.block.mixer is not None:
@@ -779,7 +1005,25 @@ def _prefill_block(bp, x, positions, cfg, kp, vp, tables, layer, st, slot,
                 tail, last_pos[:, None, None], axis=1
             )[:, 0]
             st = st.at[layer, slot].set(last.astype(st.dtype))
+    if pools is not None:
+        kp, vp = site.put(pools, kp, vp)
     return x, kp, vp, st, routed
+
+
+def _runs(cfg):
+    """The runs of a stack whose attention differs by layer: ``(stack,
+    first, stop, kind)`` for each longest run of consecutive layers of one
+    parameter stack (the leading dense layers', or the rest) and one
+    attention kind, in layer order."""
+    runs = []
+    for layer in range(cfg.n_layers):
+        stack = "dense_blocks" if layer < cfg.block.dense_layers else "blocks"
+        kind = cfg.block.kind_of(layer)
+        if runs and runs[-1][0] == stack and runs[-1][3] == kind:
+            runs[-1][2] = layer + 1
+        else:
+            runs.append([stack, layer, layer + 1, kind])
+    return [tuple(r) for r in runs]
 
 
 def _scan_layers(block, x, params, k_pages, v_pages, state, live, cfg):
@@ -797,13 +1041,44 @@ def _scan_layers(block, x, params, k_pages, v_pages, state, live, cfg):
     Layers of two kinds are two runs of the one scan over the one carry
     (ROADMAP M2): the spec's leading ``dense_layers`` first, on their own
     stack ``params["dense_blocks"]`` and with no route, so that
-    :func:`_feed_forward` gives them the dense SwiGLU; then the rest."""
+    :func:`_feed_forward` gives them the dense SwiGLU; then the rest.
+
+    A stack whose attention differs by layer (``cfg.block.layer_types``:
+    window layers among full ones) is scanned as its :func:`_runs`, one
+    scan each over the one carry, whose pools are then the pairs of the two
+    kinds: a run's kind is static, so its layers trace one body and read
+    one pool, and each layer of it is given its :class:`_Site` (``block``
+    takes it as an eighth argument) and indexes its parameters out of the
+    whole stack.  The runs of one stack report what they route in layer
+    order, joined."""
     first = cfg.block.dense_layers
     blocks, experts, r = params["blocks"], None, None
     if cfg.block.routes:
         blocks, experts = moe.stack_experts(blocks, cfg)
     if cfg.block.ffn == "experts_top1":
         r = jnp.zeros(x.shape[:2] + (cfg.block.router_hidden,), jnp.float32)
+    if cfg.block.layer_types:
+        stacks = {"dense_blocks": params.get("dense_blocks"), "blocks": blocks}
+        ring = ring_pages(cfg, k_pages[1].shape[3]) if cfg.block.window else 0
+        carry, reports, ranks = (x, r, k_pages, v_pages, state), [], {}
+        for stack, a, b, kind in _runs(cfg):
+            carry, report = _scan_run(
+                block, carry, stacks[stack], a, b,
+                first if stack == "blocks" else 0,
+                _Site(
+                    int(kind == "window"), jnp.int32(ranks.get(kind, 0) - a),
+                    cfg.block.window if kind == "window" else 0, ring,
+                ),
+                None if stack == "dense_blocks" else experts, live,
+            )
+            ranks[kind] = ranks.get(kind, 0) + b - a
+            if report is not None:
+                reports.append(report)
+        routed = None
+        if reports:
+            routed = tuple(jnp.concatenate(parts) for parts in zip(*reports))
+        x, _, k_pages, v_pages, state = carry
+        return x, k_pages, v_pages, state, routed
 
     def run(carry, blocks, layers, experts):
         def step(carry, xs):
@@ -827,6 +1102,31 @@ def _scan_layers(block, x, params, k_pages, v_pages, state, live, cfg):
         carry, blocks, (first, cfg.n_layers), experts
     )
     return x, k_pages, v_pages, state, routed
+
+
+def _scan_run(block, carry, stack, a, b, offset, site, experts, live):
+    """One run of :func:`_scan_layers` for a stack whose attention differs
+    by layer: layers ``a .. b - 1``, their parameters indexed out of
+    ``stack`` at ``layer - offset`` (the run's is never sliced out of the
+    stack), each at ``site`` with its rank ``site.at + layer``.  Returns
+    ``(carry, report)``, ``report`` the layers' routing stacked or None."""
+
+    def step(carry, layer):
+        x, r, kp, vp, st = carry
+        bp = jax.tree_util.tree_map(
+            lambda w: jax.lax.dynamic_index_in_dim(w, layer - offset, 0, False),
+            stack,
+        )
+        route = None if experts is None else (r, live, experts)
+        x, kp, vp, st, routed = block(
+            bp, x, kp, vp, st, layer, route, site._replace(at=site.at + layer)
+        )
+        report = None
+        if routed is not None:
+            r, report = routed[0], routed[1:]
+        return (x, r, kp, vp, st), report
+
+    return jax.lax.scan(step, carry, jnp.arange(a, b, dtype=jnp.int32))
 
 
 def _routing(routed, n_experts):
@@ -877,9 +1177,9 @@ def _step_forward(params, tokens, tables, indices, k_pages, v_pages, cfg,
     if cfg.block.routes:
         live = jnp.broadcast_to(tables[:, :1] > 0, (B, L))
 
-    def block(bp, x, kp, vp, st, layer, route):
+    def block(bp, x, kp, vp, st, layer, route, site=None):
         return _paged_block(
-            bp, x, positions, cfg, kp, vp, tables, layer, st, route
+            bp, x, positions, cfg, kp, vp, tables, layer, st, route, site
         )
 
     x, kps, vps, state, routed = _scan_layers(
@@ -935,7 +1235,7 @@ def _prefill_forward(params, toks, table, last_pos, k_pages, v_pages, cfg,
     if cfg.block.routes:
         live = positions <= last_pos[:, None]
 
-    def block(bp, x, kp, vp, st, layer, route):
+    def block(bp, x, kp, vp, st, layer, route, site=None):
         if cfg.block.attention == "retention":
             with jax.named_scope("attention"):
                 x, st = _retention_prefill(
@@ -946,7 +1246,7 @@ def _prefill_forward(params, toks, table, last_pos, k_pages, v_pages, cfg,
             return x, kp, vp, st, routed
         return _prefill_block(
             bp, x, positions, cfg, kp, vp, table, layer, st, slot, last_pos,
-            route,
+            route, site,
         )
 
     x, k_pages, v_pages, state, routed = _scan_layers(
@@ -975,8 +1275,10 @@ def _prefill_forward(params, toks, table, last_pos, k_pages, v_pages, cfg,
 _DONATED = ("k_pages", "v_pages", "retention")
 
 # the projections ``transformer._attn_qkv`` and ``retention.project`` read
-# through ``transformer.linear``
+# through ``transformer.linear``, and beside them the output gate's
+# (``transformer.attn_gate``), which reads the same normed input
 _QKV = ("wq", "wk", "wv")
+_TURNED = _QKV + ("w_attn_gate",)
 
 
 def serving_params(params: tfm.Params, cfg) -> tfm.Params:
@@ -1009,7 +1311,7 @@ def serving_params(params: tfm.Params, cfg) -> tfm.Params:
     for stack in ("dense_blocks", "blocks"):
         if stack in out:
             out[stack] = {
-                k: turn(v) if k in _QKV else v for k, v in out[stack].items()
+                k: turn(v) if k in _TURNED else v for k, v in out[stack].items()
             }
     return out
 

@@ -332,7 +332,31 @@ def stack_experts(blocks, cfg):
     return rest, experts
 
 
+# the token-expert pairs one sorted grouped product takes at once: a
+# prefill with more goes a run of tokens at a time (``_grouped_experts``),
+# since each of the products holds its float32 rows whole (768 MiB at 65,536
+# pairs of 3,072: a 16,384-token prefill at top-4)
+PAIRS_AT_ONCE = 1 << 14
+
+
 def _grouped_experts(yt, picks, gates, experts, layer, held, dt):
+    """:func:`_grouped_once` over the tokens, in runs of tokens of at most
+    ``PAIRS_AT_ONCE`` pairs where there are more (each run sorted and
+    multiplied on its own; a token's output is the same): ``(out [T, D],
+    sizes [held])``."""
+    T, k = picks.shape
+    runs = -(-T * k // PAIRS_AT_ONCE)
+    if runs == 1 or T % runs:
+        return _grouped_once(yt, picks, gates, experts, layer, held, dt)
+    out, sizes = jax.lax.map(
+        lambda run: _grouped_once(*run, experts, layer, held, dt),
+        (yt.reshape(runs, T // runs, -1), picks.reshape(runs, T // runs, k),
+         gates.reshape(runs, T // runs, k)),
+    )
+    return out.reshape(T, -1), sizes.sum(axis=0, dtype=sizes.dtype)
+
+
+def _grouped_once(yt, picks, gates, experts, layer, held, dt):
     """The sorted grouped products both expert layers end in.  ``yt`` [T,
     D] tokens; ``picks`` [T, k] int32, each pair's expert among the
     ``held`` this program holds (``held`` itself for a pair that goes to
@@ -401,19 +425,24 @@ def experts_top1(bp, y: jnp.ndarray, r_prev: jnp.ndarray, live: jnp.ndarray,
 
 
 def router_sigmoid(bp, y: jnp.ndarray, live: jnp.ndarray, k: int,
-                   scale: float):
-    """The DeepSeek-V3 style router without its group limit or bias on
-    tokens ``y`` [T, D]: ``s = sigmoid(y Wr)`` over all ``E`` routed
-    experts; the picks are the ``k`` largest ``s`` (the lower index on a
-    tie); their weights ``s[picks] / (sum s[picks] + 1e-20) * scale``.  In
-    float32 at full matmul precision, as :func:`router_top1`.  Returns
-    ``(picks [T, k] int32, weights [T, k] f32)``; a token that is not
-    ``live`` gets expert ``E`` ``k`` times."""
+                   scale: float, bias=None):
+    """The DeepSeek-V3 style router without its group limit on tokens
+    ``y`` [T, D]: ``s = sigmoid(y Wr)`` over all ``E`` routed experts; the
+    picks are the ``k`` largest ``s`` (the lower index on a tie), or of
+    ``s + bias`` where a selection ``bias`` [E] is given, which steers the
+    picks and not their weights; the weights are ``s[picks] / (sum
+    s[picks] + 1e-20) * scale``.  In float32 at full matmul precision, as
+    :func:`router_top1`.  Returns ``(picks [T, k] int32, weights [T, k]
+    f32)``; a token that is not ``live`` gets expert ``E`` ``k`` times."""
     s = jax.nn.sigmoid(jnp.matmul(
         y.astype(jnp.float32), bp["router"].astype(jnp.float32),
         precision=jax.lax.Precision.HIGHEST,
     ))
-    top, picks = jax.lax.top_k(s, k)
+    if bias is None:
+        top, picks = jax.lax.top_k(s, k)
+    else:
+        _, picks = jax.lax.top_k(s + bias.astype(jnp.float32), k)
+        top = jnp.take_along_axis(s, picks, axis=-1)
     w = top / (jnp.sum(top, axis=-1, keepdims=True) + 1e-20) * scale
     return jnp.where(live[:, None], picks.astype(jnp.int32), s.shape[-1]), w
 
@@ -441,8 +470,10 @@ def experts_topk(bp, y: jnp.ndarray, live: jnp.ndarray, cfg, experts, layer):
     yt = y.reshape(T, D)
     held, index = cfg.experts_held, cfg.block.experts_share[0]
     with jax.named_scope("moe/router"):
+        bias = (bp["expert_bias"],) if cfg.block.selection_bias else ()
         picks, w = router_sigmoid(
-            bp, yt, live.reshape(T), cfg.moe_top_k, cfg.block.routed_scale
+            bp, yt, live.reshape(T), cfg.moe_top_k, cfg.block.routed_scale,
+            *bias,
         )
         local = picks - index * held
         local = jnp.where((local >= 0) & (local < held), local, held)

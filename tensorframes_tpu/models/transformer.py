@@ -221,6 +221,25 @@ class BlockSpec:
     # pool then holds pages AND a state a slot
     mixer: Optional[SSMSpec] = None
     multipliers: Multipliers = Multipliers()
+    # the attention of each layer, by index: "full" or "window" (empty:
+    # every layer full).  A window layer's query at t sees the keys in
+    # (t - window, t], and its pages are a ring in a pool of its own
+    # (``kv_pager``); a full layer's see every key up to t.  Among window
+    # layers a full layer takes no rotation (NoPE); window layers are rotated
+    layer_types: Tuple[str, ...] = ()
+    window: int = 0
+    # per-head RMSNorm of q and k before rotation (gains ``q_norm``,
+    # ``k_norm`` [Dh]), on a "gqa" block
+    qk_norm: bool = False
+    # attention's output times sigmoid(RMSNorm(x) W_g), elementwise, before
+    # W_o (``w_attn_gate`` [D, h * Dh])
+    attn_gate: bool = False
+    # RMSNorm on each sublayer's output before it joins the residual
+    # (``ln_post_attn``, ``ln_post_mlp``)
+    sandwich: bool = False
+    # a per-expert bias (``expert_bias`` [E]) added to the sigmoid scores
+    # of an "experts_topk" router: it steers the picks, not their weights
+    selection_bias: bool = False
 
     def __post_init__(self):
         if self.attention not in ("gqa", "cca", "mla", "retention"):
@@ -239,6 +258,20 @@ class BlockSpec:
         index, of = self.experts_share
         if not 0 <= index < of:
             raise ValueError(f"experts_share {self.experts_share}: (index, of)")
+        if set(self.layer_types) - {"full", "window"}:
+            raise ValueError(f"layer_types {self.layer_types}: 'full' or 'window'")
+        if ("window" in self.layer_types) != (self.window > 0):
+            raise ValueError("window layers and a window size go together")
+        if self.layer_types and not self.window:
+            raise ValueError("a pattern of layer_types holds window layers")
+        if (self.layer_types or self.qk_norm) and (
+            self.attention != "gqa" or self.mixer is not None
+        ):
+            raise ValueError(
+                "layer_types and qk_norm are of a 'gqa' block without a mixer"
+            )
+        if self.selection_bias and self.ffn != "experts_topk":
+            raise ValueError("a selection bias steers an 'experts_topk' router")
 
     @property
     def stateless(self) -> bool:
@@ -247,7 +280,14 @@ class BlockSpec:
         return (
             self.attention == "gqa" and self.ffn == "swiglu"
             and self.mixer is None and self.multipliers == Multipliers()
+            and not (self.layer_types or self.qk_norm or self.attn_gate
+                     or self.sandwich)
         )
+
+    def kind_of(self, layer: int) -> Optional[str]:
+        """Layer ``layer``'s attention, "full" or "window"; None where the
+        spec states no pattern."""
+        return self.layer_types[layer] if self.layer_types else None
 
     @property
     def routes(self) -> bool:
@@ -339,6 +379,8 @@ class TransformerConfig:
             )
         if not 0 <= self.block.dense_layers <= self.n_layers:
             raise ValueError("block.dense_layers must lie within n_layers")
+        if self.block.layer_types and len(self.block.layer_types) != self.n_layers:
+            raise ValueError("block.layer_types names every layer")
         if self.n_heads % self.n_kv_heads:
             raise ValueError("n_heads must be divisible by n_kv_heads")
         if self.moe_experts and self.moe_top_k > self.moe_experts:
@@ -640,13 +682,15 @@ def _block(
     return x, aux
 
 
-def _attn_qkv(bp, x, positions, cfg):
+def _attn_qkv(bp, x, positions, cfg, rotate=True):
     """The projection half of attention shared by every cache layout:
-    rms_norm -> q/k/v projections -> RoPE -> layout shards.  Returns
-    ``(q [B, L, h, Dh], k [B, L, kvh, Dh], v [B, L, kvh, Dh])``.  Split
-    out (round 22) so the paged KV cache (``models/kv_pager.py``) runs
-    the EXACT ops of the contiguous path — bit-identity between the two
-    cache layouts is by construction, not by parallel maintenance."""
+    rms_norm -> q/k/v projections -> (per-head q/k RMSNorm) -> RoPE ->
+    layout shards.  Returns ``(q [B, L, h, Dh], k [B, L, kvh, Dh], v [B,
+    L, kvh, Dh])``.  Split out (round 22) so the paged KV cache
+    (``models/kv_pager.py``) runs the EXACT ops of the contiguous path —
+    bit-identity between the two cache layouts is by construction, not by
+    parallel maintenance.  ``rotate`` False (static) leaves q and k
+    unrotated: a full layer of a stack with ``layer_types``."""
     B, L, D = x.shape
     h, kvh, dh = cfg.n_heads, cfg.n_kv_heads, cfg.head_dim
     dt = cfg.dtype
@@ -656,16 +700,25 @@ def _attn_qkv(bp, x, positions, cfg):
     q = linear(y, bp["wq"], dt).reshape(B, L, h, dh)
     k = times(linear(y, bp["wk"], dt).reshape(B, L, kvh, dh), mult.key)
     v = linear(y, bp["wv"], dt).reshape(B, L, kvh, dh)
-    q = _saved(
-        shard(_rope(q, positions, cfg.rope_theta, cfg.block.rotary_share),
-              ("dp", "ep"), "sp", "tp", None)
-    )
-    k = _saved(
-        shard(_rope(k, positions, cfg.rope_theta, cfg.block.rotary_share),
-              ("dp", "ep"), "sp", "tp", None)
-    )
+    if cfg.block.qk_norm:
+        q = _rms_norm(q, bp["q_norm"], cfg.block.norm_eps)
+        k = _rms_norm(k, bp["k_norm"], cfg.block.norm_eps)
+    if rotate:
+        q = _rope(q, positions, cfg.rope_theta, cfg.block.rotary_share)
+        k = _rope(k, positions, cfg.rope_theta, cfg.block.rotary_share)
+    q = _saved(shard(q, ("dp", "ep"), "sp", "tp", None))
+    k = _saved(shard(k, ("dp", "ep"), "sp", "tp", None))
     v = _saved(shard(v, ("dp", "ep"), "sp", "tp", None))
     return q, k, v
+
+
+def attn_gate(bp, x, cfg):
+    """The output gate of a spec with ``attn_gate``: ``sigmoid(RMSNorm(x)
+    W_g)`` [B, L, h * Dh] in the compute dtype, from the block's input
+    ``x`` normed as :func:`_attn_qkv` norms it (the same operations, which
+    XLA computes once)."""
+    y = _rms_norm(x, bp["ln1"], cfg.block.norm_eps)
+    return jax.nn.sigmoid(linear(y, bp["w_attn_gate"], cfg.dtype))
 
 
 @jax.named_scope("mlp")
@@ -683,10 +736,13 @@ def _mlp_residual(bp, x, cfg, segments=None):
         x = x + ff_out
     else:
         mult = cfg.block.multipliers
-        x = x + swiglu(
+        ff_out = swiglu(
             y, bp["w_gate"], bp["w_up"], bp["w_down"], cfg.dtype,
             (mult.mlp_gate, mult.mlp_down),
         )
+        if cfg.block.sandwich:
+            ff_out = _rms_norm(ff_out, bp["ln_post_mlp"], cfg.block.norm_eps)
+        x = x + ff_out
         aux = jnp.zeros((), jnp.float32)
     return x, aux
 
@@ -756,14 +812,17 @@ def _attn_residual(bp, x, positions, cfg, kv=None, segments=None):
     return x, ((ck, cv) if kv is not None else None)
 
 
-def _cache_attention(q, ck, cv, positions_q):
+def _cache_attention(q, ck, cv, positions_q, window=0, k_positions=None):
     """Attention over a KV cache with GROUPED kv heads: q [B, L, h, Dh],
     ck/cv [B, S, kvh, Dh].  The h/kvh query groups index the shared kv
     head directly — the cache is never materialised h-wide (decode reads
     scale with n_kv_heads, the point of GQA).  Numerics mirror
     ``full_attention`` (f32 softmax, f32-accumulated matmuls); unwritten
     cache slots are hidden by the causal mask (their arange positions
-    exceed every query position)."""
+    exceed every query position).  ``window`` > 0 (static) also hides the
+    keys at or before ``t - window`` from a query at ``t``;
+    ``k_positions`` [B, S] gives each row's keys their positions where
+    they are not ``arange(S)`` (a window layer's ring of pages)."""
     B, L, h, dh = q.shape
     S, kvh = ck.shape[1], ck.shape[2]
     g = h // kvh
@@ -773,7 +832,14 @@ def _cache_attention(q, ck, cv, positions_q):
         "blkgd,bskd->bkgls", qg, ck, preferred_element_type=jnp.float32
     ) * scale
     k_pos = jnp.arange(S, dtype=jnp.int32)
-    mask = positions_q[:, None, None, :, None] >= k_pos[None, None, None, None, :]
+    q_pos = positions_q[:, None, None, :, None]
+    if k_positions is None:
+        k_pos = k_pos[None, None, None, None, :]
+    else:
+        k_pos = k_positions[:, None, None, None, :]
+    mask = q_pos >= k_pos
+    if window:
+        mask = mask & (q_pos - k_pos < window)
     s = jnp.where(mask, s, -jnp.inf)
     p = jax.nn.softmax(s, axis=-1).astype(q.dtype)
     att = jnp.einsum(

@@ -288,6 +288,13 @@ _counters: Dict[str, int] = {
     # Pallas kernel ``tfs_ssm_step`` (``kv_pager.ssm_kernel_fits``, asked
     # once a scheduler: all of its steps or none)
     "decode_ssm_kernel_steps": 0,
+    # a stack of window layers among full ones: the keys its window
+    # layers read, summed over live rows and steps (the host's, at
+    # ``decode.step.emit``, beside ``decode_tokens_held``: a row holds at
+    # most the window), and the steps whose window layers attend through
+    # the paged-attention kernel over their ring (asked once a scheduler)
+    "decode_window_tokens_held": 0,
+    "decode_window_kernel_steps": 0,
     # of them, those whose params held every q, k and v projection turned
     # to the layout the step's dots read in place (``transformer.OutIn``,
     # ``kv_pager.serving_params``; decided once a scheduler)

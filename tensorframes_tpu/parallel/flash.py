@@ -130,13 +130,17 @@ def _flash_kernel(
     block_q: int,
     block_k: int,
     seq_k: int,
+    window: int = 0,
 ):
     # padded QUERY rows are never masked here: their garbage outputs are
     # sliced off by the [:Lq] in _flash_fwd_impl, so only keys need seq_k
     qi = pl.program_id(1)
-    ki = pl.program_id(2)
+    j = pl.program_id(2)
+    # with a window the key axis of the grid counts from the q block's
+    # first key block (``_window_first``), not from 0
+    ki = j if not window else _window_first(qi, block_q, block_k, window) + j
 
-    @pl.when(ki == 0)
+    @pl.when(j == 0)
     def _init():
         m_scr[:] = jnp.full_like(m_scr, _NEG_INF)
         l_scr[:] = jnp.zeros_like(l_scr)
@@ -169,6 +173,8 @@ def _flash_kernel(
         mask = k_idx < seq_k  # padded keys contribute nothing
         if causal:
             mask &= q_idx >= k_idx
+        if window:
+            mask &= q_idx - k_idx < window
         s_masked = jnp.where(mask, s, _NEG_INF)
 
         m_prev = m_scr[:]  # [block_q, 1]
@@ -188,14 +194,23 @@ def _flash_kernel(
         l_scr[:] = l_new
         acc_scr[:] = acc
 
-    @pl.when(ki == pl.num_programs(2) - 1)
+    @pl.when(j == pl.num_programs(2) - 1)
     def _finish():
         l_fin = l_scr[:]
         denom = jnp.where(l_fin == 0.0, 1.0, l_fin)
         o_ref[0] = (acc_scr[:] / denom).astype(o_ref.dtype)
         # logsumexp per row — the backward's softmax residual (all-masked
         # rows keep -inf; the backward masks them out explicitly)
-        lse_ref[0] = m_scr[:] + jnp.log(denom)
+        if lse_ref is not None:
+            lse_ref[0] = m_scr[:] + jnp.log(denom)
+
+
+def _flash_forward_kernel(q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr,
+                          **kw):
+    """:func:`_flash_kernel` with no logsumexp output, for a forward nothing
+    differentiates: a ``[rows, 1]`` float32 output is laid out a lane tile
+    wide, 128 times its size (384 MiB at 48 heads of 16,384 queries)."""
+    _flash_kernel(q_ref, k_ref, v_ref, o_ref, None, m_scr, l_scr, acc_scr, **kw)
 
 
 def _pad_to(x, length, axis):
@@ -235,19 +250,31 @@ def _kv_head_map(H: int, KVH: int):
     return lambda b: (b // H) * KVH + (b % H) // g
 
 
-def _flash_fwd_impl(q, k, v, causal, block_q, block_k, interpret):
+def _flash_fwd_impl(q, k, v, causal, block_q, block_k, interpret, window=0,
+                    name=None):
     """Returns ``(out [B, Lq, H, Dh], lse [B, H, Lq_p])``.  k/v may be
-    GQA-grouped [B, Lk, KVH, Dh] with H % KVH == 0."""
+    GQA-grouped [B, Lk, KVH, Dh] with H % KVH == 0.  A ``name``d kernel (the
+    serving prefill's) computes no ``lse`` and returns ``out`` alone."""
     local = functools.partial(
         _flash_fwd_local, causal=causal, block_q=block_q, block_k=block_k,
-        interpret=_resolve_interpret(interpret),
+        interpret=_resolve_interpret(interpret), window=window, name=name,
     )
     return _per_shard(
-        local, "qkk", "qs", q.shape[0], q.shape[2], k.shape[2]
+        local, "qkk", "qs" if name is None else "q", q.shape[0], q.shape[2],
+        k.shape[2],
     )(q, k, v)
 
 
-def _flash_fwd_local(q, k, v, *, causal, block_q, block_k, interpret):
+def _window_first(qi, block_q: int, block_k: int, window: int):
+    """The first key block a causal window of ``window`` keys reaches from
+    q block ``qi``: the block of the key ``qi * block_q - window + 1``."""
+    return jax.lax.div(
+        jnp.maximum(qi * block_q - (window - 1), 0), jnp.int32(block_k)
+    )
+
+
+def _flash_fwd_local(q, k, v, *, causal, block_q, block_k, interpret,
+                     window=0, name=None):
     B, Lq, H, Dh = q.shape
     Lk, KVH = k.shape[1], k.shape[2]
     kv_of = _kv_head_map(H, KVH)
@@ -256,40 +283,62 @@ def _flash_fwd_local(q, k, v, *, causal, block_q, block_k, interpret):
 
     qb, kb, vb = _to_bh(q, Lq_p), _to_bh(k, Lk_p), _to_bh(v, Lk_p)
     grid = (B * H, Lq_p // bq, Lk_p // bk)
+    kv_block = lambda b, i, j: (kv_of(b), j, 0)  # noqa: E731
+    if window:
+        # a q block's keys are the ``bq + window - 1`` before its last
+        # query: the grid walks the blocks they touch, from the first
+        # (``_window_first``), and no other.  A step past the q block's
+        # last key block maps to that block again, which the pipeline then
+        # does not copy a second time, and skips its products
+        nk = Lk_p // bk
+        grid = grid[:2] + (min(nk, -(-(bq + window - 1) // bk) + 1),)
 
-    out, lse = pl.pallas_call(
+        def kv_block(b, i, j):
+            last = jnp.minimum(((i + 1) * bq - 1) // bk, nk - 1)
+            return (kv_of(b), jnp.minimum(_window_first(i, bq, bk, window) + j, last), 0)
+
+    out_specs = [
+        pl.BlockSpec((1, bq, Dh), lambda b, i, j: (b, i, 0)),
+        pl.BlockSpec((1, bq, 1), lambda b, i, j: (b, i, 0)),
+    ]
+    out_shape = [
+        jax.ShapeDtypeStruct((B * H, Lq_p, Dh), q.dtype),
+        jax.ShapeDtypeStruct((B * H, Lq_p, 1), jnp.float32),
+    ]
+    kernel = _flash_kernel
+    if name is not None:  # a forward alone: no logsumexp
+        out_specs, out_shape, kernel = out_specs[:1], out_shape[:1], _flash_forward_kernel
+    outs = pl.pallas_call(
         functools.partial(
-            _flash_kernel,
+            kernel,
             scale=scale,
             causal=causal,
             block_q=bq,
             block_k=bk,
             seq_k=Lk,
+            window=window,
         ),
         grid=grid,
         in_specs=[
             pl.BlockSpec((1, bq, Dh), lambda b, i, j: (b, i, 0)),
-            pl.BlockSpec((1, bk, Dh), lambda b, i, j: (kv_of(b), j, 0)),
-            pl.BlockSpec((1, bk, Dh), lambda b, i, j: (kv_of(b), j, 0)),
+            pl.BlockSpec((1, bk, Dh), kv_block),
+            pl.BlockSpec((1, bk, Dh), kv_block),
         ],
-        out_specs=[
-            pl.BlockSpec((1, bq, Dh), lambda b, i, j: (b, i, 0)),
-            pl.BlockSpec((1, bq, 1), lambda b, i, j: (b, i, 0)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((B * H, Lq_p, Dh), q.dtype),
-            jax.ShapeDtypeStruct((B * H, Lq_p, 1), jnp.float32),
-        ],
+        out_specs=out_specs,
+        out_shape=out_shape,
         scratch_shapes=[
             pltpu.VMEM((bq, 1), jnp.float32),   # running row max
             pltpu.VMEM((bq, 1), jnp.float32),   # running denominator
             pltpu.VMEM((bq, Dh), jnp.float32),  # f32 output accumulator
         ],
         interpret=interpret,
+        **({} if name is None else {"name": name}),
     )(qb, kb, vb)
 
-    out = jnp.swapaxes(out[:, :Lq].reshape(B, H, Lq, Dh), 1, 2)
-    return out, lse.reshape(B, H, Lq_p)
+    out = jnp.swapaxes(outs[0][:, :Lq].reshape(B, H, Lq, Dh), 1, 2)
+    if name is not None:
+        return out
+    return out, outs[1].reshape(B, H, Lq_p)
 
 
 # ---------------------------------------------------------------------------
@@ -695,6 +744,25 @@ def flash_attention(
     """
     out, _ = _flash_fwd_impl(q, k, v, causal, block_q, block_k, interpret)
     return out
+
+
+# the trace reduction finds the serving prefill's kernel by this name
+PREFILL_KERNEL_NAME = "tfs_flash_prefill"
+
+
+def flash_prefill(q, k, v, window=0, block=512, interpret=None):
+    """The forward of causal :func:`flash_attention` alone, for a serving
+    prefill that starts its sequence: q [1, L, H, Dh], k/v [1, L, KVH, Dh]
+    at row-major positions.  ``window`` > 0 (static) is a sliding window: a
+    query at t sees the keys in (t - window, t], and the grid walks only the
+    key blocks a q block's window touches, so the work grows with ``L *
+    window`` and not ``L^2``.  Blocks of ``block`` queries and keys; the
+    kernel is named ``PREFILL_KERNEL_NAME``."""
+    if window < 0:
+        raise ValueError(f"window {window}: a number of keys, or 0 for none")
+    return _flash_fwd_impl(
+        q, k, v, True, block, block, interpret, window, PREFILL_KERNEL_NAME
+    )
 
 
 def _fwd(q, k, v, causal, block_q, block_k, interpret):

@@ -90,6 +90,7 @@ def _paged_kernel(
     *,
     scale: float,
     max_pages: int,
+    window: int = 0,
 ):
     B, kvh, g, dh = q_ref.shape
     ring, _, ppb, P, _ = k_buf.shape
@@ -100,7 +101,20 @@ def _paged_kernel(
     zero, ring_ = jnp.int32(0), jnp.int32(ring)
     layer = layer_ref[0]
 
+    def first_page(b):
+        """The row's first page: that of its window's first key."""
+        return jax.lax.div(
+            jnp.maximum(lengths_ref[b] - window, 0), jnp.int32(P)
+        )
+
+    def last_page(b):
+        return jax.lax.div(lengths_ref[b] - 1, jnp.int32(P))
+
     def n_blocks(b):
+        if window:
+            return jax.lax.div(
+                last_page(b) - first_page(b) + ppb, jnp.int32(ppb)
+            )
         return jax.lax.div(lengths_ref[b] + (T - 1), jnp.int32(T))
 
     def fetch(b, i, slot):
@@ -111,11 +125,23 @@ def _paged_kernel(
         (masked like everything past the frontier); past the last row
         the walk stays on it, copies the kernel's end drains — always
         issued, so they schedule beside the products, not behind a
-        branch."""
-        pages = [
-            tables_ref[b * max_pages + jnp.minimum(i * ppb + j, max_pages - 1)]
-            for j in range(ppb)
-        ]
+        branch.  Under a window the row's pages are a ring: the block
+        counts from the window's first page, page ``p`` lies in table
+        slot ``p % max_pages``, and the walk past the row's last page
+        repeats that page."""
+        if window:
+            first, last = first_page(b), last_page(b)
+            pages = [
+                tables_ref[b * max_pages + jax.lax.rem(
+                    jnp.minimum(first + i * ppb + j, last), jnp.int32(max_pages)
+                )]
+                for j in range(ppb)
+            ]
+        else:
+            pages = [
+                tables_ref[b * max_pages + jnp.minimum(i * ppb + j, max_pages - 1)]
+                for j in range(ppb)
+            ]
         for x, (hbm, buf) in enumerate(kv):
             for j, page in enumerate(pages):
                 pltpu.make_async_copy(
@@ -140,6 +166,8 @@ def _paged_kernel(
     def row(b, carry):
         length = lengths_ref[b]
         q = q_ref[b]  # [kvh, g, dh]
+        if window:  # the walk starts at the window's first page
+            start = first_page(b) * P
 
         def block(i, carry):
             m, l, acc, n, fb, fi = carry
@@ -153,7 +181,11 @@ def _paged_kernel(
                 "kgd,ktd->kgt", q, k, preferred_element_type=jnp.float32
             ) * np.float32(scale)
             pos = i * T + jax.lax.broadcasted_iota(jnp.int32, s.shape, 2)
-            s = jnp.where(pos < length, s, _NEG_INF)
+            if window:
+                pos = start + pos
+                s = jnp.where((pos < length) & (pos >= length - window), s, _NEG_INF)
+            else:
+                s = jnp.where(pos < length, s, _NEG_INF)
             # every block walked holds a key under the frontier (length
             # >= 1), so m_new is finite and exp(-inf - m_new) is 0
             m_new = jnp.maximum(m, s.max(axis=-1, keepdims=True))
@@ -201,6 +233,7 @@ def paged_attention(
     lengths,
     layer,
     interpret: Optional[bool] = None,
+    window: int = 0,
 ):
     """softmax(q K^T / sqrt(d)) V of one query position a row over the
     keys the row holds in its pages of ``layer``.
@@ -215,7 +248,13 @@ def paged_attention(
     other.  Returns [B, h, Dh] in ``q.dtype``.  Keys past a row's
     frontier — the tail of its last page, pages it never reserved — get
     exact zero weight; they must be finite, as zero times them is
-    added."""
+    added.
+
+    ``window`` > 0 (static): the row attends only to its last ``window``
+    keys, positions ``length - window .. length - 1``, and its table is a
+    ring: logical page ``p`` of the sequence lies in table slot ``p %
+    max_pages``.  The walk starts at the window's first page; keys of that
+    page before the window get exact zero weight."""
     B, h, dh = q.shape
     _, kvh, _, P, _ = k_pages.shape
     g = h // kvh
@@ -229,6 +268,7 @@ def paged_attention(
             _paged_kernel,
             scale=1.0 / np.sqrt(dh),
             max_pages=max_pages,
+            window=window,
         ),
         in_specs=[smem, smem, smem, vmem, hbm, hbm],
         out_specs=vmem,

@@ -613,3 +613,51 @@ def test_flash_gqa_rejects_indivisible_heads():
     k = jnp.zeros((1, 16, 3, 8), jnp.float32)
     with pytest.raises(ValueError, match="divisible"):
         flash_attention(q, k, k, True)
+
+
+# ---------------------------------------------------------------------------
+# the serving prefill's forward, causal with or without a sliding window
+# ---------------------------------------------------------------------------
+
+
+def _windowed_reference(q, k, v, window):
+    """softmax over the keys in (t - window, t] of each query t (0: all
+    keys up to t), float32, GQA by repetition."""
+    g = q.shape[2] // k.shape[2]
+    k, v = jnp.repeat(k, g, axis=2), jnp.repeat(v, g, axis=2)
+    L = q.shape[1]
+    t = jnp.arange(L)
+    mask = t[:, None] >= t[None, :]
+    if window:
+        mask &= t[:, None] - t[None, :] < window
+    s = jnp.einsum("blhd,bshd->bhls", q, k) / np.sqrt(q.shape[-1])
+    p = jax.nn.softmax(jnp.where(mask, s, -jnp.inf), axis=-1)
+    return jnp.einsum("bhls,bshd->blhd", p, v)
+
+
+@pytest.mark.parametrize("window", [0, 1, 7, 40, 64, 150])
+@pytest.mark.parametrize("L", [64, 100])
+def test_prefill_window_matches_masked_reference(window, L):
+    """Blocks of 16 keys: windows of a key, shorter than a block, over
+    several, and wider than the chunk; a length that pads the last block."""
+    from tensorframes_tpu.parallel.flash import flash_prefill
+
+    q, k, v = _qkv(1, L, 4, 16, jnp.float32, seed=window)
+    k, v = k[:, :, :2], v[:, :, :2]
+    got = flash_prefill(q, k, v, window, block=16)
+    np.testing.assert_allclose(got, _windowed_reference(q, k, v, window), atol=1e-5)
+
+
+def test_prefill_without_a_window_is_the_causal_forward_bit_for_bit():
+    """No window is ``flash_attention``'s causal forward, and so is a window
+    at least as long as the chunk (the grid then walks every causal block in
+    the same order)."""
+    from tensorframes_tpu.parallel.flash import flash_prefill
+
+    q, k, v = _qkv(1, 96, 4, 16, jnp.float32, seed=3)
+    k, v = k[:, :, :2], v[:, :, :2]
+    causal = flash_attention(q, k, v, True, 32, 32)
+    np.testing.assert_array_equal(flash_prefill(q, k, v, 0, block=32), causal)
+    np.testing.assert_array_equal(flash_prefill(q, k, v, 96, block=32), causal)
+    with pytest.raises(ValueError):
+        flash_prefill(q, k, v, -1)

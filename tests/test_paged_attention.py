@@ -336,3 +336,89 @@ def test_serving_imports_pallas_without_its_gpu_interpreter():
         timeout=300,
     )
     assert done.returncode == 0, done.stderr[-2000:]
+
+
+# ---------------------------------------------------------------------------
+# a window over a ring of pages (a window layer of a stack whose attention
+# differs by layer): the table is the slot's ring, logical page p in slot
+# p % ring, and the row attends to its last ``window`` keys
+# ---------------------------------------------------------------------------
+
+RING_WINDOW = 40  # keys: two and a half pages of 16
+RING = kv_pager.ring_pages(tfm.TransformerConfig(block=tfm.BlockSpec(
+    layer_types=("window",), window=RING_WINDOW), n_layers=1), P)
+
+
+def _ring_problem(kvh, dtype, lengths, seed=3, layer=1):
+    """Stacked pools whose rows hold rings of ``RING`` pages; ``lengths``
+    the rows' keys, far past the window for most.  Poison in other
+    layers, as in :func:`_problem`."""
+    rng = np.random.default_rng(seed)
+    B = len(lengths)
+    n_pages = B * RING + 1
+    kp, vp = (
+        jnp.full((LAYERS, kvh, n_pages, P, DH), sign * POISON, dtype)
+        .at[layer].set(
+            jnp.asarray(rng.standard_normal((kvh, n_pages, P, DH)), dtype)
+        )
+        for sign in (1, -1)
+    )
+    q = jnp.asarray(rng.standard_normal((B, kvh * G, DH)), dtype)
+    tables = rng.permutation(np.arange(1, n_pages)).reshape(B, RING)
+    return q, kp, vp, jnp.asarray(tables.astype(np.int32)), jnp.asarray(
+        np.asarray(lengths, np.int32)
+    )
+
+
+@pytest.mark.parametrize("dtype", ["bfloat16", "float32"])
+def test_window_kernel_reads_the_ring_under_the_window(dtype):
+    """Rows short of the window, at it, and several rings deep: the kernel
+    against ``_cache_attention`` over the ring's gathered keys at their
+    sequence positions under the window's mask (the gather path of a window
+    layer), and against nothing of the keys before the window."""
+    lengths = [1, 30, RING_WINDOW, 41, 130, 1000, 16 * RING]
+    q, kp, vp, tables, lens = _ring_problem(2, jnp.dtype(dtype), lengths)
+    got = jax.jit(pa.paged_attention, static_argnames="window")(
+        q, kp, vp, tables, lens, 1, window=RING_WINDOW
+    )
+    B = len(lengths)
+    ck, cv = (
+        jnp.moveaxis(x[1][:, tables], 0, 3).reshape(B, -1, 2, DH) for x in (kp, vp)
+    )
+    pos = (lens - 1)[:, None]
+    k_pos = kv_pager._ring_positions(pos, P, RING)
+    want = tfm._cache_attention(q[:, None], ck, cv, pos, RING_WINDOW, k_pos)[:, 0]
+    np.testing.assert_allclose(
+        np.asarray(got, np.float32), np.asarray(want, np.float32),
+        rtol=TOL[dtype], atol=TOL[dtype],
+    )
+    # what the window left behind is not read: poison the ring's slots that
+    # hold no key of the row's window, and nothing moves
+    poisoned = np.asarray(kp).copy()
+    for b, n in enumerate(lengths):
+        keep = {(t // P) % RING for t in range(max(0, n - RING_WINDOW), n)}
+        for s in set(range(RING)) - keep:
+            poisoned[1, :, int(tables[b, s])] = POISON
+    again = jax.jit(pa.paged_attention, static_argnames="window")(
+        q, jnp.asarray(poisoned), vp, tables, lens, 1, window=RING_WINDOW
+    )
+    np.testing.assert_array_equal(np.asarray(again), np.asarray(got))
+
+
+@pytest.mark.parametrize("dtype", ["bfloat16", "float32"])
+def test_call_without_a_window_is_the_kernel_it_was(dtype):
+    """No window is the call without one, program text for program text
+    (``tests/test_paged_compile.py`` pins the serving steps' text, kernel
+    and all, to the tree before windows), and a window as long as the
+    capacity over the same table reads the same pages in the same blocks,
+    bit for bit."""
+    q, kp, vp, tables, lengths = _problem(8, jnp.dtype(dtype), CASES["mixed_lengths"])
+    kernel = jax.jit(pa.paged_attention, static_argnames="window")
+    plain = kernel.lower(q, kp, vp, tables, lengths, 1).as_text()
+    assert plain == kernel.lower(q, kp, vp, tables, lengths, 1, window=0).as_text()
+    assert plain != kernel.lower(q, kp, vp, tables, lengths, 1, window=CAP).as_text()
+    got = _kernel(q, kp, vp, tables, lengths, 1)
+    wide = jax.jit(pa.paged_attention, static_argnames="window")(
+        q, kp, vp, tables, lengths, 1, window=CAP
+    )
+    np.testing.assert_array_equal(np.asarray(wide), np.asarray(got))

@@ -764,3 +764,175 @@ def test_projections_held_in_by_out_are_copied_every_layer(
     cfg, compiled = _served("mistral_step", one_chip, turned=False)
     moved = _projection_moves(compiled.as_text(), cfg)
     assert len(moved) == 3, moved
+
+
+# ---------------------------------------------------------------------------
+# window layers among full ones (Trinity, PR 43): the earlier blocks' serving
+# executables lower as they did, and Trinity's compile and fit
+# ---------------------------------------------------------------------------
+
+# sha256 of each earlier block's serving executables as they lowered before
+# the layer pattern, the window, the QK-norm, the gate, the sandwich norms and
+# the selection bias came (at the benchmark's widths, the prefill at a 256
+# bucket, x64 off), each Mosaic kernel's serialized body read back without its
+# debug locations, which name the kernel's source lines
+_LOWERED_BEFORE = {
+    "mistral_7b_l8.step": "3a6157aef366b3b6c6b37e60203687ae71db0b09e25c63dbc367ab6f9c28a594",
+    "mistral_7b_l8.prefill": "4b80168b62fd66ed0af1b11c1ca3d006c0c216eaf4522088b89198af0380963c",
+    "zaya1_8b_l20.step": "92c5de685849258b46c865759f32bd36970497f7257f1a9762ae2d2248ac9d16",
+    "zaya1_8b_l20.prefill": "b49c43fe17252cb75f98712e1d4049863f37031656e38b9683b92af72c389566",
+    "axk1_l7_ep16.step": "60adcd4369d8c0be8db1df64257d30372fe3e41e7facd8e7075dd683972d6528",
+    "axk1_l7_ep16.prefill": "b3c7b07ca3b80b5de308e98b90293617f8119cb872aac60ec1b867f540af2ef7",
+    "brumby_14b_l8.step": "b08e671b512705bc83a8c54901c7370f696f7b49346f4bd3ecd605b48e7945fb",
+    "brumby_14b_l8.prefill": "eb3928fc0d5bd81e53aa9505c1f13ec8d62d785251fa7825ddd1984460584180",
+    "falcon_h1_34b_l4.step": "cf1f0e92ab7567a8abe976e2a7c57134bafe467e0bf38a83cb322b848843cd99",
+    "falcon_h1_34b_l4.prefill": "3066bbd31568be798312c0ac7f171f89a6a9f95243c31bd5ce9bf9c54844524b",
+}
+_KERNEL_BODY = re.compile(r'(\\22body\\22: \\22)([A-Za-z0-9+/=]+)(\\22)')
+
+
+def _without_locations(text):
+    """The lowered text, each Mosaic kernel's body replaced by the hash of
+    its program without debug locations."""
+    import base64
+    import hashlib
+
+    from jax._src.lib.mlir import ir
+
+    ctx = ir.Context()
+    ctx.allow_unregistered_dialects = True
+
+    def body(m):
+        with ctx:
+            module = ir.Module.parse(base64.b64decode(m.group(2)))
+        asm = module.operation.get_asm(enable_debug_info=False)
+        return m.group(1) + hashlib.sha256(asm.encode()).hexdigest() + m.group(3)
+
+    return _KERNEL_BODY.sub(body, text)
+
+
+def _lowered(case, sharding):
+    """One serving executable of an earlier block, lowered as the cell's
+    scheduler calls it."""
+    config, which = case.rsplit(".", 1)
+    i32 = lambda *shape: jax.ShapeDtypeStruct(  # noqa: E731
+        shape, jnp.int32, sharding=sharding
+    )
+    if config in ("mistral_7b_l8", "zaya1_8b_l20", "axk1_l7_ep16"):
+        cfg, (w, toks, tables, idx, kp, vp), kw = _cell(config, sharding)
+        if which == "step":
+            return kv_pager.paged_decode_step.lower(w, toks, tables, idx, kp, vp, cfg, **kw)
+        kw = {**kw, "slot": i32(1)} if kw else kw
+        return kv_pager.paged_prefill.lower(
+            w, i32(1, 256), i32(1, tables.shape[1]), i32(1), kp, vp, cfg, **kw
+        )
+    if config == "brumby_14b_l8":
+        cfg, w, state, slots = _retention_cell(sharding)
+        if which == "step":
+            return kv_pager.paged_decode_step.lower(
+                w, i32(slots), i32(slots, 1), i32(slots), None, None, cfg, retention=state
+            )
+        return kv_pager.paged_prefill.lower(
+            w, i32(1, 256), None, i32(1), None, None, cfg, slot=i32(1),
+            retention=state, start=i32(1),
+        )
+    cfg, w, (kp, vp), state, serve = _hybrid_cell(sharding)
+    slots, width = serve["max_slots"], serve["max_pages"]
+    if which == "step":
+        return kv_pager.paged_decode_step.lower(
+            w, i32(slots), i32(slots, width), i32(slots), kp, vp, cfg, retention=state
+        )
+    return kv_pager.paged_prefill.lower(
+        w, i32(1, 256), i32(1, width), i32(1), kp, vp, cfg, slot=i32(1), retention=state
+    )
+
+
+@pytest.mark.parametrize("case", sorted(_LOWERED_BEFORE))
+def test_earlier_blocks_lower_as_they_did(case, one_chip, compiled_not_interpreted):
+    """The dense, CCA, latent, retention and hybrid blocks' decode step and
+    prefill, program text for program text: nothing Trinity's stack brought
+    traces an operation into them."""
+    import hashlib
+
+    text = _without_locations(_lowered(case, one_chip).as_text())
+    assert hashlib.sha256(text.encode()).hexdigest() == _LOWERED_BEFORE[case]
+
+
+def _trinity_cell(sharding):
+    """``(cfg, weights, (kp, vp), serve, width)`` of
+    ``trinity_large_l5_ep8.decode_longmix`` as shapes on the described chip:
+    the pools are the pairs of the full and the window layers'."""
+    from perfbench.drivers import bridge_decode_trinity
+    from perfbench.refs import trinity_decoder
+
+    m = _load("configs", "trinity_large_l5_ep8")
+    serve = _load("traffic", "decode_longmix")["serve"]
+    dtype = jnp.dtype(m["dtype"])
+    cfg = bridge_decode_trinity.transformer_config(m, serve["max_seq"], dtype)
+    P = serve["tokens_per_page"]
+
+    def on_chip(tree):
+        return jax.tree.map(
+            lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=sharding),
+            tree,
+        )
+
+    pools = jax.eval_shape(lambda: kv_pager.PagePool(
+        cfg, serve["pool_pages"], tokens_per_page=P, slots=serve["max_slots"]
+    ).k_pages)
+    weights = on_chip(jax.eval_shape(lambda: kv_pager.serving_params(
+        trinity_decoder.make_weights(0, m, dtype), cfg
+    )))
+    width = kv_pager.pages_for(serve["max_seq"], P) + kv_pager.ring_pages(cfg, P)
+    return cfg, weights, (on_chip(pools), on_chip(pools)), serve, width
+
+
+def test_trinity_step_runs_the_kernel_over_both_pools(one_chip, compiled_not_interpreted):
+    """The 48-slot step: the paged kernel for the full layer and over the
+    rings for the window layers, both pools aliased to their arguments, and
+    nothing of a pool's size copied."""
+    cfg, w, (kp, vp), serve, width = _trinity_cell(one_chip)
+    slots = serve["max_slots"]
+    i32 = lambda *shape: jax.ShapeDtypeStruct(  # noqa: E731
+        shape, jnp.int32, sharding=one_chip
+    )
+    compiled = kv_pager.paged_decode_step.lower(
+        w, i32(slots), i32(slots, width), i32(slots), kp, vp, cfg
+    ).compile()
+    text = compiled.as_text()
+    assert text.count(pa.KERNEL_NAME) >= 2  # the full layer's run and the window layers'
+    for pool in kp:
+        _pool_keeps_its_layout(text, pool.shape)
+    mem = compiled.memory_analysis()
+    held = sum(a.dtype.itemsize * math.prod(a.shape) for a in (*kp, *vp))
+    assert mem.alias_size_in_bytes >= held
+    assert mem.temp_size_in_bytes < 2**28
+
+
+def test_trinity_longest_prefill_fits_beside_weights_and_pools(
+    one_chip, compiled_not_interpreted
+):
+    """The 16,384 bucket's prefill: flash attention by kind, the grouped
+    expert products a run of tokens at a time, and all of it within what
+    the weights and both pools leave of the chip."""
+    from tensorframes_tpu.parallel import flash
+
+    cfg, w, (kp, vp), serve, width = _trinity_cell(one_chip)
+    i32 = lambda *shape: jax.ShapeDtypeStruct(  # noqa: E731
+        shape, jnp.int32, sharding=one_chip
+    )
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setattr(flash, "_resolve_interpret", lambda interpret: False)
+        compiled = kv_pager.paged_prefill.lower(
+            w, i32(1, 16384), i32(1, width), i32(1), kp, vp, cfg, slot=i32(1)
+        ).compile()
+    assert flash.PREFILL_KERNEL_NAME in compiled.as_text()
+    mem = compiled.memory_analysis()
+    held = sum(a.dtype.itemsize * math.prod(a.shape) for a in (*kp, *vp))
+    assert mem.alias_size_in_bytes >= held
+    # the weights' q, k, v and gate are also held as they were made, beside
+    # the turned ones the executables read: 0.44 GB more on the chip
+    assert (
+        mem.argument_size_in_bytes + mem.output_size_in_bytes
+        + mem.temp_size_in_bytes - mem.alias_size_in_bytes + 0.44e9 < 15.5 * 2**30
+    )

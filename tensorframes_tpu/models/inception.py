@@ -19,6 +19,12 @@ path) every one of those casts is the identity.  Nothing strongly typed
 (a NumPy scalar, a float32 array) may meet an activation: jax would
 promote the whole network to float32 (``tests/test_inception_dtype.py``).
 
+The A, C and E blocks' pool branch (average pool, then 1x1 convolution)
+runs its convolution first, on the block's input, and pools the narrow
+result (``_pool_branch``; the exported GraphDef keeps the published
+order).  It rounds twice, as pooling first did: the raw accumulator once,
+then the bias and ReLU on the pool's float32 mean.
+
 Architecture follows the standard Inception-v3 (googlenet v3) layout:
 stem convs -> 3x InceptionA -> B -> 4x InceptionC -> D -> 2x InceptionE ->
 global average pool -> logits.  BatchNorm is folded to inference form
@@ -58,11 +64,9 @@ def _conv_init(key, kh, kw, cin, cout, dtype):
     }
 
 
-def _conv(p, x, stride=1, padding="SAME"):
-    """conv -> (folded) BN -> ReLU in ``x``'s type.  The MXU accumulates in
-    float32 and the epilogue runs on that accumulator; the result is rounded
-    ONCE, to the type the activation is stored in."""
-    acc = jax.lax.conv_general_dilated(
+def _conv_acc(p, x, stride=1, padding="SAME"):
+    """The convolution alone: its float32 accumulator, no bias, no ReLU."""
+    return jax.lax.conv_general_dilated(
         x,
         p["w"].astype(x.dtype),
         window_strides=(stride, stride),
@@ -70,11 +74,22 @@ def _conv(p, x, stride=1, padding="SAME"):
         dimension_numbers=("NHWC", "HWIO", "NHWC"),
         preferred_element_type=jnp.float32,
     )
+
+
+def _epilogue(p, acc, dtype):
+    """(folded) BN -> ReLU on a float32 accumulator, rounded once to ``dtype``."""
     if "scale" in p:  # unfolded inference BN: y * scale + shift
         acc = acc * p["scale"].astype(acc.dtype) + p["shift"].astype(acc.dtype)
     else:  # folded: bias only
         acc = acc + p["b"].astype(acc.dtype)
-    return jax.nn.relu(acc).astype(x.dtype)
+    return jax.nn.relu(acc).astype(dtype)
+
+
+def _conv(p, x, stride=1, padding="SAME"):
+    """conv -> (folded) BN -> ReLU in ``x``'s type.  The MXU accumulates in
+    float32 and the epilogue runs on that accumulator; the result is rounded
+    ONCE, to the type the activation is stored in."""
+    return _epilogue(p, _conv_acc(p, x, stride, padding), x.dtype)
 
 
 def fold_bn(params: Params) -> Params:
@@ -120,6 +135,8 @@ def _avg_counts_1d(n: int, size: int, stride: int) -> np.ndarray:
 
 
 def _pool(x, kind, size=3, stride=1, padding="SAME"):
+    """A max pool in ``x``'s type; an average pool as its float32 mean, for
+    the caller to finish and round."""
     if kind == "max":
         return jax.lax.reduce_window(
             x,
@@ -139,12 +156,12 @@ def _pool(x, kind, size=3, stride=1, padding="SAME"):
         padding,
     )
     if padding == "VALID":
-        return (s / (size * size)).astype(x.dtype)
+        return s / (size * size)
     h, w = x.shape[1], x.shape[2]
     counts = np.outer(
         _avg_counts_1d(h, size, stride), _avg_counts_1d(w, size, stride)
     )[None, :, :, None]
-    return (s / counts).astype(x.dtype)
+    return s / counts
 
 
 # branch spec: list of (kernel_h, kernel_w, cout, stride, padding)
@@ -163,6 +180,19 @@ def _branch_apply(ps, x, spec: BranchSpec):
     for p, (_, _, _, stride, padding) in zip(ps, spec):
         x = _conv(p, x, stride, padding)
     return x
+
+
+def _pool_branch(ps, x):
+    """The A, C and E blocks' pool branch: a 3x3 SAME average pool, then a
+    1x1 convolution, computed convolution first.  The pool weighs each
+    neighbour by 1 / count(position), the same in every channel, so it
+    commutes with the 1x1's per-position channel mix: the pool then reduces
+    the branch's 32-192 channels, not the block input's 192-2,048.  The raw
+    convolution is stored in ``x``'s type, and the bias and ReLU run on the
+    pool's float32 mean: two roundings, as pooling first had."""
+    (p,) = ps
+    raw = _conv_acc(p, x).astype(x.dtype)
+    return _epilogue(p, _pool(raw, "avg", 3, 1, "SAME"), x.dtype)
 
 
 # ---------------------------------------------------------------------------
@@ -249,12 +279,13 @@ def _block_init(key, variant, cin, dtype, pool_ch=0, c7=0):
 def _block_apply(params, x, variant, pool_ch=0, c7=0):
     cin = x.shape[-1]
     specs = _block_specs(variant, cin, pool_ch, c7)
+    # the pool branch of A, C and E convolves x first and pools the branch's
+    # narrow result (_pool_branch); B and D max-pool x itself
     if variant in ("A", "C"):
         outs = []
         for name in [k for k in specs if k != "pool"]:
             outs.append(_branch_apply(params[name], x, specs[name]))
-        pooled = _pool(x, "avg", 3, 1, "SAME")
-        outs.append(_branch_apply(params["pool"], pooled, specs["pool"]))
+        outs.append(_pool_branch(params["pool"], x))
         return jnp.concatenate(outs, axis=-1)
     if variant in ("B", "D"):
         outs = [
@@ -280,8 +311,7 @@ def _block_apply(params, x, variant, pool_ch=0, c7=0):
         ],
         axis=-1,
     )
-    pooled = _pool(x, "avg", 3, 1, "SAME")
-    b4 = _branch_apply(params["pool"], pooled, specs["pool"])
+    b4 = _pool_branch(params["pool"], x)
     return jnp.concatenate([b1, b2, b3, b4], axis=-1)
 
 

@@ -149,3 +149,86 @@ def test_bf16_program_against_the_float32_reference(seed):
     best_two = np.sort(exact[2], axis=-1)[:, -2:]
     decided = best_two[:, 1] - best_two[:, 0] > limit
     assert np.array_equal(got["prediction"][decided], exact[0][decided])
+
+
+# one block of each kind with an average-pool branch, at its place in the
+# network: (variant, kwargs, grid, channels in)
+_POOLED_BLOCKS = {
+    "mixed0_A": ("A", {"pool_ch": 32}, 35, 192),
+    "mixed4_C": ("C", {"c7": 128}, 17, 768),
+    "mixed9_E": ("E", {}, 8, 1280),
+}
+
+
+def _pool_first(ps, x):
+    """The pool branch in the published graph's order: the 3x3 SAME average
+    pool of the block input (padding left out of the mean, the counts
+    summed here from ones), rounded to ``x``'s type, then the 1x1
+    convolution."""
+    win = ((1, 3, 3, 1), (1, 1, 1, 1), "SAME")
+    s = jax.lax.reduce_window(x.astype(F32), 0.0, jax.lax.add, *win)
+    ones = jnp.ones((1, *x.shape[1:3], 1), F32)
+    n = jax.lax.reduce_window(ones, 0.0, jax.lax.add, *win)
+    return inception._conv(ps[0], (s / n).astype(x.dtype))
+
+
+def _pooled_block(block, form, seed=7):
+    """Float32 params of one block, batch norm drawn so that the
+    pre-activations take both signs (folded to a bias for ``"bias"``), and
+    an input of two rows of N(0, 1)."""
+    variant, kw, grid, cin = _POOLED_BLOCKS[block]
+    rng = np.random.RandomState(seed)
+    params = inception._block_init(rng, variant, cin, np.float32, **kw)
+    for p in (p for branch in params.values() for p in branch):
+        p["scale"] = rng.uniform(0.5, 1.5, p["scale"].shape).astype(np.float32)
+        p["shift"] = rng.normal(0.0, 0.5, p["shift"].shape).astype(np.float32)
+    if form == "bias":
+        params = inception.fold_bn({"stem": [], "blocks": [params]})["blocks"][0]
+    x = rng.normal(size=(2, grid, grid, cin)).astype(np.float32)
+    return params, x
+
+
+def _rms(a):
+    return float(np.sqrt(np.mean(np.square(a))))
+
+
+@pytest.mark.parametrize("dtype", [F32, BF16], ids=["float32", "bfloat16"])
+@pytest.mark.parametrize("form", ["bias", "scale_shift"])
+@pytest.mark.parametrize("block", list(_POOLED_BLOCKS))
+def test_pool_branch_convolves_then_pools(block, form, dtype, monkeypatch):
+    """The average pool commutes with the 1x1 convolution (its weight,
+    1 / count(position), is the same in every channel), so the branch may
+    pool the convolution's narrow output.  In float32 the whole block
+    equals the pool-first order; in bf16 the branch rounds twice, as the
+    pool-first order does, and not three times."""
+    variant, kw, _, _ = _POOLED_BLOCKS[block]
+    params, x = _pooled_block(block, form)
+    assert (np.asarray(_pool_first(params["pool"], jnp.asarray(x))) == 0).any()
+    if dtype == F32:
+        got = np.asarray(inception._block_apply(params, x, variant, **kw))
+        monkeypatch.setattr(inception, "_pool_branch", _pool_first)
+        want = np.asarray(inception._block_apply(params, x, variant, **kw))
+        np.testing.assert_allclose(
+            got, want, rtol=1e-5, atol=1e-6 * np.abs(want).max()
+        )
+        return
+    ps = [jax.tree.map(lambda a: jnp.asarray(a, BF16), p) for p in params["pool"]]
+    xb = jnp.asarray(x, BF16)
+    out = jax.eval_shape(inception._pool_branch, ps, xb)
+    casts = [
+        eqn for eqn, _ in _walk(jax.make_jaxpr(inception._pool_branch)(ps, xb).jaxpr)
+        if eqn.primitive.name == "convert_element_type"
+        and eqn.outvars[0].aval.dtype == BF16
+        and eqn.outvars[0].aval.shape == out.shape
+    ]
+    assert len(casts) == 2, casts  # the raw convolution, the branch's output
+    # each order's distance from the float32 branch on the same bf16 values:
+    # a third rounding (the pool's mean stored) reads 1.15-1.23 x pool-first
+    exact = np.asarray(inception._pool_branch(
+        [jax.tree.map(lambda a: a.astype(F32), p) for p in ps], xb.astype(F32)
+    ))
+    gap = {
+        name: _rms(np.asarray(f(ps, xb), np.float32) - exact) / _rms(exact)
+        for name, f in (("new", inception._pool_branch), ("old", _pool_first))
+    }
+    assert gap["new"] < 1.08 * gap["old"], gap

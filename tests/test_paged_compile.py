@@ -365,6 +365,26 @@ def _entry_f32_results(text):
             yield 4 * math.prod(int(d) for d in dims.split(","))
 
 
+_REDUCE_WINDOW = re.compile(
+    r"= \w+\[([0-9,]+)\]\S* reduce-window\(.*?to_apply=%?([\w.\-]+)"
+)
+
+
+def _window_sum_channels(text):
+    """The minor (channel) dimension of every ``reduce-window`` whose
+    reduction adds: the average pools, wherever a fusion took them."""
+    bodies, name = {}, None
+    for line in text.splitlines():
+        if line and not line[0].isspace() and line.rstrip().endswith("{"):
+            name = line.split()[1 if line.startswith("ENTRY") else 0].lstrip("%")
+            bodies[name] = ""
+        elif name is not None:
+            bodies[name] += line + "\n"
+    for m in _REDUCE_WINDOW.finditer(text):
+        if " add(" in bodies[m.group(2)]:
+            yield int(m.group(1).split(",")[-1])
+
+
 def test_scoring_executable_stores_no_float32_activation(
     one_chip, compiled_not_interpreted
 ):
@@ -392,9 +412,15 @@ def test_scoring_executable_stores_no_float32_activation(
     compiled = jax.jit(
         lambda w, x: inception.scoring_program(w, dtype=dtype, **kwargs)(x)
     ).lower(weights, image).compile()
-    large = [b for b in _entry_f32_results(compiled.as_text()) if b >= 100e6]
+    text = compiled.as_text()
+    large = [b for b in _entry_f32_results(text) if b >= 100e6]
     assert sum(large) < 1e9, (len(large), sum(large))
     assert compiled.memory_analysis().temp_size_in_bytes < 5e9
+    # the nine average pools reduce their branch's 1x1 convolution (32, 64
+    # or 192 channels), not the block input pooling first would read
+    # (192-2,048 channels: 8.2 GB a block moved where 1.8 do)
+    pooled = sorted(_window_sum_channels(text))
+    assert pooled == [32, 64, 64] + [192] * 6, pooled
 
 
 # ---------------------------------------------------------------------------

@@ -1329,6 +1329,13 @@ class DecodeScheduler:
         )
         # and whether its window layers do (``decode_window_kernel_steps``)
         self._window_kernel_step = self._kernel_step if self.ring else 0
+        # and whether a latent block attends through the kernel's latent
+        # form (``decode_latent_kernel_steps``)
+        self._latent_kernel_step = int(
+            kv_pager.latent_kernel_fits(
+                cfg, P, self.max_slots, 1, self.pool.dtype
+            )
+        )
         # and whether it steps a mixer's state through ``tfs_ssm_step``
         # (``decode_ssm_kernel_steps``)
         self._ssm_kernel_step = int(
@@ -1850,6 +1857,7 @@ class DecodeScheduler:
                             # all-zero tables
                 tally["decode_steps"] += 1
                 tally["decode_kernel_steps"] += self._kernel_step
+                tally["decode_latent_kernel_steps"] += self._latent_kernel_step
                 tally["decode_ssm_kernel_steps"] += self._ssm_kernel_step
                 tally["decode_proj_in_place_steps"] += self._proj_in_place
                 tally["decode_tokens"] += n_tok

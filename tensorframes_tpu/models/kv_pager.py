@@ -55,10 +55,12 @@ serving removed (PAPERS.md).  This module is the paged layout:
   row_width]``, a token's normed latent and rotated key part as one row.
   It travels where the K pool does (``v_pages`` is None), is written by
   the same :func:`_page_write`, donated and carried by the scan alike; a
-  decode step gathers the table's pages as rows and attends over them in
-  the latent space, a prefill attends over its own rows expanded by head
-  (:func:`_latent_attention`).  The kernel does not read such a pool
-  (:func:`paged_kernel_fits` refuses the block);
+  one-token step whose shapes fit (:func:`latent_kernel_fits`) attends over
+  the rows each slot holds, in place and in the latent space, through the
+  kernel's latent form (``tfs_latent_attention``: one copy a page, the values the
+  keys' first columns); any other decode chunk gathers the table's pages
+  as rows, and a prefill attends over its own rows expanded by head
+  (:func:`_latent_attention`);
 * a retention block (``BlockSpec(attention="retention")``,
   ``models/retention.py``) has NO pages: a sequence's whole past is a
   float32 state of fixed size a layer (34 MB at heads of 128), held per
@@ -642,8 +644,8 @@ def paged_kernel_fits(cfg, P: int, B: int, L: int, dtype) -> bool:
     axis left to partition over, which a Mosaic kernel cannot be
     (``flash._per_shard``; the scheduler runs on one device).  It reads a
     K and a V pool by head: a latent block's one pool, whose values are
-    its keys' first part, it cannot.  Whatever it refuses takes the
-    gather path."""
+    its keys' first part, is :func:`latent_kernel_fits`'s.  Whatever it
+    refuses takes the gather path."""
     dtype = jnp.dtype(dtype)
     return (
         L == 1
@@ -658,6 +660,31 @@ def paged_kernel_fits(cfg, P: int, B: int, L: int, dtype) -> bool:
     )
 
 
+def latent_kernel_fits(cfg, P: int, B: int, L: int, dtype) -> bool:
+    """Whether an ``mla`` block's chunk attends through the kernel's latent
+    form (``paged_attention.latent_attention``), decided at trace time as
+    :func:`paged_kernel_fits` is: ONE token a row, rows and their value
+    columns (the latent, ``kv_rank``) of whole lane tiles, pages of whole
+    sublane tiles of the pool's dtype, the pool in the compute dtype,
+    ``q`` and the output of ``B`` rows with the ring within VMEM — and no
+    mesh axis left to partition over.  Whatever it refuses takes the
+    gather path (``mla.attend_absorbed``)."""
+    if L != 1 or cfg.block.attention != "mla":
+        return False
+    dtype = jnp.dtype(dtype)
+    width, values = mla.row_width(cfg), cfg.block.latent.kv_rank
+    return (
+        width % 128 == 0
+        and values % 128 == 0
+        and P % (32 // dtype.itemsize) == 0
+        and dtype == jnp.dtype(cfg.dtype)
+        and not _mesh_partitions()
+        and paged_attention.latent_vmem_bytes(
+            B, cfg.n_heads, width, values, P, dtype
+        ) <= paged_attention.VMEM_BUDGET_BYTES
+    )
+
+
 def _latent_attention(bp, x, positions, cfg, pages, tables, layer,
                       from_zero=False):
     """The attention half of an ``mla`` block against ``layer``'s pages of
@@ -665,23 +692,38 @@ def _latent_attention(bp, x, positions, cfg, pages, tables, layer,
     ``(x', pages')``.  The chunk's rows (``mla.project``) are written as
     any K would be.  A chunk that starts its sequence (``from_zero``, a
     prefill) then attends over its own rows expanded by head, as the
-    pages hold them; any other gathers the table's pages into a ``[B,
-    max_pages * P, width]`` view and attends over it in the latent space
-    (``mla.attend_absorbed``), nothing expanded by head."""
+    pages hold them.  A one-token chunk that fits
+    (:func:`latent_kernel_fits`) attends in the latent space through the
+    kernel, which reads each row's pages in place up to its frontier;
+    any other gathers the table's pages into a ``[B, max_pages * P,
+    width]`` view and attends over it in the latent space
+    (``mla.attend_absorbed``).  Nothing is expanded by head either way."""
     dt = cfg.dtype
     q_n, q_r, row = mla.project(bp, x, positions, cfg)
     with jax.named_scope("latent_write"):
         pages, _ = _page_write(
             pages, None, row, None, positions, tables, layer, from_zero
         )
+    B, L = x.shape[:2]
+    P = pages.shape[3]
     if from_zero:
         rows = row[:, :, 0].astype(pages.dtype).astype(dt)
         att = mla.attend_expanded(bp, q_n, q_r, rows, positions, cfg)
+    elif latent_kernel_fits(cfg, P, B, L, pages.dtype):
+        q = mla.absorb(bp, q_n, q_r, pages.shape[-1], cfg)
+        with jax.named_scope("latent_kernel"):
+            # an idle row (its table all trash) reads the trash page, as
+            # on the gather path
+            lengths = jnp.clip(positions[:, 0] + 1, 1, tables.shape[1] * P)
+            o_lat = paged_attention.latent_attention(
+                q[:, 0], pages, tables, lengths, layer,
+                cfg.block.latent.kv_rank, mla.softmax_scale(cfg),
+            )
+        att = mla.up(bp, o_lat[:, None], cfg)
     else:
         with jax.named_scope("page_gather"):
-            B, max_pages = tables.shape
             rows = pages[layer, 0, tables].reshape(
-                B, max_pages * pages.shape[3], -1
+                B, tables.shape[1] * P, -1
             ).astype(dt)
         att = mla.attend_absorbed(bp, q_n, q_r, rows, positions, cfg)
     return _attn_out(bp, x, att, cfg), pages
@@ -878,7 +920,7 @@ def _paged_block(bp, x, positions, cfg, kp, vp, tables, layer, st=None,
     # scope names are metadata: a profiler session groups the device
     # operations of a step under attention / page_write / paged_kernel
     # (or page_gather, on the general path; window_write / window_kernel
-    # for a window layer)
+    # for a window layer; latent_write / latent_kernel for a latent block)
     with jax.named_scope("attention"):
         if cfg.block.attention == "mla":
             x, kp = _latent_attention(bp, x, positions, cfg, kp, tables, layer)

@@ -26,7 +26,11 @@ round:
   ``o_lat(j) = sum_s p c(s)`` over the row's first ``kv_rank`` values, and
   only then ``out(j) = o_lat(j) W_kvb^V(j)``.  Nothing of the cache's
   extent is ever expanded by head: the step reads 576 values a token held
-  and multiplies them with all 64 heads' queries at once.
+  and multiplies them with all 64 heads' queries at once.  On the serving
+  path a step whose shapes fit does the middle part — scores, softmax and
+  the weighted sum — in the Pallas kernel ``tfs_latent_attention``
+  (``parallel/paged_attention.py``), reading each row's pages where they
+  lie, between :func:`absorb` and :func:`up`.
 
 The same mathematics, summed in another order; both forms read the rows
 rounded to the page dtype, as the pages hold them.  The scores' scale is
@@ -146,19 +150,34 @@ def attend_expanded(bp, q_n, q_r, rows, positions, cfg):
     return att.reshape(B, L, cfg.n_heads * lat.v_dim)
 
 
+def absorb(bp, q_n, q_r, width, cfg):
+    """``W_kvb``'s key half moved to the query's side: ``[q_lat ; q_r ;
+    0]`` ``[B, L, H, width]``, scored against a cached row of ``width``
+    values as it lies."""
+    w_k, _ = _up_weights(bp, cfg)
+    with jax.named_scope("mla_absorb"):
+        q_lat = jnp.einsum("blhn,chn->blhc", q_n, w_k)
+        pad = jnp.zeros(q_r.shape[:-1] + (width - page_width(cfg),), q_r.dtype)
+        return jnp.concatenate([q_lat, q_r, pad], -1)
+
+
+def up(bp, o_lat, cfg):
+    """``W_kvb``'s value half on the way out: the heads' weighted sums of
+    latents ``o_lat`` [B, L, H, kv_rank] to ``[B, L, H * v]``."""
+    B, L = o_lat.shape[:2]
+    _, w_v = _up_weights(bp, cfg)
+    with jax.named_scope("mla_up"):
+        att = jnp.einsum("blhc,chv->blhv", o_lat, w_v)
+    return att.reshape(B, L, cfg.n_heads * cfg.block.latent.v_dim)
+
+
 def attend_absorbed(bp, q_n, q_r, rows, positions, cfg):
     """Attention over a cache's rows ``[B, S, row_width]`` as they lie,
     ``W_kvb`` absorbed into the query and applied to the output: ``[B, L,
     H * v]``.  Rows past a query's position have exact zero weight."""
-    lat, dt = cfg.block.latent, cfg.dtype
-    B, L = q_n.shape[:2]
-    w_k, w_v = _up_weights(bp, cfg)
+    dt = cfg.dtype
+    q = absorb(bp, q_n, q_r, rows.shape[-1], cfg)
     with jax.named_scope("mla_absorb"):
-        q_lat = jnp.einsum("blhn,chn->blhc", q_n, w_k)
-        pad = jnp.zeros(
-            q_r.shape[:-1] + (rows.shape[-1] - page_width(cfg),), q_r.dtype
-        )
-        q = jnp.concatenate([q_lat, q_r, pad], -1)  # [B, L, H, row_width]
         s = jnp.einsum(
             "blhw,bsw->bhls", q, rows, preferred_element_type=jnp.float32
         ) * np.float32(softmax_scale(cfg))
@@ -167,7 +186,5 @@ def attend_absorbed(bp, q_n, q_r, rows, positions, cfg):
         # slicing the latent out of the cache first would copy it
         o_lat = jnp.einsum(
             "bhls,bsw->blhw", p, rows, preferred_element_type=jnp.float32
-        )[..., : lat.kv_rank].astype(dt)
-    with jax.named_scope("mla_up"):
-        att = jnp.einsum("blhc,chv->blhv", o_lat, w_v)
-    return att.reshape(B, L, cfg.n_heads * lat.v_dim)
+        )[..., : cfg.block.latent.kv_rank].astype(dt)
+    return up(bp, o_lat, cfg)

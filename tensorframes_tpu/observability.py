@@ -295,6 +295,10 @@ _counters: Dict[str, int] = {
     # the paged-attention kernel over their ring (asked once a scheduler)
     "decode_window_tokens_held": 0,
     "decode_window_kernel_steps": 0,
+    # of them, those whose latent block attends through the kernel's
+    # latent form ``tfs_latent_attention`` (``kv_pager.latent_kernel_fits``,
+    # asked once a scheduler: all of its steps or none)
+    "decode_latent_kernel_steps": 0,
     # of them, those whose params held every q, k and v projection turned
     # to the layout the step's dots read in place (``transformer.OutIn``,
     # ``kv_pager.serving_params``; decided once a scheduler)

@@ -31,6 +31,12 @@ f32; the probabilities are rounded to the pool's dtype for the second
 product, as ``transformer._cache_attention`` rounds them: same
 mathematics, another order of summation.
 
+The same walk serves a latent block (``models/mla.py``): ONE pool of
+rows ``[n_layers, 1, n_pages, P, width]`` whose values are the keys'
+first ``values`` columns, so a page is one copy into one ring and the
+value product reads the buffer the scores read (:func:`latent_attention`,
+named ``tfs_latent_attention``).
+
 Off-TPU the kernel runs in Pallas interpret mode (``flash``'s rule and
 its one WARNING).
 """
@@ -48,8 +54,9 @@ from jax.experimental.pallas import tpu as pltpu
 
 from .flash import _NEG_INF, _resolve_interpret
 
-# the trace reduction and the docs find the kernel by this name
+# the trace reduction and the docs find the kernels by these names
 KERNEL_NAME = "tfs_paged_attention"
+LATENT_KERNEL_NAME = "tfs_latent_attention"
 
 # keys per compute block: one lane-width of scores
 _BLOCK_KEYS = 128
@@ -76,26 +83,42 @@ def vmem_bytes(B: int, h: int, kvh: int, dh: int, P: int, dtype) -> int:
     return buffers + 2 * B * kvh * rows * dh * 4
 
 
+def latent_vmem_bytes(B: int, h: int, width: int, values: int, P: int,
+                      dtype) -> int:
+    """VMEM :func:`latent_attention` asks for: ONE ring of row blocks, and
+    ``q`` (``width`` wide) with the output (``values`` wide), both in
+    ``dtype``, the heads padded to its sublane tile."""
+    item = jnp.dtype(dtype).itemsize
+    buffers = _RING * pages_per_block(P) * P * width * item
+    sublanes = 32 // item
+    rows = -(-h // sublanes) * sublanes
+    return buffers + B * rows * (width + values) * item
+
+
 def _paged_kernel(
     layer_ref,
     lengths_ref,
     tables_ref,
     q_ref,
-    k_hbm,
-    v_hbm,
-    o_ref,
-    k_buf,
-    v_buf,
-    sems,
-    *,
+    *refs,
     scale: float,
     max_pages: int,
     window: int = 0,
+    values: int = 0,
 ):
+    """``refs``: the pools, the output, their rings and the semaphores —
+    ``k_hbm, v_hbm, o_ref, k_buf, v_buf, sems``, or with ``values`` > 0
+    (static) one pool whose values are its keys' first ``values`` columns,
+    ``k_hbm, o_ref, k_buf, sems``."""
+    if values:
+        k_hbm, o_ref, k_buf, sems = refs
+        kv = ((k_hbm, k_buf),)
+    else:
+        k_hbm, v_hbm, o_ref, k_buf, v_buf, sems = refs
+        kv = ((k_hbm, k_buf), (v_hbm, v_buf))
     B, kvh, g, dh = q_ref.shape
     ring, _, ppb, P, _ = k_buf.shape
     T = ppb * P
-    kv = ((k_hbm, k_buf), (v_hbm, v_buf))
     # loop bounds and counters are int32 by hand: with x64 on, a Python
     # bound makes an int64 index, which Mosaic has no use for
     zero, ring_ = jnp.int32(0), jnp.int32(ring)
@@ -119,7 +142,8 @@ def _paged_kernel(
 
     def fetch(b, i, slot):
         """Start the page copies of row ``b``'s block ``i`` into buffer
-        ``slot`` — a page of every kv head a copy, K's before V's — and
+        ``slot`` — a page of every kv head a copy, K's before V's (one
+        pool's alone where the values are the keys' columns) — and
         return the block after it: the row's next, or the next row's
         first.  Table slots past the row's width repeat its last page
         (masked like everything past the frontier); past the last row
@@ -192,8 +216,11 @@ def _paged_kernel(
             p = jnp.exp(s - m_new)
             alpha = jnp.exp(m - m_new)
             l = alpha * l + p.sum(axis=-1, keepdims=True)
-            arrived(1, slot)
-            v = v_buf[slot].reshape(kvh, T, dh)
+            if values:  # the buffer the scores read: no second copy
+                v = k[..., :values]
+            else:
+                arrived(1, slot)
+                v = v_buf[slot].reshape(kvh, T, dh)
             acc = acc * alpha + jnp.einsum(
                 "kgt,ktd->kgd", p.astype(v.dtype), v,
                 preferred_element_type=jnp.float32,
@@ -205,7 +232,7 @@ def _paged_kernel(
             (
                 jnp.full((kvh, g, 1), _NEG_INF, jnp.float32),
                 jnp.zeros((kvh, g, 1), jnp.float32),
-                jnp.zeros((kvh, g, dh), jnp.float32),
+                jnp.zeros((kvh, g, values or dh), jnp.float32),
                 *carry,
             ),
         )
@@ -219,8 +246,8 @@ def _paged_kernel(
 
     def drain(d, _):
         slot = jax.lax.rem(n + d, ring_)
-        arrived(0, slot)
-        arrived(1, slot)
+        for x in range(len(kv)):
+            arrived(x, slot)
 
     jax.lax.fori_loop(zero, ring_ - 1, drain, None)
 
@@ -255,33 +282,65 @@ def paged_attention(
     ring: logical page ``p`` of the sequence lies in table slot ``p %
     max_pages``.  The walk starts at the window's first page; keys of that
     page before the window get exact zero weight."""
+    return _walk(
+        q, (k_pages, v_pages), tables, lengths, layer, interpret, KERNEL_NAME,
+        scale=1.0 / np.sqrt(q.shape[2]), window=window,
+    )
+
+
+def latent_attention(
+    q,
+    pages,
+    tables,
+    lengths,
+    layer,
+    values: int,
+    scale: float,
+    interpret: Optional[bool] = None,
+):
+    """softmax(scale q R^T) R[:, :values] of one query position a row over
+    the rows ``R`` it holds in its pages of ``layer``: a latent block's
+    absorbed decode attention (``mla.absorb``), every head against the one
+    pool.
+
+    q: [B, h, width]; pages: the stacked pool [n_layers, 1, n_pages, P,
+    width], read at ``layer`` alone; tables, lengths and ``layer`` as
+    :func:`paged_attention`'s.  ``values`` and ``scale`` are static.
+    Returns [B, h, values] in ``q.dtype``.  A page is one copy and the
+    value product reads the buffer the scores read."""
+    return _walk(
+        q, (pages,), tables, lengths, layer, interpret, LATENT_KERNEL_NAME,
+        scale=scale, values=values,
+    )
+
+
+def _walk(q, pools, tables, lengths, layer, interpret, name, **static):
+    """The one ``pallas_call`` of both kernels: ``q`` [B, h, dh] whole in
+    VMEM as ``kvh`` groups of heads, each of ``pools`` read in HBM through
+    a ring of its own.  Returns [B, h, values or dh]."""
     B, h, dh = q.shape
-    _, kvh, _, P, _ = k_pages.shape
+    _, kvh, _, P, _ = pools[0].shape
     g = h // kvh
-    max_pages = tables.shape[1]
-    buf = pltpu.VMEM((_RING, kvh, pages_per_block(P), P, dh), k_pages.dtype)
+    dv = static.get("values") or dh
+    buf = pltpu.VMEM((_RING, kvh, pages_per_block(P), P, dh), pools[0].dtype)
     smem = pl.BlockSpec(memory_space=pltpu.SMEM)
     vmem = pl.BlockSpec(memory_space=pltpu.VMEM)
     hbm = pl.BlockSpec(memory_space=pl.ANY)
-    out = pl.pallas_call(
+    return pl.pallas_call(
         functools.partial(
-            _paged_kernel,
-            scale=1.0 / np.sqrt(dh),
-            max_pages=max_pages,
-            window=window,
+            _paged_kernel, max_pages=tables.shape[1], **static
         ),
-        in_specs=[smem, smem, smem, vmem, hbm, hbm],
+        in_specs=[smem, smem, smem, vmem] + [hbm] * len(pools),
         out_specs=vmem,
-        out_shape=jax.ShapeDtypeStruct((B, kvh, g, dh), q.dtype),
-        scratch_shapes=[buf, buf, pltpu.SemaphoreType.DMA((2, _RING))],
+        out_shape=jax.ShapeDtypeStruct((B, kvh, g, dv), q.dtype),
+        scratch_shapes=[buf] * len(pools)
+        + [pltpu.SemaphoreType.DMA((len(pools), _RING))],
         interpret=_resolve_interpret(interpret),
-        name=KERNEL_NAME,
+        name=name,
     )(
         jnp.asarray(layer, jnp.int32).reshape(1),
         lengths.astype(jnp.int32),
         tables.astype(jnp.int32).reshape(-1),
         q.reshape(B, kvh, g, dh),
-        k_pages,
-        v_pages,
-    )
-    return out.reshape(B, h, dh)
+        *pools,
+    ).reshape(B, h, dv)

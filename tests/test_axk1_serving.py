@@ -280,9 +280,10 @@ def test_the_shares_add_up_to_the_uncut_layer():
 
 
 def test_paged_kernel_refuses_the_latent_block():
-    """The Pallas kernel reads a K and a V pool by head; the latent block's
-    one pool (keys 576 wide, values the keys' first 512) takes the gather
-    path, at the tiny sizes and at the published ones alike."""
+    """The Pallas kernel's GQA form reads a K and a V pool by head; the
+    latent block's one pool (keys 576 wide, values the keys' first 512) is
+    its latent form's (``latent_kernel_fits``), at the tiny sizes and at
+    the published ones alike."""
     assert not kv_pager.paged_kernel_fits(CFG, 16, 4, 1, jnp.float32)
     published = transformer_config(json.load(open(
         os.path.join(ROOT, "perfbench", "configs", "axk1_l7_ep16.json"))), 3072, jnp.bfloat16)
@@ -494,3 +495,68 @@ def test_counters_ride_the_dispatch(weights):
     assert d["moe_busiest_expert_tokens"] <= d["moe_routed_tokens"]
     assert d["decode_tokens_held"] == 7 + 8 + 9 + 10  # the prompt's 6 and the tokens fed since
     assert d["decode_kernel_steps"] == 0
+    assert d["decode_latent_kernel_steps"] == 0  # a latent of 16 takes the gather path
+
+
+# ---------------------------------------------------------------------------
+# the kernel's latent form on the served path
+# ---------------------------------------------------------------------------
+
+# the tiny file with a latent of one lane tile, which the kernel takes (pages
+# of 8 in float32, rows of 256)
+KM = bench_run.overlay(M, {"kv_lora_rank": 128})
+KCFG = transformer_config(KM, CAP, jnp.float32)
+KPAGE = 8
+
+
+@pytest.fixture()
+def fresh_traces():
+    """The step traced anew before and after: the gate is asked when the
+    step traces, and a jit of a module's function keeps its trace."""
+    jax.clear_caches()
+    yield
+    jax.clear_caches()
+
+
+def test_latent_kernel_serves_the_gather_paths_tokens(fresh_traces, monkeypatch):
+    """Through ``DecodeScheduler`` with two slots and four requests, so that
+    retirements and admissions fall between steps: the tokens served through
+    the kernel are the gather path's (the test steers; the program has no
+    option), and ``decode_latent_kernel_steps`` counts every step."""
+    weights = ref.make_weights(9, KM, jnp.float32)
+    assert kv_pager.latent_kernel_fits(KCFG, KPAGE, 2, 1, jnp.float32)
+    spec = [(5, 7), (11, 4), (3, 9), (9, 6)]
+    prompts = [_tokens(n, 60 + i) for i, (n, _) in enumerate(spec)]
+
+    def serve():
+        out = [None] * len(spec)
+        before = obs.counters()
+        sched = DecodeScheduler(weights, KCFG, max_slots=2, tokens_per_page=KPAGE, max_seq=CAP)
+
+        def run(i):
+            while out[i] is None:
+                try:
+                    out[i] = sched.submit(prompts[i], spec[i][1], timeout_s=120)
+                except DecodeRefused:
+                    time.sleep(0.05)
+
+        try:
+            threads = [threading.Thread(target=run, args=(i,)) for i in range(len(spec))]
+            for t in threads:
+                t.start()
+            for t in threads:
+                t.join()
+        finally:
+            sched.close()
+        return out, obs.counters_delta(before), sched._latent_kernel_step
+
+    kernel, counted, flag = serve()
+    assert flag == 1 and counted["decode_steps"] > 0
+    assert counted["decode_latent_kernel_steps"] == counted["decode_steps"]
+    monkeypatch.setattr(kv_pager, "latent_kernel_fits", lambda *a: False)
+    jax.clear_caches()
+    gather, counted, flag = serve()
+    assert flag == 0 and counted["decode_latent_kernel_steps"] == 0
+    assert [len(o) for o in kernel] == [n for _, n in spec]
+    assert kernel == gather
+    assert len({tuple(o[:4]) for o in kernel}) > 1  # not one stream four times

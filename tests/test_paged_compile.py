@@ -51,7 +51,7 @@ CELLS = {
     "zaya1_8b_l20": "decode_reason",
     "mistral_7b_l8": "decode_chat",
 }
-# the latent block's cell: its step takes the gather path, not the kernel
+# the latent block's cell: its step takes the kernel's latent form
 LATENT = {"axk1_l7_ep16": "decode_grounded"}
 
 
@@ -271,44 +271,110 @@ def test_prefill_writes_whole_pages_and_keeps_the_layout(
 
 
 # ---------------------------------------------------------------------------
-# the latent block (A.X-K1): ONE pool, absorbed decode on the gather path
+# the latent block (A.X-K1): ONE pool, absorbed decode through the kernel's
+# latent form, the gather path where the gate refuses
 # ---------------------------------------------------------------------------
+
+
+def _capacity_gathered(text, slots, max_pages, P, width):
+    """Whether the compiled text holds the table's pages of every slot
+    gathered as rows: the capacity, 252 MB a layer at the cell's shapes."""
+    return any(
+        shape in text for shape in (
+            f"bf16[{slots * max_pages},{P},{width}]",
+            f"bf16[{slots},{max_pages},{P},{width}]",
+            f"bf16[{slots},{max_pages * P},{width}]",
+        )
+    )
+
+
+@pytest.mark.parametrize("config", list(LATENT))
+def test_latent_kernel_compiles_at_real_widths(config, one_chip, compiled_not_interpreted):
+    """64 slots' queries of 64 heads x 640 and their 512-wide output whole in
+    VMEM beside one ring of row blocks (``latent_vmem_bytes``, within the
+    budget), the stacked pool read in HBM at the layer asked for."""
+    cfg, (_, toks, tables, indices, pool, _), _ = _cell(config, one_chip)
+    slots, P, width = toks.shape[0], pool.shape[3], pool.shape[4]
+    assert kv_pager.latent_kernel_fits(cfg, P, slots, 1, pool.dtype)
+    values = cfg.block.latent.kv_rank
+    assert pa.latent_vmem_bytes(
+        slots, cfg.n_heads, width, values, P, pool.dtype
+    ) <= pa.VMEM_BUDGET_BYTES
+    shape = lambda *s, dt=cfg.dtype: jax.ShapeDtypeStruct(  # noqa: E731
+        s, dt, sharding=one_chip
+    )
+    compiled = jax.jit(pa.latent_attention, static_argnums=(5, 6)).lower(
+        shape(slots, cfg.n_heads, width), shape(*pool.shape), tables, indices,
+        shape(dt=jnp.int32), values, mla.softmax_scale(cfg),
+    ).compile()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text and pa.LATENT_KERNEL_NAME in text
+    assert compiled.memory_analysis().temp_size_in_bytes == 0
 
 
 @pytest.mark.parametrize("config", list(LATENT))
 def test_latent_step_attends_in_the_latent_space_and_moves_no_pool(
     config, one_chip, compiled_not_interpreted
 ):
-    """The scheduler's whole step at the cell's 64 slots x 3,072: no Pallas
-    kernel of the repo's (``paged_kernel_fits`` refuses the block), the one
-    pool donated, aliased and left as it lies, the table's pages gathered
-    as rows of ``mla.row_width`` — and nothing of the capacity's extent
-    expanded by head (``[slots, capacity, 64, 192]`` keys would be 4.8 GB a
-    layer)."""
+    """The scheduler's whole step at the cell's 64 slots x 3,072: the
+    kernel's latent form reads each row's pages where they lie
+    (``latent_kernel_fits``; ``paged_kernel_fits`` refuses the block), the
+    one pool donated, aliased and left as it lies — no page of the capacity
+    gathered as rows (``bf16[12288,16,640]``, 252 MB a layer) and nothing of
+    the capacity's extent expanded by head (``[slots, capacity, 64, 192]``
+    keys would be 4.8 GB a layer)."""
     cfg, args, kwargs = _cell(config, one_chip)
     slots, max_pages = args[2].shape
     pool = args[4]
     P, width = pool.shape[3:]
     assert args[5] is None and pool.shape[1] == 1 and width == mla.row_width(cfg) == 640
     assert not kv_pager.paged_kernel_fits(cfg, P, slots, 1, pool.dtype)
+    assert kv_pager.latent_kernel_fits(cfg, P, slots, 1, pool.dtype)
     compiled = kv_pager.paged_decode_step.lower(*args, cfg, **kwargs).compile()
     text = compiled.as_text()
+    assert "tpu_custom_call" in text and pa.LATENT_KERNEL_NAME in text
     assert pa.KERNEL_NAME not in text
     _pool_keeps_its_layout(text, pool.shape)
     _pools_stay_where_they_lie(compiled, pool, pools=1)
+    assert not _capacity_gathered(text, slots, max_pages, P, width)
     cap, lat = max_pages * P, cfg.block.latent
-    assert f"bf16[{slots},{max_pages},{P},{width}]" in text  # the gathered rows
     for per_head in (lat.nope_dim + lat.rope_dim, lat.nope_dim, lat.v_dim,
                      lat.nope_dim + lat.v_dim):
         assert f"[{slots},{cap},{cfg.n_heads},{per_head}]" not in text
         assert f"[{slots},{cfg.n_heads},{cap},{per_head}]" not in text
     mem = compiled.memory_analysis()
-    # scores and weights of 64 heads over the capacity, and the gathered rows
-    assert mem.temp_size_in_bytes < 2**29
+    # no scores over the capacity and no gathered rows: a layer's pool is
+    # 252 MB, the step's temporaries are under a fifth of it
+    assert mem.temp_size_in_bytes < 2**26
     assert (
         mem.argument_size_in_bytes + mem.output_size_in_bytes
         + mem.temp_size_in_bytes - mem.alias_size_in_bytes < 14 * 2**30
     )
+
+
+@pytest.mark.parametrize("config", list(LATENT))
+def test_latent_step_off_the_kernel_gathers_the_capacity(
+    config, one_chip, compiled_not_interpreted, monkeypatch
+):
+    """What the gate refuses runs the gather path as before: the table's
+    pages gathered as rows of ``mla.row_width``, scored over the capacity
+    (the test steers the gate; the program has no option)."""
+    monkeypatch.setattr(kv_pager, "latent_kernel_fits", lambda *a: False)
+    jax.clear_caches()
+    try:
+        cfg, args, kwargs = _cell(config, one_chip)
+        slots, max_pages = args[2].shape
+        pool = args[4]
+        compiled = kv_pager.paged_decode_step.lower(*args, cfg, **kwargs).compile()
+    finally:
+        jax.clear_caches()
+    text = compiled.as_text()
+    assert pa.LATENT_KERNEL_NAME not in text and pa.KERNEL_NAME not in text
+    assert _capacity_gathered(text, slots, max_pages, *pool.shape[3:])
+    _pool_keeps_its_layout(text, pool.shape)
+    _pools_stay_where_they_lie(compiled, pool, pools=1)
+    # scores and weights of 64 heads over the capacity, and the gathered rows
+    assert 2**26 < compiled.memory_analysis().temp_size_in_bytes < 2**29
 
 
 @pytest.mark.parametrize("config", list(LATENT))
@@ -807,7 +873,9 @@ _LOWERED_BEFORE = {
     "mistral_7b_l8.prefill": "4b80168b62fd66ed0af1b11c1ca3d006c0c216eaf4522088b89198af0380963c",
     "zaya1_8b_l20.step": "92c5de685849258b46c865759f32bd36970497f7257f1a9762ae2d2248ac9d16",
     "zaya1_8b_l20.prefill": "b49c43fe17252cb75f98712e1d4049863f37031656e38b9683b92af72c389566",
-    "axk1_l7_ep16.step": "60adcd4369d8c0be8db1df64257d30372fe3e41e7facd8e7075dd683972d6528",
+    # since its decode attention reads each row's pages in place through the
+    # kernel's latent form (``tfs_latent_attention``)
+    "axk1_l7_ep16.step": "5cff429d6e240692d511e4bd40b92ca692e2796d41b124d7fd37ef7531fd88e9",
     "axk1_l7_ep16.prefill": "b3c7b07ca3b80b5de308e98b90293617f8119cb872aac60ec1b867f540af2ef7",
     "brumby_14b_l8.step": "b08e671b512705bc83a8c54901c7370f696f7b49346f4bd3ecd605b48e7945fb",
     "brumby_14b_l8.prefill": "eb3928fc0d5bd81e53aa9505c1f13ec8d62d785251fa7825ddd1984460584180",

@@ -1228,21 +1228,17 @@ class DecodeScheduler:
     attention sums the same terms in another order: equal to rounding,
     and in the suite the same greedy tokens, not the same bits.
 
-    A model whose block keeps a STATE and no pages (``BlockSpec(attention=
-    "retention")``) is admitted by slot alone: a sequence costs one slot's
-    state at token 1 and at token 32,768, so ``submit`` reserves nothing,
-    the slot's state is charged to the budget when the slot is taken, and
-    length never refuses.  Its prompt goes in dispatches of at most
-    ``retention.PREFILL_TOKENS`` tokens, each at its own bucket, the
-    second and later RESUMING from the state the one before left in the
-    slot — what a decode step does with one token.  A block whose pool
-    holds pages AND a state a slot (a state-space mixer beside attention,
-    ``BlockSpec(mixer=...)``) reserves both at ``submit``: its pages and
-    its slot's state are one charge.  A stack of window layers among full
-    ones (``BlockSpec(layer_types=..., window=...)``) reserves its full
-    pages and is charged for them and for what it holds of the ring of
-    window pages its slot owns (``PagePool.ring_of``); a slot's table row
-    is the two tables end to end, the ring's written at admission.
+    The scheduler knows no attention kind: what the model's block keeps
+    and how its executables take it is ``kv_pager``'s.  A pool that holds
+    no pages (``kv_pager.holds_pages``: a retention block, whose state
+    costs a slot the same at any length) is admitted by slot alone:
+    ``submit`` reserves nothing, the slot's state is charged when the slot
+    is taken, and length never refuses; its prompt goes in dispatches of
+    at most ``kv_pager.prefill_tokens``, the later RESUMING from the state
+    the one before left in the slot.  A pool with pages and a state a slot
+    charges both at ``submit``; one with a ring of window pages a slot
+    (``PagePool.ring``) ends each table row with the ring its slot owns
+    (``PagePool.ring_of``), written at admission.
 
     ``speculative`` runs the draft/verify path (B=1 by its contract)
     solo in the caller's thread — an opt-in per-request latency knob,
@@ -1275,7 +1271,6 @@ class DecodeScheduler:
         self._params = kv_pager.serving_params(
             decode_mod.cast_params(params, cfg.dtype), cfg
         )
-        self._proj_in_place = int(kv_pager.projects_in_place(self._params))
         self.draft_params = draft_params
         self.draft_cfg = draft_cfg
         self.max_slots = max(
@@ -1298,9 +1293,6 @@ class DecodeScheduler:
         # path is pinned at exactly this capacity (``cache_len=cap``)
         self.max_pages = 1 if self._by_slot else kv_pager.pages_for(cap, P)
         self.cap = cap if self._by_slot else self.max_pages * P
-        # a window layer's ring of pages, which ends each table row (0:
-        # no window layers)
-        self.ring = kv_pager.ring_pages(cfg, P) if cfg.block.window else 0
         n_pages = (
             int(pool_pages)
             if pool_pages is not None
@@ -1309,38 +1301,23 @@ class DecodeScheduler:
         self.pool = kv_pager.PagePool(
             cfg, n_pages, tokens_per_page=P, slots=self.max_slots
         )
+        # a window layer's ring of pages, which ends each table row (0:
+        # no window layers)
+        self.ring = self.pool.ring
         # the scheduler TAKES the arrays: both executables donate the pools,
         # so they have this one holder from here to ``close`` (the pool
-        # keeps shapes, free list and accounting, and no array).  ``_state``
-        # is a 'cca' block's convolution state, one row a slot (None for
-        # other blocks): an argument and a result of both executables,
-        # like the pages; a prefill overwrites the admitted slot's row
+        # keeps shapes, free list and accounting, and no array); ``_state``
+        # is the block's slot state (None where it keeps none), threaded
+        # through the executables like the pages
         self._kp, self._vp, self._state = self.pool.take()
-        # the state a slot retains (a retention block's, its only pool; a
-        # mixer's, beside the pages): donated like the pages
-        self._ret = self.pool.take_retention()
-        # whether the step executable attends through the paged-attention
-        # kernel: what ``kv_pager._paged_block`` will decide when it traces
-        # this pool's one-token step, asked once (``decode_kernel_steps``)
-        self._kernel_step = 0 if self._by_slot else int(
-            kv_pager.paged_kernel_fits(
-                cfg, P, self.max_slots, 1, self.pool.dtype
-            )
-        )
-        # and whether its window layers do (``decode_window_kernel_steps``)
-        self._window_kernel_step = self._kernel_step if self.ring else 0
-        # and whether a latent block attends through the kernel's latent
-        # form (``decode_latent_kernel_steps``)
-        self._latent_kernel_step = int(
-            kv_pager.latent_kernel_fits(
-                cfg, P, self.max_slots, 1, self.pool.dtype
-            )
-        )
-        # and whether it steps a mixer's state through ``tfs_ssm_step``
-        # (``decode_ssm_kernel_steps``)
-        self._ssm_kernel_step = int(
-            cfg.block.mixer is not None and kv_pager.ssm_kernel_fits(cfg)
-        )
+        # what a step adds to the counters of steps that took a kernel or
+        # read the projections in place, as ``kv_pager`` traces it: 0 or 1
+        self._step_counts = {
+            **kv_pager.kernel_steps(self.pool, self.max_slots),
+            "decode_proj_in_place_steps": int(
+                kv_pager.projects_in_place(self._params)
+            ),
+        }
         # ``routing_trace`` > 0 keeps, for that many retired requests, the
         # expert (a top-k router's k) every fed position chose in every
         # expert layer (``routing_of``): what a reference needs to follow
@@ -1638,41 +1615,25 @@ class DecodeScheduler:
         observability.note_decode_driver(tally)
         tally.clear()
 
+    # the slot state by the name the benchmark's Brumby and Falcon-H1
+    # harnesses read it, and drop it, by
+    _ret = property(lambda s: s._state, lambda s, v: setattr(s, "_state", v))
+
     def _run(self, fn, *args, slot=None, start=None):
-        """Dispatch a serving executable on the current pools (and, for a
-        block that is not the dense one, the convolution state, with the
-        admitted ``slot`` for a prefill, and for a chunk that may resume
-        the position it starts at), keep what it returns of them —
-        the executables donate the pools they are passed, so the arrays
-        held before the call are gone after it — and hand back ``(tokens,
-        stats)``: device arrays, ``stats`` the dispatch's routing counts
-        or None."""
-        if self.cfg.block.stateless:
-            toks, self._kp, self._vp = self._dispatch(
-                fn, self._params, *args, self._kp, self._vp, self.cfg
-            )
-            return toks, None
-        if self._by_slot:
-            at = {} if slot is None else {
-                "slot": np.array([slot], np.int32), "start": start,
-            }
-            toks, self._ret = self._dispatch(
-                functools.partial(fn, retention=self._ret, **at),
-                self._params, *args, None, None, self.cfg,
-            )
-            return toks, None
-        extra = () if slot is None else (np.array([slot], np.int32),)
-        # a state retained beside the pages goes as ``retention``, donated,
-        # and comes back where a 'cca' block's state does
-        held = {} if self._ret is None else {"retention": self._ret}
-        toks, self._kp, self._vp, state, stats = self._dispatch(
-            functools.partial(fn, **held), self._params, *args, self._kp,
-            self._vp, self.cfg, self._state, *extra,
+        """Dispatch a serving executable on the held pools and slot state
+        (a prefill with its ``slot`` and ``start``, as ``kv_pager`` says the
+        block's executables take them), keep what it returns of them — the
+        arrays passed are donated — and hand back ``(tokens, stats)``:
+        device arrays, ``stats`` the dispatch's routing counts or None."""
+        kv = self._kv
+        extra, named = kv.dispatch_args(self.pool, self._state, slot, start)
+        out = self._dispatch(
+            functools.partial(fn, **named), self._params, *args, self._kp,
+            self._vp, self.cfg, *extra,
         )
-        if self._ret is None:
-            self._state = state
-        else:
-            self._ret = state
+        toks, (self._kp, self._vp), self._state, stats = kv.dispatch_results(
+            self.cfg, out
+        )
         return toks, stats
 
     def _fetch(self, toks, routing):
@@ -1782,10 +1743,8 @@ class DecodeScheduler:
                         if self._by_slot and not self._charge_slot(req):
                             continue
                         slot = self._free.pop()
-                        if self.ring:  # the ring of window pages it owns
-                            req.table_row[self.max_pages:] = (
-                                self.pool.ring_of(slot)
-                            )
+                        # the ring of window pages it owns, if any
+                        req.table_row[self.max_pages:] = self.pool.ring_of(slot)
                         inputs.admit(slot, req.table_row)
                         self._active[slot] = req
                         admitted.append((slot, req))
@@ -1802,10 +1761,16 @@ class DecodeScheduler:
                     sp.end(admitted=len(admitted))
                 if not n_active:
                     continue
-                if admitted and not self._prefill(admitted, jnp):
-                    # every admitted stream was one token long and no
-                    # other is active: nothing to step
-                    continue
+                if admitted:
+                    # the disaggregated prefill lane: ONE dispatch per
+                    # admitted request, in admission order
+                    for slot, req in admitted:
+                        self._prefill_one(slot, req, jnp)
+                    with self._cv:
+                        if not self._active:
+                            # every admitted stream was one token long and
+                            # no other is active: nothing to step
+                            continue
                 # decode lane: one fixed-shape step for the population
                 with span(
                     "decode.step", _DECODE_TRACK,
@@ -1836,7 +1801,7 @@ class DecodeScheduler:
                             if self.ring:  # what the window layers read
                                 held_w = int(np.minimum(
                                     inputs.indices[list(self._active)] + 1,
-                                    self.cfg.block.window,
+                                    self.pool.window,
                                 ).sum())
                             for slot, req in list(self._active.items()):
                                 if keep:
@@ -1856,28 +1821,19 @@ class DecodeScheduler:
                             # writes land on the trash page via their
                             # all-zero tables
                 tally["decode_steps"] += 1
-                tally["decode_kernel_steps"] += self._kernel_step
-                tally["decode_latent_kernel_steps"] += self._latent_kernel_step
-                tally["decode_ssm_kernel_steps"] += self._ssm_kernel_step
-                tally["decode_proj_in_place_steps"] += self._proj_in_place
+                for key, n in self._step_counts.items():
+                    tally[key] += n
                 tally["decode_tokens"] += n_tok
                 tally["decode_tokens_held"] += held
                 if self.ring:
                     tally["decode_window_tokens_held"] += held_w
-                    tally["decode_window_kernel_steps"] += (
-                        self._window_kernel_step
-                    )
-                if self._ret is not None:
+                if self.pool.donates_state:
                     tally["decode_state_slots_held"] += n_tok
                 tally["decode_step_wait_ns"] += sp_w.ns
                 self._flush_tally()
         except BaseException as e:  # noqa: BLE001 — fail every waiter
             with self._cv:
-                for req in list(self._active.values()):
-                    self.pool.free(req.charge)
-                    req.error = e
-                    req.done.set()
-                for req in self._pending:
+                for req in (*self._active.values(), *self._pending):
                     self.pool.free(req.charge)
                     req.error = e
                     req.done.set()
@@ -1890,18 +1846,8 @@ class DecodeScheduler:
                 # the old dropped first so that two pairs never stand, and
                 # the step inputs' copy on the device goes with them
                 inputs.reset()
-                self._kp = self._vp = self._state = self._ret = None
+                self._kp = self._vp = self._state = None
                 self._kp, self._vp, self._state = self.pool.zeros()
-                self._ret = self.pool.retention_zeros()
-
-    def _prefill(self, admitted, jnp) -> bool:
-        """The disaggregated prefill lane: ONE dispatch per admitted
-        request, in admission order.  Returns whether any stream is left
-        to step: a one-token stream retires here."""
-        for slot, req in admitted:
-            self._prefill_one(slot, req, jnp)
-        with self._cv:
-            return bool(self._active)
 
     def _first_token(self, slot: int, req: _PagedSeq, tok: int) -> None:
         """A prefill's token is the request's first: the slot's frontier,
@@ -1922,85 +1868,49 @@ class DecodeScheduler:
             self.total_tokens += 1
 
     def _prefill_one(self, slot: int, req: _PagedSeq, jnp) -> None:
-        """One request's prefill (``kv_pager.paged_prefill``: one row,
-        its table row, the head at its last position) at the bucket of
-        its OWN prompt, so executables stay keyed by the bucket alone.
-        No live row is in the dispatch, and the request's first token
-        and its stamps follow it, not the boundary."""
+        """One request's prefill (``kv_pager.paged_prefill``: one row, its
+        table row, the head at its last position), each dispatch at the
+        bucket of its OWN chunk, so executables stay keyed by the bucket
+        alone: the whole prompt, or where the block's state resumes
+        dispatches of at most ``kv_pager.prefill_tokens``, each later one
+        from the state the one before left.  No live row is in a dispatch;
+        the last one's token is the request's first, and only it is waited
+        for, so the request's stamps follow it, not the boundary."""
         tally = self._tally
-        lp = int(req.prompt.size)
-        if self._by_slot:
-            return self._prefill_resuming(slot, req, jnp)
-        lb = min(max(bucketing.bucket_for(lp), 1), self.cap)
-        lb = max(lb, lp)
-        with observability.span(
-            "decode.prefill", _DECODE_TRACK,
-            bucket=lb, admitted=1, slots=str(slot),
-        ) as sp:
-            toks = np.zeros((1, lb), np.int32)
-            toks[0, :lp] = req.prompt
-            tok0, stats = self._run(
-                self._kv.paged_prefill,
-                jnp.asarray(toks),
-                jnp.asarray(req.table_row[None]),
-                jnp.asarray(np.array([lp - 1], np.int32)),
-                slot=slot,
-            )
-            with observability.span("decode.prefill.wait", _DECODE_TRACK):
-                tok0, chosen = self._fetch(tok0, stats)
-                tok = int(tok0[0])
-            if self._routing_keep and chosen is not None:
-                req.routing.append(chosen[:, :lp])
-            self._first_token(slot, req, tok)
-            self.prefill_batches += 1
-            ttft = req.t_first - req.t_submit
-        tally["decode_prefill_ns"] += sp.ns
-        tally["decode_prefill_batches"] += 1
-        tally["decode_prefill_prompt_tokens"] += lp
-        tally["decode_prefill_run_tokens"] += lb
-        tally["decode_admitted"] += 1
-        tally["decode_first_tokens"] += 1
-        tally["decode_ttft_ns"] += ttft
-        tally["decode_tokens"] += 1
-        self._flush_tally()
-
-    def _prefill_resuming(self, slot: int, req: _PagedSeq, jnp) -> None:
-        """:meth:`_prefill_one` for a block whose state resumes: the prompt
-        in dispatches of at most ``retention.PREFILL_TOKENS`` tokens, each
-        padded to its own bucket, the first from zeros and every later one
-        from the state the one before left in the slot.  Only the last
-        one's token is the request's first, and only it is waited for."""
-        from ..models import retention
-
-        tally = self._tally
+        most = self._kv.prefill_tokens(self.cfg)
         lp, fed = int(req.prompt.size), 0
         while fed < lp:
-            n = min(lp - fed, retention.PREFILL_TOKENS)
-            lb = max(min(bucketing.bucket_for(n), retention.PREFILL_TOKENS), n)
+            n = lp - fed if most is None else min(lp - fed, most)
+            lb = max(min(bucketing.bucket_for(n), most or self.cap), n)
+            resumes = {} if most is None else {"start": fed}
             with observability.span(
                 "decode.prefill", _DECODE_TRACK,
-                bucket=lb, admitted=1, slots=str(slot), start=fed,
+                bucket=lb, admitted=1, slots=str(slot), **resumes,
             ) as sp:
                 toks = np.zeros((1, lb), np.int32)
                 toks[0, :n] = req.prompt[fed:fed + n]
-                tok0, _ = self._run(
+                tok0, stats = self._run(
                     self._kv.paged_prefill,
                     jnp.asarray(toks),
-                    None,
+                    None if self._by_slot
+                    else jnp.asarray(req.table_row[None]),
                     jnp.asarray(np.array([n - 1], np.int32)),
                     slot=slot,
-                    start=jnp.asarray(np.array([fed], np.int32)),
+                    start=jnp.asarray(np.array([fed], np.int32))
+                    if resumes else None,
                 )
-                last = fed + n >= lp
-                if last:
+                if fed + n >= lp:
                     with observability.span(
                         "decode.prefill.wait", _DECODE_TRACK
                     ):
-                        tok = int(np.asarray(tok0)[0])
-                    self._first_token(slot, req, tok)
+                        tok0, chosen = self._fetch(tok0, stats)
+                    if self._routing_keep and chosen is not None:
+                        req.routing.append(chosen[:, :n])
+                    self._first_token(slot, req, int(tok0[0]))
             tally["decode_prefill_ns"] += sp.ns
             tally["decode_prefill_batches"] += 1
-            tally["decode_prefill_resumes"] += int(fed > 0)
+            if resumes:
+                tally["decode_prefill_resumes"] += int(fed > 0)
             self.prefill_batches += 1
             tally["decode_prefill_prompt_tokens"] += n
             tally["decode_prefill_run_tokens"] += lb

@@ -50,51 +50,26 @@ serving removed (PAPERS.md).  This module is the paged layout:
   its capacity.  Same mathematics (f32 scores, softmax and accumulation;
   exact zero weight past the frontier), another order of summation: it
   agrees with the gather path to rounding, not bit for bit;
-* a latent block (``BlockSpec(attention="mla")``, ``models/mla.py``) has
-  ONE pool where the others have a pair: ``[n_layers, 1, n_pages, P,
-  row_width]``, a token's normed latent and rotated key part as one row.
-  It travels where the K pool does (``v_pages`` is None), is written by
-  the same :func:`_page_write`, donated and carried by the scan alike; a
-  one-token step whose shapes fit (:func:`latent_kernel_fits`) attends over
-  the rows each slot holds, in place and in the latent space, through the
-  kernel's latent form (``tfs_latent_attention``: one copy a page, the values the
-  keys' first columns); any other decode chunk gathers the table's pages
-  as rows, and a prefill attends over its own rows expanded by head
-  (:func:`_latent_attention`);
-* a retention block (``BlockSpec(attention="retention")``,
-  ``models/retention.py``) has NO pages: a sequence's whole past is a
-  float32 state of fixed size a layer (34 MB at heads of 128), held per
-  decode slot in ``PagePool.retention`` — ``(S [n_layers, slots, kvh, O,
-  dh, dh], z [n_layers, slots, kvh, O, dh])`` — an argument and a result
-  of both executables, donated and carried by the scan like the pools.  A
-  decode step updates and reads a live slot's state in one pass of the
-  kernel ``parallel/retention.py`` (or the ``jnp`` step where it does not
-  fit); a prefill runs the chunked form from the slot's state, or from
-  zeros where the dispatch starts the sequence, and leaves the state of
-  the prompt's last real token.  A "page" of such a pool is one slot's
-  state, all layers: what a sequence costs at any length;
-* a block with a state-space mixer beside its attention
-  (``BlockSpec(mixer=SSMSpec(...))``, ``models/ssm.py``: Falcon-H1's
-  Mamba-2) keeps BOTH: the pages of its attention and, in
-  ``PagePool.retention``, a state a slot — ``(S [n_layers, slots, heads,
-  P, N] float32, tail [n_layers, slots, d_conv - 1, conv_dim])`` — passed
-  to both executables as ``retention`` and donated, as a retention
-  block's is.  A decode step steps a live slot's state in one pass of the
-  kernel ``parallel/ssm.py`` (or ``ssm.step``); a prefill runs SSD's
-  chunked form and overwrites the slot's state and tail whole.  A
-  sequence is charged its pages and its slot's state together;
+* each attention kind a block may have (``BlockSpec.attention``) is ONE
+  part of ``_KINDS``, and a state-space mixer beside attention is one
+  more (:class:`_Mixer`): each owns the shapes of its pages, its state a
+  decode slot, its half of a decode step and of a prefill, and the decode
+  kernel its step takes (:class:`_Part`).  A latent block (``mla``) has
+  ONE pool where the others have a pair; a ``retention`` block has NO
+  pages, a sequence's whole past being a float32 state of fixed size, so
+  a "page" of its pool is one slot's state; a ``cca`` block and a mixer
+  keep a state a slot beside their pages.  :func:`_parts` is the one
+  place the serving path looks a block's kind up, and the executables'
+  convention is this module's alone (:func:`dispatch_args`,
+  :func:`dispatch_results`): the decode scheduler knows no kind;
 * a stack whose attention differs by layer (``BlockSpec(layer_types=...,
   window=...)``: Trinity's window layers among full ones) keeps each
-  kind's pages in a pool of its own, ``k_pages`` and ``v_pages`` each a
-  pair ``(full [n_full, kvh, n_pages, P, Dh], window [n_window, kvh,
-  window_pages, P, Dh])``, a layer indexed by its rank among its kind.
-  A sequence's table row is its full table and then its RING, the
-  :func:`ring_pages` pages a window layer holds of it at any length:
-  logical page ``p`` lies in ring slot ``p % ring``, so a window layer
-  writes over the page that has fallen behind its window, and a prompt
-  longer than the ring writes only what the ring keeps (the earlier pages
-  to the trash page).  The layer scan runs the stack as runs of like
-  layers (:func:`_scan_layers`), a sequence is charged both pools' pages;
+  kind's pages in a pool of its own (:class:`PagePool`), a layer indexed
+  by its rank among its kind (:class:`_Site`).  A sequence's table row is
+  its full table and then its RING, the :func:`ring_pages` pages a window
+  layer holds of it at any length: logical page ``p`` lies in ring slot
+  ``p % ring``, so a window layer writes over the page that has fallen
+  behind its window (:func:`_page_write`);
 * :func:`paged_prefill` is the serving prefill — ONE admitted prompt
   and nothing else.  A prefill starts at position 0, so the only keys
   its queries may see are the chunk's own: it writes them to the pages
@@ -192,23 +167,19 @@ class PagePool:
     the prefill/step executables and keeps the returned (updated)
     arrays, the same buffers from its construction to its close.  A pool
     that was taken from keeps its shapes, free list and accounting and
-    holds no array (``k_pages`` / ``v_pages`` / ``conv_state`` are None).
+    holds no array (``k_pages`` / ``v_pages`` / ``state`` are None).
 
     Page 0 is the trash page: idle slots and pad tokens write there, so
     every scatter is unconditional.  It is excluded from the free list
     and from capacity accounting.
 
-    For a ``cca`` block the pool also holds ``conv_state``, the per-slot
-    convolution state (``slots`` rows a layer; None for other blocks).
-    ``retention`` is the per-slot state a block retains and the
-    executables donate, taken by :meth:`take_retention`: for a
-    ``retention`` block ``(S, z)`` in float32 and NO pages (``k_pages`` /
-    ``v_pages`` None; a page is then one slot's state, so ``n_pages`` is
-    the slots and one more, and a sequence allocates ONE); for a block
-    with a mixer ``(S, tail)`` (``models/ssm.py``) beside its pages; None
-    for the others.  A sequence's charge is its pages and its slot's
-    state, ``n * page_bytes + state_bytes``, both read off the arrays'
-    own shapes.
+    ``state`` is the state a decode slot keeps, of whichever part of the
+    block keeps one (:func:`_parts`; None where none does).  A retention
+    block has NO pages (``k_pages`` / ``v_pages`` None): a page is then one
+    slot's state, ``n_pages`` the slots and one more, and a sequence
+    allocates ONE.  A state the executables donate (``donates_state``) is
+    charged with a sequence's pages, ``n * page_bytes + state_bytes``, both
+    read off the arrays' own shapes.
 
     A stack whose attention differs by layer (``cfg.block.layer_types``)
     has TWO pools of pages: ``k_pages`` and ``v_pages`` are pairs ``(full,
@@ -241,35 +212,20 @@ class PagePool:
         self.tokens_per_page = P
         self.n_pages = int(n_pages)
         self.dtype = jnp.dtype(dtype or cfg.dtype)
-        # the second kind of per-sequence state: a ``cca`` block's decode
-        # step needs the previous position's convolution inputs, a fixed
-        # ``cca.state_width`` values a layer whatever the length.  One
-        # array [n_layers, slots, width] beside the pages, indexed by the
-        # decode slot, threaded through the executables like the pages
-        self.slots = None
-        if (
-            cfg.block.attention in ("cca", "retention")
-            or cfg.block.mixer is not None
-        ):
-            if slots is None:
-                raise ValueError(
-                    f"a {cfg.block.attention!r} block with mixer "
-                    f"{cfg.block.mixer} keeps a state per decode slot: "
-                    f"PagePool(..., slots=max_slots)"
-                )
-            self.slots = int(slots)
-        # a window layer's ring, and the window pool's pages
-        self.ring = ring_pages(cfg, P) if cfg.block.window else 0
-        self.window_pages = 0
-        if self.ring:
-            if slots is None:
-                raise ValueError(
-                    "a stack with window layers holds a ring of pages a "
-                    "decode slot: PagePool(..., slots=max_slots)"
-                )
-            self.window_pages = int(slots) * self.ring + 1
-        self.k_pages, self.v_pages, self.conv_state = self.zeros()
-        self.retention = self.retention_zeros()
+        self._parts = [p for p in _parts(cfg) if p is not None]
+        # a window layer's window (tokens) and ring, and the window pool's
+        # pages
+        self.window = cfg.block.window
+        self.ring = ring_pages(cfg, P) if self.window else 0
+        if slots is None and (self.ring or any(p.state for p in self._parts)):
+            raise ValueError(
+                "this block keeps a state or a ring of pages a decode slot: "
+                "PagePool(..., slots=max_slots)"
+            )
+        self.slots = None if slots is None else int(slots)
+        self.window_pages = self.slots * self.ring + 1 if self.ring else 0
+        self.donates_state = any(p.donated for p in self._parts)
+        self.k_pages, self.v_pages, self.state = self.zeros()
 
         def page_bytes(pools, n):
             return sum(
@@ -279,24 +235,17 @@ class PagePool:
 
         # what the budget LRU accounts, read off the arrays' own shapes:
         # one page's HBM across all layers, of every pool there is (K and
-        # V together, or the one latent pool), and one slot's retained
+        # V together, or the one latent pool), and one slot's donated
         # state, all layers.  A pool with no pages (retention) has the
         # slot's state as its page
-        self.window_page_bytes = 0
+        full, window = (self.k_pages, self.v_pages), ()
         if self.ring:
-            self.page_bytes = page_bytes(
-                (self.k_pages[0], self.v_pages[0]), self.n_pages
-            )
-            self.window_page_bytes = page_bytes(
-                (self.k_pages[1], self.v_pages[1]), self.window_pages
-            )
-        else:
-            self.page_bytes = page_bytes(
-                (self.k_pages, self.v_pages), self.n_pages
-            )
+            full, window = zip(self.k_pages, self.v_pages)
+        self.page_bytes = page_bytes(full, self.n_pages)
+        self.window_page_bytes = page_bytes(window, self.window_pages)
         self.state_bytes = sum(
             a.size // self.slots * a.dtype.itemsize
-            for a in self.retention or ()
+            for a in (self.state if self.donates_state else ())
         )
         if not self.page_bytes:
             self.page_bytes, self.state_bytes = self.state_bytes, 0
@@ -308,61 +257,43 @@ class PagePool:
 
     def zeros(self):
         """Arrays of the pool's shapes, all zero: ``(k_pages, v_pages,
-        conv_state)``, the last None unless the block is ``cca``.  A
+        state)``, as the block's parts shape them (:func:`_parts`).  A
         latent block (``mla``) has ONE pool, a token's row of
         ``mla.row_width`` values laid out as one head; it travels where
         the K pool does, and ``v_pages`` is None."""
-        cfg = self.cfg
-        if not holds_pages(cfg):
-            return None, None, None
-        if cfg.block.attention == "mla":
-            heads, widths = 1, (mla.row_width(cfg), None)
-        else:
-            heads, widths = cfg.n_kv_heads, (cfg.head_dim,) * 2
-        state = None
-        if cfg.block.attention == "cca":
-            state = cca.init_state(cfg, self.slots, self.dtype)
+        cfg, parts = self.cfg, self._parts
+        k = v = None
+        if parts[0].pools is not None:
+            heads, *widths = parts[0].pools(cfg)
 
-        def pool(layers, n_pages, w):
-            return None if w is None else jnp.zeros(
-                (layers, heads, n_pages, self.tokens_per_page, w), self.dtype
-            )
+            def pool(layers, n_pages, w):
+                return None if w is None else jnp.zeros(
+                    (layers, heads, n_pages, self.tokens_per_page, w),
+                    self.dtype,
+                )
 
-        if self.ring:
             n_full = cfg.block.layer_types.count("full")
             k, v = (
                 (pool(n_full, self.n_pages, w),
                  pool(cfg.n_layers - n_full, self.window_pages, w))
+                if self.ring else pool(cfg.n_layers, self.n_pages, w)
                 for w in widths
             )
-            return k, v, state
-        k, v = (pool(cfg.n_layers, self.n_pages, w) for w in widths)
+        state = next(
+            (p.state(cfg, self.slots, self.dtype) for p in parts if p.state),
+            None,
+        )
         return k, v, state
 
     def take(self):
         """Hand the arrays to their one holder: ``(k_pages, v_pages,
-        conv_state)``, of which the pool then keeps no reference — a
-        second holder would pin a copy the executables' donation could
-        not reuse (and read a deleted buffer after the first dispatch)."""
-        arrays = self.k_pages, self.v_pages, self.conv_state
-        self.k_pages = self.v_pages = self.conv_state = None
+        state)``, of which the pool then keeps no reference — a second
+        holder would pin a copy the executables' donation could not reuse
+        (and read a deleted buffer after the first dispatch; a retention
+        block's state is 2-5 GB, with no room for a second)."""
+        arrays = self.k_pages, self.v_pages, self.state
+        self.k_pages = self.v_pages = self.state = None
         return arrays
-
-    def retention_zeros(self):
-        """The retained state of the pool's slots, all zero: a retention
-        block's ``(S, z)`` in float32 whatever the pool's dtype, a mixer's
-        ``(S, tail)`` (``ssm.init_state``); None for other blocks."""
-        if self.cfg.block.attention == "retention":
-            return retention.init_state(self.cfg, self.slots)
-        if self.cfg.block.mixer is not None:
-            return ssm.init_state(self.cfg, self.slots, self.dtype)
-        return None
-
-    def take_retention(self):
-        """:meth:`take` for the retained state: both executables donate
-        it, and 2-5 GB have no room for a second holder."""
-        state, self.retention = self.retention, None
-        return state
 
     # -- allocation ----------------------------------------------------------
 
@@ -374,10 +305,6 @@ class PagePool:
     def free_count(self) -> int:
         with self._lock:
             return len(self._free)
-
-    def used_count(self) -> int:
-        with self._lock:
-            return self.capacity - len(self._free)
 
     def allocate(
         self, n: int, tenant: Optional[str] = None
@@ -458,7 +385,7 @@ class PagePool:
 def holds_pages(cfg) -> bool:
     """Whether a block's pool holds pages: every kind's but a retention
     block's, whose whole past is its state."""
-    return cfg.block.attention != "retention"
+    return _parts(cfg)[0].pools is not None
 
 
 def pages_for(tokens: int, tokens_per_page: int) -> int:
@@ -685,50 +612,6 @@ def latent_kernel_fits(cfg, P: int, B: int, L: int, dtype) -> bool:
     )
 
 
-def _latent_attention(bp, x, positions, cfg, pages, tables, layer,
-                      from_zero=False):
-    """The attention half of an ``mla`` block against ``layer``'s pages of
-    the ONE stacked latent pool ``[n_layers, 1, n_pages, P, width]``:
-    ``(x', pages')``.  The chunk's rows (``mla.project``) are written as
-    any K would be.  A chunk that starts its sequence (``from_zero``, a
-    prefill) then attends over its own rows expanded by head, as the
-    pages hold them.  A one-token chunk that fits
-    (:func:`latent_kernel_fits`) attends in the latent space through the
-    kernel, which reads each row's pages in place up to its frontier;
-    any other gathers the table's pages into a ``[B, max_pages * P,
-    width]`` view and attends over it in the latent space
-    (``mla.attend_absorbed``).  Nothing is expanded by head either way."""
-    dt = cfg.dtype
-    q_n, q_r, row = mla.project(bp, x, positions, cfg)
-    with jax.named_scope("latent_write"):
-        pages, _ = _page_write(
-            pages, None, row, None, positions, tables, layer, from_zero
-        )
-    B, L = x.shape[:2]
-    P = pages.shape[3]
-    if from_zero:
-        rows = row[:, :, 0].astype(pages.dtype).astype(dt)
-        att = mla.attend_expanded(bp, q_n, q_r, rows, positions, cfg)
-    elif latent_kernel_fits(cfg, P, B, L, pages.dtype):
-        q = mla.absorb(bp, q_n, q_r, pages.shape[-1], cfg)
-        with jax.named_scope("latent_kernel"):
-            # an idle row (its table all trash) reads the trash page, as
-            # on the gather path
-            lengths = jnp.clip(positions[:, 0] + 1, 1, tables.shape[1] * P)
-            o_lat = paged_attention.latent_attention(
-                q[:, 0], pages, tables, lengths, layer,
-                cfg.block.latent.kv_rank, mla.softmax_scale(cfg),
-            )
-        att = mla.up(bp, o_lat[:, None], cfg)
-    else:
-        with jax.named_scope("page_gather"):
-            rows = pages[layer, 0, tables].reshape(
-                B, tables.shape[1] * P, -1
-            ).astype(dt)
-        att = mla.attend_absorbed(bp, q_n, q_r, rows, positions, cfg)
-    return _attn_out(bp, x, att, cfg), pages
-
-
 def retention_kernel_fits(cfg) -> bool:
     """Whether a retention block's decode step runs through the Pallas
     kernel (``parallel/retention.py``), decided at trace time like
@@ -749,62 +632,6 @@ def ssm_kernel_fits(cfg) -> bool:
     )
 
 
-def _retention_step(bp, x, positions, cfg, st, live, layer):
-    """The attention half of a ``retention`` block for ONE token a row
-    against ``layer`` of the stacked state ``st = (S, z)``: ``(x', st')``.
-    A live row's state is decayed, takes the token and is read out, in one
-    pass where the kernel fits; a row that holds no sequence keeps its
-    state untouched and reads zeros."""
-    if x.shape[1] != 1:
-        raise NotImplementedError(
-            "a retention block steps one token a row; a chunk is a prefill"
-        )
-    S, z = st
-    q, k, v, log_g = (
-        t[:, 0] for t in retention.project(bp, x, positions, cfg)
-    )
-    with jax.named_scope("retention_step"):
-        if retention_kernel_fits(cfg):
-            y, S, z = retention_kernel.retention_step(
-                retention.scaled(q), retention.scaled(k),
-                v.astype(jnp.float32), log_g, S, z, live, layer,
-            )
-        else:
-            y, s1, z1 = retention.step(q, k, v, log_g, S[layer], z[layer], live)
-            S, z = S.at[layer].set(s1), z.at[layer].set(z1)
-    return _attn_out(bp, x, y.astype(cfg.dtype)[:, None], cfg), (S, z)
-
-
-def _retention_prefill(bp, x, positions, cfg, st, layer, slot, last_pos,
-                       resume):
-    """The attention half of a ``retention`` block for a chunk of ONE
-    sequence: the chunked form from the slot's state of ``layer`` where
-    the dispatch resumes a sequence, from zeros where it starts one — so
-    nothing of the slot's previous tenant survives admission — leaving in
-    the slot what the last real token leaves.  ``(x', st')``."""
-    S, z = st
-    q, k, v, log_g = (t[0] for t in retention.project(bp, x, positions, cfg))
-    valid = jnp.arange(x.shape[1], dtype=jnp.int32) <= last_pos[0]
-    # int32 by hand: with x64 on, a Python 0 beside them is an int64
-    at = (jnp.asarray(layer, jnp.int32), slot[0].astype(jnp.int32))
-    zero = jnp.int32(0)
-
-    def held(a):
-        mine = jax.lax.dynamic_slice(
-            a, at + (zero,) * (a.ndim - 2), (1, 1) + a.shape[2:]
-        )[0, 0]
-        return jnp.where(resume, mine, 0.0)
-
-    y, s1, z1 = retention.chunked(q, k, v, log_g, held(S), held(z), valid)
-    S, z = (
-        jax.lax.dynamic_update_slice(
-            a, new[None, None], at + (zero,) * new.ndim
-        )
-        for a, new in ((S, s1), (z, z1))
-    )
-    return _attn_out(bp, x, y.astype(cfg.dtype)[None], cfg), (S, z)
-
-
 class _Site(NamedTuple):
     """Where a layer of a stack whose attention differs by layer
     (``cfg.block.layer_types``) keeps its pages: its kind's pool of the
@@ -816,18 +643,6 @@ class _Site(NamedTuple):
     at: jnp.ndarray
     window: int
     ring: int
-
-    def select(self, kp, vp, tables):
-        """This layer's pools and table out of the pairs and the row."""
-        split = tables.shape[1] - self.ring
-        table = tables[:, split:] if self.window else tables[:, :split]
-        return kp[self.pool], vp[self.pool], table
-
-    def put(self, pools, kp, vp):
-        """The pairs with this layer's kind's pools written back."""
-        pair = list(zip(*pools))
-        pair[self.pool] = (kp, vp)
-        return tuple(zip(*pair))
 
 
 def _rotates(cfg, site) -> bool:
@@ -866,189 +681,453 @@ def _prefill_attention(q, k, v, positions, window=0):
         return flash.flash_prefill(q, k, v, window)
 
 
-def _paged_block(bp, x, positions, cfg, kp, vp, tables, layer, st=None,
-                 route=None, site=None):
-    """One decoder block against ``layer``'s pages of the stacked pools.
+# ---------------------------------------------------------------------------
+# the parts of a paged block: one object per attention kind, and the mixer
+# ---------------------------------------------------------------------------
 
-    ``kp``/``vp``: [n_layers, kvh, n_pages, P, Dh], written and read at
-    ``layer`` where they lie; ``tables``: [B, max_pages];
-    ``positions``: [B, L] absolute positions (per-row frontiers).  The
-    chunk's k/v scatter to ``tables[b, pos // P]`` at offset ``pos %
-    P`` — table slots a sequence never reserved hold 0, so pad tokens
-    and idle slots write the trash page.  A one-token chunk that fits
-    (:func:`paged_kernel_fits`) then attends over its pages in place,
-    through the table, up to its frontier; any other gathers the table's
-    pages of the layer into a [B, max_pages * P] contiguous view and runs
-    the UNMODIFIED ``transformer._cache_attention`` on it.  Either way
-    positions past a row's frontier have exact zero weight, so stale page
-    contents (previous tenants included) never contribute a bit.
 
-    A ``cca`` block (``L`` = 1) also takes the convolution state of all
-    layers ``st`` [n_layers, B, width] and leaves this position's in the
-    layer's rows; a block with a mixer takes its state ``st = (S, tail)``
-    and adds the mixer's output (:func:`ssm.mix_step`, on the same input
-    as attention) to attention's; an ``experts_top1`` block takes
-    ``route`` (:func:`_feed_forward`).  Returns ``(x', kp', vp', st',
-    routed)``, the last two None where the spec has no such thing.
+class _Chunk(NamedTuple):
+    """What every layer of one dispatch reads beside its weights, pools and
+    state: the tokens' ``positions`` [B, L] and the page ``tables``; for a
+    prefill also its ``slot`` [1], the prompt's last real position
+    ``last_pos`` [1] and the position ``start`` [1] it continues from."""
+
+    positions: jnp.ndarray
+    tables: Optional[jnp.ndarray]
+    slot: Optional[jnp.ndarray] = None
+    last_pos: Optional[jnp.ndarray] = None
+    start: Optional[jnp.ndarray] = None
+
+
+def _select(site, kp, vp, tables, layer):
+    """A layer's pools, table, index and window: ``(kp, vp, tables, at,
+    window, put)``.  A layer at a :class:`_Site` takes its kind's pools out
+    of the pairs and its table out of the rows, at its rank, and ``put(kp,
+    vp)`` gives back the pairs the scan carries; any other takes the stacks
+    as they are, at ``layer``."""
+    if site is None:
+        return kp, vp, tables, layer, 0, lambda kp, vp: (kp, vp)
+    split = tables.shape[1] - site.ring
+    tables = tables[:, split:] if site.window else tables[:, :split]
+
+    def put(k, v):
+        pairs = list(zip(kp, vp))
+        pairs[site.pool] = (k, v)
+        return tuple(zip(*pairs))
+
+    return kp[site.pool], vp[site.pool], tables, site.at, site.window, put
+
+
+def _write(kp, vp, k, v, positions, tables, at, window, from_zero=False,
+           last_pos=None):
+    """:func:`_page_write` of a layer's chunk, a window layer's through its
+    ring (``window_write``)."""
+    if not window:
+        return _page_write(
+            kp, vp, k, v, positions, tables, at, from_zero=from_zero
+        )
+    with jax.named_scope("window_write"):
+        return _page_write(
+            kp, vp, k, v, positions, tables, at, from_zero=from_zero,
+            ring=True, last_pos=last_pos,
+        )
+
+
+class _Part:
+    """One part of a paged block, its attention of one kind or the mixer
+    beside it, and what it owns: ``pools(cfg)``, the ``(heads, k width, v
+    width)`` of its pages (a width None: no such pool; the attribute None:
+    no pages); ``state(cfg, slots, dtype)``, its state of ``slots`` decode
+    slots, zeros (the attribute None: no state); ``step`` and ``prefill``,
+    its half of a decode step and of a prefill, against the stacked pools
+    and state, written where they lie; ``kernels(pool, B)``, the decode
+    kernel its one-token step of ``B`` rows takes, ``{counter: 1}`` (0
+    where the shapes refuse it).  ``donated``: the executables take its
+    state as ``retention``, donated, and a sequence is charged its slot's;
+    ``turned``: it projects q, k and v through ``transformer.linear``
+    (:func:`serving_params`)."""
+
+    pools = state = None
+    donated = turned = False
+
+    # the most prompt tokens one prefill dispatch takes, a later one
+    # resuming from the state the one before left in the slot (None: a
+    # prompt goes whole)
+    prefill_tokens = None
+
+    def kernels(self, pool, B):
+        return {}
+
+
+class _Gqa(_Part):
+    """Grouped-query attention over pages of K and V, ``[n_layers, kvh,
+    n_pages, P, Dh]`` each.  A chunk's k/v scatter to ``tables[b, pos //
+    P]`` at offset ``pos % P`` — table slots a sequence never reserved hold
+    0, so pad tokens and idle slots write the trash page.  A one-token
+    chunk that fits (:func:`paged_kernel_fits`) then attends over its pages
+    in place, through the table, up to its frontier (``paged_kernel``);
+    any other gathers the table's pages of the layer into a [B, max_pages *
+    P] contiguous view and runs the UNMODIFIED ``transformer.
+    _cache_attention`` on it (``page_gather``).  Either way positions past a
+    row's frontier have exact zero weight, so stale page contents (previous
+    tenants included) never contribute a bit.  A prefill's queries may see
+    only its own keys, which the layer has just computed: it attends over
+    them causally (rounded to the page dtype, as the pages hold them) and
+    gathers nothing back (:func:`_prefill_attention`).
 
     A layer of a stack whose attention differs by layer takes its
-    :class:`_Site`, ``kp`` / ``vp`` the pairs of pools and ``tables`` the
-    whole rows: it reads and writes its kind's pool at its rank, a window
-    layer through its ring (``window_write``; ``window_kernel``, or the
-    ring's gather under the window's mask), and returns the pairs."""
-    if cfg.block.attention == "retention":
-        # no pages: ``st`` is the state, ``tables[:, 0]`` says who is live
-        with jax.named_scope("attention"):
-            x, st = _retention_step(
-                bp, x, positions, cfg, st, tables[:, 0] > 0, layer
-            )
-        x, routed = _feed_forward(bp, x, cfg, layer, route)
-        return x, kp, vp, st, routed
-    B, L = x.shape[:2]
-    dt = cfg.dtype
-    pools, at, window = None, layer, 0
-    if site is not None:
-        if site.window and L != 1:
+    :class:`_Site`: it reads and writes its kind's pool at its rank, a
+    window layer through its ring (``window_write``; ``window_kernel``, or
+    the ring's gather under the window's mask) and, in a prefill, over the
+    last ``window`` keys of each query."""
+
+    turned = True
+
+    def pools(self, cfg):
+        return cfg.n_kv_heads, cfg.head_dim, cfg.head_dim
+
+    def kernels(self, pool, B):
+        keys = ("decode_kernel_steps",) + (
+            ("decode_window_kernel_steps",) if pool.ring else ()
+        )
+        return dict.fromkeys(keys, int(paged_kernel_fits(
+            pool.cfg, pool.tokens_per_page, B, 1, pool.dtype
+        )))
+
+    # a step's ``(q, k, v)`` and the state it leaves; a prefill's, and what
+    # it leaves in the state once the block's feed-forward is done
+    def qkv(self, bp, x, c, cfg, st, layer, site):
+        return tfm._attn_qkv(bp, x, c.positions, cfg, _rotates(cfg, site)), st
+
+    def qkv_prefill(self, bp, x, c, cfg, site):
+        return tfm._attn_qkv(bp, x, c.positions, cfg, _rotates(cfg, site)), None
+
+    def step(self, bp, x, c, cfg, kp, vp, st, layer, site):
+        B, L = x.shape[:2]
+        if site is not None and site.window and L != 1:
             raise NotImplementedError(
                 "a window layer steps one token a row; a chunk is a prefill"
             )
-        pools, at, window = (kp, vp), site.at, site.window
-        kp, vp, tables = site.select(kp, vp, tables)
-    P = kp.shape[3]
-    cap = tables.shape[1] * P
+        kp, vp, tables, at, window, put = _select(site, kp, vp, c.tables, layer)
+        positions, dt, P = c.positions, cfg.dtype, kp.shape[3]
+        cap = tables.shape[1] * P
+        (q, k, v), st = self.qkv(bp, x, c, cfg, st, layer, site)
+        kvh, dh = k.shape[2:]
+        kp, vp = _write(kp, vp, k, v, positions, tables, at, window)
+        if paged_kernel_fits(cfg, P, B, L, kp.dtype):
+            with jax.named_scope("window_kernel" if window else "paged_kernel"):
+                # an idle row (any index, its table all trash) attends the
+                # trash page like the gather path: at least one key, at
+                # most the capacity (a ring holds any length)
+                lengths = (
+                    jnp.maximum(positions[:, 0] + 1, 1) if window
+                    else jnp.clip(positions[:, 0] + 1, 1, cap)
+                )
+                att = paged_attention.paged_attention(
+                    q[:, 0], kp, vp, tables, lengths, at, window=window
+                )[:, None]
+        else:
+            with jax.named_scope("page_gather"):
+                # gather each row's pages of the layer, straight from the
+                # stack, into its contiguous cache view: the two advanced
+                # indices lead, [B, max_pages, kvh, P, Dh]
+                ck, cv = (
+                    jnp.moveaxis(pages[at, :, tables], 2, 3).reshape(
+                        B, cap, kvh, dh
+                    ).astype(dt)
+                    for pages in (kp, vp)
+                )
+            k_pos = (
+                _ring_positions(positions, P, tables.shape[1]) if window
+                else None
+            )
+            att = tfm._cache_attention(q, ck, cv, positions, window, k_pos)
+        return (_attn_out(bp, x, att, cfg), *put(kp, vp), st)
+
+    def prefill(self, bp, x, c, cfg, kp, vp, st, layer, site):
+        kp, vp, tables, at, window, put = _select(site, kp, vp, c.tables, layer)
+        (q, k, v), leave = self.qkv_prefill(bp, x, c, cfg, site)
+        kp, vp = _write(
+            kp, vp, k, v, c.positions, tables, at, window, True, c.last_pos
+        )
+        dt = cfg.dtype
+        att = _prefill_attention(
+            q, k.astype(kp.dtype).astype(dt), v.astype(vp.dtype).astype(dt),
+            c.positions, window,
+        )
+        return (_attn_out(bp, x, att, cfg), *put(kp, vp), st, leave)
+
+
+class _Cca(_Gqa):
+    """Compressed convolutional attention (``models/cca.py``): ``gqa``'s
+    pages and a state a slot, ``[n_layers, slots, cca.state_width]``, the
+    previous position's convolution inputs.  A step reads and writes its
+    layer's rows; a prefill OVERWRITES the slot's row with what the
+    prompt's last real position leaves (``attention/conv_state``), once
+    the block's feed-forward is done."""
+
+    turned = False
+
+    def state(self, cfg, slots, dtype):
+        return cca.init_state(cfg, slots, dtype)
+
+    def qkv(self, bp, x, c, cfg, st, layer, site):
+        q, k, v, row = cca.qkv_step(bp, x, c.positions, st[layer], cfg)
+        return (q, k, v), st.at[layer].set(row)
+
+    def qkv_prefill(self, bp, x, c, cfg, site):
+        q, k, v, tail = cca.qkv_sequence(bp, x, c.positions, cfg)
+
+        def leave(st, layer):
+            with jax.named_scope("attention/conv_state"):
+                last = jnp.take_along_axis(
+                    tail, c.last_pos[:, None, None], axis=1
+                )[:, 0]
+                return st.at[layer, c.slot].set(last.astype(st.dtype))
+
+        return (q, k, v), leave
+
+
+class _Mla(_Part):
+    """Latent attention (``models/mla.py``): ONE pool ``[n_layers, 1,
+    n_pages, P, mla.row_width]``, a token's normed latent and rotated key
+    part as one row (``mla.project``), written as any K would be
+    (``latent_write``) and travelling where the K pool does (``vp`` None).
+    A prefill attends over its own rows expanded by head, as the pages hold
+    them.  A one-token step that fits (:func:`latent_kernel_fits`) attends
+    in the latent space through the kernel's latent form, which reads each
+    row's pages in place up to its frontier (``latent_kernel``); any other
+    gathers the table's pages into a ``[B, max_pages * P, width]`` view and
+    attends over it in the latent space (``mla.attend_absorbed``).  Nothing
+    is expanded by head either way."""
+
+    def pools(self, cfg):
+        return 1, mla.row_width(cfg), None
+
+    def kernels(self, pool, B):
+        return {"decode_latent_kernel_steps": int(latent_kernel_fits(
+            pool.cfg, pool.tokens_per_page, B, 1, pool.dtype
+        ))}
+
+    def _rows(self, bp, x, c, cfg, pages, layer, from_zero=False):
+        q_n, q_r, row = mla.project(bp, x, c.positions, cfg)
+        with jax.named_scope("latent_write"):
+            pages, _ = _page_write(
+                pages, None, row, None, c.positions, c.tables, layer, from_zero
+            )
+        return q_n, q_r, row, pages
+
+    def step(self, bp, x, c, cfg, kp, vp, st, layer, site):
+        q_n, q_r, _, kp = self._rows(bp, x, c, cfg, kp, layer)
+        B, L = x.shape[:2]
+        P, tables = kp.shape[3], c.tables
+        if latent_kernel_fits(cfg, P, B, L, kp.dtype):
+            q = mla.absorb(bp, q_n, q_r, kp.shape[-1], cfg)
+            with jax.named_scope("latent_kernel"):
+                # an idle row (its table all trash) reads the trash page, as
+                # on the gather path
+                cap = tables.shape[1] * P
+                lengths = jnp.clip(c.positions[:, 0] + 1, 1, cap)
+                o_lat = paged_attention.latent_attention(
+                    q[:, 0], kp, tables, lengths, layer,
+                    cfg.block.latent.kv_rank, mla.softmax_scale(cfg),
+                )
+            att = mla.up(bp, o_lat[:, None], cfg)
+        else:
+            with jax.named_scope("page_gather"):
+                rows = kp[layer, 0, tables].reshape(
+                    B, tables.shape[1] * P, -1
+                ).astype(cfg.dtype)
+            att = mla.attend_absorbed(bp, q_n, q_r, rows, c.positions, cfg)
+        return _attn_out(bp, x, att, cfg), kp, vp, st
+
+    def prefill(self, bp, x, c, cfg, kp, vp, st, layer, site):
+        q_n, q_r, row, kp = self._rows(bp, x, c, cfg, kp, layer, True)
+        rows = row[:, :, 0].astype(kp.dtype).astype(cfg.dtype)
+        att = mla.attend_expanded(bp, q_n, q_r, rows, c.positions, cfg)
+        return _attn_out(bp, x, att, cfg), kp, vp, st, None
+
+
+class _Retention(_Part):
+    """Power retention (``models/retention.py``): NO pages, a sequence's
+    whole past a float32 state of fixed size a layer, ``(S [n_layers,
+    slots, kvh, O, dh, dh], z [n_layers, slots, kvh, O, dh])``, donated.
+    ``tables[:, 0] > 0`` says which rows of a step are live."""
+
+    donated = turned = True
+
+    def state(self, cfg, slots, dtype):
+        return retention.init_state(cfg, slots)
+
+    @property
+    def prefill_tokens(self):
+        return retention.PREFILL_TOKENS
+
+    def step(self, bp, x, c, cfg, kp, vp, st, layer, site):
+        """ONE token a row against ``layer`` of the stacked state ``st =
+        (S, z)``.  A live row's state is decayed, takes the token and is
+        read out, in one pass where the kernel fits; a row that holds no
+        sequence keeps its state untouched and reads zeros."""
+        live = c.tables[:, 0] > 0
+        if x.shape[1] != 1:
+            raise NotImplementedError(
+                "a retention block steps one token a row; a chunk is a prefill"
+            )
+        S, z = st
+        q, k, v, log_g = (
+            t[:, 0] for t in retention.project(bp, x, c.positions, cfg)
+        )
+        with jax.named_scope("retention_step"):
+            if retention_kernel_fits(cfg):
+                y, S, z = retention_kernel.retention_step(
+                    retention.scaled(q), retention.scaled(k),
+                    v.astype(jnp.float32), log_g, S, z, live, layer,
+                )
+            else:
+                y, s1, z1 = retention.step(
+                    q, k, v, log_g, S[layer], z[layer], live
+                )
+                S, z = S.at[layer].set(s1), z.at[layer].set(z1)
+        x = _attn_out(bp, x, y.astype(cfg.dtype)[:, None], cfg)
+        return x, kp, vp, (S, z)
+
+    def prefill(self, bp, x, c, cfg, kp, vp, st, layer, site):
+        """A chunk of ONE sequence from position ``start``: the chunked
+        form from the slot's state of ``layer`` where the dispatch resumes a
+        sequence, from zeros where it starts one — so nothing of the slot's
+        previous tenant survives admission — leaving in the slot what the
+        last real token leaves."""
+        positions, resume = c.positions + c.start[:, None], c.start[0] > 0
+        S, z = st
+        q, k, v, log_g = (
+            t[0] for t in retention.project(bp, x, positions, cfg)
+        )
+        valid = jnp.arange(x.shape[1], dtype=jnp.int32) <= c.last_pos[0]
+        # int32 by hand: with x64 on, a Python 0 beside them is an int64
+        at = (jnp.asarray(layer, jnp.int32), c.slot[0].astype(jnp.int32))
+        zero = jnp.int32(0)
+
+        def held(a):
+            mine = jax.lax.dynamic_slice(
+                a, at + (zero,) * (a.ndim - 2), (1, 1) + a.shape[2:]
+            )[0, 0]
+            return jnp.where(resume, mine, 0.0)
+
+        y, s1, z1 = retention.chunked(q, k, v, log_g, held(S), held(z), valid)
+        S, z = (
+            jax.lax.dynamic_update_slice(
+                a, new[None, None], at + (zero,) * new.ndim
+            )
+            for a, new in ((S, s1), (z, z1))
+        )
+        x = _attn_out(bp, x, y.astype(cfg.dtype)[None], cfg)
+        return x, kp, vp, (S, z), None
+
+
+class _Mixer(_Part):
+    """A state-space mixer beside ``gqa`` attention (``models/ssm.py``:
+    Falcon-H1's Mamba-2) on the same input: no pages, a state a slot ``(S
+    float32, tail)``, donated.  A step steps a live slot's state in one
+    pass of ``parallel/ssm.py``'s kernel where it fits (``ssm.step`` where
+    not); a prefill runs SSD's chunked form and OVERWRITES the slot's
+    state and tail.  Its halves return ``(m, st')``, ``m`` added to
+    attention's output."""
+
+    donated = True
+
+    def state(self, cfg, slots, dtype):
+        return ssm.init_state(cfg, slots, dtype)
+
+    def kernels(self, pool, B):
+        return {"decode_ssm_kernel_steps": int(ssm_kernel_fits(pool.cfg))}
+
+    def step(self, bp, x, c, cfg, st, layer):
+        return ssm.mix_step(
+            bp, x, st, c.tables[:, 0] > 0, layer, cfg, ssm_kernel_fits(cfg)
+        )
+
+    def prefill(self, bp, x, c, cfg, st, layer):
+        return ssm.mix_prefill(bp, x, st, layer, c.slot, c.last_pos, cfg)
+
+
+_KINDS = {
+    "gqa": _Gqa(), "cca": _Cca(), "mla": _Mla(), "retention": _Retention(),
+}
+_MIXER = _Mixer()
+
+
+def _parts(cfg):
+    """The parts of ``cfg``'s block: ``(attention, mixer)``, the mixer None
+    where the spec has none.  The one place the serving path asks a block
+    its attention kind; at most one part keeps a state, as a mixer runs
+    beside ``gqa`` alone."""
+    return (
+        _KINDS[cfg.block.attention],
+        None if cfg.block.mixer is None else _MIXER,
+    )
+
+
+# the decode-kernel counters every block's step adds to, 0 if it lacks theirs
+_KERNEL_STEPS = ("decode_kernel_steps", "decode_latent_kernel_steps",
+                 "decode_ssm_kernel_steps")
+
+
+def prefill_tokens(cfg) -> Optional[int]:
+    """The most prompt tokens a prefill dispatch of ``cfg``'s block takes, a
+    later one resuming from the state left in the slot (None: all)."""
+    return _parts(cfg)[0].prefill_tokens
+
+
+def kernel_steps(pool, B: int) -> Dict[str, int]:
+    """What a one-token step of ``B`` rows on ``pool`` adds to the
+    decode-kernel counters: 1 where the block's parts trace it a kernel."""
+    steps = dict.fromkeys(_KERNEL_STEPS, 0)
+    for part in _parts(pool.cfg):
+        if part is not None:
+            steps.update(part.kernels(pool, B))
+    return steps
+
+
+def _paged_block(c, cfg, bp, x, kp, vp, st, layer, route=None, site=None):
+    """One decoder block of a decode step, chunk ``c``, against ``layer`` of
+    the stacked pools and state: attention by its kind (:func:`_parts`),
+    the mixer beside it where the spec has one, the feed-forward
+    (``route``: :func:`_feed_forward`).  ``(x', kp', vp', st', routed)``."""
+    attention, mixer = _parts(cfg)
     x_in = x
     # scope names are metadata: a profiler session groups the device
     # operations of a step under attention / page_write / paged_kernel
     # (or page_gather, on the general path; window_write / window_kernel
     # for a window layer; latent_write / latent_kernel for a latent block)
     with jax.named_scope("attention"):
-        if cfg.block.attention == "mla":
-            x, kp = _latent_attention(bp, x, positions, cfg, kp, tables, layer)
-        else:
-            if cfg.block.attention == "cca":
-                q, k, v, row = cca.qkv_step(bp, x, positions, st[layer], cfg)
-                st = st.at[layer].set(row)
-            else:
-                q, k, v = tfm._attn_qkv(
-                    bp, x, positions, cfg, _rotates(cfg, site)
-                )
-            kvh, dh = k.shape[2:]
-            if window:
-                with jax.named_scope("window_write"):
-                    kp, vp = _page_write(
-                        kp, vp, k, v, positions, tables, at, ring=True
-                    )
-            else:
-                kp, vp = _page_write(kp, vp, k, v, positions, tables, at)
-            if paged_kernel_fits(cfg, P, B, L, kp.dtype):
-                with jax.named_scope(
-                    "window_kernel" if window else "paged_kernel"
-                ):
-                    # an idle row (any index, its table all trash) attends
-                    # the trash page like the gather path: at least one key,
-                    # at most the capacity (a ring holds any length)
-                    lengths = (
-                        jnp.maximum(positions[:, 0] + 1, 1) if window
-                        else jnp.clip(positions[:, 0] + 1, 1, cap)
-                    )
-                    att = paged_attention.paged_attention(
-                        q[:, 0], kp, vp, tables, lengths, at, window=window
-                    )[:, None]
-            else:
-                with jax.named_scope("page_gather"):
-                    # gather each row's pages of the layer, straight from the
-                    # stack, into its contiguous cache view: the two advanced
-                    # indices lead, [B, max_pages, kvh, P, Dh]
-                    ck, cv = (
-                        jnp.moveaxis(pages[at, :, tables], 2, 3).reshape(
-                            B, cap, kvh, dh
-                        ).astype(dt)
-                        for pages in (kp, vp)
-                    )
-                k_pos = (
-                    _ring_positions(positions, P, tables.shape[1]) if window
-                    else None
-                )
-                att = tfm._cache_attention(q, ck, cv, positions, window, k_pos)
-            x = _attn_out(bp, x, att, cfg)
-    if cfg.block.mixer is not None:
+        x, kp, vp, st = attention.step(bp, x, c, cfg, kp, vp, st, layer, site)
+    if mixer is not None:
         with jax.named_scope("mixer"):
-            m, st = ssm.mix_step(
-                bp, x_in, st, tables[:, 0] > 0, layer, cfg,
-                ssm_kernel_fits(cfg),
-            )
+            m, st = mixer.step(bp, x_in, c, cfg, st, layer)
         x = x + m
     x, routed = _feed_forward(bp, x, cfg, layer, route)
-    if pools is not None:
-        kp, vp = site.put(pools, kp, vp)
     return x, kp, vp, st, routed
 
 
-def _prefill_block(bp, x, positions, cfg, kp, vp, tables, layer, st, slot,
-                   last_pos, route=None, site=None):
-    """:func:`_paged_block` for ONE sequence whose chunk STARTS it
-    (``positions`` count from 0): the same projections and page write,
-    but the only keys such a chunk's queries may see are its own, which
-    the layer has just computed — so attention runs causally over the
-    chunk's k/v (rounded to the page dtype, as the pages hold them) and
-    nothing is gathered back from the pages.  A ``cca`` block's state
-    row of ``slot`` [1] is OVERWRITTEN with what the prompt's last real
-    position ``last_pos`` [1] leaves, and so are a mixer's state and tail
-    (:func:`ssm.mix_prefill`), so nothing of the slot's previous tenant
-    survives admission.  Returns ``(x', kp', vp', st', routed)``.
-
-    A chunk whose float32 scores would pass ``PREFILL_SCORES_BYTES``
-    attends through the flash kernel (``attention/flash_prefill``).  A
-    layer with a :class:`_Site` takes its kind's pool as
-    :func:`_paged_block` does; a window layer writes the pages its ring
-    keeps and attends over the last ``window`` keys of each query."""
-    dt = cfg.dtype
-    tail = None
+def _prefill_block(c, cfg, bp, x, kp, vp, st, layer, route=None,
+                   site=None):
+    """:func:`_paged_block` for ONE sequence whose chunk starts it (a
+    retention block's may resume it), each part's ``prefill``: what the
+    chunk leaves in the slot's state OVERWRITES it, so nothing of the
+    slot's previous tenant survives admission."""
+    attention, mixer = _parts(cfg)
     x_in = x
-    pools, at, window = None, layer, 0
-    if site is not None:
-        pools, at, window = (kp, vp), site.at, site.window
-        kp, vp, tables = site.select(kp, vp, tables)
     with jax.named_scope("attention"):
-        if cfg.block.attention == "mla":
-            x, kp = _latent_attention(
-                bp, x, positions, cfg, kp, tables, layer, from_zero=True
-            )
-        else:
-            if cfg.block.attention == "cca":
-                q, k, v, tail = cca.qkv_sequence(bp, x, positions, cfg)
-            else:
-                q, k, v = tfm._attn_qkv(
-                    bp, x, positions, cfg, _rotates(cfg, site)
-                )
-            if window:
-                with jax.named_scope("window_write"):
-                    kp, vp = _page_write(
-                        kp, vp, k, v, positions, tables, at, from_zero=True,
-                        ring=True, last_pos=last_pos,
-                    )
-            else:
-                kp, vp = _page_write(
-                    kp, vp, k, v, positions, tables, at, from_zero=True
-                )
-            att = _prefill_attention(
-                q, k.astype(kp.dtype).astype(dt), v.astype(vp.dtype).astype(dt),
-                positions, window,
-            )
-            x = _attn_out(bp, x, att, cfg)
-    if cfg.block.mixer is not None:
+        x, kp, vp, st, leave = attention.prefill(
+            bp, x, c, cfg, kp, vp, st, layer, site
+        )
+    if mixer is not None:
         with jax.named_scope("mixer"):
-            m, st = ssm.mix_prefill(bp, x_in, st, layer, slot, last_pos, cfg)
+            m, st = mixer.prefill(bp, x_in, c, cfg, st, layer)
         x = x + m
     x, routed = _feed_forward(bp, x, cfg, layer, route)
-    if tail is not None:
-        with jax.named_scope("attention/conv_state"):
-            last = jnp.take_along_axis(
-                tail, last_pos[:, None, None], axis=1
-            )[:, 0]
-            st = st.at[layer, slot].set(last.astype(st.dtype))
-    if pools is not None:
-        kp, vp = site.put(pools, kp, vp)
+    if leave is not None:
+        st = leave(st, layer)
     return x, kp, vp, st, routed
 
 
@@ -1208,10 +1287,10 @@ def _step_forward(params, tokens, tables, indices, k_pages, v_pages, cfg,
                   state=None):
     """A token chunk against the paged cache, whatever the block:
     ``(logits, k_pages', v_pages', state', stats)``; see
-    :func:`apply_paged`.  ``state`` [n_layers, B, width] is the ``cca``
-    convolution state of the rows (for a ``retention`` block the rows'
-    ``(S, z)``, and no pages; for a mixer's ``(S, tail)``), ``stats`` is :func:`_routing` over the
-    rows that hold a sequence (a reserved page: ``tables[:, 0]``)."""
+    :func:`apply_paged`.  ``state`` is the slot state of the rows (a
+    ``cca`` block's [n_layers, B, width], a retention block's ``(S, z)``,
+    a mixer's ``(S, tail)``), ``stats`` is :func:`_routing` over the rows
+    that hold a sequence (a reserved page: ``tables[:, 0]``)."""
     B, L = tokens.shape
     positions = indices[:, None] + jnp.arange(L, dtype=jnp.int32)[None, :]
     x = _embed(params, tokens, cfg)
@@ -1219,11 +1298,7 @@ def _step_forward(params, tokens, tables, indices, k_pages, v_pages, cfg,
     if cfg.block.routes:
         live = jnp.broadcast_to(tables[:, :1] > 0, (B, L))
 
-    def block(bp, x, kp, vp, st, layer, route, site=None):
-        return _paged_block(
-            bp, x, positions, cfg, kp, vp, tables, layer, st, route, site
-        )
-
+    block = functools.partial(_paged_block, _Chunk(positions, tables), cfg)
     x, kps, vps, state, routed = _scan_layers(
         block, x, params, k_pages, v_pages, state, live, cfg
     )
@@ -1262,14 +1337,12 @@ def _prefill_forward(params, toks, table, last_pos, k_pages, v_pages, cfg,
                      state=None, slot=None, start=None):
     """ONE sequence from position 0, whatever the block: ``(logits [1, V]
     at last_pos, k_pages', v_pages', state', stats)``; see
-    :func:`paged_prefill`.  A ``cca`` block's state row of ``slot`` [1]
-    (a mixer's state and tail) is OVERWRITTEN with what the prompt's last
-    real position leaves, so nothing of the slot's previous tenant
-    survives admission; tokens past
-    ``last_pos`` are padding, routed to no expert and counted nowhere.  A
-    ``retention`` block's chunk may also CONTINUE its sequence, from
-    position ``start`` [1] and the slot's state (``state`` is ``(S, z)``):
-    a chunk at ``start`` 0 starts from zeros."""
+    :func:`paged_prefill`.  The slot state of ``slot`` [1] is OVERWRITTEN
+    with what the prompt's last real position leaves, so nothing of the
+    slot's previous tenant survives admission; tokens past ``last_pos``
+    are padding, routed to no expert and counted nowhere.  A ``retention``
+    block's chunk may also CONTINUE its sequence, from position ``start``
+    [1] and the slot's state: a chunk at ``start`` 0 starts from zeros."""
     B, L = toks.shape
     positions = jnp.broadcast_to(jnp.arange(L, dtype=jnp.int32), (B, L))
     x = _embed(params, toks, cfg)
@@ -1277,20 +1350,9 @@ def _prefill_forward(params, toks, table, last_pos, k_pages, v_pages, cfg,
     if cfg.block.routes:
         live = positions <= last_pos[:, None]
 
-    def block(bp, x, kp, vp, st, layer, route, site=None):
-        if cfg.block.attention == "retention":
-            with jax.named_scope("attention"):
-                x, st = _retention_prefill(
-                    bp, x, positions + start[:, None], cfg, st, layer, slot,
-                    last_pos, start[0] > 0,
-                )
-            x, routed = _feed_forward(bp, x, cfg, layer, route)
-            return x, kp, vp, st, routed
-        return _prefill_block(
-            bp, x, positions, cfg, kp, vp, table, layer, st, slot, last_pos,
-            route, site,
-        )
-
+    block = functools.partial(
+        _prefill_block, _Chunk(positions, table, slot, last_pos, start), cfg
+    )
     x, k_pages, v_pages, state, routed = _scan_layers(
         block, x, params, k_pages, v_pages, state, live, cfg
     )
@@ -1306,14 +1368,11 @@ def _prefill_forward(params, toks, table, last_pos, k_pages, v_pages, cfg,
 # Both DONATE the pools (PR 33): the arrays passed in are consumed, and the
 # ones returned are the same buffers, written where they lie.  A caller
 # keeps what a call returns and never what it passed.  A ``cca`` block's
-# state (5 MB at ZAYA1's widths, against the pools' 1 GB) is carried by the
-# scan like the pools but NOT donated: the benchmark's fault test
-# (``perfbench/tests/test_correct_zaya.py``) hands back the state it
-# passed, to see that a stale one is caught.  A ``retention`` block's state
-# IS its pool (5.45 GB at 20 slots of 8 layers: no second copy fits), an
-# argument of its own, ``retention``, donated like the pools; a mixer's
-# (2.16 GB at 128 slots of Falcon-H1's 4 layers) travels the same way,
-# beside the pages.
+# state (5 MB at ZAYA1's widths) is carried by the scan but NOT donated:
+# the benchmark's fault test (``perfbench/tests/test_correct_zaya.py``)
+# hands back the state it passed, to see that a stale one is caught.  A
+# retention block's (5.45 GB at 20 slots of 8 layers) and a mixer's (2.16
+# GB at 128 slots of Falcon-H1's 4 layers) go as ``retention``, donated.
 _DONATED = ("k_pages", "v_pages", "retention")
 
 # the projections ``transformer._attn_qkv`` and ``retention.project`` read
@@ -1333,7 +1392,7 @@ def serving_params(params: tfm.Params, cfg) -> tfm.Params:
     (48 MB a layer at Mistral's widths, 70 MB at Brumby's).  Other blocks
     and quantized leaves are returned as they are; a leaf sharded by name
     keeps its spec, turned with it."""
-    if cfg.block.attention not in ("gqa", "retention"):
+    if not _parts(cfg)[0].turned:
         return params
 
     def turn(a):
@@ -1372,12 +1431,46 @@ def _results(tokens, k_pages, v_pages, state, stats, cfg):
     """What a serving executable returns: the dense block's three, and for
     any other block also its state and its routing (:func:`_routing`;
     either may be None), which a dense model has none of.  A retention
-    block has no pages to return: its tokens and its state."""
+    block has no pages to return: its tokens and its state.  This module
+    alone knows the convention: :func:`dispatch_args` and
+    :func:`dispatch_results` are the scheduler's side of it."""
     if cfg.block.stateless:
         return tokens, k_pages, v_pages
-    if cfg.block.attention == "retention":
+    if not holds_pages(cfg):
         return tokens, state
     return tokens, k_pages, v_pages, state, stats
+
+
+def dispatch_args(pool, state, slot=None, start=None):
+    """What a serving executable on ``pool`` is handed after the config,
+    of the slot state held and a prefill's ``slot`` and ``start``: ``(args,
+    kwargs)``.  The dense block takes none; a donated state goes as
+    ``retention``, with a retention block's ``slot`` and ``start`` by name;
+    any other block takes its state (None if donated) and ``slot`` in
+    place."""
+    cfg = pool.cfg
+    if cfg.block.stateless:
+        return (), {}
+    at = () if slot is None else (np.array([slot], np.int32),)
+    if not holds_pages(cfg):
+        return (), dict(retention=state, **(
+            {"slot": at[0], "start": start} if at else {}
+        ))
+    if pool.donates_state:
+        return (None, *at), {"retention": state}
+    return (state, *at), {}
+
+
+def dispatch_results(cfg, out):
+    """What a serving executable returned, whatever the block: ``(tokens,
+    (k_pages, v_pages), state, stats)``, None where it returns no such
+    thing."""
+    if cfg.block.stateless:
+        out = (*out, None, None)
+    elif not holds_pages(cfg):
+        out = (out[0], None, None, out[1], None)
+    tokens, kp, vp, state, stats = out
+    return tokens, (kp, vp), state, stats
 
 
 @functools.partial(
@@ -1388,16 +1481,13 @@ def paged_decode_step(params, toks, tables, indices, k_pages, v_pages, cfg,
     """One greedy decode step for the whole slot batch: toks [B] ->
     next tokens [B].  Fixed [max_slots] shapes — the ONE executable the
     scheduler reuses for every step of every request population (idle
-    slots decode garbage into the trash page that nobody reads).
-    Returns ``(next, k_pages', v_pages')``; a block that is not the dense
-    one (``cfg.block``) also takes the pool's convolution ``state`` and
-    returns ``(..., state', stats)`` (:func:`_results`).  The pools are
-    donated: the call consumes them and returns them updated in place.  A
-    ``retention`` block takes no pools (None) but its state ``retention``
-    ``(S, z)``, donated, and ``tables`` [B, 1] that say which rows hold a
-    sequence (> 0): ``(next, (S', z'))``.  A block with a mixer takes the
-    pools and its state as ``retention``, donated, and returns it where
-    a ``cca`` block returns its ``state'``."""
+    slots decode garbage into the trash page that nobody reads).  The
+    pools, and a state passed as ``retention``, are donated: the call
+    consumes them and returns them updated in place.  What a block takes
+    beside the pools is :func:`dispatch_args`'s, what it returns
+    :func:`_results`'s: ``(next, k_pages', v_pages')`` for the dense
+    block; a ``retention`` block takes no pools (None), and ``tables`` [B,
+    1] that say which rows hold a sequence (> 0)."""
     logits, k_pages, v_pages, state, stats = _step_forward(
         params, toks[:, None], tables, indices, k_pages, v_pages, cfg,
         state if retention is None else retention,
@@ -1433,15 +1523,13 @@ def paged_prefill(params, toks, table, last_pos, k_pages, v_pages, cfg,
     from the page pools and the table row nothing here scales with the
     slot count or the capacity.  Returns the first greedy token [1] —
     argmax over the logits at the prompt's frontier, exactly what the
-    contiguous ``generate`` samples from ``logits[:, -1]`` — and the
-    pools; a block that is not the dense one also takes the pool's
-    convolution ``state`` and the sequence's ``slot`` [1] and returns
-    ``(..., state', stats)`` as :func:`paged_decode_step` does, the pools
-    donated as there.  One executable per prompt bucket (the ladder
-    bounds the grid).  A ``retention`` block takes no pools and no table
-    (None) but its state ``retention``, donated, the ``slot`` and the
+    contiguous ``generate`` samples from ``logits[:, -1]`` — and what
+    else the block returns, as :func:`paged_decode_step` does; a block
+    that is not the dense one also takes the sequence's ``slot`` [1].  One
+    executable per prompt bucket (the ladder bounds the grid).  A
+    ``retention`` block takes no pools and no table (None), and the
     position ``start`` [1] the chunk continues its sequence from (0: it
-    starts one), and returns ``(tok, (S', z'))``."""
+    starts one)."""
     logits, k_pages, v_pages, state, stats = _prefill_forward(
         params, toks, table, last_pos, k_pages, v_pages, cfg,
         state if retention is None else retention, slot, start,

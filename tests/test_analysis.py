@@ -704,6 +704,38 @@ def test_lint_flags_undeclared_counter(tmp_path):
     assert "counter-decl" in out and "unheard_of" in out
 
 
+def test_lint_flags_a_block_kind_read_in_the_bridge(tmp_path):
+    root = _mini_repo(tmp_path, "")
+    bridge = root / "tensorframes_tpu" / "bridge"
+    bridge.mkdir()
+    (bridge / "sched.py").write_text(
+        "def ring(cfg, pool):\n"
+        "    return pool.ring if cfg.block.window else 0\n"
+        "def kernel(self):\n"
+        "    return self.cfg.block.mixer is not None\n"
+    )
+    rc, out = _lint(root)
+    assert rc == 1
+    assert out.count("[block-kind]") == 2
+    assert ".block.window" in out and ".block.mixer" in out
+
+
+def test_lint_accepts_the_kind_asked_of_kv_pager(tmp_path):
+    root = _mini_repo(tmp_path, "")
+    bridge = root / "tensorframes_tpu" / "bridge"
+    bridge.mkdir()
+    (bridge / "sched.py").write_text(
+        "def ring(cfg, pool, kv_pager):\n"
+        "    return pool.ring, pool.window, kv_pager.holds_pages(cfg)\n"
+    )
+    # the models themselves read the kind: the rule is the bridge's
+    (root / "tensorframes_tpu" / "kinds.py").write_text(
+        "def kind(cfg):\n    return cfg.block.attention\n"
+    )
+    rc, out = _lint(root)
+    assert rc == 0, out
+
+
 def test_lint_flags_uncheckpointed_block_loop(tmp_path):
     root = _mini_repo(tmp_path, "")
     ops = root / "tensorframes_tpu" / "ops"

@@ -291,7 +291,7 @@ def test_paged_kernel_refuses_the_latent_block():
     sched = DecodeScheduler(ref.make_weights(1, M, jnp.float32), CFG, max_slots=2,
                             tokens_per_page=PAGE, max_seq=CAP)
     try:
-        assert sched._kernel_step == 0
+        assert sched._step_counts["decode_kernel_steps"] == 0
     finally:
         sched.close()
 
@@ -300,7 +300,7 @@ def test_page_pool_accounts_for_the_one_pool():
     """``page_bytes`` and ``stats()`` come from the pool's own shapes: one
     pool of rows, not two of ``kvh x head_dim``."""
     pool = kv_pager.PagePool(CFG, 9, tokens_per_page=PAGE)
-    assert pool.v_pages is None and pool.conv_state is None
+    assert pool.v_pages is None and pool.state is None
     assert pool.k_pages.shape == (LAYERS, 1, 9, PAGE, mla.row_width(CFG))
     assert pool.page_bytes == pool.stats()["page_bytes"] == LAYERS * PAGE * mla.row_width(CFG) * 4
     pages, none, state = pool.take()
@@ -548,7 +548,7 @@ def test_latent_kernel_serves_the_gather_paths_tokens(fresh_traces, monkeypatch)
                 t.join()
         finally:
             sched.close()
-        return out, obs.counters_delta(before), sched._latent_kernel_step
+        return out, obs.counters_delta(before), sched._step_counts["decode_latent_kernel_steps"]
 
     kernel, counted, flag = serve()
     assert flag == 1 and counted["decode_steps"] > 0

@@ -227,8 +227,8 @@ def test_kernel_writes_the_state_where_it_lies():
 
 def test_pool_holds_no_pages_and_counts_state_bytes():
     pool = kv_pager.PagePool(CFG, SLOTS + 1, slots=SLOTS)
-    assert pool.k_pages is None and pool.v_pages is None and pool.conv_state is None
-    S, z = pool.retention
+    assert pool.k_pages is None and pool.v_pages is None
+    S, z = pool.state
     O, dh, kvh = retention.offsets(16), 16, CFG.n_kv_heads
     assert S.shape == (CFG.n_layers, SLOTS, kvh, O, dh, dh) and z.shape == (CFG.n_layers, SLOTS, kvh, O, dh)
     assert S.dtype == z.dtype == jnp.float32  # whatever the compute dtype
@@ -240,13 +240,13 @@ def test_pool_holds_no_pages_and_counts_state_bytes():
     pool.free(charge)
     pool.free(None)  # a request no slot was given holds nothing
     assert frame_cache.budget_bytes_resident() == before
-    assert pool.take() == (None, None, None)
-    taken = pool.take_retention()
-    assert taken[0] is S and pool.retention is None
+    none, nothing, taken = pool.take()
+    assert none is None and nothing is None
+    assert taken[0] is S and pool.state is None
     with pytest.raises(ValueError, match="slots"):
         kv_pager.PagePool(CFG, SLOTS + 1)
     bf16 = kv_pager.PagePool(transformer_config(M, CAP, jnp.bfloat16), SLOTS + 1, slots=SLOTS)
-    assert bf16.retention[0].dtype == jnp.float32
+    assert bf16.state[0].dtype == jnp.float32
 
 
 def test_state_is_donated_by_both_executables(weights):
@@ -338,8 +338,8 @@ def sched(weights):
 
 
 def test_scheduler_serves_the_reference_tokens_and_counts_them(weights, sched):
-    assert sched.pool.k_pages is None and sched.pool.retention is None and sched._kp is None
-    assert sched.cap == CAP and sched.max_pages == 1 and sched._kernel_step == 0
+    assert sched.pool.k_pages is None and sched.pool.state is None and sched._kp is None
+    assert sched.cap == CAP and sched.max_pages == 1 and sched._step_counts["decode_kernel_steps"] == 0
     c0 = obs.counters()
     prompts = [_tokens(n, 20 + n) for n in (9, 4, 14)]
     outs = [sched.submit(p, 5, timeout_s=120) for p in prompts]
@@ -376,7 +376,7 @@ def test_a_served_state_reads_out_what_the_reference_sums(weights, sched, monkey
     prompt = _tokens(21, 41)
     served = sched.submit(prompt, 5, timeout_s=120)
     slot = sched._free[-1]
-    S, z = (a[:, slot] for a in sched._ret)
+    S, z = (a[:, slot] for a in sched._state)
     fed = np.concatenate([prompt, served[:-1]]).astype(np.int32)
     q, want = ref.read_outs(weights, M, fed, len(fed) - 1)
     got = retention.read_out(q, S, z)
@@ -406,7 +406,7 @@ def test_a_retired_slots_state_does_not_reach_its_next_tenant(weights, sched):
     prompt = _tokens(10, 41)
     first = sched.submit(_tokens(13, 40), 4, timeout_s=120)
     assert len(first) == 4
-    sched._ret = tuple(a + 37.0 for a in sched._ret)
+    sched._state = tuple(a + 37.0 for a in sched._state)
     out = sched.submit(prompt, 5, timeout_s=120)
     assert _gap(weights, prompt, out) < ATOL
 

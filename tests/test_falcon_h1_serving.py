@@ -78,10 +78,10 @@ def _pool(cfg):
     """``(kp, vp, state, table of slot 1)``: every page of slot 1 reserved."""
     max_pages = kv_pager.pages_for(CAP, P)
     pool = kv_pager.PagePool(cfg, SLOTS * max_pages + 1, tokens_per_page=P, slots=SLOTS)
-    kp, vp, _ = pool.take()
+    kp, vp, state = pool.take()
     table = np.zeros((SLOTS, max_pages), np.int32)
     table[1] = np.arange(1, max_pages + 1)
-    return kp, vp, pool.take_retention(), table
+    return kp, vp, state, table
 
 
 def _prefill(weights, cfg, pools, prompt, bucket, slot=1):
@@ -195,7 +195,7 @@ def test_kernel_is_named_and_aliased():
 def test_pool_holds_pages_and_a_state_and_charges_both():
     cfg = _cfg(M)
     pool = kv_pager.PagePool(cfg, 9, tokens_per_page=P, slots=SLOTS)
-    S, tail = pool.retention
+    S, tail = pool.state
     assert S.shape == (2, SLOTS, 4, 8, 16) and S.dtype == jnp.float32
     assert tail.shape == (2, SLOTS, 3, 32 + 2 * 2 * 16) and tail.dtype == jnp.float32
     assert pool.k_pages.shape == (2, 2, 9, P, 16) and pool.v_pages.shape == pool.k_pages.shape
@@ -208,8 +208,8 @@ def test_pool_holds_pages_and_a_state_and_charges_both():
     assert frame_cache._budget.tenant_bytes["h1"] - before == 3 * pool.page_bytes + pool.state_bytes
     pool.free(charge)
     assert frame_cache._budget.tenant_bytes.get("h1", 0) == before
-    taken = pool.take_retention()
-    assert taken[0] is S and pool.retention is None
+    *pages, taken = pool.take()
+    assert taken[0] is S and pool.state is None
 
 
 def test_state_and_pages_are_donated_by_both_executables(weights):
@@ -316,8 +316,8 @@ def test_scheduler_serves_the_reference_tokens_through_the_kernel_and_counts_the
     w, cfg = weights["kernel"], _cfg(M_K)
     sched = DecodeScheduler(w, cfg, max_slots=SLOTS, max_seq=CAP, tokens_per_page=P)
     try:
-        assert sched._kp is not None and sched._ret is not None and not sched._by_slot
-        assert sched._ssm_kernel_step == 1
+        assert sched._kp is not None and sched._state is not None and not sched._by_slot
+        assert sched._step_counts["decode_ssm_kernel_steps"] == 1
         c0 = obs.counters()
         prompts = [_tokens(n, 20 + n) for n in (9, 4, 14)]
         outs = [sched.submit(p, 5, timeout_s=240) for p in prompts]
@@ -325,7 +325,7 @@ def test_scheduler_serves_the_reference_tokens_through_the_kernel_and_counts_the
             assert len(out) == 5 and _gap(w, M_K, p, out) < ATOL
         # the stale-state test: every slot's state poisoned, as a retired
         # sequence would leave it and worse; the next tenant is served as fresh
-        sched._ret = tuple(a + 37.0 for a in sched._ret)
+        sched._state = tuple(a + 37.0 for a in sched._state)
         prompt = _tokens(10, 41)
         out = sched.submit(prompt, 5, timeout_s=240)
         assert _gap(w, M_K, prompt, out) < ATOL

@@ -121,7 +121,7 @@ def _through_kernel(bp, cfg, pages, q_n, q_r, tables, lengths, layer, static_lay
 
 def _gathered(bp, cfg, pages, q_n, q_r, tables, lengths, layer):
     """``mla.attend_absorbed`` over every row's gathered capacity: the
-    gather path of ``kv_pager._latent_attention``, in float32 over the
+    gather path of ``kv_pager._Mla.step``, in float32 over the
     same values (XLA:CPU runs no batched bfloat16 product into float32,
     which the weighted sum of rows is)."""
     B = tables.shape[0]

@@ -678,7 +678,7 @@ def test_scheduler_takes_the_pool_arrays_and_the_executables_donate_them(
     try:
         pool = sched.pool
         assert pool.k_pages is None and pool.v_pages is None
-        assert pool.conv_state is None
+        assert pool.state is None
         assert pool.free_count() == pool.capacity == 2 * (CAP // PAGE)
         first = (sched._kp, sched._vp)
         shape = (CFG.n_layers, CFG.n_kv_heads, pool.n_pages, PAGE, CFG.head_dim)

@@ -162,7 +162,7 @@ def test_scheduler_counts_the_steps_that_read_in_place_and_replies_as_before(
     assert d["decode_steps"] > 0
     turned = kind != "mla"
     assert d["decode_proj_in_place_steps"] == (d["decode_steps"] if turned else 0)
-    assert sched._proj_in_place == int(turned)
+    assert sched._step_counts["decode_proj_in_place_steps"] == int(turned)
     assert "tfs_decode_proj_in_place_steps_total" in obs.metrics_text()
     monkeypatch.setattr(kv_pager, "serving_params", lambda p, c: p)
     plain, d_plain, _ = _serve(cfg, params, prompts)

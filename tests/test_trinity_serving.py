@@ -305,7 +305,7 @@ def test_a_window_layer_holds_its_ring_and_no_more(weights):
 
 def test_page_pool_holds_two_pools_and_charges_both():
     pool = _pool(slots=3)
-    (kf, kw), (vf, vw), state = pool.k_pages, pool.v_pages, pool.conv_state
+    (kf, kw), (vf, vw), state = pool.k_pages, pool.v_pages, pool.state
     assert state is None and kf.shape == vf.shape == (1, 2, 3 * MAX_PAGES + 1, PAGE, 16)
     assert kw.shape == vw.shape == (3, 2, 3 * RING + 1, PAGE, 16)
     per_layer = 2 * 2 * PAGE * 16 * 4
@@ -372,7 +372,8 @@ def test_scheduler_table_rows_carry_the_slots_ring(weights):
     try:
         assert sched.ring == RING and sched._inputs.tables.shape == (2, MAX_PAGES + RING)
         assert sched.pool.window_pages == 2 * RING + 1
-        assert sched._kernel_step == sched._window_kernel_step == 0  # heads of 16
+        kernel = sched._step_counts  # heads of 16
+        assert kernel["decode_kernel_steps"] == kernel["decode_window_kernel_steps"] == 0
         sched.submit(_tokens(6, 1), 3)
     finally:
         sched.close()

@@ -216,7 +216,7 @@ def test_router_and_experts_are_the_references(weights):
 def test_prefill_logits_and_state_come_from_the_last_real_position(weights, prompt_len, bucket):
     prompt = _tokens(prompt_len, 11)
     pool = _pool()
-    poisoned = pool.conv_state + 37.0  # a previous tenant's leftovers
+    poisoned = pool.state + 37.0  # a previous tenant's leftovers
     logits, (kp, vp, st), stats = _prefill(
         weights, (pool.k_pages, pool.v_pages, poisoned), prompt, bucket, _table(1, prompt_len + 4), 1)
     want = np.asarray(ref.logits(weights, M, prompt))[-1]
@@ -243,7 +243,7 @@ def test_decode_steps_match_reference_with_two_slots_at_different_positions(weig
     seqs = {0: _tokens(5 + 6, 21), 2: _tokens(11 + 6, 22)}
     lens = {0: 5, 2: 11}
     pool = _pool()
-    state = (pool.k_pages, pool.v_pages, pool.conv_state)
+    state = (pool.k_pages, pool.v_pages, pool.state)
     tables = np.zeros((SLOTS, MAX_PAGES), np.int32)
     for slot, first in ((0, 1), (2, 9)):
         tables[slot] = _table(first, len(seqs[slot]))
@@ -359,7 +359,7 @@ def test_moe_counters_ride_the_dispatch_and_a_dense_model_bumps_none(weights):
     sched = DecodeScheduler(tfm.init(jax.random.PRNGKey(0), dense), dense, max_slots=2,
                             tokens_per_page=PAGE, max_seq=CAP)
     try:
-        assert sched._state is None and sched.pool.conv_state is None
+        assert sched._state is None and sched.pool.state is None
         sched.submit(_tokens(6, 41) % 61, 5, timeout_s=120)
     finally:
         sched.close()
@@ -373,7 +373,7 @@ def test_scheduler_takes_the_convolution_state_with_the_pools(weights):
     sched = DecodeScheduler(weights, CFG, max_slots=2, tokens_per_page=PAGE, max_seq=CAP)
     try:
         pool = sched.pool
-        assert pool.k_pages is None and pool.v_pages is None and pool.conv_state is None
+        assert pool.k_pages is None and pool.v_pages is None and pool.state is None
         first = (sched._kp, sched._vp, sched._state)
         assert first[2].shape == (M["num_hidden_layers"], 2, cca.state_width(CFG))
         assert float(jnp.abs(first[2]).max()) == 0.0
@@ -391,6 +391,6 @@ def test_cca_pool_needs_its_slot_count():
     with pytest.raises(ValueError):
         kv_pager.PagePool(CFG, 9, tokens_per_page=PAGE)
     pool = kv_pager.PagePool(CFG, 9, tokens_per_page=PAGE, slots=5)
-    assert pool.conv_state.shape == (M["num_hidden_layers"], 5, cca.state_width(CFG))
+    assert pool.state.shape == (M["num_hidden_layers"], 5, cca.state_width(CFG))
     assert pool.k_pages.shape == (
         M["num_hidden_layers"], M["num_key_value_heads"], 9, PAGE, M["head_dim"])

@@ -32,6 +32,11 @@ Rules (each violation prints ``file:line: [rule] message``):
   f-string, ``%``, ``+``, ``.format``): the always-on span table keeps
   one entry a name for the life of the process, so what varies rides in
   the arguments.
+* **block-kind** — inside ``tensorframes_tpu/bridge/``, no attribute
+  read of a model block's kind (``.block.attention``, ``.block.mixer``,
+  ``.block.window``, ``.block.layer_types``, ``.block.stateless``): what a
+  block keeps and how its executables take it is ``models/kv_pager.py``'s
+  to say, so the decode scheduler serves a new kind unchanged.
 * **checkpoint-coverage** — in ``ops/block_loop.py`` (the engine's one
   block loop), ``ops/engine.py`` (its chunk loops) and
   ``ops/pipeline.py``, every block-dispatch loop (a ``for``/``while``
@@ -349,6 +354,28 @@ def check_span_names(root: str) -> List[Violation]:
     return out
 
 
+# what a model block says of its kind; the serving layer asks kv_pager
+BLOCK_KIND_FIELDS = {"attention", "mixer", "window", "layer_types", "stateless"}
+
+
+def check_block_kind(root: str) -> List[Violation]:
+    out: List[Violation] = []
+    for path in _iter_py(root, os.path.join(PKG, "bridge")):
+        for node in ast.walk(ast.parse(open(path).read())):
+            if (
+                isinstance(node, ast.Attribute)
+                and node.attr in BLOCK_KIND_FIELDS
+                and isinstance(node.value, ast.Attribute)
+                and node.value.attr == "block"
+            ):
+                out.append(Violation(
+                    _rel(root, path), node.lineno, "block-kind",
+                    f"the bridge reads .block.{node.attr}; a block's kind is "
+                    f"models/kv_pager.py's to say (its pool and functions)",
+                ))
+    return out
+
+
 def _walk_own_body(loop: ast.AST):
     """Yield the loop's nodes EXCLUDING nested For/While subtrees —
     nested loops are each checked on their own, so an inner loop's
@@ -421,6 +448,7 @@ def run(root: str) -> List[Violation]:
         check_knobs,
         check_counters,
         check_span_names,
+        check_block_kind,
         check_checkpoints,
     )
     out: List[Violation] = []
